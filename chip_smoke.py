@@ -1,0 +1,494 @@
+#!/usr/bin/env python
+"""Chip smoke: the quickest proof that the system still starts on the chip.
+
+One process drives the main paths once, through the entry points a user
+script calls (``fluid.Executor().run``, ``freeze_program`` →
+``post_training_quantize`` → ``InferenceServer``), at the full width of the
+models, with seeded random weights and inputs. It is a smoke run, not a
+benchmark: the seconds it prints are mostly compiles.
+
+    python chip_smoke.py             # one TPU chip: train BERT-base seq-2048,
+                                     # train ResNet-50, serve ResNet-50 INT8
+    python chip_smoke.py --chips 4   # four chips: ONLY the mesh path — BERT
+                                     # on one chip vs dp=4 vs dp=2 x tp=2
+    python chip_smoke.py --tiny      # CPU rehearsal of the control flow at
+                                     # toy sizes; its last line says "cpu"
+
+Every phase prints one JSON line (seconds, of which compile, the device's
+peak bytes so far, what was compared). A phase that fails raises, so the
+exit code is non-zero and the last line is never printed. The LAST line is
+
+    {"ok": true, "device": {"platform": ..., "kind": ..., "count": N}}
+
+with the device exactly as JAX reports it. Without an accelerator (and
+without ``--tiny``) the script exits non-zero and prints no result.
+"""
+
+import argparse
+import gc
+import glob
+import json
+import os
+import re
+import sys
+import time
+
+import jax
+import numpy as np
+
+import paddle_tpu.fluid as fluid
+from paddle_tpu import models, native
+from paddle_tpu.platform import use_compilation_cache
+
+FULL = {
+    "bert": dict(seq_len=2048, vocab_size=30522, d_model=768, n_layers=12,
+                 n_heads=12, d_inner=3072),
+    "bert_batch": 4,
+    "mesh_batch": 8,
+    "resnet": dict(dataset="imagenet", depth=50, class_num=1000),
+    "image": (3, 224, 224),
+    "resnet_batch": 128,
+}
+TINY = {
+    "bert": dict(seq_len=128, vocab_size=512, d_model=64, n_layers=2,
+                 n_heads=2, d_inner=128),
+    "bert_batch": 2,
+    "mesh_batch": 8,
+    "resnet": dict(dataset="cifar10", depth=20, class_num=10),
+    "image": (3, 32, 32),
+    "resnet_batch": 4,
+}
+STEPS = 3
+SERVE_BUCKETS = (1, 2, 4, 8)
+SERVE_ROWS = (1, 3, 2, 8, 5, 1, 4)  # one request each, mixed row counts
+# bf16 matmuls under fp32 accumulation: what two correct computations of
+# the same loss may differ by
+BF16_RTOL = 2e-2
+
+
+def _scalar(v):
+    return float(np.asarray(v).reshape(-1)[0])
+
+
+class _Run:
+    """What every phase needs: the sizes, the devices as JAX reports them,
+    and a clock of the seconds JAX spends in the XLA backend compile (or
+    in the persistent-cache read that replaces it) of every executable.
+    Tracing and lowering are not in it: their events nest and would count
+    twice."""
+
+    def __init__(self, size):
+        self.size = size
+        self.devices = jax.devices()
+        self.on_tpu = self.devices[0].platform == "tpu"
+        self.compile_seconds = 0.0
+        jax.monitoring.register_event_duration_secs_listener(self._listen)
+
+    def _listen(self, event, seconds, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.compile_seconds += seconds
+
+    def start(self):
+        return time.perf_counter(), self.compile_seconds
+
+    def report(self, phase, start, step_seconds, **what):
+        """One line per phase: wall seconds, of which XLA compile, and the
+        device's peak bytes — high-water marks of the whole process, live
+        buffers (``in_use``) apart from what executables reserve for
+        their temporaries (``reserved``)."""
+        stats = self.devices[0].memory_stats() or {}
+        print(json.dumps(dict(
+            phase=phase, seconds=round(time.perf_counter() - start[0], 1),
+            compile_seconds=round(self.compile_seconds - start[1], 1),
+            step_seconds=[round(s, 3) for s in step_seconds],
+            peak_bytes_in_use=stats.get("peak_bytes_in_use"),
+            peak_bytes_reserved=stats.get("peak_bytes_reserved"), **what)),
+            flush=True)
+
+
+def _force_flash(program, on):
+    """Pin every attention op of ``program`` to the Pallas kernels (in
+    interpret mode off the TPU) or to the XLA composition. The programs of
+    the main path are never pinned on the chip: there the dispatch must
+    pick the kernels itself."""
+    for op in program.desc.global_block().ops:
+        if op.type.startswith("fused_attention"):
+            op.attrs["force_flash"] = on
+    return program
+
+
+def _step_hlo(exe, program, feed, scope):
+    """Optimized HLO of the executable the last ``exe.run`` used, compiled
+    again from the engine's own jitted step (a persistent-cache hit)."""
+    eng = exe.engine
+    compiled = next(reversed(eng._cache.values()))
+    _, feed_values = eng._coerce_feed(program.desc.block(0), feed)
+    mutated = [eng._state_value(scope, n) for n in compiled.mutated_names]
+    readonly = [eng._state_value(scope, n) for n in compiled.readonly_names]
+    return compiled.jitted.lower(
+        feed_values, mutated, readonly,
+        (np.uint32(0), np.uint32(1))).compile().as_text()
+
+
+def _train(program, startup, feed, fetch, steps, mesh_kwargs=None,
+           changes=None):
+    """Run ``steps`` optimizer steps of ``program`` from a fresh scope and
+    executor. Every step ends in a real host read, and what it read must
+    be finite; with ``changes`` (the Program that owns the parameters),
+    its first, middle and last parameter must have moved and stayed
+    finite. Returns per-step fetches, per-step wall seconds, the executor
+    and the scope."""
+    exe = fluid.Executor()
+    scope = fluid.Scope()
+    names = [p.name for p in changes.all_parameters()] if changes else []
+    names = names and [names[0], names[len(names) // 2], names[-1]]
+    fetched, seconds = [], []
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        before = {n: np.array(scope.get(n)) for n in names}
+        for _ in range(steps):
+            t = time.perf_counter()
+            out = exe.run(program, feed=feed, fetch_list=fetch,
+                          **(mesh_kwargs or {}))
+            fetched.append([np.asarray(v) for v in out])
+            seconds.append(time.perf_counter() - t)
+            assert all(np.isfinite(v).all() for v in fetched[-1]), fetch
+    for name, old in before.items():
+        new = np.asarray(scope.get(name))
+        assert np.isfinite(new).all(), name
+        assert not np.array_equal(new, old), "%s did not change" % name
+    return fetched, seconds, exe, scope
+
+
+def _rel_err(a, b):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return float(np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-30))
+
+
+# -- phase 1: long-context training ------------------------------------------
+
+def phase_train_bert(run):
+    start = run.start()
+    on_tpu = run.on_tpu
+    cfg, batch = run.size["bert"], run.size["bert_batch"]
+    feed = models.bert.make_fake_batch(
+        batch, cfg["seq_len"], cfg["vocab_size"], cfg["n_heads"],
+        rng=np.random.RandomState(0))
+
+    def build(dropout, n_layers):
+        main, startup, h = models.bert.get_model(
+            batch_size=batch, dropout=dropout, lr=1e-4,
+            max_position=cfg["seq_len"],
+            **dict(cfg, n_layers=n_layers))
+        fluid.contrib.mixed_precision.enable_bf16(main)
+        return main, startup, h
+
+    # the step itself: dropout on, the dispatch left alone on the chip
+    main, startup, h = build(0.1, cfg["n_layers"])
+    if not on_tpu:
+        _force_flash(main, True)
+    fetched, seconds, exe, scope = _train(
+        main, startup, feed, [h["loss"]], STEPS, changes=main)
+    losses = [_scalar(f[0]) for f in fetched]
+    # forward numbers: the same program in test mode (dropout off) on the
+    # parameters those steps left, kernels against the XLA composition
+    eval_off = _force_flash(main.clone(for_test=True), False)
+    with fluid.scope_guard(scope):
+        hlo = _step_hlo(exe, main, feed, scope)
+        (l_on,) = exe.run(main.clone(for_test=True), feed=feed,
+                          fetch_list=[h["loss"]])
+        (l_off,) = fluid.Executor().run(eval_off, feed=feed,
+                                        fetch_list=[h["loss"]])
+    kernels = hlo.count("tpu_custom_call")
+    if on_tpu:
+        # forward, dQ and dK/dV per layer; fewer means a kernel gave way
+        # to the XLA composition
+        assert kernels >= 3 * cfg["n_layers"], kernels
+    l_on, l_off = _scalar(l_on), _scalar(l_off)
+    np.testing.assert_allclose(l_on, l_off, rtol=BF16_RTOL)
+    del exe, scope
+    gc.collect()
+
+    # backward numbers, through the same entry point: one step of the
+    # dropout-0 program at depth 2 (the XLA composition's O(T^2) residuals
+    # do not fit at full depth), fetching the gradients of the first
+    # layer's Q/K/V weights, which sit below every attention backward.
+    # Three ways: the kernels, the XLA composition, and the composition at
+    # highest matmul precision (attention's internals in f32), which is
+    # what the other two are held to.
+    grads = {}
+    for how in ("kernels", "xla", "xla_f32"):
+        m2, s2, h2 = build(0.0, 2)
+        if how != "kernels" or not on_tpu:
+            _force_flash(m2, how == "kernels")
+        qkv = [p.name for p in m2.all_parameters()
+               if p.name.startswith("fc_") and ".w" in p.name][:3]
+        fetch = [h2["loss"]] + [n + "@GRAD" for n in qkv]
+        with jax.default_matmul_precision(
+                "highest" if how == "xla_f32" else "default"):
+            grads[how] = _train(m2, s2, feed, fetch, steps=1)[0][0]
+    truth = grads["xla_f32"]
+    err = {how: [_rel_err(a, b) for a, b in zip(grads[how][1:], truth[1:])]
+           for how in ("kernels", "xla")}
+    for how in ("kernels", "xla"):
+        np.testing.assert_allclose(_scalar(grads[how][0]), _scalar(truth[0]),
+                                   rtol=BF16_RTOL)
+    # dWv is well conditioned: bf16 tolerance. dWq/dWk are a small
+    # difference of large terms at initialisation (their norm is ~1% of
+    # dWv's), which the kernels' delta = rowsum(dO * O), taken from the
+    # bf16-rounded O, resolves less finely than the composition does (see
+    # PERF.md): held to a bound that a wrong mask, scale or sign breaks,
+    # with the composition's own error printed beside it.
+    assert err["kernels"][2] < BF16_RTOL, err
+    assert max(err["kernels"][:2]) < 0.5, err
+    direct = _kernels_against_f32(on_tpu, batch, cfg)
+    assert max(direct) < BF16_RTOL, direct
+    run.report("train_bert", start, seconds, model=cfg, batch=batch,
+               losses=losses, tpu_custom_calls_in_step_hlo=kernels,
+               eval_loss_kernels=l_on, eval_loss_xla_composition=l_off,
+               depth2_qkv_weight_grad_rel_err_vs_f32_attention=err,
+               kernel_out_dq_dk_dv_rel_err_vs_f32=direct)
+
+
+def _kernels_against_f32(on_tpu, batch, cfg):
+    """The three kernels alone at the step's attention shape, on seeded
+    normal inputs: output and dQ/dK/dV against plain f32 attention."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels.flash_attention import (flash_attention,
+                                                    pick_block)
+    from paddle_tpu.parallel.ring_attention import reference_attention
+
+    T = cfg["seq_len"]
+    shape = (batch, cfg["n_heads"], T, cfg["d_model"] // cfg["n_heads"])
+    rng = np.random.RandomState(0)
+    q, k, v, g = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+                  for _ in range(4))
+    blk = pick_block(T, q.dtype)
+
+    def kernels(q_, k_, v_):
+        return flash_attention(q_, k_, v_, block_q=blk, block_k=blk,
+                               interpret=not on_tpu)
+
+    out, vjp = jax.vjp(jax.jit(kernels), q, k, v)
+    got = (out,) + vjp(g)
+    with jax.default_matmul_precision("highest"):
+        f32 = [x.astype(jnp.float32) for x in (q, k, v, g)]
+        out, vjp = jax.vjp(jax.jit(reference_attention), *f32[:3])
+        want = (out,) + vjp(f32[3])
+    return [_rel_err(a, b) for a, b in zip(got, want)]
+
+
+# -- phase 2: the headline model ---------------------------------------------
+
+def _image_feed(size, rows, rng, classes=None):
+    feed = {"img": rng.randn(rows, *size["image"]).astype(np.float32)}
+    if classes:
+        feed["label"] = rng.randint(0, classes, (rows, 1)).astype(np.int64)
+    return feed
+
+
+def phase_train_resnet(run):
+    start = run.start()
+    size = run.size
+    cfg, batch = size["resnet"], size["resnet_batch"]
+    main, startup, h = models.resnet.get_model(lr=0.01, **cfg)
+    fluid.contrib.mixed_precision.enable_bf16(main)
+    feed = _image_feed(size, batch, np.random.RandomState(0),
+                       cfg["class_num"])
+    fetched, seconds, _, _ = _train(main, startup, feed, [h["loss"]], STEPS,
+                                    changes=main)
+    losses = [_scalar(f[0]) for f in fetched]
+    run.report("train_resnet", start, seconds, model=cfg, batch=batch,
+               losses=losses)
+
+
+# -- phase 3: INT8 serving, the fork's claim ----------------------------------
+
+_S8_CONV = re.compile(r"= s32\[[0-9,]*\]\S* convolution\(")
+
+
+def phase_serve_int8(run):
+    from paddle_tpu.inference import (InferenceServer, freeze_program,
+                                      post_training_quantize)
+
+    start = run.start()
+    size = run.size
+    cfg = size["resnet"]
+    rng = np.random.RandomState(0)
+    main, startup, h = models.resnet.get_model(**cfg)
+    exe = fluid.Executor()
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+    feed_names, fetch_names = ["img"], [h["logits"].name]
+    frozen, _ = freeze_program(main, feed_names, fetch_names, scope=scope)
+    edge = SERVE_BUCKETS[-1]
+    int8_prog, _, qrep = post_training_quantize(
+        frozen, [_image_feed(size, edge, rng) for _ in range(2)],
+        feed_names, fetch_names, scope=scope, executor=exe, max_batches=2)
+    requests = [_image_feed(size, n, rng) for n in SERVE_ROWS]
+
+    server = InferenceServer(int8_prog, feed_names, fetch_names, scope=scope,
+                             buckets=SERVE_BUCKETS, name="chip_smoke")
+    with server:
+        t = time.perf_counter()
+        server.warmup(requests[0])
+        warmup_s = time.perf_counter() - t
+        # warm-up compiles the buckets in order: the last is the widest
+        hlo = _step_hlo(server._exe, int8_prog,
+                        _image_feed(size, edge, rng), scope)
+        t = time.perf_counter()
+        futures = [server.submit(r) for r in requests]
+        served = [f.result(timeout=600)[0] for f in futures]
+        serve_s = time.perf_counter() - t
+    int8 = np.concatenate(served, axis=0)
+    assert int8.shape == (sum(SERVE_ROWS), cfg["class_num"]), int8.shape
+    assert np.isfinite(int8).all()
+
+    # the fp32 frozen program on the same rows, in batches of the top edge
+    rows = np.concatenate([r["img"] for r in requests], axis=0)
+    fp32 = []
+    with fluid.scope_guard(scope):
+        for i in range(0, len(rows), edge):
+            chunk = rows[i:i + edge]
+            pad = np.zeros((edge - len(chunk),) + chunk.shape[1:],
+                           chunk.dtype)
+            (out,) = exe.run(frozen,
+                             feed={"img": np.concatenate([chunk, pad])},
+                             fetch_list=fetch_names)
+            fp32.append(np.asarray(out)[:len(chunk)])
+    fp32 = np.concatenate(fp32, axis=0)
+    # top-1 within the point tests/test_int8_accuracy.py allows the
+    # freeze -> quantize path. With seeded random weights the logits are
+    # dominated by an input-independent component, so top-1 alone would
+    # pass a broken contraction: the logits themselves must agree too.
+    top1_agree = float((fp32.argmax(1) == int8.argmax(1)).mean())
+    assert top1_agree >= 0.99, top1_agree
+    logits_err = _rel_err(int8, fp32)
+    assert logits_err < 0.1, logits_err
+    s8_convs = len(_S8_CONV.findall(hlo))
+    if run.on_tpu:
+        # the native branch of ops/quant_ops.py, not the fp32 emulation
+        assert s8_convs > 0, "no s8 x s8 -> s32 convolution in served HLO"
+    run.report("serve_int8", start, [warmup_s, serve_s], model=cfg,
+               buckets=SERVE_BUCKETS, request_rows=SERVE_ROWS,
+               quantized_ops=len(qrep.quantized),
+               s32_convs_in_served_hlo=s8_convs,
+               top1_agreement_with_fp32=top1_agree,
+               logits_rel_err_vs_fp32=logits_err)
+
+
+# -- four chips: the mesh path and what it is compared with -------------------
+
+def _param_bytes_per_device(program, scope, devices):
+    held = {d.id: 0 for d in devices}
+    for p in program.all_parameters():
+        for shard in scope.get(p.name).addressable_shards:
+            held[shard.device.id] += shard.data.nbytes
+    return [held[d.id] for d in devices]
+
+
+def phase_mesh_bert(run):
+    """BERT, global batch 8, dropout 0, on one chip, on dp=4 through
+    ``Executor.run(mesh=...)`` and on dp=2 x tp=2 through
+    ``CompiledProgram.with_spmd`` with the Megatron rule table. Each run
+    initialises on device 0 (``exe.run(startup)``) and is resharded onto
+    the mesh at its first step."""
+    from __graft_entry__ import build_bert_spmd
+    from paddle_tpu.analysis.spmd import measured_collectives
+    from paddle_tpu.parallel import ShardingRules, make_mesh
+
+    size, devices, on_tpu = run.size, run.devices, run.on_tpu
+    spmd, main, startup, h, feed = build_bert_spmd(
+        4, batch_size=size["mesh_batch"], **size["bert"])
+    fluid.contrib.mixed_precision.enable_bf16(main)
+    if not on_tpu:
+        _force_flash(main, True)
+    runs = (
+        ("one_chip", main, None),
+        ("dp4", main, dict(mesh=make_mesh({"dp": 4}),
+                           shard_rules=ShardingRules())),
+        ("dp2_tp2", spmd, None),
+    )
+    losses, param_bytes = {}, {}
+    for name, program, mesh_kwargs in runs:
+        start = run.start()
+        fetched, seconds, exe, scope = _train(
+            program, startup, feed, [h["loss"]], STEPS, mesh_kwargs)
+        losses[name] = [_scalar(f[0]) for f in fetched]
+        with fluid.scope_guard(scope):
+            hlo = _step_hlo(exe, main, feed, scope)
+        kernels = hlo.count("tpu_custom_call")
+        colls = measured_collectives(hlo)
+        in_use = [(d.memory_stats() or {}).get("bytes_in_use")
+                  for d in devices]
+        param_bytes[name] = _param_bytes_per_device(main, scope, devices)
+        if on_tpu:
+            assert kernels > 0, "no Pallas kernel in the %s step" % name
+        if name != "one_chip":
+            np.testing.assert_allclose(losses[name], losses["one_chip"],
+                                       rtol=BF16_RTOL)
+            assert colls["by_kind"].get("all-reduce", {}).get("count"), colls
+            assert all(param_bytes[name]), param_bytes[name]
+            if on_tpu:
+                assert all(in_use), in_use
+        run.report("mesh_bert." + name, start, seconds,
+                   model=size["bert"], batch=size["mesh_batch"],
+                   losses=losses[name], tpu_custom_calls_in_step_hlo=kernels,
+                   collectives=colls["by_kind"], bytes_in_use=in_use,
+                   param_bytes_per_device=param_bytes[name])
+        del exe, scope
+        gc.collect()
+    # tensor parallelism shards the weights: less on each device than the
+    # whole model one chip holds
+    assert max(param_bytes["dp2_tp2"]) < param_bytes["one_chip"][0], \
+        param_bytes
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: run only the mesh phase, on four chips")
+    ap.add_argument("--tiny", action="store_true",
+                    help="CPU rehearsal at toy sizes (Pallas kernels in "
+                         "interpret mode); the last line then says cpu")
+    args = ap.parse_args(argv)
+
+    run = _Run(TINY if args.tiny else FULL)
+    dev = run.devices[0]
+    if not run.on_tpu and not args.tiny:
+        print("chip_smoke: JAX found no TPU (platform %r); use --tiny for "
+              "the CPU rehearsal" % dev.platform, file=sys.stderr)
+        return 1
+    if len(run.devices) != args.chips:
+        print("chip_smoke: --chips %d but JAX reports %d device(s)"
+              % (args.chips, len(run.devices)), file=sys.stderr)
+        return 1
+
+    had_so = glob.glob(os.path.join(os.path.dirname(native.__file__),
+                                    "*.so"))
+    lib = native.lib()
+    print(json.dumps(dict(
+        compile_cache=use_compilation_cache(),
+        native_library=dict(loaded=lib is not None,
+                            built_now=lib is not None and not had_so,
+                            file=lib and os.path.basename(lib._name)))),
+        flush=True)
+
+    if args.chips == 4:
+        phase_mesh_bert(run)
+    else:
+        phase_train_bert(run)
+        gc.collect()
+        phase_train_resnet(run)
+        gc.collect()
+        phase_serve_int8(run)
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(run.devices)}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1175,11 +1175,21 @@ _DTYPE_BYTES = {
 _COLLECTIVE_RE = re.compile(
     r"%(?P<name>(?:all-reduce|all-gather|reduce-scatter|all-to-all|"
     r"collective-permute)(?:-start)?[.\w]*) = "
-    r"(?P<sig>[^=]*?)(?P<kind>all-reduce|all-gather|reduce-scatter|"
+    r"(?P<sig>.*?)(?P<kind>all-reduce|all-gather|reduce-scatter|"
     r"all-to-all|collective-permute)(?:-start)?\("
     r"(?P<operands>[^)]*)\)")
 
 _SHAPE_RE = re.compile(r"(?P<dt>[a-z]+\d*|pred)\[(?P<dims>[0-9,]*)\]")
+
+_COMMENT_RE = re.compile(r"/\*.*?\*/")
+
+# "%name = <result shape(s)> opcode(": the definition an untyped operand
+# reference resolves to (layout suffixes such as {1,0:T(8,128)} never
+# start a lowercase "word(" after a space, so the first such match is
+# the opcode)
+_DEF_RE = re.compile(
+    r"^\s*(?:ROOT )?%(?P<name>[^\s=]+) = (?P<sig>.*?) [a-z][\w-]*\(",
+    re.M)
 
 
 def _shape_bytes(dt, dims):
@@ -1190,21 +1200,40 @@ def _shape_bytes(dt, dims):
     return n * _DTYPE_BYTES.get(dt, 4)
 
 
+def _sig_bytes(sig):
+    return sum(_shape_bytes(m.group("dt"), m.group("dims"))
+               for m in _SHAPE_RE.finditer(sig))
+
+
 def hlo_collectives(text):
     """Parse compiled HLO text into the collective ledger:
     ``[{kind, name, nbytes, n_operands}]`` where ``nbytes`` sums the
     per-device operand payload shapes (HLO shapes ARE shard shapes).
     A combined all-reduce over k tensors counts k logical psums —
     ``n_operands`` carries that multiplicity. ``-done`` halves of async
-    pairs are skipped (the ``-start`` carries the payload)."""
+    pairs are skipped (the ``-start`` carries the payload).
+
+    XLA prints operands as bare ``%name`` references unless asked for
+    operand shapes, so an operand's payload is the result shape of the
+    instruction that defines it; an operand printed with its shape
+    inline uses that."""
+    defs = None
     out = []
     for m in _COLLECTIVE_RE.finditer(text):
         name = m.group("name")
         if "-done" in name:
             continue
-        operands = [mm for mm in _SHAPE_RE.finditer(m.group("operands"))]
-        nbytes = sum(_shape_bytes(mm.group("dt"), mm.group("dims"))
-                     for mm in operands)
+        # long operand lists carry /*index=5*/ position comments
+        operands = [o for o in _COMMENT_RE.sub(
+            "", m.group("operands")).split(", ") if o]
+        nbytes = 0
+        for o in operands:
+            if "[" not in o:
+                if defs is None:
+                    defs = {d.group("name"): d.group("sig")
+                            for d in _DEF_RE.finditer(text)}
+                o = defs.get(o.strip().lstrip("%"), "")
+            nbytes += _sig_bytes(o)
         out.append({
             "kind": m.group("kind"),
             "name": name,

@@ -77,11 +77,9 @@ class Executor:
                       scope=None, accumulate_steps=1, remat_segments=0,
                       opt_level=None):
         """XLA's cost and memory analysis of the compiled step — the
-        roofline workflow as a first-class API (round 5 used it to pin
-        ResNet-50 at 145.5 GB/step against 670 GB/s achieved; see
-        MFU_r05.md). Compiles the same executable ``run`` would (without
-        executing — no state is mutated, no cache entry added) and
-        returns::
+        roofline workflow as a first-class API. Compiles the same
+        executable ``run`` would (without executing — no state is
+        mutated, no cache entry added) and returns::
 
             {"bytes_accessed": float, "flops": float,
              "cost": <full XLA cost dict>,

@@ -38,14 +38,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30
 # Lane width for the logsumexp/delta residuals, carried as rank-3
@@ -57,9 +52,7 @@ _LSE_LANES = 1
 
 
 def _smem_spec():
-    if _HAS_PLTPU:
-        return pl.BlockSpec(memory_space=pltpu.SMEM)
-    return pl.BlockSpec(memory_space=None)  # pragma: no cover
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _keep_mask(seed, b, q_pos, k_pos, t_k, rate):
@@ -198,15 +191,6 @@ def _stream_kvmap(block_q, block_k, causal, offsets):
     return kvmap
 
 
-def _require_pltpu(what):
-    if not _HAS_PLTPU:
-        raise RuntimeError(
-            "flash %s needs pallas-TPU scratch support (pltpu "
-            "unimportable here); use the XLA fallback (forward: the "
-            "plain composition; backward: PADDLE_TPU_FLASH_BWD=xla)"
-            % what)
-
-
 def _offsets_arr(offsets):
     """[q_off, k_off] int32 SMEM scalars — the Q/K global base positions
     (ring-attention shard offsets); [0, 0] for ordinary full attention."""
@@ -233,7 +217,6 @@ def _flash_forward(q, k, v, seq_lens, offsets, seed, causal, scale, rate,
         lens = jnp.full((B * H,), Tk, jnp.int32)
     seed_arr = jnp.asarray(seed, jnp.int32).reshape(1)
 
-    _require_pltpu("forward")
     _kvmap = _stream_kvmap(block_q, block_k, causal, offsets)
     kernel = functools.partial(
         _attn_kernel, block_q=block_q, block_k=block_k, causal=causal,
@@ -445,7 +428,6 @@ def _flash_backward(q, k, v, out, lse, g, g_lse, seq_lens, offsets, seed,
         delta = delta - g_lse.reshape(B * H, Tq).astype(jnp.float32)
     delta = jnp.broadcast_to(delta[..., None], (B * H, Tq, _LSE_LANES))
 
-    _require_pltpu("backward")
     _kvmap_dq = _stream_kvmap(bq_dq, bk_dq, causal, offsets)
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, block_q=bq_dq, block_k=bk_dq,
@@ -837,15 +819,14 @@ def _flash_min_seq():
 
 
 def flash_dispatch_ok(tq, tk):
-    """Whether the Pallas kernels apply to a (Tq, Tk) attention: pallas-TPU
-    importable, real TPU backend, tileable blocks, and at least
+    """Whether the Pallas kernels apply to a (Tq, Tk) attention: real
+    TPU backend, tileable blocks, and at least
     PADDLE_TPU_FLASH_MIN_SEQ keys (the measured crossover — see
     ``fused_attention``). The single dispatch predicate shared by
     ``fused_attention`` and the ring-attention body so the two paths can
     never diverge."""
     tileable = tq % min(128, tq) == 0 and tk % min(128, tk) == 0
-    return (_HAS_PLTPU and _on_tpu() and tileable
-            and tk >= _flash_min_seq())
+    return _on_tpu() and tileable and tk >= _flash_min_seq()
 
 
 # --- SPMD (shard_map) wrapping ---------------------------------------------
@@ -859,21 +840,12 @@ def flash_dispatch_ok(tq, tk):
 # parallel/ring_attention.py's sp-axis ring (which remains the sequence
 # axis story; these wraps leave the sequence dim whole).
 
-try:
-    from jax import shard_map as _shard_map_raw
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map as _shard_map_raw
-
-
 def _shard_map(body, mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across the jax rename
-    (check_vma today, check_rep before)."""
-    try:
-        return _shard_map_raw(body, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs)
-    except TypeError:
-        return _shard_map_raw(body, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+    """shard_map with the varying-manual-axes check off: a pallas_call's
+    out_shape carries no ``vma``, so tracing the kernels under
+    ``check_vma=True`` raises."""
+    return shard_map(body, mesh=mesh, in_specs=in_specs,
+                     out_specs=out_specs, check_vma=False)
 
 
 def _spmd_attention_axes(B, H):

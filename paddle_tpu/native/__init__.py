@@ -6,34 +6,54 @@ components that are C++ in the reference stay C++ here — SURVEY.md §2.11).
 callers fall back to pure-Python implementations in that case.
 """
 
+import contextlib
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
+import tempfile
 import threading
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SOURCES = ["recordio.cc", "blocking_queue.cc", "multislot.cc"]
-_SO_PATH = os.path.join(_DIR, "libpaddle_tpu_native.so")
+_BUILD = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC"]
 
 _lock = threading.Lock()
 _lib = None
 _build_failed = False
 
 
-def _needs_build():
-    if not os.path.exists(_SO_PATH):
-        return True
-    so_mtime = os.path.getmtime(_SO_PATH)
-    return any(
-        os.path.getmtime(os.path.join(_DIR, s)) > so_mtime for s in _SOURCES
-    )
+def _so_path():
+    """The library's file name carries a hash of the sources' content and
+    the build command, so a binary is trusted only if it was built from
+    exactly these files — never by mtime, which a copied tree does not
+    keep. A tree that arrives with another tree's binary rebuilds."""
+    h = hashlib.sha256(" ".join(_BUILD).encode())
+    for s in _SOURCES:
+        with open(os.path.join(_DIR, s), "rb") as f:
+            h.update(f.read())
+    return os.path.join(
+        _DIR, "libpaddle_tpu_native.%s.so" % h.hexdigest()[:16])
 
 
-def _build():
+def _build(so_path):
+    """Compile beside the target and rename into place: several test
+    workers may build at once, and each must load a whole file."""
     srcs = [os.path.join(_DIR, s) for s in _SOURCES]
-    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-o", _SO_PATH,
-           *srcs, "-lpthread"]
-    subprocess.run(cmd, check=True, capture_output=True)
+    fd, tmp = tempfile.mkstemp(dir=_DIR, suffix=".so.tmp")
+    os.close(fd)
+    try:
+        subprocess.run([*_BUILD, "-o", tmp, *srcs, "-lpthread"],
+                       check=True, capture_output=True)
+        os.replace(tmp, so_path)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    for stale in glob.glob(os.path.join(_DIR, "libpaddle_tpu_native*.so")):
+        if stale != so_path:
+            with contextlib.suppress(OSError):  # another worker got there
+                os.unlink(stale)
 
 
 def _bind(lib):
@@ -105,9 +125,10 @@ def lib():
         if _lib is not None or _build_failed:
             return _lib
         try:
-            if _needs_build():
-                _build()
-            _lib = _bind(ctypes.CDLL(_SO_PATH))
+            so_path = _so_path()
+            if not os.path.exists(so_path):
+                _build(so_path)
+            _lib = _bind(ctypes.CDLL(so_path))
         except Exception:
             _build_failed = True
             _lib = None

@@ -6,9 +6,9 @@ lookup_table_op.cc. Lowerings emit lax convolutions (MXU) and keep the
 public NCHW layout contract; XLA's TPU layout assignment picks the physical
 layout, so no data_layout_transform pass is needed (reference:
 paddle/fluid/framework/data_layout_transform.cc becomes a no-op concern).
-Verified on hardware in round 4: an end-to-end NHWC ResNet-50 formulation
-times within +0.3% of this NCHW lowering (tools/resnet_probe.py
-full-nhwc, MFU_r04.md) — the logical layout is immaterial under XLA:TPU.
+Pre-round record (one v5e, July 2026): an end-to-end NHWC ResNet-50
+formulation timed within +0.3% of this NCHW lowering (tools/resnet_probe.py
+full-nhwc) — the logical layout is immaterial under XLA:TPU.
 """
 
 import numpy as np
@@ -70,7 +70,7 @@ def conv2d_grad(ctx, ins, attrs):
     bilinear, so each gradient is a ``jax.linear_transpose`` of the conv
     with the other operand fixed — this emits ONLY the transposed
     convolution, never a recomputed forward primal for XLA to CSE away
-    (the round-2 per-op jax.vjp residue, MFU.md)."""
+    (the round-2 per-op jax.vjp residue)."""
     x = single(ins, "Input")
     w = single(ins, "Filter")
     g = single(ins, "Output@GRAD")
@@ -404,7 +404,10 @@ def fused_attention_op(ctx, ins, attrs):
     out, lse = dispatch_attention_lse(
         q, k, v, bool(attrs.get("causal", False)),
         attrs.get("scale", None), lens, rate, seed,
-        attrs.get("__force_flash__", None),  # tests: interpret-mode kernel
+        # test hook: True runs the kernels (interpret mode off-TPU),
+        # False the XLA composition. Not a "__" name: the engine strips
+        # those before lowerings (lowering.clean_attrs)
+        attrs.get("force_flash", None),
         raw_lse=True)  # kernel-native layout: zero-relayout backward read
     # the XLA branch's lse binds the program's Lse var too (the direct
     # grad op ignores it there and XLA DCEs it when nothing reads it)
@@ -441,7 +444,7 @@ def fused_attention_grad_op(ctx, ins, attrs):
         dq, dk, dv = vjp(g)
         return {"Q@GRAD": [dq], "K@GRAD": [dk], "V@GRAD": [dv]}
     Tq, Tk = q.shape[2], k.shape[2]
-    force = attrs.get("__force_flash__", None)
+    force = attrs.get("force_flash", None)
     flash_ok = flash_dispatch_ok(Tq, Tk) if force is None else bool(force)
     out = single(ins, "Out") if ins.get("Out") else None
     lse = single(ins, "Lse") if ins.get("Lse") else None

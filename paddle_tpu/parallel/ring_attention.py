@@ -27,13 +27,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # older jax
-    from jax.experimental.shard_map import shard_map
 
 _NEG = -1e30
 
@@ -179,18 +174,6 @@ def ring_attention(q, k, v, mesh=None, axis_name="sp", causal=False,
             scale=scale)
 
     spec = P(batch_axis, None, axis_name, None)
-    try:
-        fn = shard_map(
-            body, mesh=mesh,
-            in_specs=(spec, spec, spec),
-            out_specs=spec,
-            check_vma=False,
-        )
-    except TypeError:  # pre-rename jax spells it check_rep
-        fn = shard_map(
-            body, mesh=mesh,
-            in_specs=(spec, spec, spec),
-            out_specs=spec,
-            check_rep=False,
-        )
+    fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                   out_specs=spec, check_vma=False)
     return fn(q, k, v)

@@ -7,6 +7,8 @@ backend — there is no dynload'd driver stack to manage (PJRT plays the role
 of the reference's platform/dynload layer).
 """
 
+import os
+
 import jax
 
 
@@ -23,8 +25,7 @@ class CPUPlace(Place):
         return "CPUPlace"
 
     def jax_device(self):
-        cpus = [d for d in jax.devices() if d.platform == "cpu"]
-        return cpus[0] if cpus else jax.devices()[0]
+        return jax.devices("cpu")[0]
 
 
 class TPUPlace(Place):
@@ -36,7 +37,11 @@ class TPUPlace(Place):
 
     def jax_device(self):
         devs = jax.devices()
-        return devs[self.device_id % len(devs)]
+        if not 0 <= self.device_id < len(devs):
+            raise ValueError(
+                "%r: this process has %d device(s) (%s)"
+                % (self, len(devs), devs[0].platform))
+        return devs[self.device_id]
 
 
 class CUDAPinnedPlace(CPUPlace):
@@ -66,3 +71,21 @@ def default_accelerator_place():
 def cuda_device_count():
     """Accelerator count (name kept for API compat)."""
     return len([d for d in jax.devices() if d.platform != "cpu"]) or 1
+
+
+def use_compilation_cache():
+    """Point JAX's persistent compilation cache somewhere stable, for
+    entry points that pay large compiles (chip_smoke.py, bench.py) —
+    never at import. Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX has
+    already taken the directory from it and no path is set in code;
+    otherwise the cache is ``<checkout>/.jax_cache``, a fixed path (the
+    path is part of the cache key, so one that moves never hits).
+    Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path

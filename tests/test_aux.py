@@ -205,7 +205,7 @@ class TestProfiler:
     def test_executor_cost_analysis(self):
         """Executor.cost_analysis returns XLA's bytes-accessed/flops and
         memory stats for the compiled step WITHOUT executing it (the
-        roofline workflow of MFU_r05.md as a first-class API)."""
+        roofline workflow as a first-class API)."""
         from paddle_tpu import models
 
         main, startup, h = models.mnist.get_model(lr=0.01)
@@ -457,3 +457,57 @@ def test_dlpack_interop():
     host = np.ones((2, 2), np.float32)
     t2 = torch.utils.dlpack.from_dlpack(dlpack.to_dlpack(host))
     np.testing.assert_array_equal(t2.numpy(), host)
+
+
+def test_place_names_a_device_or_raises():
+    """A Place resolves to the device it names: an out-of-range
+    ``device_id`` raises instead of wrapping round to chip 0, and
+    ``CPUPlace`` is a CPU device, never whatever the default backend
+    has."""
+    import jax
+
+    from paddle_tpu.platform import CPUPlace, TPUPlace
+
+    devs = jax.devices()
+    assert TPUPlace(len(devs) - 1).jax_device() == devs[-1]
+    for bad in (len(devs), -1):
+        with pytest.raises(ValueError, match="device"):
+            TPUPlace(bad).jax_device()
+    assert CPUPlace().jax_device().platform == "cpu"
+
+
+def test_compilation_cache_is_placed_from_outside(monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` wins and no path is set in code;
+    unset, the cache goes to the fixed ``<checkout>/.jax_cache``."""
+    import os
+
+    import jax
+
+    from paddle_tpu.platform import use_compilation_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        assert use_compilation_cache() == "/some/dir"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert use_compilation_cache() == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            repo, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_importing_the_framework_and_launcher_takes_no_device():
+    """One process per chip: the gang supervisor
+    (``python -m paddle_tpu.distributed.launch``) and anything else that
+    only imports the framework must not initialise a JAX backend — a
+    parent that has would hold the chip its workers need."""
+    code = (
+        "import paddle_tpu.fluid, paddle_tpu.distributed.launch, "
+        "paddle_tpu.resilience, paddle_tpu.observability.health\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge.backends_are_initialized()\n")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    subprocess.run([sys.executable, "-c", code], check=True, cwd=repo)

@@ -17,6 +17,33 @@ def test_native_lib_builds():
     assert native_lib() is not None
 
 
+def test_native_lib_is_trusted_by_source_content(tmp_path, monkeypatch):
+    """Staleness is decided from the sources' content, which a copied tree
+    keeps, not from mtimes, which it does not: the library's name carries
+    the hash, an edit to a source names a new library, and building it
+    drops the binaries of other sources."""
+    import os
+    import shutil
+
+    from paddle_tpu import native
+
+    assert native_lib()._name == native._so_path()
+    for src in native._SOURCES:
+        shutil.copy(os.path.join(native._DIR, src), tmp_path / src)
+    foreign = tmp_path / "libpaddle_tpu_native.so"
+    foreign.write_bytes(b"not built from these sources")
+    monkeypatch.setattr(native, "_DIR", str(tmp_path))
+    same = native._so_path()
+    assert os.path.basename(same) == os.path.basename(native_lib()._name)
+    with open(tmp_path / native._SOURCES[0], "a") as f:
+        f.write("// edited\n")
+    edited = native._so_path()
+    assert edited != same and not os.path.exists(edited)
+    native._build(edited)
+    assert os.path.exists(edited) and not foreign.exists()
+    assert [p.name for p in tmp_path.glob("*.tmp")] == []
+
+
 class TestRecordIO:
     def test_roundtrip(self, tmp_path):
         path = str(tmp_path / "data.recordio")

@@ -290,3 +290,21 @@ def test_multi_head_attention_sequence_parallel():
         np.testing.assert_allclose(outs[1], outs[0], rtol=2e-3, atol=2e-3)
     finally:
         set_default_mesh(None)
+
+
+def test_dryrun_multichip_runs_in_process(capsys):
+    """The driver entry point on the conftest's 8 virtual devices, in this
+    process; asked for more devices than exist it raises and says how to
+    get a virtual mesh — it never re-executes itself on another
+    platform."""
+    import json
+
+    import __graft_entry__ as graft
+
+    graft.dryrun_multichip(8)
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert line["dryrun_multichip"]["platform"] == "cpu"
+    assert line["dryrun_multichip"]["bert_mesh"] == {"dp": 4, "tp": 2}
+    assert np.isfinite(line["dryrun_multichip"]["bert_loss"])
+    with pytest.raises(RuntimeError, match="xla_force_host_platform"):
+        graft.dryrun_multichip(len(jax.devices()) + 1)

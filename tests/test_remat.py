@@ -189,7 +189,7 @@ def test_remat_through_flash_attention_kernels():
             fluid.optimizer.SGD(learning_rate=0.5).minimize(loss)
         for op in main.desc.global_block().ops:
             if op.type.startswith("fused_attention"):
-                op.attrs["__force_flash__"] = True   # Pallas, interpret
+                op.attrs["force_flash"] = True   # Pallas, interpret
         return main, startup, loss
 
     rng = np.random.RandomState(0)

@@ -234,6 +234,10 @@ _FAKE_HLO = """
   %all-reduce-done.2 = f32[8]{0} all-reduce-done(%all-reduce-start.2)
   %all-reduce.3 = (f32[4]{0}, s32[2]{0}) all-reduce(f32[4]{0} %a, s32[2]{0} %b), channel_id=3
   %all-gather.4 = f32[16,4]{1,0} all-gather(f32[8,4]{1,0} %p2), channel_id=4
+  %dot.2 = f32[32,16]{1,0} dot(%x, %y), lhs_contracting_dims={1}
+  ROOT %fusion.3 = f32[4,32]{1,0:T(8,128)} fusion(%z), kind=kLoop
+  %all-reduce.5 = (f32[32,16]{1,0}, /*index=1*/f32[4,32]{1,0}) all-reduce(%dot.2, /*index=1*/%fusion.3), channel_id=5
+  %all-gather.6 = f32[64,16]{1,0} all-gather(%dot.2), channel_id=6, dimensions={0}
 """
 
 
@@ -248,10 +252,16 @@ def test_hlo_collectives_parser():
     # combined all-reduce over 2 tensors = 2 logical psums
     assert by_name["all-reduce.3"]["n_operands"] == 2
     assert by_name["all-reduce.3"]["nbytes"] == 4 * 4 + 2 * 4
+    # operands printed as bare names (what compiled.as_text() gives):
+    # multiplicity from the operand list, payload from the operands'
+    # definitions — for an all-gather the shard, not the gathered result
+    assert by_name["all-reduce.5"]["n_operands"] == 2
+    assert by_name["all-reduce.5"]["nbytes"] == (32 * 16 + 4 * 32) * 4
+    assert by_name["all-gather.6"]["nbytes"] == 32 * 16 * 4
     m = measured_collectives(_FAKE_HLO)
-    assert m["psum_count"] == 4  # 1 + 1(async) + 2(combined)
-    assert m["all_gather_count"] == 1
-    assert m["total_bytes"] == 256 + 32 + 24 + 128
+    assert m["psum_count"] == 6  # 1 + 1(async) + 2(combined) + 2(untyped)
+    assert m["all_gather_count"] == 2
+    assert m["total_bytes"] == 256 + 32 + 24 + 128 + 2560 + 2048
 
 
 # ---------------------------------------------------------------------------

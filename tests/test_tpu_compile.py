@@ -1,0 +1,201 @@
+"""Compile the main path's kernels for a TPU that is described, not
+attached: what the chip's compiler refuses (a slice off the tiling, a
+kernel over its VMEM, a custom call under a checked ``shard_map``) fails
+here, on the CPU, at no chip time. Nothing runs, so nothing here says
+anything about results or speed.
+
+The topology is described inside a fixture, never at import: only one
+process may hold the TPU library, every xdist worker imports this file,
+and only the worker that runs it may load the library. All compiles happen
+in this process, and in this one file, for the same reason.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.kernels.flash_attention import (
+    _LSE_LANES, _flash_backward, dispatch_attention_lse,
+    flash_attention_raw_lse, pick_block, pick_bwd_blocks)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps it undescribed
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    # an entry compiled for a described device cannot be read back without
+    # one: keep these compiles out of the persistent cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _attention_args(shape, sharding, masked, lse_sharding=None,
+                    lens_sharding=None):
+    """Shapes of one attention call in the layouts the fused_attention op
+    and its grad op hand the kernels: one [B, H, T, D] activation (q, k,
+    v, out and the cotangent alike), the saved logsumexp, the lengths."""
+    B, H, T, D = shape
+    act = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
+    lse = jax.ShapeDtypeStruct((B * H, T, _LSE_LANES), jnp.float32,
+                               sharding=lse_sharding or sharding)
+    lens = (jax.ShapeDtypeStruct((B,), jnp.int32,
+                                 sharding=lens_sharding or sharding)
+            if masked else None)
+    return act, lse, lens
+
+
+# (shape, causal, masked by seq_lens, dropout rate): the BERT seq-2048 step
+# chip_smoke.py trains, its seq-4096 sibling, a causal (NMT decoder) shape,
+# and the longest context the committed block table lists
+def _longest_seq():
+    from tools.flash_block_sweep import DEFAULT_SEQS
+
+    return max(DEFAULT_SEQS)
+
+
+ATTENTION_CASES = {
+    "bert-seq2048": ((4, 12, 2048, 64), False, True, 0.1),
+    "bert-seq4096": ((8, 12, 4096, 64), False, True, 0.1),
+    "causal-seq2048": ((4, 12, 2048, 64), True, False, 0.0),
+    "longest-table-seq": (None, False, True, 0.1),
+}
+
+
+def _case(name):
+    shape, causal, masked, rate = ATTENTION_CASES[name]
+    if shape is None:
+        shape = (1, 12, _longest_seq(), 64)
+    return shape, causal, masked, rate
+
+
+@pytest.mark.parametrize("name", list(ATTENTION_CASES))
+def test_flash_forward_compiles(one_chip, name):
+    shape, causal, masked, rate = _case(name)
+    act, _, lens = _attention_args(shape, one_chip, masked)
+    blk = pick_block(shape[2], jnp.bfloat16)
+
+    def fwd(q, k, v, lens_):
+        return flash_attention_raw_lse(q, k, v, lens_, 7, causal,
+                                       shape[3] ** -0.5, rate, blk, blk,
+                                       False)
+
+    hlo = _compile(fwd, act, act, act, lens)
+    assert hlo.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("which", ["dq", "dkv"])
+@pytest.mark.parametrize("name", list(ATTENTION_CASES))
+def test_flash_backward_compiles(one_chip, name, which):
+    """``_flash_backward`` as ``fused_attention_grad`` calls it, with the
+    table's per-kernel blocks; the unused kernel's call is dead code, so
+    each case compiles exactly one."""
+    shape, causal, masked, rate = _case(name)
+    T = shape[2]
+    act, lse, lens = _attention_args(shape, one_chip, masked)
+    blk = pick_block(T, jnp.bfloat16)
+    dq_blocks, dkv_blocks = pick_bwd_blocks(T, T, jnp.bfloat16, (blk, blk))
+
+    def bwd(q, k, v, out, lse_, g, lens_):
+        dq, dk, dv = _flash_backward(
+            q, k, v, out, lse_, g, None, lens_, None, 7, causal,
+            shape[3] ** -0.5, rate, blk, blk, False, dq_blocks=dq_blocks,
+            dkv_blocks=dkv_blocks)
+        return dq if which == "dq" else (dk, dv)
+
+    hlo = _compile(bwd, act, act, act, act, lse, act, lens)
+    assert hlo.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("which", ["forward", "backward"])
+def test_flash_in_shard_map_compiles_for_a_mesh(topo, monkeypatch, which):
+    """The regression test for the unchecked ``shard_map`` wrap: the
+    Pallas dispatch and the direct backward under ``spmd_lowering`` on a
+    dp=2 x tp=2 mesh of the described chips — the path every mesh run
+    with long sequences takes on the chip, which no CPU run reaches."""
+    import sys
+
+    from paddle_tpu.kernels.flash_attention import flash_backward_spmd
+    from paddle_tpu.parallel.mesh import spmd_lowering
+
+    # the dispatch asks the default backend whether to interpret the
+    # kernels; this process's is the CPU, the compile's target is not
+    monkeypatch.setattr(sys.modules["paddle_tpu.kernels.flash_attention"],
+                        "_on_tpu", lambda: True)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("dp", "tp"))
+    shape = (8, 12, 2048, 64)
+    act, lse, lens = _attention_args(
+        shape, NamedSharding(mesh, P("dp", "tp", None, None)), True,
+        lse_sharding=NamedSharding(mesh, P()),
+        lens_sharding=NamedSharding(mesh, P("dp")))
+    blk = pick_block(shape[2], jnp.bfloat16)
+
+    def fwd(q, k, v, lens_):
+        return dispatch_attention_lse(q, k, v, seq_lens=lens_,
+                                      dropout_rate=0.1, seed=7,
+                                      force_pallas=True, raw_lse=True)
+
+    def bwd(q, k, v, out, lse_, g, lens_):
+        return flash_backward_spmd(q, k, v, out, lse_, g, lens_, 7, False,
+                                   shape[3] ** -0.5, 0.1, blk, blk, False)
+
+    with spmd_lowering(mesh, ("dp",)):
+        if which == "forward":
+            hlo = _compile(fwd, act, act, act, lens)
+        else:
+            hlo = _compile(bwd, act, act, act, act, lse, act, lens)
+    assert hlo.count("tpu_custom_call") == (1 if which == "forward" else 2)
+
+
+def test_s8_convolution_compiles(one_chip):
+    """The native branch of ``quantized_conv2d`` (ops/quant_ops.py) at a
+    ResNet-50 width: s8 x s8 -> s32, NCHW/OIHW, 3x3."""
+    x = jax.ShapeDtypeStruct((8, 256, 14, 14), jnp.int8, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((256, 256, 3, 3), jnp.int8, sharding=one_chip)
+
+    def conv(x_, w_):
+        dn = jax.lax.conv_dimension_numbers(x_.shape, w_.shape,
+                                            ("NCHW", "OIHW", "NCHW"))
+        return jax.lax.conv_general_dilated(
+            x_, w_, window_strides=(1, 1), padding=[(1, 1), (1, 1)],
+            dimension_numbers=dn, preferred_element_type=jnp.int32)
+
+    hlo = _compile(conv, x, w)
+    assert "s32[8,256,14,14]" in hlo and "convolution(" in hlo
+
+
+def test_s8_matmul_compiles(one_chip):
+    """The native branch of ``quantized_matmul``: ResNet-50's classifier,
+    [8, 2048] x [2048, 1000], s8 x s8 -> s32."""
+    x = jax.ShapeDtypeStruct((8, 2048), jnp.int8, sharding=one_chip)
+    y = jax.ShapeDtypeStruct((2048, 1000), jnp.int8, sharding=one_chip)
+
+    hlo = _compile(
+        lambda x_, y_: jax.lax.dot(x_, y_,
+                                   preferred_element_type=jnp.int32), x, y)
+    assert "s32[8,1000]" in hlo

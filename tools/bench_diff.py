@@ -9,7 +9,7 @@ sentinel/goodput sub-dicts), classifies each delta by the metric's
 direction, and exits nonzero when a directional metric regressed past
 the threshold:
 
-    python tools/bench_diff.py BENCH_r05.json BENCH_r06.json
+    python tools/bench_diff.py old.json new.json
     python tools/bench_diff.py --threshold 0.10 old.json new.json
     python tools/bench_diff.py --all old.json new.json   # every delta
 
