@@ -124,7 +124,9 @@ class Engine:
     # -- public ------------------------------------------------------------
     def run_block(self, program_desc, block_idx, scope, **kwargs):
         """One engine step, wrapped in the telemetry step span (a no-op
-        ctx mgr when PADDLE_TPU_METRICS is down).
+        ctx mgr unless PADDLE_TPU_METRICS is up or a JAX profiler session
+        is on; in the profiler's trace it is the ``StepTraceAnnotation``
+        ``pt.step``, with the phases of ``_run_block_impl`` as children).
 
         ``dispatch_steps=N`` (N>1) enqueues the step into the async
         dispatch window instead of materializing its fetches: the call
@@ -140,7 +142,7 @@ class Engine:
             # depth changed mid-run (or a windowed run is followed by a
             # plain one): serialize cleanly before the synchronous step
             self.window.sync()
-        with obs.span("step", step=self._run_counter + 1), \
+        with obs.step_span("step", self._run_counter + 1), \
                 obs.time_block("engine.step_ms"):
             out = self._run_block_impl(program_desc, block_idx, scope,
                                        dispatch_steps=dispatch_steps,
@@ -209,7 +211,12 @@ class Engine:
         feed = feed or {}
         fetch_list = fetch_list or []
         block = program_desc.block(block_idx)
-        feed_names, feed_values = self._coerce_feed(block, feed)
+        # the step's phases, each a child span of ``step`` carrying the
+        # step's number: feed, lookup, gather, run (compile on an
+        # executable's first call), writeback, fetch
+        step = self._run_counter + 1
+        with obs.span("feed", step=step):
+            feed_names, feed_values = self._coerce_feed(block, feed)
         if obs.enabled():
             obs.inc("engine.feed_bytes",
                     sum(int(getattr(v, "nbytes", 0)) for v in feed_values))
@@ -222,71 +229,18 @@ class Engine:
             # the step, so donation is off under SDC (keyed into the
             # executable cache — toggling the flag never aliases).
             donate_state = False
-        compiled = self.get_compiled(
-            program_desc, block_idx, feed_names, feed_values, fetch_list,
-            is_test, donate_state, amp, accumulate_steps,
-            cache_key_extra=cache_key_extra, mesh=mesh,
-            shard_rules=shard_rules, data_axes=data_axes,
-            remat_segments=remat_segments, verify=verify,
-            opt_level=opt_level, sdc=sdc, scope=scope)
+        with obs.span("lookup", step=step):
+            compiled = self.get_compiled(
+                program_desc, block_idx, feed_names, feed_values,
+                fetch_list, is_test, donate_state, amp, accumulate_steps,
+                cache_key_extra=cache_key_extra, mesh=mesh,
+                shard_rules=shard_rules, data_axes=data_axes,
+                remat_segments=remat_segments, verify=verify,
+                opt_level=opt_level, sdc=sdc, scope=scope)
 
-        mutated = [self._state_value(scope, n) for n in compiled.mutated_names]
-        readonly = [self._state_value(scope, n) for n in compiled.readonly_names]
-
-        if mesh is not None and jax.process_count() > 1:
-            # Multi-host SPMD: the jit's in_shardings span devices of
-            # OTHER processes, so every argument must arrive as a GLOBAL
-            # jax.Array. Host values carry the same global value on
-            # every process (the gen_nccl_id-era data contract), so each
-            # process materializes its local shards of the declared
-            # sharding via make_array_from_callback; a jax.Array still
-            # committed to this process's local devices (params right
-            # after the un-meshed startup run) round-trips through the
-            # host once. After the first step the state comes back
-            # globally sharded and passes through untouched.
-            mesh_devs = frozenset(mesh.devices.flat)
-
-            def _globalize(v, sharding):
-                if (isinstance(v, jax.Array)
-                        and frozenset(v.sharding.device_set) == mesh_devs):
-                    return v
-                host = np.asarray(v)
-                return jax.make_array_from_callback(
-                    host.shape, sharding, lambda idx: host[idx])
-
-            feed_sh, mut_sh, ro_sh = compiled.in_shardings
-            feed_values = [_globalize(v, s)
-                           for v, s in zip(feed_values, feed_sh)]
-            mutated = [_globalize(v, s)
-                       for v, s in zip(mutated, mut_sh)]
-            readonly = [_globalize(v, s)
-                        for v, s in zip(readonly, ro_sh)]
-        elif mesh is not None:
-            # Single-process mesh: jit reshards undonated args freely,
-            # but the DONATED state buffers must already match the
-            # declared in_shardings — a live array laid out by a
-            # previous rule table trips pjit's donation check otherwise
-            # (the "two rule tables, one scope" sequence). Reshard only
-            # on mismatch; steady-state steps pass through untouched.
-            # This same seam migrates live donated state onto a SHRUNK
-            # mesh after an elastic device loss (resilience/elastic.py):
-            # mesh_from_flag re-plans over the survivors, mesh_signature
-            # keys a fresh executable, and the mismatch branch moves the
-            # arrays — counted so shrink recovery is observable.
-            _, mut_sh, _ = compiled.in_shardings
-            moved = 0
-            resharded = []
-            for v, s in zip(mutated, mut_sh):
-                if isinstance(v, jax.Array) and v.sharding != s:
-                    v = jax.device_put(v, s)
-                    moved += 1
-                resharded.append(v)
-            mutated = resharded
-            if moved:
-                obs.inc("engine.state_resharded", moved)
-                obs.event("engine.state_resharded", arrays=moved,
-                          mesh=dict((str(k), int(n))
-                                    for k, n in mesh.shape.items()))
+        with obs.span("gather", step=step):
+            feed_values, mutated, readonly = self._gather(
+                compiled, scope, feed_values, mesh)
 
         self._run_counter += 1
         # The PRNG key is derived INSIDE the jitted function from two scalar
@@ -297,14 +251,34 @@ class Engine:
         # jax.jit compiles on the executable's FIRST call — telemetry
         # books that wall as "compile" (the honest XLA-compile time the
         # cache-miss build above does not see), later calls as "run"
-        # (async dispatch wall).
+        # (async dispatch wall). The first call belongs to the cache-miss
+        # seam: its span is always recorded, and carries the seconds JAX
+        # reports for tracing, lowering and backend-compiling this
+        # function (observability/tracing.py JAX_DURATIONS).
         first = compiled.run_count == 0
-        with obs.span("compile" if first else "run",
-                      step=self._run_counter), \
+        args = (feed_values, mutated, readonly, rng_seed)
+        if first and compiled.provenance is not None:
+            # the device join, lazy: what it needs of this executable (its
+            # arguments' shapes, before the call donates them) is put
+            # together here, at the seam
+            compiled.opprof_note = obs.opprof.make_note(
+                compiled.jitted, args, compiled.provenance,
+                block=compiled.block_program.block,
+                feed_names=compiled.block_program.feed_names)
+        if compiled.opprof_note is not None and obs.spans_live():
+            # ... and handed over on the first step that runs while spans
+            # are live or the flag is up; opprof lowers, compiles and
+            # parses it when the map is asked for, after the window —
+            # never on a step
+            obs.opprof.keep_note(compiled.opprof_note)
+            compiled.opprof_note = None
+            obs.inc("opprof.executables")
+        with (obs.seam_span("compile", fun_name=compiled.name,
+                            step=self._run_counter) if first
+              else obs.span("run", step=self._run_counter)), \
                 obs.time_block("engine.compile_ms" if first
                                else "engine.run_ms"):
-            fetches, state_out = compiled.jitted(feed_values, mutated,
-                                                 readonly, rng_seed)
+            fetches, state_out = compiled.jitted(*args)
         compiled.run_count += 1
 
         if obs.goodput.enabled():
@@ -442,34 +416,6 @@ class Engine:
             # watermark, and the edge-triggered memory_pressure event.
             obs.memory.record_step_memory(scope, step=self._run_counter)
 
-        if (obs.enabled()
-                and not getattr(compiled, "opprof_registered", True)):
-            # Op-provenance registration, once per executable (retried
-            # on the first observed step, so executables compiled before
-            # the profiler/metrics gate went up still register): parse
-            # the jitted HLO (lower() hits jax's caches — a retrace, not
-            # a second XLA compile) into the instruction -> provenance
-            # tag map and join the per-op FLOPs/bytes estimates, feeding
-            # the opprof registry that profiler.stop_profiler and
-            # perf_report --roofline attribute xplane device time with.
-            compiled.opprof_registered = True
-            try:
-                from paddle_tpu.observability import opprof as _opprof
-
-                hlo = compiled.jitted.lower(
-                    feed_values, mutated, readonly,
-                    rng_seed).compile().as_text()
-                _opprof.register_executable(
-                    hlo, compiled.provenance,
-                    block=compiled.block_program.block,
-                    feed_shapes={
-                        n: tuple(v.shape) for n, v in zip(
-                            compiled.block_program.feed_names,
-                            feed_values)})
-                obs.inc("opprof.executables")
-            except Exception:
-                obs.inc("opprof.register_crashes")
-
         defer = dispatch_steps > 1
         probes = []
         if self.check_nan_inf:
@@ -494,9 +440,10 @@ class Engine:
                               step=self._run_counter, kind="fetch")
 
         if state_writeback:
-            for name, val in zip(compiled.block_program.state_out_names,
-                                 state_out):
-                scope.set(name, val)
+            with obs.span("writeback", step=step):
+                for name, val in zip(
+                        compiled.block_program.state_out_names, state_out):
+                    scope.set(name, val)
         else:
             # Inference mode (serving): a frozen test program only
             # re-emits state values it read unchanged, so skipping the
@@ -543,13 +490,78 @@ class Engine:
         if return_numpy:
             # one batched host transfer for all fetches (device_get on the
             # list) — per-value np.asarray syncs serially
-            fetches = list(jax.device_get(list(fetches)))
+            with obs.span("fetch", step=step):
+                fetches = list(jax.device_get(list(fetches)))
         else:
             fetches = list(fetches)
         if obs.enabled():
             obs.inc("engine.fetch_bytes",
                     sum(int(getattr(v, "nbytes", 0)) for v in fetches))
         return fetches
+
+    def _gather(self, compiled, scope, feed_values, mesh):
+        """The executable's arguments: the state it reads from the scope,
+        and under a mesh the feeds and the state laid out as its
+        in_shardings declare. -> (feed_values, mutated, readonly)."""
+        mutated = [self._state_value(scope, n)
+                   for n in compiled.mutated_names]
+        readonly = [self._state_value(scope, n)
+                    for n in compiled.readonly_names]
+        if mesh is not None and jax.process_count() > 1:
+            # Multi-host SPMD: the jit's in_shardings span devices of
+            # OTHER processes, so every argument must arrive as a GLOBAL
+            # jax.Array. Host values carry the same global value on
+            # every process (the gen_nccl_id-era data contract), so each
+            # process materializes its local shards of the declared
+            # sharding via make_array_from_callback; a jax.Array still
+            # committed to this process's local devices (params right
+            # after the un-meshed startup run) round-trips through the
+            # host once. After the first step the state comes back
+            # globally sharded and passes through untouched.
+            mesh_devs = frozenset(mesh.devices.flat)
+
+            def _globalize(v, sharding):
+                if (isinstance(v, jax.Array)
+                        and frozenset(v.sharding.device_set) == mesh_devs):
+                    return v
+                host = np.asarray(v)
+                return jax.make_array_from_callback(
+                    host.shape, sharding, lambda idx: host[idx])
+
+            feed_sh, mut_sh, ro_sh = compiled.in_shardings
+            feed_values = [_globalize(v, s)
+                           for v, s in zip(feed_values, feed_sh)]
+            mutated = [_globalize(v, s)
+                       for v, s in zip(mutated, mut_sh)]
+            readonly = [_globalize(v, s)
+                        for v, s in zip(readonly, ro_sh)]
+        elif mesh is not None:
+            # Single-process mesh: jit reshards undonated args freely,
+            # but the DONATED state buffers must already match the
+            # declared in_shardings — a live array laid out by a
+            # previous rule table trips pjit's donation check otherwise
+            # (the "two rule tables, one scope" sequence). Reshard only
+            # on mismatch; steady-state steps pass through untouched.
+            # This same seam migrates live donated state onto a SHRUNK
+            # mesh after an elastic device loss (resilience/elastic.py):
+            # mesh_from_flag re-plans over the survivors, mesh_signature
+            # keys a fresh executable, and the mismatch branch moves the
+            # arrays — counted so shrink recovery is observable.
+            _, mut_sh, _ = compiled.in_shardings
+            moved = 0
+            resharded = []
+            for v, s in zip(mutated, mut_sh):
+                if isinstance(v, jax.Array) and v.sharding != s:
+                    v = jax.device_put(v, s)
+                    moved += 1
+                resharded.append(v)
+            mutated = resharded
+            if moved:
+                obs.inc("engine.state_resharded", moved)
+                obs.event("engine.state_resharded", arrays=moved,
+                          mesh=dict((str(k), int(n))
+                                    for k, n in mesh.shape.items()))
+        return feed_values, mutated, readonly
 
     @staticmethod
     def _coerce_feed(block, feed):
@@ -651,13 +663,20 @@ class Engine:
         compiled = self._cache.get(key)
         if compiled is None:
             obs.inc("engine.cache_miss")
+            # the jitted step's name, from the program's content: the
+            # same in every process that runs the same program
+            name = "pt_%s_b%d" % (key[0][:10], block_idx)
             if faultinject.active():
                 # transient compile failure (a real pod sees these as
                 # coordinator hiccups / OOM-ed compile servers); the
                 # resilience driver retries the step, which re-enters
                 # this cache-miss path
                 faultinject.fault_point("compile")
-            with obs.span("trace", block=block_idx, opt_level=opt_level), \
+            # the cache-miss seam: this span and its children (transform,
+            # verify, lower, the plans) are recorded whatever is switched
+            # on — it runs once an executable, never on a steady step
+            with obs.seam_span("trace", block=block_idx,
+                               opt_level=opt_level), \
                     obs.time_block("engine.trace_ms"):
                 run_desc = program_desc
                 if opt_level > 0:
@@ -743,7 +762,7 @@ class Engine:
                             remat_segments=remat_segments or auto_remat,
                             memory_plan=memory_plan, sdc=sdc,
                             zero=zero and not auto_remat,
-                            grad_bucket_mb=grad_bucket_mb,
+                            grad_bucket_mb=grad_bucket_mb, name=name,
                         )
                     except NotImplementedError:
                         # the remat lowering statically rejects some
@@ -763,7 +782,7 @@ class Engine:
                             accumulate_steps=accumulate_steps,
                             remat_segments=remat_segments,
                             memory_plan=memory_plan, sdc=sdc,
-                            zero=zero, grad_bucket_mb=grad_bucket_mb,
+                            zero=zero, grad_bucket_mb=grad_bucket_mb, name=name,
                         )
             # measured-feedback re-planning metadata (_maybe_replan):
             # eligible exactly where auto-remat was legal, with a rebuild
@@ -820,7 +839,7 @@ class Engine:
                     accumulate_steps=accumulate_steps,
                     remat_segments=new_segments, memory_plan=new_plan,
                     sdc=sdc, zero=zero and not new_segments,
-                    grad_bucket_mb=grad_bucket_mb)
+                    grad_bucket_mb=grad_bucket_mb, name=name)
 
             compiled._rebuild = _rebuild
             # the cache-miss build (trace/transform/verify/lower) is
@@ -917,7 +936,7 @@ class Engine:
                  mesh=None, feed_values=None, shard_rules=None,
                  data_axes=("dp",), amp=False, accumulate_steps=1,
                  remat_segments=0, memory_plan=None, sdc=False,
-                 zero=False, grad_bucket_mb=0.0):
+                 zero=False, grad_bucket_mb=0.0, name="pt_block"):
         if accumulate_steps > 1 and remat_segments:
             raise NotImplementedError(
                 "accumulate_steps and remat_segments cannot combine yet; "
@@ -1143,14 +1162,20 @@ class Engine:
                          + (1 if sdc else 0)),
                 [state_sharding(n) for n in bp.state_out_names],
             )
+        # A stable name for the jitted step: XLA names the module after
+        # it (``jit_pt_<program>_b<idx>`` on the trace's ``XLA Modules``
+        # line) and JAX names its compile events by it, so both can be
+        # matched to this executable.
+        wrapped.__name__ = wrapped.__qualname__ = name
         jitted = jax.jit(wrapped, donate_argnums=donate, **jit_kwargs)
         in_sh = (tuple(jit_kwargs["in_shardings"][:3])
                  if "in_shardings" in jit_kwargs else None)
         cb = CompiledBlock(bp, jitted, mutated, readonly,
                            in_shardings=in_sh, memory_plan=memory_plan,
                            remat_segments=remat_segments)
+        cb.name = wrapped.__name__
         cb.provenance = prov
-        cb.opprof_registered = prov is None
+        cb.opprof_note = None
         if sdc:
             from paddle_tpu.resilience.sentinel import EWMABand
 

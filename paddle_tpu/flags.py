@@ -159,7 +159,13 @@ DEFS = {
         "Runtime telemetry (paddle_tpu.observability): engine "
         "cache/compile/run counters + timing histograms and host-side "
         "spans exportable as chrome-trace JSON. Off = no-op stubs at "
-        "every instrumented seam (near-zero overhead)."),
+        "every instrumented seam (near-zero overhead). The spans alone "
+        "are also switched on by a JAX profiler session "
+        "(jax.profiler.start_trace, fluid.profiler): while one is on "
+        "they are recorded with this flag down, and written into the "
+        "profiler's trace as pt.<name> on the device's clock. The "
+        "cache-miss seam's spans (trace, lower, first-call compile) are "
+        "always recorded."),
     "goodput": (
         bool, False,
         "Goodput ledger (observability/goodput.py): charge every "
@@ -190,10 +196,13 @@ DEFS = {
         "Op-level profiling provenance (observability/opprof.py): wrap "
         "every op's lowering in jax.named_scope('pt.<type>.<blk>_<idx>') "
         "so XLA op_metadata carries framework-op identity through "
-        "fusion, and register the compiled HLO's instruction->op map on "
-        "first run for xplane attribution. named_scope is metadata-only "
-        "(lowering stays bit-identical — test_opprof.py asserts it); "
-        "off skips the scope wrap and the registration walk. The engine "
+        "fusion. On the first step an executable runs under the metrics "
+        "flag or a JAX profiler session the engine leaves a note of it; "
+        "the instruction->(op, phase) map is made from the notes when asked "
+        "for (opprof.instruction_phases, profiler.stop_profiler), after "
+        "the profiled window and never on a step. named_scope is "
+        "metadata-only (lowering stays bit-identical — test_opprof.py "
+        "asserts it); off skips the scope wrap and the note. The engine "
         "keys its executable cache on the value."),
     "metrics_sink": (
         str, "",

@@ -21,6 +21,15 @@ allocation — so the instrumented seams stay at PR-2 latency
 ``flags.set_flags({"metrics": True})`` takes effect immediately;
 ``PADDLE_TPU_METRICS=1`` in the environment is read once at import.
 
+Spans alone have a second switch: a JAX profiler session
+(``jax.profiler.start_trace`` .. ``stop_trace``, whoever started it).
+While one is on, ``span`` is live with the flag down, and every live span
+is also written into the profiler's trace as ``pt.<name>``, on the clock
+of the device planes. Counters, histograms, the memory census and the
+rest stay on the flag, so a traced slice carries spans and nothing
+heavier. The cache-miss seam (``seam_span``: once an executable) is
+recorded in memory whatever is switched on.
+
 Entry points: ``snapshot()``, ``dump_chrome_trace(path)``,
 ``inc/observe/set_gauge/time_block``, ``span/event``, ``reset()``.
 ``paddle_tpu.profiler`` is the user-facing façade that starts/stops
@@ -52,6 +61,8 @@ from paddle_tpu.observability.metrics import (  # noqa: F401
 from paddle_tpu.observability.tracing import (  # noqa: F401
     SpanRecord,
     SpanTracer,
+    self_time,
+    session_on,
 )
 
 __all__ = [
@@ -59,8 +70,9 @@ __all__ = [
     "attach_sink", "counter_value", "detach_sink", "dump_chrome_trace",
     "enabled", "event", "flush_sink", "goodput", "inc", "observe",
     "opprof", "registry", "reqtrace",
-    "health", "reset", "set_enabled", "set_gauge", "sink", "snapshot",
-    "snapshot_text", "span", "spans", "time_block", "tracer",
+    "health", "reset", "seam_span", "self_time", "set_enabled",
+    "set_gauge", "sink", "snapshot", "snapshot_text", "span", "spans",
+    "spans_live", "step_span", "time_block", "tracer",
 ]
 
 registry = MetricsRegistry()
@@ -210,11 +222,31 @@ def counter_value(name, default=0):
 
 
 # -- spans -----------------------------------------------------------------
+def spans_live():
+    """Whether ``span`` records: the flag is up, a JAX profiler session
+    is on, or a cache-miss seam span is open."""
+    return _ENABLED or tracer.seam_open > 0 or session_on()
+
+
 def span(name, **args):
     """RAII host span: wall start + duration, nests per thread."""
-    if not _ENABLED:
+    if not spans_live():
         return NULL_BLOCK
     return tracer.span(name, **args)
+
+
+def step_span(name, step):
+    """``span`` for one step of the program: a ``StepTraceAnnotation``
+    (``step_num`` = ``step``) in the profiler's trace."""
+    if not spans_live():
+        return NULL_BLOCK
+    return tracer.step_span(name, step)
+
+
+def seam_span(name, fun_name=None, **args):
+    """A span of the cache-miss seam, always recorded
+    (``SpanTracer.seam_span``)."""
+    return tracer.seam_span(name, fun_name=fun_name, **args)
 
 
 def event(name, **args):

@@ -14,7 +14,12 @@ granularity in three stages:
    rewrite so the tag can be expanded back to its source op list.
 2. **Attribution** (:func:`attribute`): the compiled HLO text is parsed
    into an instruction -> tag map (:func:`hlo_op_map`; a fusion carries
-   its root's tag — the *dominant* policy, recorded in the output), the
+   its root's tag — the *dominant* policy, recorded in the output). The
+   engine only leaves a note of an executable on its first observed
+   call (:func:`make_note`, :func:`keep_note`); the note is resolved — lowered and
+   compiled again, from JAX's caches, for its HLO text — when the map
+   is asked for (:func:`instruction_phases`, :func:`registry_snapshot`),
+   after the profiled window and never on a step. The
    xplane device planes are aggregated per tag, and per-op FLOPs/bytes
    estimates (``analysis.spmd.op_flops_bytes``) join in to yield a
    roofline verdict per op: compute-bound / memory-bound / comm-bound
@@ -28,7 +33,8 @@ granularity in three stages:
 
 Plane parsing (:func:`iter_planes`, :func:`top_ops`) lives HERE — the
 package must never import from ``tools/``; ``tools/xplane_top_ops.py``
-is a thin CLI shim over this module.
+is a thin CLI shim over this module. Traces are read through
+``jax.profiler.ProfileData``, with nothing but JAX.
 
 CPU-probe caveat: CPU xplane planes attribute coarsely (thread lines
 interleave HLO thunks with runtime events, durations include dispatch
@@ -101,20 +107,11 @@ def tag_op_type(tag):
     return m.group(1) if m else None
 
 
-def hlo_op_map(hlo_text):
-    """Parse compiled HLO text into ``(instr_tags, instr_kinds)``:
-    ``{instruction name: provenance tag or None}`` and
-    ``{instruction name: opcode}``.
-
-    A fusion instruction carries its ROOT's ``op_name`` — the dominant
-    policy. Instructions with no metadata of their own (e.g.
-    ``reduce-window``) inherit the dominant tag of any computation they
-    call (``to_apply=%region...``), and in the other direction a tagged
-    caller charges its called computations' untagged member
-    instructions (a scatter-expanded ``while`` loop's add/copy/
-    dynamic-update-slice plumbing executes as per-iteration thunks on
-    CPU — that time belongs to the op that owns the loop). The fixpoint
-    iterates so nested regions (fusion inside a while body) resolve."""
+def _parse_hlo(hlo_text):
+    """-> (instr_tags, instr_kinds, instr_calls, comp_of): each
+    instruction's own provenance tag (None without ``op_name`` metadata)
+    and opcode, the computations it calls, and the computation it lives
+    in."""
     instr_tags = {}
     instr_kinds = {}
     instr_calls = {}
@@ -137,6 +134,29 @@ def hlo_op_map(hlo_text):
             cm = _COMP_RE.match(line)
             if cm is not None and "{" in line:
                 current = cm.group("name")
+    return instr_tags, instr_kinds, instr_calls, comp_of
+
+
+def hlo_op_map(hlo_text):
+    """Parse compiled HLO text into ``(instr_tags, instr_kinds)``:
+    ``{instruction name: provenance tag or None}`` and
+    ``{instruction name: opcode}``.
+
+    A fusion instruction carries the ``op_name`` XLA gives it (its
+    root's, or its matmul's or convolution's where it holds one) — the
+    dominant policy. Instructions with no metadata of their own (e.g.
+    ``reduce-window``) inherit the dominant tag of any computation they
+    call (``to_apply=%region...``), and in the other direction a tagged
+    caller charges its called computations' untagged member
+    instructions (a scatter-expanded ``while`` loop's add/copy/
+    dynamic-update-slice plumbing executes as per-iteration thunks on
+    CPU — that time belongs to the op that owns the loop). The fixpoint
+    iterates so nested regions (fusion inside a while body) resolve."""
+    return _inherit_tags(*_parse_hlo(hlo_text))
+
+
+def _inherit_tags(own_tags, instr_kinds, instr_calls, comp_of):
+    instr_tags = dict(own_tags)
 
     def _dominant(comp):
         votes = defaultdict(int)
@@ -179,6 +199,27 @@ def hlo_op_map(hlo_text):
     return instr_tags, instr_kinds
 
 
+def mixed_phase_instructions(own_tags, instr_calls, comp_of, tag_phase):
+    """``{instruction: "backward+optimizer"}`` for the instructions
+    (fusions, as a rule) whose called computations hold members of more
+    than one phase: a fusion is booked whole to the op XLA names it
+    after, so this is the time the phase split books to a neighbour —
+    XLA on the TPU fuses a weight gradient's matmul with the optimizer's
+    update of that weight. ``own_tags``, ``instr_calls``, ``comp_of``:
+    ``_parse_hlo``'s; ``tag_phase``: tag -> phase."""
+    members = defaultdict(set)  # computation -> phases of its members
+    for instr, comp in comp_of.items():
+        phase = tag_phase.get(own_tags.get(instr))
+        if phase:
+            members[comp].add(phase)
+    out = {}
+    for name, comps in instr_calls.items():
+        phases = set().union(*(members[c] for c in comps))
+        if len(phases) > 1:
+            out[name] = "+".join(sorted(phases))
+    return out
+
+
 # -- process-level provenance registry --------------------------------------
 # Accumulates across every executable registered since the last reset —
 # a profiled run typically compiles startup + train-step blocks and all
@@ -188,29 +229,140 @@ _REGISTRY = {
     "policy": "dominant",
     "instr_tags": {},   # instr name -> tag or None
     "instr_kinds": {},  # instr name -> opcode
-    "costs": {},        # tag -> {op_type, flops, bytes, src_ops}
+    "costs": {},        # tag -> {op_type, op_role, flops, bytes, src_ops}
+    "mixed_phase": {},  # instr -> "backward+optimizer": members span phases
     "collectives": {"hlo_psums": 0, "hlo_bytes": 0, "instances": 0},
 }
+# Executables the engine has seen run while spans were live or the flag
+# was up, not yet resolved: [``make_note``'s tuples].
+_NOTES = []
+PHASES = ("forward", "backward", "optimizer")
 
 
 def reset():
     with _LOCK:
+        del _NOTES[:]
         _REGISTRY["instr_tags"] = {}
         _REGISTRY["instr_kinds"] = {}
         _REGISTRY["costs"] = {}
+        _REGISTRY["mixed_phase"] = {}
         _REGISTRY["collectives"] = {
             "hlo_psums": 0, "hlo_bytes": 0, "instances": 0}
 
 
 def registry_snapshot():
+    """The registry, with every pending note resolved first."""
+    resolve_notes()
     with _LOCK:
         return {
             "policy": _REGISTRY["policy"],
             "instr_tags": dict(_REGISTRY["instr_tags"]),
             "instr_kinds": dict(_REGISTRY["instr_kinds"]),
             "costs": {t: dict(c) for t, c in _REGISTRY["costs"].items()},
+            "mixed_phase": dict(_REGISTRY["mixed_phase"]),
             "collectives": dict(_REGISTRY["collectives"]),
         }
+
+
+def role_phase(op_role):
+    """``forward``, ``backward`` or ``optimizer`` (Optimize and LRSched)
+    from an op's ``op_role`` bits (framework.OpRole); None without one."""
+    if op_role is None:
+        return None
+    from paddle_tpu.framework import OpRole
+
+    role = int(op_role)
+    if role & OpRole.Backward:
+        return "backward"
+    if role & (OpRole.Optimize | OpRole.LRSched):
+        return "optimizer"
+    return "forward"
+
+
+def make_note(jitted, args, prov, block=None, feed_names=()):
+    """What the device join needs of one executable, made on its first
+    call (the cache-miss seam: a shape for each argument, taken before
+    the call donates them). Nothing is lowered, compiled or parsed here.
+    ``prov`` is the lowering's ``{tag: OpDesc}``, filled by the first
+    call's trace. The engine keeps the note on the executable and hands
+    it to ``keep_note`` when a step runs while spans are live."""
+    import jax
+
+    def shape_of(v):
+        # A sharding is kept only where it spans devices: a shape with a
+        # one-device sharding lowers to another module text than the call
+        # itself made (``sdy.sharding`` on every argument), which JAX's
+        # caches would not know.
+        sharding = getattr(v, "sharding", None)
+        if sharding is not None and len(sharding.device_set) < 2:
+            sharding = None
+        if not hasattr(v, "dtype"):
+            import numpy as np
+
+            v = np.asarray(v)
+        return jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=sharding)
+
+    avals = jax.tree_util.tree_map(shape_of, args)
+    feed_shapes = {n: tuple(a.shape) for n, a in zip(feed_names, avals[0])}
+    return (jitted, avals, prov, block, feed_shapes)
+
+
+def keep_note(note):
+    """Remember an executable for the device join: O(1), on a step.
+    ``resolve_notes`` lowers, compiles and parses it when the map is
+    asked for, after the window."""
+    with _LOCK:
+        _NOTES.append(note)
+
+
+def resolve_notes():
+    """Resolve every pending note into the registry: lower and compile
+    the noted function again for its shapes (JAX's tracing cache and the
+    persistent compilation cache serve it where they can), parse the HLO
+    text, register. -> (executables resolved, seconds it took)."""
+    import time
+
+    with _LOCK:
+        notes = list(_NOTES)
+        del _NOTES[:]
+    t0, done = time.perf_counter(), 0
+    for jitted, avals, prov, block, feed_shapes in notes:
+        try:
+            hlo = jitted.lower(*avals).compile().as_text()
+            register_executable(hlo, prov, block=block,
+                                feed_shapes=feed_shapes)
+            done += 1
+        except Exception as e:  # noqa: BLE001 - the table goes on without it
+            import warnings
+
+            from paddle_tpu import observability as obs
+
+            obs.inc("opprof.register_crashes")
+            warnings.warn("opprof: an executable's note could not be "
+                          "resolved, its instructions stay unattributed: "
+                          "%r" % (e,), RuntimeWarning)
+    return done, time.perf_counter() - t0
+
+
+def instruction_phases():
+    """``{HLO instruction name: (tag, op type, phase)}`` over every
+    executable noted so far (pending notes are resolved first). ``phase``
+    is ``forward``, ``backward`` or ``optimizer`` by the ``op_role`` of
+    the Fluid op whose lowering made the instruction (a fusion: its
+    root's); all three are None where an instruction carries no tag.
+    An instruction name is the text between ``%`` and `` = `` of an
+    ``XLA Ops`` event's name."""
+    snap = registry_snapshot()
+    costs = snap["costs"]
+    out = {}
+    for instr, tag in snap["instr_tags"].items():
+        if tag is None:
+            out[instr] = (None, None, None)
+            continue
+        row = costs.get(tag, {})
+        out[instr] = (tag, row.get("op_type") or tag_op_type(tag),
+                      role_phase(row.get("op_role", 0)))
+    return out
 
 
 def register_executable(hlo_text, prov, block=None, feed_shapes=None):
@@ -221,7 +373,9 @@ def register_executable(hlo_text, prov, block=None, feed_shapes=None):
     accumulated lowering's once-op index offset)."""
     from paddle_tpu.analysis import spmd
 
-    instr_tags, instr_kinds = hlo_op_map(hlo_text)
+    own_tags, instr_kinds, instr_calls, comp_of = _parse_hlo(hlo_text)
+    instr_tags, _ = _inherit_tags(own_tags, instr_kinds, instr_calls,
+                                  comp_of)
     try:
         measured = spmd.measured_collectives(hlo_text)
     except Exception:
@@ -236,14 +390,19 @@ def register_executable(hlo_text, prov, block=None, feed_shapes=None):
         src = op.attrs.get("__src_ops__")
         costs[tag] = {
             "op_type": op.type,
+            "op_role": int(op.attrs.get("op_role", 0) or 0),
             "flops": int(flops),
             "bytes": int(nbytes),
             "src_ops": list(src) if src else [op.type],
         }
+    mixed = mixed_phase_instructions(
+        own_tags, instr_calls, comp_of,
+        {t: role_phase(c["op_role"]) for t, c in costs.items()})
     with _LOCK:
         _REGISTRY["instr_tags"].update(instr_tags)
         _REGISTRY["instr_kinds"].update(instr_kinds)
         _REGISTRY["costs"].update(costs)
+        _REGISTRY["mixed_phase"].update(mixed)
         _REGISTRY["collectives"]["hlo_psums"] += int(
             measured.get("psum_count", 0))
         _REGISTRY["collectives"]["hlo_bytes"] += int(
@@ -282,16 +441,18 @@ def load_sidecar(trace_dir):
 
 # -- xplane parsing (hoisted from tools/xplane_top_ops.py) ------------------
 def iter_planes(trace_dir):
-    """Yield every non-empty DISTINCT plane from the .xplane.pb files
-    under ``trace_dir`` (shared by tools/xplane_top_ops.py,
-    tools/timeline.py and observability/tracing.py). Byte-identical
-    planes are skipped — some sessions embed the same device plane in
-    more than one dump file, which would double every aggregate — while
-    genuine multi-host planes (same name, different events/timestamps)
-    all pass through."""
+    """Yield every non-empty DISTINCT plane (``jax.profiler.ProfileData``
+    planes: ``.name``, ``.lines`` of ``.events`` with ``.name``,
+    ``.start_ns``, ``.duration_ns``, ``.stats``) from the .xplane.pb
+    files under ``trace_dir`` (shared by tools/xplane_top_ops.py,
+    tools/timeline.py and observability/tracing.py). Planes alike to the
+    last event are skipped — some sessions embed the same device plane
+    in more than one dump file, which would double every aggregate —
+    while genuine multi-host planes (same name, different
+    events/timestamps) all pass through."""
     import hashlib
 
-    from tensorflow.tsl.profiler.protobuf import xplane_pb2
+    from jax.profiler import ProfileData
 
     files = sorted(glob.glob("%s/**/*.xplane.pb" % trace_dir,
                              recursive=True))
@@ -299,18 +460,26 @@ def iter_planes(trace_dir):
         raise FileNotFoundError("no xplane.pb under %s" % trace_dir)
     seen = set()
     for f in files:
-        xs = xplane_pb2.XSpace()
-        with open(f, "rb") as fh:
-            xs.ParseFromString(fh.read())
-        for plane in xs.planes:
-            if not sum(len(l.events) for l in plane.lines):
+        for plane in ProfileData.from_file(f).planes:
+            digest = hashlib.sha256(plane.name.encode())
+            events = 0
+            for line in plane.lines:
+                digest.update(line.name.encode())
+                for e in line.events:
+                    events += 1
+                    digest.update(("%s|%r|%r" % (
+                        e.name, e.start_ns, e.duration_ns)).encode())
+            if not events or digest.digest() in seen:
                 continue
-            digest = hashlib.sha256(
-                plane.SerializeToString(deterministic=True)).digest()
-            if digest in seen:
-                continue
-            seen.add(digest)
+            seen.add(digest.digest())
             yield plane
+
+
+def instruction_name(event_name):
+    """The HLO instruction an ``XLA Ops`` event ran: the trace names the
+    event by the instruction's whole text (``%fusion.12 = f32[...]
+    fusion(...``) or by its name alone."""
+    return event_name.split(" = ", 1)[0].split(" ", 1)[0].lstrip("%")
 
 
 def top_ops(trace_dir, top_n=25, group="op"):
@@ -321,16 +490,15 @@ def top_ops(trace_dir, top_n=25, group="op"):
     total = 0.0
     for plane in iter_planes(trace_dir):
         if "/device:" in plane.name:
-            meta = {m.id: m.name for m in plane.event_metadata.values()}
             for line in plane.lines:
                 if line.name != "XLA Ops":
                     continue
                 for e in line.events:
-                    name = meta.get(e.metadata_id, "?")
+                    name = e.name
                     if group == "kind":
                         name = re.split(r"[.\d]", name, 1)[0]
-                    per[name] += e.duration_ps / 1e9
-                    total += e.duration_ps / 1e9
+                    per[name] += e.duration_ns / 1e6
+                    total += e.duration_ns / 1e6
     rows = sorted(per.items(), key=lambda kv: -kv[1])[:top_n]
     return rows, total
 
@@ -350,30 +518,28 @@ def device_op_events(trace_dir, known=None):
     known = known or ()
     device_events, cpu_events = [], []
     for plane in iter_planes(trace_dir):
-        meta = {m.id: m.name for m in plane.event_metadata.values()}
         if "/device:" in plane.name:
             for line in plane.lines:
                 if line.name != "XLA Ops":
                     continue
                 for e in line.events:
                     device_events.append(
-                        (meta.get(e.metadata_id, "?").lstrip("%"),
-                         e.duration_ps / 1e9))
+                        (instruction_name(e.name), e.duration_ns / 1e6))
         elif "/host:CPU" in plane.name:
-            stat_meta = {m.id: m.name
-                         for m in plane.stat_metadata.values()}
             for line in plane.lines:
                 if not line.name.startswith("tf_XLA"):
                     continue
                 for e in line.events:
-                    name = meta.get(e.metadata_id, "?").lstrip("%")
-                    has_hlo_stat = any(
-                        stat_meta.get(s.metadata_id) == "hlo_op"
-                        for s in e.stats)
-                    if (name not in known and not has_hlo_stat
-                            and _NON_HLO_EVENT_RE.search(name)):
+                    # "end: <instr>": the close of an instruction's
+                    # parallel task on an Eigen thread
+                    name = instruction_name(e.name[5:] if e.name.startswith(
+                        "end: ") else e.name)
+                    if (name not in known
+                            and _NON_HLO_EVENT_RE.search(e.name)
+                            and not any(k == "hlo_op"
+                                        for k, _ in e.stats)):
                         continue
-                    cpu_events.append((name, e.duration_ps / 1e9))
+                    cpu_events.append((name, e.duration_ns / 1e6))
     if device_events:
         return device_events, "tpu"
     return cpu_events, "cpu-coarse"
@@ -409,7 +575,15 @@ def attribute(trace_dir, sidecar=None, peak_flops=None, peak_membw=None):
                        intensity, verdict, frac}},
          "total_ms", "attributed_ms", "unattributed_ms",
          "attributed_frac", "comm_ms", "collective_instances",
-         "expected_collective_instances", "fusion_policy", "source"}
+         "expected_collective_instances", "fusion_policy", "source",
+         "by_type": {op type: ms}, "by_phase": {phase: ms},
+         "mixed_phase_ms": {"backward+optimizer": ms, ...}}
+
+    ``by_phase`` splits the attributed time into ``forward``,
+    ``backward`` and ``optimizer`` by the op's ``op_role``;
+    ``mixed_phase_ms`` is the part of it spent in fusions whose members
+    come from more than one phase (each booked whole to one op), by the
+    phases they hold.
 
     Every tag the registry knows appears in ``ops`` even at 0 ms (XLA
     may constant-fold an op away entirely; "every ProgramDesc op in the
@@ -422,6 +596,9 @@ def attribute(trace_dir, sidecar=None, peak_flops=None, peak_membw=None):
     instr_tags = sc.get("instr_tags", {})
     instr_kinds = sc.get("instr_kinds", {})
     costs = sc.get("costs", {})
+    mixed = sc.get("mixed_phase", {})
+    tag_phase = {t: role_phase(c.get("op_role", 0))
+                 for t, c in costs.items()}
     events, source = device_op_events(trace_dir, known=instr_tags)
 
     ops = {}
@@ -433,6 +610,8 @@ def attribute(trace_dir, sidecar=None, peak_flops=None, peak_membw=None):
             "flops": c.get("flops", 0), "bytes": c.get("bytes", 0),
         }
     total = attributed = comm_ms = unattributed = 0.0
+    by_type, by_phase = defaultdict(float), defaultdict(float)
+    mixed_ms = defaultdict(float)
     comm_tags = set()
     seen_collectives = set()
     for name, ms in events:
@@ -462,6 +641,10 @@ def attribute(trace_dir, sidecar=None, peak_flops=None, peak_membw=None):
         })
         row["ms"] += ms
         row["events"] += 1
+        by_type[row["op_type"]] += ms
+        by_phase[tag_phase.get(tag, "forward")] += ms
+        if name in mixed:
+            mixed_ms[mixed[name]] += ms
         if is_coll:
             comm_tags.add(tag)
 
@@ -487,6 +670,9 @@ def attribute(trace_dir, sidecar=None, peak_flops=None, peak_membw=None):
             sc.get("collectives", {}).get("instances", 0)),
         "fusion_policy": sc.get("policy", "dominant"),
         "source": source,
+        "by_type": dict(by_type),
+        "by_phase": dict(by_phase),
+        "mixed_phase_ms": dict(mixed_ms),
     }
 
 

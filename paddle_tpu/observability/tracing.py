@@ -12,14 +12,34 @@ up on the wall clock (both timebases are ns-since-epoch).
 
 Span timestamps come from ``perf_counter_ns`` re-anchored to the epoch
 once at import: monotonic durations, epoch-aligned starts.
+
+While a JAX profiler session is on, a span is also written into the
+profiler's own trace as ``pt.<name>`` (``jax.profiler.TraceAnnotation``;
+a step span as a ``StepTraceAnnotation``), so it lands on ``/host:CPU``
+on the clock of the device planes.
 """
 
 import json
 import threading
 import time
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
 from paddle_tpu.observability.export import (DEFAULT_FLIGHT_DEPTH,
                                              FlightRecorder)
+
+# True while a JAX profiler session is recording host annotations
+# (``jax.profiler.start_trace`` .. ``stop_trace``): a static method of the
+# public class, about 80 ns a call.
+session_on = TraceAnnotation.is_enabled
+
+# JAX's own durations of a jitted function's first call, charged to the
+# engine's first-call span as arguments (``SpanTracer.seam_span``).
+JAX_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax_trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax_lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_compile_s",
+}
 
 # perf_counter is monotonic but has an arbitrary zero; anchor it to the
 # epoch once so span starts align with device-trace timestamps.
@@ -62,6 +82,12 @@ class SpanTracer:
         # reports. Plain attribute write on span enter/exit (no lock:
         # an approximate label, read racily by the heartbeat thread).
         self._phase_name = None
+        # open cache-miss seam spans (any thread): while one is open
+        # every span is recorded, whatever is switched on
+        self.seam_open = 0
+        # the open first-call span JAX's durations are charged to
+        self._first_call = None
+        self._listening = False
 
     # -- record -----------------------------------------------------------
     def _stack(self):
@@ -129,6 +155,44 @@ class SpanTracer:
 
     def span(self, name, **args):
         return _Span(self, name, args)
+
+    def step_span(self, name, step):
+        """A span of one step: in the profiler's trace it is a
+        ``StepTraceAnnotation`` with ``step_num`` = ``step``."""
+        return _Span(self, name, {"step": step}, step_num=step)
+
+    def seam_span(self, name, fun_name=None, **args):
+        """A span of the cache-miss seam (once an executable, never on a
+        steady step): recorded whatever is switched on, and so is every
+        span opened while it is open. With ``fun_name`` (a jitted
+        function's ``__name__``) it is that function's first call: the
+        seconds JAX reports for tracing, lowering and backend-compiling
+        ``fun_name`` while the span is open become its arguments
+        (``JAX_DURATIONS``)."""
+        if fun_name is not None:
+            self._listen()
+        return _SeamSpan(self, name, args, fun_name)
+
+    def _listen(self):
+        with self._lock:
+            if self._listening:
+                return
+            self._listening = True
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(
+            self._charge_jax_duration)
+
+    def _charge_jax_duration(self, event, seconds, fun_name=None, **_):
+        """The program's one ``jax.monitoring`` listener: JAX names the
+        tracing event by the function and the two later ones by
+        ``jit(<function>)``; anything else (the benchmark's own jits,
+        eager ops) is not the engine's."""
+        span, key = self._first_call, JAX_DURATIONS.get(event)
+        if span is None or key is None or fun_name not in (
+                span.fun_name, "jit(%s)" % span.fun_name):
+            return
+        span.args[key] = span.args.get(key, 0.0) + seconds
 
     def current_phase(self):
         """The innermost open span's name (any thread), or None."""
@@ -211,12 +275,16 @@ class SpanTracer:
         return path
 
     def summary(self):
-        """Aggregate by span name: {name: {calls, total_ms, min_ms,
-        max_ms, ave_ms}} — the reference profiler's summary-table rows
-        (reference: platform/profiler.cc PrintProfiler)."""
+        """Aggregate by span name: {name: {calls, total_ms, self_ms,
+        min_ms, max_ms, ave_ms}} — the reference profiler's summary-table
+        rows (reference: platform/profiler.cc PrintProfiler); ``self_ms``
+        is ``self_time``'s."""
         agg = {}
-        for s in self.spans():
+        spans = self.spans()
+        own = self_time(spans)
+        for s in spans:
             row = agg.setdefault(s.name, {"calls": 0, "total_ms": 0.0,
+                                          "self_ms": own[s.name] / 1e3,
                                           "min_ms": None, "max_ms": None})
             ms = s.dur_us / 1e3
             row["calls"] += 1
@@ -230,13 +298,44 @@ class SpanTracer:
         return agg
 
 
+def self_time(spans):
+    """{span name: microseconds} of self time: each span's duration minus
+    the part of it that its child spans on the same thread cover, summed
+    by name. A child is a span that lies within another of its thread;
+    zero-duration events count for nothing."""
+    out = {}
+    by_tid = {}
+    for s in spans:
+        out.setdefault(s.name, 0.0)
+        if s.dur_us > 0.0:
+            by_tid.setdefault(s.tid, []).append(s)
+    for rows in by_tid.values():
+        # a parent starts no later and ends no earlier than its child;
+        # of two spans with one start the longer is the parent
+        rows.sort(key=lambda s: (s.ts_us, -s.dur_us))
+        stack = []  # the open ancestors: (end_us, name)
+        for s in rows:
+            end = s.ts_us + s.dur_us
+            # the clock's rounding may end a child a hair after its parent
+            while stack and stack[-1][0] < end - 1e-3:
+                stack.pop()
+            if stack:
+                out[stack[-1][1]] -= s.dur_us
+            out[s.name] += s.dur_us
+            stack.append((end, s.name))
+    return out
+
+
 def xplane_to_chrome_trace(trace_dir, line_filter=None):
     """-> chrome-trace dict {"traceEvents": [...], "displayTimeUnit":
     "ms"} from every distinct .xplane.pb under ``trace_dir``
     (byte-identical duplicate dumps are skipped by the shared plane
     iterator). Every plane becomes a chrome "process", every line a
     "thread", events map to complete ("X") slices with microsecond
-    timestamps sharing the epoch wall clock the host spans use.
+    timestamps on the profiler's own clock (``ProfileData`` counts from
+    the session's start, not from the epoch): the host spans that line up
+    with the device lanes are the ``pt.<name>`` events the session wrote
+    on ``/host:CPU``, in the same file.
     ``line_filter`` (substring, e.g. "XLA Ops") keeps matching lines
     only. Folded in from tools/timeline.py so the package owns ONE
     trace-export entry point (``dump_chrome_trace(path, xplane_dir)``);
@@ -247,47 +346,59 @@ def xplane_to_chrome_trace(trace_dir, line_filter=None):
     for pid, plane in enumerate(iter_planes(trace_dir), start=1):
         events.append({"name": "process_name", "ph": "M", "pid": pid,
                        "args": {"name": plane.name}})
-        meta = {m.id: m.name for m in plane.event_metadata.values()}
         for tid, line in enumerate(plane.lines):
             if line_filter and line_filter not in line.name:
                 continue
             events.append({"name": "thread_name", "ph": "M",
                            "pid": pid, "tid": tid,
                            "args": {"name": line.name}})
-            t0_ns = line.timestamp_ns
             for e in line.events:
                 events.append({
-                    "name": meta.get(e.metadata_id, "?"),
+                    "name": e.name,
                     "ph": "X",
                     "pid": pid,
                     "tid": tid,
-                    "ts": (t0_ns + e.offset_ps / 1e3) / 1e3,  # us
-                    "dur": e.duration_ps / 1e6,               # us
+                    "ts": e.start_ns / 1e3,     # us
+                    "dur": e.duration_ns / 1e3,  # us
                 })
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 
 class _Span:
     """RAII span: start on __enter__, record on __exit__ (also usable as
-    a decorator-free plain object for manual begin/end)."""
+    a decorator-free plain object for manual begin/end). While a profiler
+    session is on it is written into the profiler's trace too."""
 
-    __slots__ = ("tracer", "name", "args", "_t0_ns", "_depth")
+    __slots__ = ("tracer", "name", "args", "step_num", "_t0_ns", "_depth",
+                 "_annotation")
 
-    def __init__(self, tracer, name, args):
+    def __init__(self, tracer, name, args, step_num=None):
         self.tracer = tracer
         self.name = name
         self.args = args or None
+        self.step_num = step_num
 
     def __enter__(self):
         stack = self.tracer._stack()
         self._depth = len(stack)
         stack.append(self)
         self.tracer._phase_name = self.name
+        self._annotation = None
+        if session_on():
+            if self.step_num is None:
+                self._annotation = TraceAnnotation(
+                    "pt." + self.name, **(self.args or {}))
+            else:
+                self._annotation = StepTraceAnnotation(
+                    "pt." + self.name, step_num=self.step_num)
+            self._annotation.__enter__()
         self._t0_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur_ns = time.perf_counter_ns() - self._t0_ns
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         stack = self.tracer._stack()
         if stack and stack[-1] is self:
             stack.pop()
@@ -296,3 +407,28 @@ class _Span:
             self.name, (_EPOCH_ANCHOR_NS + self._t0_ns) / 1e3,
             dur_ns / 1e3, threading.get_ident(), self._depth, self.args))
         return False
+
+
+class _SeamSpan(_Span):
+    """A span of the cache-miss seam (``SpanTracer.seam_span``)."""
+
+    __slots__ = ("fun_name", "_outer")
+
+    def __init__(self, tracer, name, args, fun_name=None):
+        super().__init__(tracer, name, args)
+        self.fun_name = fun_name
+        if fun_name is not None:
+            self.args = dict(args, fun_name=fun_name)
+
+    def __enter__(self):
+        self.tracer.seam_open += 1
+        if self.fun_name is not None:
+            self._outer, self.tracer._first_call = (
+                self.tracer._first_call, self)
+        return super().__enter__()
+
+    def __exit__(self, exc_type, exc, tb):
+        self.tracer.seam_open -= 1
+        if self.fun_name is not None:
+            self.tracer._first_call = self._outer
+        return super().__exit__(exc_type, exc, tb)

@@ -8,10 +8,11 @@ Drives BOTH halves of the telemetry stack together:
   tools/timeline.py (the CUPTI + chrome-trace pipeline of the
   reference, SURVEY.md §5);
 * the **host** half: paddle_tpu.observability spans (step → trace →
-  transform/lower → compile/run) and the metrics registry.
-  ``start_profiler`` forces the host collectors on for the session even
-  when ``PADDLE_TPU_METRICS`` is down; ``stop_profiler`` restores the
-  flag-controlled gate.
+  transform/lower → compile/run) and the metrics registry. The session
+  itself switches the spans on, and they land in the device trace too
+  (``pt.<name>`` on ``/host:CPU``); ``start_profiler`` also forces the
+  metric collectors on for the session even when ``PADDLE_TPU_METRICS``
+  is down, and ``stop_profiler`` restores the flag-controlled gate.
 
 ``stop_profiler(sorted_key, profile_path)`` writes the host-span summary
 table to ``profile_path`` sorted by ``sorted_key`` (calls / total / max /
@@ -56,7 +57,8 @@ def start_profiler(state="All", tracer_option=None):
 def summary_table(sorted_key=None):
     """The host-span summary as text (reference:
     platform/profiler.cc PrintProfiler's table): one row per span name
-    with calls / total / min / max / ave milliseconds."""
+    with calls / total / self (``tracing.self_time``) / min / max / ave
+    milliseconds."""
     if sorted_key not in _SORT_KEYS:
         raise ValueError(
             "sorted_key must be one of %s, got %r"
@@ -66,13 +68,13 @@ def summary_table(sorted_key=None):
     field = _SORT_KEYS[sorted_key]
     if field is not None:
         rows.sort(key=lambda kv: kv[1][field], reverse=field != "min_ms")
-    lines = ["%-32s %8s %12s %12s %12s %12s"
-             % ("Event", "Calls", "Total(ms)", "Min(ms)", "Max(ms)",
-                "Ave(ms)")]
+    lines = ["%-32s %8s %12s %12s %12s %12s %12s"
+             % ("Event", "Calls", "Total(ms)", "Self(ms)", "Min(ms)",
+                "Max(ms)", "Ave(ms)")]
     for name, r in rows:
-        lines.append("%-32s %8d %12.3f %12.3f %12.3f %12.3f"
-                     % (name[:32], r["calls"], r["total_ms"], r["min_ms"],
-                        r["max_ms"], r["ave_ms"]))
+        lines.append("%-32s %8d %12.3f %12.3f %12.3f %12.3f %12.3f"
+                     % (name[:32], r["calls"], r["total_ms"], r["self_ms"],
+                        r["min_ms"], r["max_ms"], r["ave_ms"]))
     if not rows:
         lines.append("(no host spans recorded)")
     return "\n".join(lines)
@@ -103,6 +105,24 @@ def op_summary_text(table, top_k=15):
         "(unattributed %.3f ms, comm lane %.3f ms)"
         % (100.0 * table["attributed_frac"], table["total_ms"],
            table["unattributed_ms"], table["comm_ms"]))
+    total = table["total_ms"] or 1.0
+    lines += ["", "Device time by op type",
+              "%-36s %10s %6s" % ("op type", "ms", "%")]
+    by_type = sorted(table["by_type"].items(), key=lambda kv: -kv[1])
+    for op_type, ms in by_type[:top_k]:
+        lines.append("%-36s %10.3f %5.1f%%"
+                     % (str(op_type)[:36], ms, 100.0 * ms / total))
+    lines += ["", "Device time by phase (a fusion is booked whole to the "
+              "op XLA names it after)"]
+    phases = dict(table["by_phase"], unattributed=table["unattributed_ms"])
+    for phase in opprof.PHASES + ("unattributed",):
+        ms = phases.get(phase, 0.0)
+        lines.append("%-36s %10.3f %5.1f%%"
+                     % (phase, ms, 100.0 * ms / total))
+    for pair, ms in sorted(table["mixed_phase_ms"].items()):
+        lines.append("%-36s %10.3f %5.1f%%"
+                     % ("of it in %s fusions" % pair, ms,
+                        100.0 * ms / total))
     return "\n".join(lines)
 
 
@@ -204,7 +224,7 @@ def cuda_profiler(output_file, output_mode=None, config=None):
 @contextlib.contextmanager
 def record_event(name):
     """RAII span (reference: platform/profiler.h:82 RecordEvent) — lands
-    in BOTH timelines: a host observability span and a device-trace
-    annotation the xplane dump attributes kernels to."""
-    with observability.span(name), jax.profiler.TraceAnnotation(name):
+    in BOTH timelines: a host observability span, which a profiler
+    session also writes into the device trace as ``pt.<name>``."""
+    with observability.span(name):
         yield

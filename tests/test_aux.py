@@ -235,19 +235,10 @@ class TestProfiler:
         """tools/timeline.py converts a jax profiler xplane dump into
         chrome://tracing JSON (capability parity with the reference
         repo's tools/timeline.py — same workflow: profile, convert,
-        open in the trace viewer)."""
+        open in the trace viewer). The dump is read through
+        ``jax.profiler.ProfileData``: no TensorFlow is needed."""
         import json
-        import os
 
-        os.environ.setdefault(
-            "PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION", "python")
-        try:
-            from tensorflow.tsl.profiler.protobuf import (  # noqa: F401
-                xplane_pb2)
-        except Exception as e:  # pragma: no cover
-            import pytest
-
-            pytest.skip("xplane proto unavailable: %s" % e)
         import jax
         import jax.numpy as jnp
 

@@ -285,29 +285,42 @@ def test_profiled_mlp_attribution(tmp_path):
         opprof.reset()
 
 
-def test_attribute_joins_synthetic_xplane_against_sidecar(tmp_path):
+def _write_synthetic_xplane(path, events):
+    """A device plane with an ``XLA Ops`` line of ``events`` ((name,
+    milliseconds), back to back), written as an ``.xplane.pb`` with
+    nothing but JAX: ``ProfileData`` turns the text form of the proto
+    into its bytes."""
+    from jax.profiler import ProfileData
+
+    meta, rows, at = [], [], 0
+    for i, (name, ms) in enumerate(events, start=1):
+        ps = int(ms * 1e9)
+        meta.append('event_metadata { key: %d value { id: %d name: "%s" } }'
+                    % (i, i, name))
+        rows.append("events { metadata_id: %d offset_ps: %d "
+                    "duration_ps: %d }" % (i, at, ps))
+        at += ps
+    text = ('planes { name: "/device:TPU:0 (synthetic)" '
+            'lines { name: "XLA Ops" timestamp_ns: 1000 %s } %s }'
+            % (" ".join(rows), " ".join(meta)))
+    with open(path, "wb") as f:
+        f.write(ProfileData.text_proto_to_serialized_xspace(text))
+
+
+# the trace names an event by its instruction's whole text on the chip,
+# and by the instruction's name alone elsewhere: both must join
+@pytest.mark.parametrize("multiply_event", [
+    "%multiply.1",
+    "%multiply.1 = f32[8]{0} multiply(f32[8]{0} %p0, f32[8]{0} %p0)"])
+def test_attribute_joins_synthetic_xplane_against_sidecar(tmp_path,
+                                                          multiply_event):
     """Offline attribution: a hand-built device plane + sidecar joins
     deterministically (perf_report --roofline runs out-of-process, no
     live registry) — tagged time lands on its op, untagged time in the
-    explicit unattributed bucket, and fused-away ops seed 0-ms rows."""
-    os.environ.setdefault(
-        "PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION", "python")
-    xplane_pb2 = pytest.importorskip(
-        "tensorflow.tsl.profiler.protobuf.xplane_pb2")
-
-    xs = xplane_pb2.XSpace()
-    plane = xs.planes.add()
-    plane.name = "/device:TPU:0 (synthetic)"
-    for mid, name in ((1, "%multiply.1"), (2, "%copy.7")):
-        plane.event_metadata[mid].id = mid
-        plane.event_metadata[mid].name = name
-    line = plane.lines.add()
-    line.name = "XLA Ops"
-    for mid, ms in ((1, 3.0), (2, 1.0)):
-        ev = line.events.add()
-        ev.metadata_id = mid
-        ev.duration_ps = int(ms * 1e9)
-    (tmp_path / "host.xplane.pb").write_bytes(xs.SerializeToString())
+    explicit unattributed bucket, and fused-away ops seed 0-ms rows.
+    The plane is read through ``jax.profiler.ProfileData``."""
+    _write_synthetic_xplane(str(tmp_path / "host.xplane.pb"),
+                            [(multiply_event, 3.0), ("%copy.7", 1.0)])
 
     sidecar = {
         "policy": "dominant",
@@ -331,3 +344,49 @@ def test_attribute_joins_synthetic_xplane_against_sidecar(tmp_path):
     assert table["total_ms"] == pytest.approx(4.0)
     assert table["unattributed_ms"] == pytest.approx(1.0)
     assert table["attributed_frac"] == pytest.approx(0.75)
+    assert table["by_type"] == {"mul": pytest.approx(3.0)}
+    assert table["by_phase"] == {"forward": pytest.approx(3.0)}
+
+
+def test_synthetic_xplane_splits_device_time_by_phase(tmp_path):
+    """Device time by op type and by phase from the op_role the registry
+    keeps for each tag; a fusion whose members come from two phases is
+    counted under ``mixed_phase_ms``; twin planes are read once."""
+    from paddle_tpu.framework import OpRole
+
+    events = [("%dot.1", 4.0), ("%dot.2", 6.0), ("%fusion.3", 2.0),
+              ("%copy.7", 1.0)]
+    _write_synthetic_xplane(str(tmp_path / "a.xplane.pb"), events)
+    _write_synthetic_xplane(str(tmp_path / "b.xplane.pb"), events)
+    rows, total = opprof.top_ops(str(tmp_path))
+    assert total == pytest.approx(13.0)  # not 26: the twin is skipped
+    assert rows[0] == ("%dot.2", pytest.approx(6.0))
+
+    def cost(op_type, role):
+        return {"op_type": op_type, "op_role": int(role), "flops": 0,
+                "bytes": 0, "src_ops": [op_type]}
+
+    sidecar = {
+        "instr_tags": {"dot.1": "pt.mul.0_0", "dot.2": "pt.mul_grad.0_5",
+                       "fusion.3": "pt.adam.0_9", "copy.7": None},
+        "instr_kinds": {},
+        "costs": {"pt.mul.0_0": cost("mul", OpRole.Forward),
+                  "pt.mul_grad.0_5": cost("mul_grad", OpRole.Backward),
+                  "pt.adam.0_9": cost("adam", OpRole.Optimize)},
+        "mixed_phase": {"fusion.3": "backward+optimizer"},
+    }
+    table = opprof.attribute(str(tmp_path), sidecar=sidecar)
+    assert table["by_type"] == {"mul": pytest.approx(4.0),
+                                "mul_grad": pytest.approx(6.0),
+                                "adam": pytest.approx(2.0)}
+    assert table["by_phase"] == {"forward": pytest.approx(4.0),
+                                 "backward": pytest.approx(6.0),
+                                 "optimizer": pytest.approx(2.0)}
+    assert table["mixed_phase_ms"] == {
+        "backward+optimizer": pytest.approx(2.0)}
+    assert table["unattributed_ms"] == pytest.approx(1.0)
+    from paddle_tpu import profiler
+
+    text = profiler.op_summary_text(table)
+    assert "Device time by op type" in text and "mul_grad" in text
+    assert "Device time by phase" in text and "optimizer" in text

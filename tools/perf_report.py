@@ -22,12 +22,11 @@ slowest-worker attribution, per-worker heartbeat ages (which rank went
 quiet or stalled first), and each worker's aggregate HBM watermarks.
 
 Usage:
-    PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION=python \\
-        python tools/perf_report.py HOST_TRACE.json [XPLANE_DIR] [--top N]
+    python tools/perf_report.py HOST_TRACE.json [XPLANE_DIR] [--top N]
     python tools/perf_report.py --merge DUMP_DIR
 
-With no XPLANE_DIR (or without the xplane protos installed) the report
-is host-only.
+With no XPLANE_DIR the report is host-only (the device planes are read
+through ``jax.profiler.ProfileData``).
 """
 import argparse
 import json
@@ -537,8 +536,6 @@ def report(host_path, xplane_dir=None, top_n=15):
 
 
 def main(argv=None):
-    os.environ.setdefault(
-        "PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION", "python")
     p = argparse.ArgumentParser(
         description="Merged host-span + device-op perf report")
     p.add_argument("host_trace", nargs="?", default=None,

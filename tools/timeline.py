@@ -9,8 +9,7 @@ spans + device planes into a single perfetto view). This CLI is kept
 for the reference workflow (reference repo's tools/timeline.py:36 —
 convert a profiler dump, open in chrome://tracing):
 
-Usage: PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION=python \
-           python tools/timeline.py <trace_dir> <out.json> [line_filter]
+Usage: python tools/timeline.py <trace_dir> <out.json> [line_filter]
 """
 import json
 import os

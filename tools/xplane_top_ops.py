@@ -8,8 +8,7 @@ Thin CLI shim: the plane iterator and aggregation live in
 tools/); ``iter_planes``/``top_ops`` are re-exported here for
 back-compat with older scripts.
 
-Usage: PROTOCOL_BUFFERS_PYTHON_IMPLEMENTATION=python \
-           python tools/xplane_top_ops.py <trace_dir> [top_n] [group]
+Usage: python tools/xplane_top_ops.py <trace_dir> [top_n] [group]
 ``group``: 'op' (default, per fused-computation name) or 'kind'
 (collapse to the HLO opcode-ish prefix, e.g. fusion/copy/convolution).
 """
