@@ -251,7 +251,7 @@ def phase_train_bert(run):
 
 
 def _kernels_against_f32(on_tpu, batch, cfg):
-    """The three kernels alone at the step's attention shape, on seeded
+    """The flash kernels alone at the step's attention shape, on seeded
     normal inputs: output and dQ/dK/dV against plain f32 attention."""
     import jax.numpy as jnp
 

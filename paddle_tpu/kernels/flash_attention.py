@@ -6,12 +6,18 @@ MXU-shaped contractions (the kernel the reference implements as
 math/softmax.cu + matmuls, fused here instead; fused-op strategy per
 paddle/fluid/operators/fused/).
 
-Backward is the FlashAttention-2 decomposition: a cheap XLA delta
-precompute (rowsum(dO*O)), a dQ kernel (Q block resident, K/V streamed)
-and a dK/dV kernel (K/V block resident, Q streamed), all re-deriving the
-softmax from the saved logsumexp instead of materializing the [Tq, Tk]
-probability matrix. The plain-XLA recompute path remains the fallback
-(PADDLE_TPU_FLASH_BWD=xla, or shapes the kernels cannot tile).
+Backward re-derives the softmax from the saved logsumexp instead of
+materializing the [Tq, Tk] probability matrix: a cheap XLA delta
+precompute (rowsum(dO*O)), then ONE kernel with a K/V block resident and
+Q streamed that derives s, p, dp and ds once per tile and feeds dQ, dK
+and dV from them (5 tile matmuls, 1 exp pass); dQ accumulates over the K
+blocks in a full-length [Tq, D] f32 VMEM scratch. Where that accumulator
+does not fit (``_bwd_fused_fits``, reckoned from the static shapes: past
+8192 positions in bf16 at head size 64) the FlashAttention-2 pair runs in
+its place, a dQ kernel (Q block resident, K/V streamed) and the same
+K/V-resident kernel without its dQ part (7 matmuls, 2 exp passes, VMEM
+bounded by the blocks alone). The plain-XLA recompute path remains the
+fallback (PADDLE_TPU_FLASH_BWD=xla, or shapes the kernels cannot tile).
 
 Mosaic layout notes (what made round-2's kernels fail to lower on the
 real chip): every block's last two dims must be (8, 128)-tileable or span
@@ -27,7 +33,7 @@ Masking is TPU-first: key-padding masks are passed as per-sequence
 tensors — the kernel compares against a key-position iota. Causal masking
 is a static flag. Attention dropout runs *inside* the kernel using a
 counter-based hash RNG (murmur3 finalizer over the global (batch, q, k)
-coordinate), so the forward and both backward kernels regenerate the
+coordinate), so the forward and every backward kernel regenerate the
 identical mask from (seed, coords) with no [Tq, Tk] mask ever stored.
 
 ``fused_attention`` is the dispatch point: the Pallas kernel on TPU (or in
@@ -58,8 +64,8 @@ def _smem_spec():
 def _keep_mask(seed, b, q_pos, k_pos, t_k, rate):
     """Deterministic dropout keep-mask from the *global* (b, q, k)
     coordinate: murmur3 finalizer bits -> uniform [0,1) -> >= rate.
-    Counter-based, so the dQ and dK/dV kernels reproduce the forward's
-    mask exactly regardless of their different iteration orders.
+    Counter-based, so every backward kernel reproduces the forward's
+    mask exactly regardless of its iteration order.
 
     (Round-5 measured the in-kernel dropout at ~25% of whole-kernel time
     and tried a strip-hoisted 1-multiply variant of this hash: the
@@ -308,23 +314,39 @@ def _bwd_dq_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
-                    lse_ref, delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                    block_q, block_k, causal, scale, rate, masked):
-    """dK/dV with the Q dimension STREAMED over the innermost grid axis
-    (grid = (B*H, Tk/block_k, Tq/block_q)) and f32 accumulation in VMEM
-    scratch — the earlier form held full-length Q/dO/lse/delta resident
-    per program, so its VMEM footprint grew linearly with Tq and capped
-    trainable context at ~2-4k tokens (seq-4096+dropout exceeded the 16MB
-    scoped limit by 672KB; seq-8192 by 8.75MB). TPU grids iterate
-    sequentially, so the accumulator pattern (zero at j==0, emit at
-    j==nq-1) is the standard one — cf. the public pallas flash kernel's
-    block_q_major streaming (jax.experimental.pallas.ops.tpu)."""
+def _bwd_fused_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref,
+                      do_ref, lse_ref, delta_ref, *outs_and_scratch, with_dq,
+                      block_q, block_k, causal, scale, rate, masked):
+    """The backward with a K/V tile resident and the Q dimension STREAMED
+    over the innermost grid axis (grid = (B*H, Tk/block_k, Tq/block_q)),
+    f32 accumulation in VMEM scratch — the earlier form held full-length
+    Q/dO/lse/delta resident per program, so its VMEM footprint grew
+    linearly with Tq and capped trainable context at ~2-4k tokens
+    (seq-4096+dropout exceeded the 16MB scoped limit by 672KB; seq-8192
+    by 8.75MB). TPU grids iterate sequentially, so the accumulator
+    pattern (zero at j==0, emit at j==nq-1) is the standard one — cf. the
+    public pallas flash kernel's block_q_major streaming
+    (jax.experimental.pallas.ops.tpu).
+
+    ``with_dq=False`` is the dK/dV kernel of the two-kernel form. With
+    ``with_dq=True`` the same pass over the scores also feeds dQ: s, p, dp
+    and ds are derived once per tile (5 matmuls and 1 exp pass where the
+    pair runs 7 and 2), and each tile's ``ds @ k`` is added into rows
+    ``j*block_q...`` of a full-length ``[Tq, D]`` f32 accumulator, which
+    is written out once per (batch, head) at the last (k block, q block)
+    step — the dQ output block spans Tq and its index moves with ``b``
+    alone, so it is flushed once and not per K block. That accumulator is
+    why this form fits only up to a length (``_bwd_fused_fits``)."""
+    if with_dq:
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = outs_and_scratch
+    else:
+        dk_ref, dv_ref, dk_acc, dv_acc = outs_and_scratch
     b = pl.program_id(0)
     s_idx = pl.program_id(1)
     j = pl.program_id(2)
+    ns = pl.num_programs(1)
     nq = pl.num_programs(2)
-    t_k = dk_ref.shape[1] * pl.num_programs(1)
+    t_k = dk_ref.shape[1] * ns
     length = len_ref[b]
     seed = seed_ref[0]
     q_off, k_off = off_ref[0], off_ref[1]
@@ -333,6 +355,11 @@ def _bwd_dkv_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    if with_dq:
+        @pl.when(jnp.logical_and(s_idx == 0, j == 0))
+        def _init_dq():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
 
     def compute():
         k_blk = k_ref[0]                       # [block_k, D]
@@ -370,10 +397,15 @@ def _bwd_dkv_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
             preferred_element_type=jnp.float32)
         if keep is not None:
             dp = jnp.where(keep, dp, 0.0) * inv
-        ds = p * (dp - delta) * scale
+        ds = (p * (dp - delta) * scale).astype(q.dtype)
         dk_acc[...] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+        if with_dq:
+            rows = pl.ds(pl.multiple_of(j * block_q, block_q), block_q)
+            dq_acc[rows, :] += jax.lax.dot_general(
+                ds, k_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
 
     if causal:
         # q blocks whose last global row is before this k block's first
@@ -389,24 +421,58 @@ def _bwd_dkv_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
+    if with_dq:
+        @pl.when(jnp.logical_and(s_idx == ns - 1, j == nq - 1))
+        def _emit_dq():
+            dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+
+
+# What the fused backward may ask of VMEM, handed to Mosaic as the
+# kernel's limit (the default scoped limit is 16 MiB of the chip's 128).
+_FUSED_BWD_VMEM_LIMIT = 32 * 2 ** 20
+
+
+def _bwd_fused_fits(tq, d, dtype, block_q, block_k, rate=0.0):
+    """Whether the one-kernel backward applies, reckoned from the static
+    shapes: the VMEM that grows with the sequence (the f32 dQ accumulator
+    and its double-buffered output block), the streamed and resident
+    blocks and the tile's intermediates, minor dims padded to 128 lanes,
+    within seven eighths of the kernel's limit. Longer sequences run the
+    two kernels, whose VMEM does not grow with the length."""
+    item = jnp.dtype(dtype).itemsize
+    lanes = -(-d // 128) * 128
+    dq = tq * lanes * (4 + 2 * item)
+    streamed = 2 * block_q * (2 * lanes * item + 2 * 128 * 4)
+    resident = block_k * lanes * (2 * 4 * item + 2 * 4)
+    # what Mosaic keeps of a tile: the scores in f32, p and ds in the
+    # MXU's operand dtype, the dropout mask as a word (compiled for a
+    # v5e, bf16 and f32, 256..1024 squared, 2048..16384 positions: the
+    # least limit that compiles is 0 to 6 MiB under this sum)
+    tile = block_q * block_k * (4 + 2 * item + (4 if rate > 0.0 else 0))
+    return (dq + streamed + resident + tile
+            <= _FUSED_BWD_VMEM_LIMIT * 7 // 8)
+
 
 def _flash_backward(q, k, v, out, lse, g, g_lse, seq_lens, offsets, seed,
-                    causal, scale, rate, block_q, block_k, interpret,
-                    dq_blocks=None, dkv_blocks=None):
-    """``dq_blocks``/``dkv_blocks``: optional (block_q, block_k) overrides
-    per backward kernel — the two have different residency patterns (dQ
-    keeps the Q tile resident and streams K/V; dK/dV the reverse), so the
-    block sweep tunes them independently (VERDICT r4 Next #4)."""
+                    causal, scale, rate, block_q, block_k, interpret):
+    """dQ, dK, dV from the saved (out, lse). One kernel where its
+    full-length dQ accumulator fits VMEM (``_bwd_fused_fits``, from the
+    static shapes; blocks from ``pick_bwd_blocks``), the dQ and dK/dV
+    kernels at the caller's blocks beyond. The counters
+    ``flash.bwd_fused`` / ``flash.bwd_split`` count the lowered calls of
+    each form."""
+    from paddle_tpu import observability as obs
+
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     qr = q.reshape(B * H, Tq, D)
     kr = k.reshape(B * H, Tk, D)
     vr = v.reshape(B * H, Tk, D)
     do = g.reshape(B * H, Tq, D)
-    bq_dq, bk_dq = dq_blocks or (block_q, block_k)
-    bq_kv, bk_kv = dkv_blocks or (block_q, block_k)
-    bq_dq, bk_dq = min(bq_dq, Tq), min(bk_dq, Tk)
-    bq_kv, bk_kv = min(bq_kv, Tq), min(bk_kv, Tk)
+    bq, bk = min(block_q, Tq), min(block_k, Tk)
+    fused_blocks = pick_bwd_blocks(Tq, Tk, q.dtype, (bq, bk))
+    fused = _bwd_fused_fits(Tq, D, q.dtype, *fused_blocks, rate)
+    obs.inc("flash.bwd_fused" if fused else "flash.bwd_split")
 
     masked = seq_lens is not None
     if masked:
@@ -427,31 +493,37 @@ def _flash_backward(q, k, v, out, lse, g, g_lse, seq_lens, offsets, seed,
     if g_lse is not None:
         delta = delta - g_lse.reshape(B * H, Tq).astype(jnp.float32)
     delta = jnp.broadcast_to(delta[..., None], (B * H, Tq, _LSE_LANES))
+    args = (lens, seed_arr, off_arr, qr, kr, vr, do, lse, delta)
+    static = dict(causal=causal, scale=scale, rate=rate, masked=masked)
 
-    _kvmap_dq = _stream_kvmap(bq_dq, bk_dq, causal, offsets)
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, block_q=bq_dq, block_k=bk_dq,
-                          causal=causal, scale=scale, rate=rate,
-                          masked=masked, t_k=Tk),
-        out_shape=jax.ShapeDtypeStruct(qr.shape, q.dtype),
-        grid=(B * H, Tq // bq_dq, Tk // bk_dq),
-        in_specs=[
-            _smem_spec(),
-            _smem_spec(),
-            _smem_spec(),
-            pl.BlockSpec((1, bq_dq, D), lambda b, j, s: (b, j, 0)),
-            pl.BlockSpec((1, bk_dq, D), _kvmap_dq),
-            pl.BlockSpec((1, bk_dq, D), _kvmap_dq),
-            pl.BlockSpec((1, bq_dq, D), lambda b, j, s: (b, j, 0)),
-            pl.BlockSpec((1, bq_dq, _LSE_LANES),
-                         lambda b, j, s: (b, j, 0)),
-            pl.BlockSpec((1, bq_dq, _LSE_LANES),
-                         lambda b, j, s: (b, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bq_dq, D), lambda b, j, s: (b, j, 0)),
-        scratch_shapes=[pltpu.VMEM((bq_dq, D), jnp.float32)],
-        interpret=interpret,
-    )(lens, seed_arr, off_arr, qr, kr, vr, do, lse, delta)
+    if fused:
+        bq, bk = fused_blocks
+    else:
+        _kvmap = _stream_kvmap(bq, bk, causal, offsets)
+
+        def qspec(lanes):
+            return pl.BlockSpec((1, bq, lanes), lambda b, j, s: (b, j, 0))
+
+        dq = pl.pallas_call(
+            functools.partial(_bwd_dq_kernel, block_q=bq, block_k=bk,
+                              t_k=Tk, **static),
+            out_shape=jax.ShapeDtypeStruct(qr.shape, q.dtype),
+            grid=(B * H, Tq // bq, Tk // bk),
+            in_specs=[
+                _smem_spec(),
+                _smem_spec(),
+                _smem_spec(),
+                qspec(D),
+                pl.BlockSpec((1, bk, D), _kvmap),
+                pl.BlockSpec((1, bk, D), _kvmap),
+                qspec(D),
+                qspec(_LSE_LANES),
+                qspec(_LSE_LANES),
+            ],
+            out_specs=qspec(D),
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+            interpret=interpret,
+        )(*args)
 
     # q/do/lse/delta stream over the innermost grid axis (VMEM bounded by
     # the block size, not Tq — what makes seq >= 4096 compile). Causal
@@ -462,46 +534,51 @@ def _flash_backward(q, k, v, out, lse, g, g_lse, seq_lens, offsets, seed,
     # (traced) offsets keep the identity map — fetches for skipped steps
     # are wasted bandwidth but never wrong.
     if causal and offsets is None:
-        nq_kv = Tq // bq_kv
+        nq = Tq // bq
 
         def _qmap(b, s, j):
             # lower-clamp to the causal frontier, upper-clamp to the last
             # real Q block (Tk > Tq puts whole k blocks past every q —
             # the body is skipped there, but the fetch must stay in range)
-            return (b, jnp.minimum(jnp.maximum(j, (s * bk_kv) // bq_kv),
-                                   nq_kv - 1), 0)
+            return (b, jnp.minimum(jnp.maximum(j, (s * bk) // bq), nq - 1),
+                    0)
     else:
         def _qmap(b, s, j):
             return (b, j, 0)
-    scratch = [pltpu.VMEM((bk_kv, D), jnp.float32),
-               pltpu.VMEM((bk_kv, D), jnp.float32)]
-    dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, block_q=bq_kv, block_k=bk_kv,
-                          causal=causal, scale=scale, rate=rate,
-                          masked=masked),
-        out_shape=[
-            jax.ShapeDtypeStruct(kr.shape, k.dtype),
-            jax.ShapeDtypeStruct(vr.shape, v.dtype),
-        ],
-        grid=(B * H, Tk // bk_kv, Tq // bq_kv),
+    kspec = pl.BlockSpec((1, bk, D), lambda b, s, j: (b, s, 0))
+    kv_shapes = [jax.ShapeDtypeStruct(kr.shape, k.dtype),
+                 jax.ShapeDtypeStruct(vr.shape, v.dtype)]
+    kv_scratch = [pltpu.VMEM((bk, D), jnp.float32),
+                  pltpu.VMEM((bk, D), jnp.float32)]
+    call = functools.partial(
+        pl.pallas_call,
+        functools.partial(_bwd_fused_kernel, with_dq=fused, block_q=bq,
+                          block_k=bk, **static),
+        grid=(B * H, Tk // bk, Tq // bq),
         in_specs=[
             _smem_spec(),
             _smem_spec(),
             _smem_spec(),
-            pl.BlockSpec((1, bq_kv, D), _qmap),
-            pl.BlockSpec((1, bk_kv, D), lambda b, s, j: (b, s, 0)),
-            pl.BlockSpec((1, bk_kv, D), lambda b, s, j: (b, s, 0)),
-            pl.BlockSpec((1, bq_kv, D), _qmap),
-            pl.BlockSpec((1, bq_kv, _LSE_LANES), _qmap),
-            pl.BlockSpec((1, bq_kv, _LSE_LANES), _qmap),
+            pl.BlockSpec((1, bq, D), _qmap),
+            kspec,
+            kspec,
+            pl.BlockSpec((1, bq, D), _qmap),
+            pl.BlockSpec((1, bq, _LSE_LANES), _qmap),
+            pl.BlockSpec((1, bq, _LSE_LANES), _qmap),
         ],
-        out_specs=[
-            pl.BlockSpec((1, bk_kv, D), lambda b, s, j: (b, s, 0)),
-            pl.BlockSpec((1, bk_kv, D), lambda b, s, j: (b, s, 0)),
-        ],
-        scratch_shapes=scratch,
-        interpret=interpret,
-    )(lens, seed_arr, off_arr, qr, kr, vr, do, lse, delta)
+        interpret=interpret)
+    if fused:
+        dq, dk, dv = call(
+            out_shape=[jax.ShapeDtypeStruct(qr.shape, q.dtype)] + kv_shapes,
+            out_specs=[pl.BlockSpec((1, Tq, D), lambda b, s, j: (b, 0, 0)),
+                       kspec, kspec],
+            scratch_shapes=[pltpu.VMEM((Tq, D), jnp.float32)] + kv_scratch,
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_FUSED_BWD_VMEM_LIMIT),
+        )(*args)
+    else:
+        dk, dv = call(out_shape=kv_shapes, out_specs=[kspec, kspec],
+                      scratch_shapes=kv_scratch)(*args)
 
     return (dq.reshape(B, H, Tq, D), dk.reshape(B, H, Tk, D),
             dv.reshape(B, H, Tk, D))
@@ -585,10 +662,10 @@ def _block_table():
 
 
 def _table_row(t, dtype):
-    """Nearest swept row for (dtype, seq); an int (one block for every
-    kernel) or a dict {"fwd": int, "dq": [bq, bk], "dkv": [bq, bk]} when
-    the backward kernels were swept independently (their residency
-    patterns differ: dQ keeps the Q tile resident, dK/dV the K/V tile)."""
+    """Nearest swept row for (dtype, seq): an int (one block for every
+    kernel) or a dict {"fwd": int, "bwd": [block_q, block_k]} where the
+    fused backward was swept apart from the forward (it keeps a K/V tile
+    and the whole dQ resident, the forward a Q tile)."""
     table = _block_table().get(
         jnp.dtype(dtype).name if dtype is not None else "bfloat16")
     if not table:
@@ -617,28 +694,19 @@ def pick_block(t, dtype=None):
 
 
 def pick_bwd_blocks(tq, tk, dtype, default):
-    """Independent (block_q, block_k) choices for the dQ and dK/dV
-    kernels (VERDICT r4 Next #4: the two have different residency
-    patterns, so the table MAY tune them apart from the forward). The
-    round-5 hardware sweep measured seq-2048 bf16 candidates
-    (256/512 combos per kernel) and found no winner outside session
-    noise — one-sided runs suggested bq 256/bk 512 at ~5% but an A-B
-    validation read identical medians — so the committed table keeps
-    shared blocks and this lookup is dormant capability for shapes where
-    a future sweep DOES separate them. Returns (dq_blocks, dkv_blocks);
-    any entry that does not tile the actual shapes falls back to
-    ``default`` (the caller's blocks), so explicit-block callers and
-    off-table shapes are never overridden incorrectly."""
+    """(block_q, block_k) of the fused backward kernel: the table's
+    ``bwd`` pair (tools/flash_block_sweep.py --bwd, on the chip) when
+    ``default``, the caller's blocks, are the table's own forward choice
+    and the pair tiles the shapes; ``default`` otherwise, so an explicit
+    block choice (e.g. to bound VMEM) and off-table shapes are never
+    overridden. The two-kernel form runs ``default``."""
     row = _table_row(tk, dtype)
-    out = []
-    for key in ("dq", "dkv"):
-        pair = row.get(key) if isinstance(row, dict) else None
-        if (isinstance(pair, (list, tuple)) and len(pair) == 2
-                and tq % int(pair[0]) == 0 and tk % int(pair[1]) == 0):
-            out.append((int(pair[0]), int(pair[1])))
-        else:
-            out.append(default)
-    return tuple(out)
+    pair = row.get("bwd") if isinstance(row, dict) else None
+    if (pair and default == (min(pick_block(tq, dtype), tq),
+                             min(pick_block(tk, dtype), tk))
+            and tq % int(pair[0]) == 0 and tk % int(pair[1]) == 0):
+        return int(pair[0]), int(pair[1])
+    return default
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
@@ -704,13 +772,11 @@ def _fa_bwd_core(q, k, v, out, lse_k, g_out, g_lse, seq_lens, offsets,
                  seed, causal, scale, rate, block_q, block_k, interpret):
     """Shared backward preamble for both custom_vjps: the
     PADDLE_TPU_FLASH_BWD=xla escape hatch (with its dropout/offset
-    guards), the table-driven per-kernel block choice, and the
-    _flash_backward dispatch. ``lse_k`` is the kernel-layout
+    guards) and the _flash_backward dispatch. ``lse_k`` is the kernel-layout
     [B*H, Tq, _LSE_LANES] residual; ``g_lse`` the public [B, H, Tq]
     cotangent (or None)."""
     scale_ = scale if scale is not None else q.shape[-1] ** -0.5
-    Tq, Tk = q.shape[2], k.shape[2]
-    bq, bk = min(block_q, Tq), min(block_k, Tk)
+    Tq = q.shape[2]
     if _use_xla_bwd():
         if rate > 0.0:
             raise RuntimeError(
@@ -733,18 +799,9 @@ def _fa_bwd_core(q, k, v, out, lse_k, g_out, g_lse, seq_lens, offsets,
                                                   scale_, seq_lens),
             q, k, v)
         return vjp((g_out, gl))
-    # table-driven per-kernel blocks apply ONLY when the caller used the
-    # table's own forward defaults — an explicit block choice (e.g. to
-    # bound VMEM) is never overridden
-    if (bq, bk) == (min(pick_block(Tq, q.dtype), Tq),
-                    min(pick_block(Tk, q.dtype), Tk)):
-        dq_blocks, dkv_blocks = pick_bwd_blocks(Tq, Tk, q.dtype, (bq, bk))
-    else:
-        dq_blocks = dkv_blocks = (bq, bk)
     return _flash_backward(q, k, v, out, lse_k, g_out, g_lse, seq_lens,
-                           offsets, seed, causal, scale_, rate, bq, bk,
-                           interpret, dq_blocks=dq_blocks,
-                           dkv_blocks=dkv_blocks)
+                           offsets, seed, causal, scale_, rate, block_q,
+                           block_k, interpret)
 
 
 def _fa_bwd(causal, scale, rate, block_q, block_k, interpret, res, g):
@@ -983,8 +1040,7 @@ def dispatch_attention_lse(q, k, v, causal=False, scale=None, seq_lens=None,
 
 
 def flash_backward_spmd(q, k, v, out, lse_k, g, seq_lens, seed, causal,
-                        scale, rate, block_q, block_k, interpret,
-                        dq_blocks=None, dkv_blocks=None):
+                        scale, rate, block_q, block_k, interpret):
     """``_flash_backward`` for the registered grad op, shard_mapped over
     the active mesh's data/tp axes when an SPMD lowering context is up
     (per-(batch, head) independence makes the wrap exact — the same
@@ -998,8 +1054,7 @@ def flash_backward_spmd(q, k, v, out, lse_k, g, seq_lens, seed, causal,
     if spmd is None:
         return _flash_backward(q, k, v, out, lse_k, g, None, seq_lens,
                                None, seed, causal, scale, rate, block_q,
-                               block_k, interpret, dq_blocks=dq_blocks,
-                               dkv_blocks=dkv_blocks)
+                               block_k, interpret)
     mesh, batch_axes, head_axis = spmd
     from jax.sharding import PartitionSpec as P
 
@@ -1015,7 +1070,7 @@ def flash_backward_spmd(q, k, v, out, lse_k, g, seq_lens, seed, causal,
         return _flash_backward(
             q_, k_, v_, out_, lse4_.reshape(Bl * Hl, Tq, -1), g_, None,
             lens_, None, seed_, causal, scale, rate, block_q, block_k,
-            interpret, dq_blocks=dq_blocks, dkv_blocks=dkv_blocks)
+            interpret)
 
     out_specs = (qspec, qspec, qspec)
     if seq_lens is not None:

@@ -417,8 +417,9 @@ def fused_attention_op(ctx, ins, attrs):
 @register_no_grad_op("fused_attention_grad", needs_rng=True)
 def fused_attention_grad_op(ctx, ins, attrs):
     """Direct attention backward. When the forward took the Pallas path
-    and saved (Out, Lse), this calls the FlashAttention-2 backward
-    kernels with the saved softmax residuals — no forward re-execution.
+    and saved (Out, Lse), this calls the flash backward (one fused
+    kernel, or the dQ and dK/dV pair at long sequences) with the saved
+    softmax residuals — no forward re-execution.
     Every other branch (ring, XLA composition, a program built without
     the Lse output) differentiates the same forward dispatch inline,
     which is exactly what the generic vjp route did."""
@@ -427,8 +428,7 @@ def fused_attention_grad_op(ctx, ins, attrs):
                                                     dispatch_attention_lse,
                                                     flash_backward_spmd,
                                                     flash_dispatch_ok,
-                                                    pick_block,
-                                                    pick_bwd_blocks)
+                                                    pick_block)
 
     q, k, v, lens, rate, seed = _fused_attention_args(ctx, ins, attrs)
     causal = bool(attrs.get("causal", False))
@@ -459,15 +459,17 @@ def fused_attention_grad_op(ctx, ins, attrs):
         lse_k = jnp.broadcast_to(
             jnp.asarray(lse, jnp.float32).reshape(B * H, Tq, -1)[..., :1],
             (B * H, Tq, _LSE_LANES))
-        dq_blocks, dkv_blocks = pick_bwd_blocks(
-            Tq, Tk, q.dtype, (min(bq, Tq), min(bk, Tk)))
         # spmd-aware entry: under a mesh-targeted trace the backward
-        # kernels run shard_mapped over the same dp/tp decomposition the
-        # forward dispatch used; single-device traces call straight in
+        # runs shard_mapped over the same dp/tp decomposition the forward
+        # dispatch used; single-device traces call straight in. One
+        # Pallas call (dQ, dK and dV from a single pass over the scores)
+        # where the sequence lets the dQ accumulator stay in VMEM, the dQ
+        # and dK/dV kernels beyond: _flash_backward decides from the
+        # shapes, and takes the fused kernel's blocks from the table
         dq, dk, dv = flash_backward_spmd(
             q, k, v, out.astype(q.dtype), lse_k, g, lens,
             seed, causal, scale_, rate, min(bq, Tq), min(bk, Tk),
-            not _on_tpu(), dq_blocks=dq_blocks, dkv_blocks=dkv_blocks)
+            not _on_tpu())
         return {"Q@GRAD": [dq], "K@GRAD": [dk], "V@GRAD": [dv]}
 
     # program lacks the saved residuals (old desc) or took the XLA branch:

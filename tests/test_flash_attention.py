@@ -8,12 +8,35 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+import importlib
+
 from paddle_tpu.kernels import flash_attention
 from paddle_tpu.kernels.flash_attention import _xla_attention
+
+# the module: the package exports the function under the same name
+_fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
 
 
 def _rand(shape, seed=0):
     return np.random.RandomState(seed).randn(*shape).astype(np.float32)
+
+
+@pytest.fixture(params=["fused", "split"])
+def form(request, monkeypatch):
+    """Runs a backward test under each form of ``_flash_backward``: the
+    one kernel these small shapes take by themselves, and the dQ and
+    dK/dV pair that sequences too long for the resident dQ accumulator
+    take (steered here, in the test: the program has no switch). The
+    counters say afterwards that only the form asked for was lowered."""
+    from paddle_tpu import observability as obs
+
+    if request.param == "split":
+        monkeypatch.setattr(_fa, "_bwd_fused_fits", lambda *a: False)
+    obs.set_enabled(True)
+    yield request.param
+    other = {"fused": "split", "split": "fused"}[request.param]
+    assert obs.counter_value("flash.bwd_" + request.param) > 0
+    assert obs.counter_value("flash.bwd_" + other) == 0
 
 
 def _flash(q, k, v, causal=False, seq_lens=None, rate=0.0, seed=0,
@@ -34,7 +57,7 @@ class TestFlashAttentionKernel:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-4)
 
-    def test_gradients(self):
+    def test_gradients(self, form):
         B, H, T, D = 1, 2, 64, 16
         q, k, v = (_rand((B, H, T, D), s) for s in (3, 4, 5))
 
@@ -67,7 +90,7 @@ class TestSeqLensMask:
                                    atol=2e-5, rtol=2e-4)
 
     @pytest.mark.parametrize("causal", [False, True])
-    def test_grads_match_masked_xla(self, causal):
+    def test_grads_match_masked_xla(self, causal, form):
         B, H, T, D = 2, 2, 128, 16
         q, k, v = (_rand((B, H, T, D), s) for s in (3, 4, 5))
         lens = jnp.array([90, 128], jnp.int32)
@@ -97,7 +120,28 @@ class TestSeqLensMask:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-4)
 
-    def test_causal_cross_attention_grads_tk_gt_tq(self):
+    def test_cross_attention_grads_tq_gt_tk(self, form):
+        """Tq > Tk, masked, 4 Q blocks against 2 K blocks: every row of
+        the dQ accumulator is added to from more than one K block."""
+        B, H, Tq, Tk, D = 2, 2, 128, 64, 16
+        q = _rand((B, H, Tq, D), 0)
+        k, v = _rand((B, H, Tk, D), 1), _rand((B, H, Tk, D), 2)
+        lens = jnp.array([64, 23], jnp.int32)
+        g = jnp.asarray(_rand((B, H, Tq, D), 6))
+        _, vjp_f = jax.vjp(
+            lambda a, b, c: _flash(a, b, c, seq_lens=lens, block_q=32,
+                                   block_k=32),
+            *map(jnp.asarray, (q, k, v)))
+        _, vjp_r = jax.vjp(
+            lambda a, b, c: _xla_attention(a, b, c, False, D ** -0.5,
+                                           seq_lens=lens),
+            *map(jnp.asarray, (q, k, v)))
+        for got, want, name in zip(vjp_f(g), vjp_r(g), ("dq", "dk", "dv")):
+            np.testing.assert_allclose(
+                np.asarray(got), np.asarray(want), atol=5e-4, rtol=5e-3,
+                err_msg=name)
+
+    def test_causal_cross_attention_grads_tk_gt_tq(self, form):
         """Tk > Tq with causal=True: every k block past the last q row is
         a fully-skipped dkv grid step whose fetch index must clamp to the
         last REAL q block (the streamed-kernel regression case)."""
@@ -138,7 +182,7 @@ class TestInKernelDropout:
         assert np.abs(np.asarray(out1).mean()
                       - np.asarray(base).mean()) < 0.05
 
-    def test_dropout_gradients_finite_differences(self):
+    def test_dropout_gradients_finite_differences(self, form):
         """The analytic grads (backward kernels regenerating the hash mask)
         must match finite differences of the same stochastic-but-
         deterministic forward."""
@@ -286,7 +330,7 @@ class TestFlashBackwardKernel:
     @pytest.mark.parametrize("causal", [False, True])
     @pytest.mark.parametrize("T,bq,bk", [(128, 128, 128), (256, 128, 128),
                                          (128, 64, 32), (96, 32, 32)])
-    def test_grads_match_xla(self, causal, T, bq, bk):
+    def test_grads_match_xla(self, causal, T, bq, bk, form):
         B, H, D = 2, 2, 32
         q, k, v = (_rand((B, H, T, D), s) for s in (7, 8, 9))
         g = _rand((B, H, T, D), 10)
@@ -306,7 +350,38 @@ class TestFlashBackwardKernel:
                 np.asarray(got), np.asarray(want), atol=5e-4, rtol=5e-3,
                 err_msg=name)
 
-    def test_bf16_grads_finite_and_close(self):
+    @pytest.mark.parametrize("causal,masked", [(False, False), (False, True),
+                                               (True, False)])
+    @pytest.mark.parametrize("rate", [0.0, 0.1])
+    def test_fused_backward_matches_the_two_kernels(self, monkeypatch,
+                                                    causal, masked, rate):
+        """The one-kernel backward against the dQ and dK/dV pair on the
+        same saved (out, lse): Tq != Tk, 4 Q blocks by 3 K blocks. With
+        dropout both regenerate the forward's mask from the global
+        coordinate, so they agree as closely as without."""
+        from paddle_tpu.kernels.flash_attention import flash_attention_lse
+
+        B, H, Tq, Tk, D = 2, 2, 128, 96, 16
+        q = jnp.asarray(_rand((B, H, Tq, D), 0))
+        k, v = (jnp.asarray(_rand((B, H, Tk, D), s)) for s in (1, 2))
+        g = jnp.asarray(_rand((B, H, Tq, D), 3))
+        lens = jnp.array([96, 41], jnp.int32) if masked else None
+        out, lse = flash_attention_lse(q, k, v, lens, None, 5, causal, None,
+                                       rate, 32, 32, True)
+
+        def backward():
+            return _fa._flash_backward(
+                q, k, v, out, lse.reshape(B * H, Tq, 1), g, None, lens,
+                None, 5, causal, D ** -0.5, rate, 32, 32, True)
+
+        fused = backward()
+        monkeypatch.setattr(_fa, "_bwd_fused_fits", lambda *a: False)
+        split = backward()
+        for got, want, name in zip(fused, split, ("dq", "dk", "dv")):
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                       atol=1e-6, rtol=1e-6, err_msg=name)
+
+    def test_bf16_grads_finite_and_close(self, form):
         B, H, T, D = 1, 2, 128, 32
         q, k, v = (jnp.asarray(_rand((B, H, T, D), s), jnp.bfloat16)
                    for s in (1, 2, 3))
@@ -390,7 +465,7 @@ class TestChunkedLse:
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5, rtol=2e-4)
 
-    def test_chunked_gradients_including_lse_cotangent(self):
+    def test_chunked_gradients_including_lse_cotangent(self, form):
         """Differentiating through the merge sends a cotangent into lse;
         the backward kernels fold it into delta — grads must match the
         full-attention vjp."""
@@ -409,7 +484,7 @@ class TestChunkedLse:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=5e-4, rtol=5e-3, err_msg=name)
 
-    def test_unaligned_chunks_match_full(self):
+    def test_unaligned_chunks_match_full(self, form):
         """Offsets need NOT be block-aligned: splitting K unevenly (8 +
         24) makes rows 0..7 of the second call fully masked under causal
         — the kernels' fully-masked-row guard must zero them (without it
@@ -487,6 +562,39 @@ class TestChunkedLse:
                                    atol=2e-4, rtol=2e-4)
 
 
+def test_backward_form_follows_the_shapes():
+    """Which backward runs is reckoned from the static shapes: the one
+    kernel where the full-length dQ accumulator fits VMEM (the benchmark
+    cell's 2048 x 64 in bf16, at the table's blocks), the dQ and dK/dV
+    pair at the table's longest sequence. The counters count lowered
+    calls of each form."""
+    from paddle_tpu import observability as obs
+    from tools.flash_block_sweep import DEFAULT_SEQS
+
+    def lower_backward(T):
+        blk = _fa.pick_block(T, jnp.bfloat16)
+        act = jax.ShapeDtypeStruct((1, 2, T, 64), jnp.bfloat16)
+        lse = jax.ShapeDtypeStruct((2, T, 1), jnp.float32)
+        jax.eval_shape(
+            lambda q, k, v, out, lse_, g: _fa._flash_backward(
+                q, k, v, out, lse_, g, None, None, None, 0, False, 0.125,
+                0.0, blk, blk, False),
+            act, act, act, act, lse, act)
+        return (obs.counter_value("flash.bwd_fused"),
+                obs.counter_value("flash.bwd_split"))
+
+    longest = max(DEFAULT_SEQS)
+    blk = _fa.pick_block(2048, jnp.bfloat16)
+    assert _fa._bwd_fused_fits(
+        2048, 64, jnp.bfloat16,
+        *_fa.pick_bwd_blocks(2048, 2048, jnp.bfloat16, (blk, blk)), 0.1)
+    blk = _fa.pick_block(longest, jnp.bfloat16)
+    assert not _fa._bwd_fused_fits(longest, 64, jnp.bfloat16, blk, blk)
+    obs.set_enabled(True)
+    assert lower_backward(2048) == (1, 0)
+    assert lower_backward(longest) == (1, 1)
+
+
 def test_pick_block_table_driven():
     """pick_block consults the committed sweep table per (dtype, seq) and
     clamps to a block that tiles the sequence (VERDICT r3 Next #9)."""
@@ -503,12 +611,25 @@ def test_pick_block_table_driven():
     table = json.load(open(path))
     assert "bfloat16" in table and "float32" in table
     for dtype, rows in table.items():
-        for seq, blk in rows.items():
-            got = fa.pick_block(int(seq), dtype)
-            assert int(seq) % got == 0
+        for seq, row in rows.items():
+            seq = int(seq)
+            blk = row["fwd"] if isinstance(row, dict) else row
+            got = fa.pick_block(seq, dtype)
+            assert seq % got == 0
             # the table's winner is used verbatim whenever it tiles
-            if int(seq) % int(blk) == 0:
+            if seq % int(blk) == 0:
                 assert got == int(blk), (dtype, seq)
+            # the fused backward's pair, where the row was swept for one,
+            # goes with the table's own forward block and tiles; blocks
+            # the caller chose are never overridden
+            bwd = fa.pick_bwd_blocks(seq, seq, dtype, (got, got))
+            if isinstance(row, dict):
+                assert list(bwd) == row["bwd"], (dtype, seq)
+            else:
+                assert bwd == (got, got), (dtype, seq)
+            assert seq % bwd[0] == 0 and seq % bwd[1] == 0
+            assert fa.pick_bwd_blocks(seq, seq, dtype, (128, 128)) == (
+                128, 128) or got == 128
     # off-table seq snaps to the nearest tier but must still tile
     assert 768 % fa.pick_block(768, jnp.bfloat16) == 0
     assert 8192 % fa.pick_block(8192, jnp.float32) == 0

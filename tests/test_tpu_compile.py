@@ -21,7 +21,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.kernels.flash_attention import (
-    _LSE_LANES, _flash_backward, dispatch_attention_lse,
+    _LSE_LANES, _bwd_fused_fits, _flash_backward, dispatch_attention_lse,
     flash_attention_raw_lse, pick_block, pick_bwd_blocks)
 
 
@@ -56,12 +56,12 @@ def _compile(fn, *args):
 
 
 def _attention_args(shape, sharding, masked, lse_sharding=None,
-                    lens_sharding=None):
+                    lens_sharding=None, dtype=jnp.bfloat16):
     """Shapes of one attention call in the layouts the fused_attention op
     and its grad op hand the kernels: one [B, H, T, D] activation (q, k,
     v, out and the cotangent alike), the saved logsumexp, the lengths."""
     B, H, T, D = shape
-    act = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)
+    act = jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
     lse = jax.ShapeDtypeStruct((B * H, T, _LSE_LANES), jnp.float32,
                                sharding=lse_sharding or sharding)
     lens = (jax.ShapeDtypeStruct((B,), jnp.int32,
@@ -109,40 +109,70 @@ def test_flash_forward_compiles(one_chip, name):
     assert hlo.count("tpu_custom_call") == 1
 
 
-@pytest.mark.parametrize("which", ["dq", "dkv"])
+@pytest.mark.parametrize("form", ["fused", "split"])
 @pytest.mark.parametrize("name", list(ATTENTION_CASES))
-def test_flash_backward_compiles(one_chip, name, which):
-    """``_flash_backward`` as ``fused_attention_grad`` calls it, with the
-    table's per-kernel blocks; the unused kernel's call is dead code, so
-    each case compiles exactly one."""
+def test_flash_backward_compiles(one_chip, monkeypatch, name, form):
+    """``_flash_backward`` as ``fused_attention_grad`` calls it, in both
+    forms: the one kernel (dQ, dK and dV from a single pass, with the
+    table's blocks and the VMEM limit it asks for: an overrun shows here)
+    and the dQ and dK/dV pair, which every shape can be steered to from a
+    test. The longest sequence of the table is the one shape here whose
+    dQ accumulator the reckoning refuses: left to itself it must compile
+    as the pair."""
+    import sys
+
     shape, causal, masked, rate = _case(name)
     T = shape[2]
     act, lse, lens = _attention_args(shape, one_chip, masked)
     blk = pick_block(T, jnp.bfloat16)
-    dq_blocks, dkv_blocks = pick_bwd_blocks(T, T, jnp.bfloat16, (blk, blk))
+    fits = _bwd_fused_fits(
+        T, shape[3], jnp.bfloat16,
+        *pick_bwd_blocks(T, T, jnp.bfloat16, (blk, blk)), rate)
+    assert fits == (T < _longest_seq())
+    if form == "split":
+        monkeypatch.setattr(
+            sys.modules["paddle_tpu.kernels.flash_attention"],
+            "_bwd_fused_fits", lambda *a: False)
 
     def bwd(q, k, v, out, lse_, g, lens_):
-        dq, dk, dv = _flash_backward(
+        return _flash_backward(
             q, k, v, out, lse_, g, None, lens_, None, 7, causal,
-            shape[3] ** -0.5, rate, blk, blk, False, dq_blocks=dq_blocks,
-            dkv_blocks=dkv_blocks)
-        return dq if which == "dq" else (dk, dv)
+            shape[3] ** -0.5, rate, blk, blk, False)
+
+    hlo = _compile(bwd, act, act, act, act, lse, act, lens)
+    assert hlo.count("tpu_custom_call") == (
+        1 if form == "fused" and fits else 2)
+
+
+def test_flash_backward_compiles_float32(one_chip):
+    """The table's float32 row at 2048 positions, with dropout: the fused
+    backward at the pair swept for it, wider than the forward's block."""
+    shape, T = (4, 12, 2048, 64), 2048
+    act, lse, lens = _attention_args(shape, one_chip, True,
+                                     dtype=jnp.float32)
+    blk = pick_block(T, jnp.float32)
+    assert pick_bwd_blocks(T, T, jnp.float32, (blk, blk)) != (blk, blk)
+
+    def bwd(q, k, v, out, lse_, g, lens_):
+        return _flash_backward(
+            q, k, v, out, lse_, g, None, lens_, None, 7, False,
+            shape[3] ** -0.5, 0.1, blk, blk, False)
 
     hlo = _compile(bwd, act, act, act, act, lse, act, lens)
     assert hlo.count("tpu_custom_call") == 1
 
 
 def test_flash_custom_calls_are_named_by_the_lowering_scope(one_chip):
-    """Under the lowering's ``pt.<op>.<block>_<idx>`` scope the three
-    kernels' custom calls are instructions named by that scope, which is
-    what the benchmark's flash readers match in a trace. A ``name=`` on
-    the ``pallas_call`` would take that place (``%flash_fwd.1``: tried in
-    PR 26), so the calls carry none."""
+    """Under the lowering's ``pt.<op>.<block>_<idx>`` scope the kernels'
+    custom calls (one forward, one backward at 2048 positions) are
+    instructions named by that scope, which is what the benchmark's
+    flash readers match in a trace. A ``name=`` on the ``pallas_call``
+    would take that place (``%flash_fwd.1``: tried in PR 26), so the
+    calls carry none."""
     shape = (8, 12, 2048, 64)
     T = shape[2]
     act, lse, lens = _attention_args(shape, one_chip, True)
     blk = pick_block(T, jnp.bfloat16)
-    dq_blocks, dkv_blocks = pick_bwd_blocks(T, T, jnp.bfloat16, (blk, blk))
 
     def step(q, k, v, lse_, g, lens_):
         with jax.named_scope("pt.fused_attention.0_17"):
@@ -152,8 +182,7 @@ def test_flash_custom_calls_are_named_by_the_lowering_scope(one_chip):
         with jax.named_scope("pt.fused_attention_grad.0_476"):
             return _flash_backward(
                 q, k, v, out, lse_, g, None, lens_, None, 7, False,
-                shape[3] ** -0.5, 0.0, blk, blk, False,
-                dq_blocks=dq_blocks, dkv_blocks=dkv_blocks)
+                shape[3] ** -0.5, 0.0, blk, blk, False)
 
     hlo = _compile(step, act, act, act, lse, act, lens)
     from paddle_tpu.observability.opprof import (hlo_op_map,
@@ -162,10 +191,10 @@ def test_flash_custom_calls_are_named_by_the_lowering_scope(one_chip):
     tags, _ = hlo_op_map(hlo)
     calls = [instruction_name(line.strip().removeprefix("ROOT "))
              for line in hlo.splitlines() if "tpu_custom_call" in line]
-    assert len(calls) == 3, calls
+    assert len(calls) == 2, calls
     assert sum(n.startswith("pt.fused_attention.0_17") for n in calls) == 1
     assert sum(n.startswith("pt.fused_attention_grad.0_476")
-               for n in calls) == 2
+               for n in calls) == 1
     assert {tags[n] for n in calls} == {
         "pt.fused_attention.0_17", "pt.fused_attention_grad.0_476"}
 
@@ -207,7 +236,7 @@ def test_flash_in_shard_map_compiles_for_a_mesh(topo, monkeypatch, which):
             hlo = _compile(fwd, act, act, act, lens)
         else:
             hlo = _compile(bwd, act, act, act, act, lse, act, lens)
-    assert hlo.count("tpu_custom_call") == (1 if which == "forward" else 2)
+    assert hlo.count("tpu_custom_call") == 1
 
 
 def test_s8_convolution_compiles(one_chip):

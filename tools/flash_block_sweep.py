@@ -16,8 +16,18 @@ config's signal is ~3s. Configs that fail to compile (VMEM OOM at wide
 blocks x long f32 seqs) are skipped; the table is dumped incrementally
 after every (dtype, seq) row so a late failure cannot lose the sweep.
 
+``--bwd`` sweeps the backward apart from the forward, for the rows whose
+sequence takes the fused backward kernel (dQ, dK and dV in one pass; see
+``_flash_backward``): every (block_q, block_k) of the candidates that
+tiles and fits, timed on the backward alone from a saved (out, lse), the
+two-kernel form at the row's forward block timed beside them for the
+record. The forward, and the two-kernel backward of longer sequences,
+keep the row's one block.
+
 Writes paddle_tpu/kernels/flash_block_table.json:
-    {"bfloat16": {"256": best_block, ...}, "float32": {...}}
+    {"bfloat16": {"256": best_block,
+                  "2048": {"fwd": best_block, "bwd": [block_q, block_k]},
+                  ...}, "float32": {...}}
 """
 
 import json
@@ -131,12 +141,106 @@ def sweep(seqs=DEFAULT_SEQS, blocks=DEFAULT_BLOCKS,
                 _dump(table)
                 continue
             best = min(med, key=med.get)
-            table[dtype][str(seq)] = best
+            row = table[dtype].get(str(seq))
+            if isinstance(row, dict):       # keep the backward's own pair
+                row["fwd"] = best
+            else:
+                table[dtype][str(seq)] = best
             print("dtype=%s seq=%d dn=%d -> block %d   %s" % (
                 dtype, seq, dn, best,
                 " ".join("%d:%.3fms" % (b_, m * 1e3)
                          for b_, m in sorted(med.items()))), flush=True)
             _dump(table)                             # incremental dump
+    return table
+
+
+def sweep_bwd(seqs=(2048,), blocks=(256, 512, 1024), dtypes=("bfloat16",),
+              batch=8, heads=12, dim=64, reps=3, target_signal_s=2.0):
+    """Blocks of the fused backward, on the benchmark cell's attention by
+    default ([8, 12, 2048, 64] bf16, key-padding mask, no dropout)."""
+    import jax
+    import jax.numpy as jnp
+
+    import importlib
+
+    # the module: the package exports the function under the same name
+    fa = importlib.import_module("paddle_tpu.kernels.flash_attention")
+
+    assert jax.default_backend() != "cpu", "sweep needs the TPU backend"
+    with open(OUT) as f:
+        table = json.load(f)
+    fits = fa._bwd_fused_fits
+    for dtype in dtypes:
+        for seq in seqs:
+            row = table.get(dtype, {}).get(str(seq))
+            if row is None:
+                print("dtype=%s seq=%d: the table has no such row (the "
+                      "forward sweep makes it)" % (dtype, seq), flush=True)
+                continue
+            fwd = row["fwd"] if isinstance(row, dict) else row
+            # candidates are passed as the caller's blocks: the lookup
+            # must find no pair of an earlier sweep to put in their place
+            fa._block_table()[dtype][str(seq)] = fwd
+            rng = np.random.RandomState(0)
+            q, k, v, g = (jax.device_put(jnp.asarray(
+                rng.randn(batch, heads, seq, dim), dtype))
+                for _ in range(4))
+            lens = jnp.full((batch,), seq, jnp.int32)
+            scale = dim ** -0.5
+            out, lse = jax.jit(
+                lambda a, b, c, le: fa.flash_attention_raw_lse(
+                    a, b, c, le, 0, False, scale, 0.0, fwd, fwd,
+                    False))(q, k, v, lens)
+            saved = (out, lse.reshape(batch * heads, seq, -1), g, lens)
+
+            def bwd(bq, bk, fused):
+                def fn(q_, k_, v_, out_, lse_, g_, lens_):
+                    # the form is read while the loop is traced
+                    fa._bwd_fused_fits = fits if fused else (
+                        lambda *a: False)
+                    try:
+                        return fa._flash_backward(
+                            q_, k_, v_, out_, lse_, g_, None, lens_, None,
+                            0, False, scale, 0.0, bq, bk, False)
+                    finally:
+                        fa._bwd_fused_fits = fits
+                return fn
+
+            # 7 and 5 half-filled tile matmuls of 2*B*H*T^2*D, at half of
+            # ~200 TFLOP/s: errs toward a longer window
+            est_s = 7 * 2 * batch * heads * seq * seq * dim / 100e12
+            dn = int(min(4096, max(16, target_signal_s / est_s)))
+            n_lo, n_hi = 2, 2 + dn
+            cands = {"split": bwd(fwd, fwd, False)}
+            for bq in blocks:
+                for bk in blocks:
+                    if (seq % bq == 0 and seq % bk == 0
+                            and fits(seq, dim, dtype, bq, bk)):
+                        cands[(bq, bk)] = bwd(bq, bk, True)
+            variants = {}
+            for key, fn in cands.items():
+                try:
+                    fn_lo = chained_grad_loop(fn, n_lo)
+                    jax.device_get(fn_lo(q, k, v, *saved))
+                except Exception as e:              # noqa: BLE001
+                    print("dtype=%s seq=%d bwd %s skipped: %s"
+                          % (dtype, seq, key, str(e)[:200]), flush=True)
+                    continue
+                variants[key] = (fn_lo, n_lo,
+                                 chained_grad_loop(fn, n_hi), n_hi)
+            measured = run_marginal_protocol(variants, (q, k, v) + saved,
+                                             reps)
+            med = {key: m for key, (m, _) in measured.items() if m > 0}
+            print("dtype=%s seq=%d dn=%d backward   %s" % (
+                dtype, seq, dn,
+                " ".join("%s:%.3fms" % (key, m * 1e3)
+                         for key, m in sorted(med.items(), key=str))),
+                flush=True)
+            fused = {key: m for key, m in med.items() if key != "split"}
+            if fused:
+                best = min(fused, key=fused.get)
+                table[dtype][str(seq)] = {"fwd": fwd, "bwd": list(best)}
+                _dump(table)
     return table
 
 
@@ -161,7 +265,15 @@ if __name__ == "__main__":
                          "candidates like 1024 are in the default set "
                          "— a default re-sweep must never clobber a "
                          "committed wide-block winner)")
+    ap.add_argument("--bwd", action="store_true",
+                    help="sweep the fused backward's (block_q, block_k) "
+                         "of the rows in --seqs (batch 8, 12 heads: the "
+                         "benchmark cell's attention) instead")
     a = ap.parse_args()
-    sweep(seqs=tuple(a.seqs), dtypes=tuple(a.dtypes), reps=a.reps,
-          blocks=tuple(a.blocks), fresh=a.fresh)
+    if a.bwd:
+        sweep_bwd(seqs=tuple(a.seqs), dtypes=tuple(a.dtypes), reps=a.reps,
+                  blocks=tuple(a.blocks))
+    else:
+        sweep(seqs=tuple(a.seqs), dtypes=tuple(a.dtypes), reps=a.reps,
+              blocks=tuple(a.blocks), fresh=a.fresh)
     print("wrote", OUT)

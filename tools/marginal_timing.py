@@ -162,19 +162,21 @@ def main(argv=None):
 
 
 def chained_grad_loop(grad_fn, n):
-    """One jitted call running ``n`` fwd+bwd steps of ``grad_fn(q, k, v)
-    -> (dq, dk, dv)`` chained by a data dependency: the 1e-30*dq term
-    makes step i+1 depend on step i's output so XLA cannot collapse the
-    loop, while perturbing q by less than one bf16 ulp."""
+    """One jitted call running ``n`` fwd+bwd steps of ``grad_fn(q, k, v,
+    *rest) -> (dq, dk, dv)`` chained by a data dependency: the 1e-30*dq
+    term makes step i+1 depend on step i's output so XLA cannot collapse
+    the loop, while perturbing q by less than one bf16 ulp. ``rest`` is
+    passed through unchanged (a backward alone takes its saved forward
+    there)."""
     import jax
     import jax.numpy as jnp
     from jax import lax
 
     @jax.jit
-    def run(q, k, v):
+    def run(q, k, v, *rest):
         def body(_, carry):
             dq, dk, dv = grad_fn(
-                q + (1e-30 * carry[0]).astype(q.dtype), k, v)
+                q + (1e-30 * carry[0]).astype(q.dtype), k, v, *rest)
             return dq, dk, dv
         init = (jnp.zeros_like(q), jnp.zeros_like(k), jnp.zeros_like(v))
         return lax.fori_loop(0, n, body, init)
