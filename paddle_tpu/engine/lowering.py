@@ -340,7 +340,10 @@ def _lower_grad_op(op, block, ins, rng_key, is_test):
         grads = ins.get(s + "@GRAD", [])
         cvals = []
         for i, p in enumerate(slot_primals):
-            if i < len(grads) and grads[i] is not None:
+            if not jnp.issubdtype(p.dtype, jnp.inexact):
+                # an integer output (indices, counts) has no gradient
+                cvals.append(np.zeros(p.shape, jax.dtypes.float0))
+            elif i < len(grads) and grads[i] is not None:
                 cvals.append(
                     jnp.asarray(grads[i], dtype=p.dtype).reshape(p.shape)
                 )

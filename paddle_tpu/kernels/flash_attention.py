@@ -31,7 +31,13 @@ lanes; both lower, the unit lane costs 128x less HBM.
 Masking is TPU-first: key-padding masks are passed as per-sequence
 *lengths* living in SMEM (scalar memory), not as [B, H, T, T] additive
 tensors — the kernel compares against a key-position iota. Causal masking
-is a static flag. Attention dropout runs *inside* the kernel using a
+is a static flag, and so is ``window`` (causal only: query ``t`` sees keys
+``t-window+1 .. t``): the K-block range of a Q tile is clamped below by the
+window as it is above by causality, in the loop bounds and in the fetch
+index, so a window layer visits fewer tiles, not the same tiles with more
+masking. K/V may have fewer heads than Q (grouped-query attention): Q head
+``h`` reads K/V head ``h // (Hq // Hkv)`` through the K/V index maps; the
+backward makes dK/dV per Q head and ``_flash_backward`` sums each group. Attention dropout runs *inside* the kernel using a
 counter-based hash RNG (murmur3 finalizer over the global (batch, q, k)
 coordinate), so the forward and every backward kernel regenerate the
 identical mask from (seed, coords) with no [Tq, Tk] mask ever stored.
@@ -106,9 +112,36 @@ def _causal_blocks(q_off, k_off, j, block_q, block_k):
     return (q_off - k_off + (j + 1) * block_q - 1) // block_k + 1
 
 
+def _window_first_block(q_off, k_off, j, block_q, block_k, window):
+    """First K block that any row of Q block ``j`` can see under
+    ``window``: the block of the first row's oldest visible key."""
+    return jnp.maximum(q_off - k_off + j * block_q - (window - 1),
+                       0) // block_k
+
+
+def _window_last_q_block(s, block_q, block_k, window):
+    """Last Q block with a row that still sees K block ``s`` under
+    ``window`` (offsets 0): the block of the query ``window - 1`` past the
+    block's last key."""
+    return ((s + 1) * block_k - 1 + window - 1) // block_q
+
+
+def _score_mask(sij, q_pos, k_pos, q_off, k_off, length, causal, window,
+                masked):
+    """The scores with every key a query may not see set to ``_NEG``."""
+    if causal:
+        sij = jnp.where(q_pos + q_off >= k_pos + k_off, sij, _NEG)
+    if window is not None:
+        sij = jnp.where((q_pos + q_off) - (k_pos + k_off) < window, sij,
+                        _NEG)
+    if masked:
+        sij = jnp.where(k_pos < length, sij, _NEG)
+    return sij
+
+
 def _attn_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
                  lse_ref, acc_s, m_s, l_s, *, block_q, block_k, causal,
-                 scale, rate, masked, t_k):
+                 scale, rate, masked, t_k, window=None):
     """Online-softmax forward with K/V STREAMED over the innermost grid
     axis (grid = (B*H, Tq/block_q, Tk/block_k)) and the (acc, m, l)
     carry in VMEM scratch — VMEM bounded by the block sizes, not Tk
@@ -130,8 +163,12 @@ def _attn_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
 
     causal_hi = _causal_blocks(q_off, k_off, j, block_q, block_k)
     nk_eff = _nk_limit(ns, causal_hi, length, block_k, masked, causal)
+    live = s < nk_eff
+    if window is not None:
+        live = jnp.logical_and(live, s >= _window_first_block(
+            q_off, k_off, j, block_q, block_k, window))
 
-    @pl.when(s < nk_eff)
+    @pl.when(live)
     def _step():
         q = q_ref[0]                           # [block_q, D], input dtype
         k_blk = k_ref[0]                       # [block_k, D]
@@ -143,10 +180,8 @@ def _attn_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
             preferred_element_type=jnp.float32) * scale
         k_pos = s * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
-        if causal:
-            sij = jnp.where(q_pos + q_off >= k_pos + k_off, sij, _NEG)
-        if masked:
-            sij = jnp.where(k_pos < length, sij, _NEG)
+        sij = _score_mask(sij, q_pos, k_pos, q_off, k_off, length, causal,
+                          window, masked)
         m = m_s[...]
         m_new = jnp.maximum(m, jnp.max(sij, axis=-1, keepdims=True))
         corr = jnp.exp(m - m_new)
@@ -180,21 +215,54 @@ def _attn_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
         lse_ref[0] = jnp.broadcast_to(lse, (block_q, _LSE_LANES))
 
 
-def _stream_kvmap(block_q, block_k, causal, offsets):
+def _stream_kvmap(block_q, block_k, causal, offsets, window=None, group=1):
     """Index map for K/V blocks streamed over the innermost grid axis of
     a (b, q-block, k-block) grid. For causal runs without (traced) ring
-    offsets the fetch index clamps to the causal frontier so skipped
-    steps re-fetch the block a live step needs (consecutive equal
-    indices elide the copy); ring-step offsets keep the identity map —
-    wasted fetches on skipped steps, never wrong."""
+    offsets the fetch index clamps to the causal frontier, and under a
+    ``window`` to the window's first block as well, so skipped steps
+    re-fetch the block a live step needs (consecutive equal indices
+    elide the copy); ring-step offsets keep the identity map — wasted
+    fetches on skipped steps, never wrong. ``group`` Q heads share one
+    K/V head: program ``b`` reads K/V row ``b // group``."""
+    def head(b):
+        return b // group if group > 1 else b
+
     if causal and offsets is None:
         def kvmap(b, j, s):
-            return (b, jnp.minimum(s, ((j + 1) * block_q - 1) // block_k),
-                    0)
+            blk = jnp.minimum(s, ((j + 1) * block_q - 1) // block_k)
+            if window is not None:
+                blk = jnp.maximum(blk, _window_first_block(
+                    0, 0, j, block_q, block_k, window))
+            return (head(b), blk, 0)
     else:
         def kvmap(b, j, s):
-            return (b, s, 0)
+            return (head(b), s, 0)
     return kvmap
+
+
+def _kv_group(q, k, window, causal):
+    """Q heads per K/V head, from the shapes; counts the lowered calls
+    of the grouped and windowed forms (``flash.gqa_calls``,
+    ``flash.window_calls``)."""
+    from paddle_tpu import observability as obs
+
+    if window is not None and not causal:
+        raise ValueError("a window needs causal attention")
+    hq, hkv = q.shape[1], k.shape[1]
+    if hq % hkv:
+        raise ValueError("Q heads (%d) are no multiple of the K/V heads "
+                         "(%d)" % (hq, hkv))
+    if hq != hkv:
+        obs.inc("flash.gqa_calls")
+    if window is not None:
+        obs.inc("flash.window_calls")
+    return hq // hkv
+
+
+def _window_kw(window):
+    """The kernels' ``window`` keyword, left out where there is none so
+    that a call without a window is lowered as it always was."""
+    return {} if window is None else {"window": int(window)}
 
 
 def _offsets_arr(offsets):
@@ -206,12 +274,13 @@ def _offsets_arr(offsets):
 
 
 def _flash_forward(q, k, v, seq_lens, offsets, seed, causal, scale, rate,
-                   block_q, block_k, interpret):
+                   block_q, block_k, interpret, window=None):
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
+    group = _kv_group(q, k, window, causal)
     qr = q.reshape(B * H, Tq, D)
-    kr = k.reshape(B * H, Tk, D)
-    vr = v.reshape(B * H, Tk, D)
+    kr = k.reshape(B * H // group, Tk, D)
+    vr = v.reshape(B * H // group, Tk, D)
     block_q = min(block_q, Tq)
     block_k = min(block_k, Tk)
     grid = (B * H, Tq // block_q)
@@ -223,10 +292,11 @@ def _flash_forward(q, k, v, seq_lens, offsets, seed, causal, scale, rate,
         lens = jnp.full((B * H,), Tk, jnp.int32)
     seed_arr = jnp.asarray(seed, jnp.int32).reshape(1)
 
-    _kvmap = _stream_kvmap(block_q, block_k, causal, offsets)
+    _kvmap = _stream_kvmap(block_q, block_k, causal, offsets, window, group)
     kernel = functools.partial(
         _attn_kernel, block_q=block_q, block_k=block_k, causal=causal,
-        scale=scale, rate=rate, masked=masked, t_k=Tk)
+        scale=scale, rate=rate, masked=masked, t_k=Tk,
+        **_window_kw(window))
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[
@@ -257,7 +327,7 @@ def _flash_forward(q, k, v, seq_lens, offsets, seed, causal, scale, rate,
 
 def _bwd_dq_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, delta_ref, dq_ref, dq_acc, *, block_q, block_k,
-                   causal, scale, rate, masked, t_k):
+                   causal, scale, rate, masked, t_k, window=None):
     """dQ with K/V streamed over the innermost grid axis and the dq
     accumulator in VMEM scratch (same restructure as the forward — the
     resident-K/V form's VMEM grew with Tk)."""
@@ -275,8 +345,12 @@ def _bwd_dq_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
 
     causal_hi = _causal_blocks(q_off, k_off, j, block_q, block_k)
     nk_eff = _nk_limit(ns, causal_hi, length, block_k, masked, causal)
+    live = s < nk_eff
+    if window is not None:
+        live = jnp.logical_and(live, s >= _window_first_block(
+            q_off, k_off, j, block_q, block_k, window))
 
-    @pl.when(s < nk_eff)
+    @pl.when(live)
     def _step():
         q = q_ref[0]                          # [block_q, D]
         do = do_ref[0]                        # [block_q, D]
@@ -291,10 +365,8 @@ def _bwd_dq_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
             preferred_element_type=jnp.float32) * scale
         k_pos = s * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
-        if causal:
-            sij = jnp.where(q_pos + q_off >= k_pos + k_off, sij, _NEG)
-        if masked:
-            sij = jnp.where(k_pos < length, sij, _NEG)
+        sij = _score_mask(sij, q_pos, k_pos, q_off, k_off, length, causal,
+                          window, masked)
         # fully-masked rows carry lse ~= -1e30; exp(sij - lse) would
         # overflow to inf there — such rows contribute no gradient
         p = jnp.where(lse > 0.5 * _NEG, jnp.exp(sij - lse), 0.0)
@@ -316,7 +388,8 @@ def _bwd_dq_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
 
 def _bwd_fused_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref,
                       do_ref, lse_ref, delta_ref, *outs_and_scratch, with_dq,
-                      block_q, block_k, causal, scale, rate, masked):
+                      block_q, block_k, causal, scale, rate, masked,
+                      window=None):
     """The backward with a K/V tile resident and the Q dimension STREAMED
     over the innermost grid axis (grid = (B*H, Tk/block_k, Tq/block_q)),
     f32 accumulation in VMEM scratch — the earlier form held full-length
@@ -375,10 +448,8 @@ def _bwd_fused_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref,
             jnp.int32, (block_q, block_k), 0)
         k_pos = s_idx * block_k + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
-        if causal:
-            sij = jnp.where(q_pos + q_off >= k_pos + k_off, sij, _NEG)
-        if masked:
-            sij = jnp.where(k_pos < length, sij, _NEG)
+        sij = _score_mask(sij, q_pos, k_pos, q_off, k_off, length, causal,
+                          window, masked)
         # guard fully-masked rows (lse ~= -1e30) as in the dQ kernel
         p = jnp.where(lse > 0.5 * _NEG, jnp.exp(sij - lse),
                       0.0)                     # [block_q, block_k]
@@ -410,9 +481,15 @@ def _bwd_fused_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref,
     if causal:
         # q blocks whose last global row is before this k block's first
         # see none of it — same frontier as the old fori j0, now a
-        # skipped grid step
-        pl.when((j + 1) * block_q - 1 + q_off
-                >= s_idx * block_k + k_off)(compute)
+        # skipped grid step; under a window so do the q blocks whose
+        # first row is ``window`` or more past this k block's last key
+        live = ((j + 1) * block_q - 1 + q_off
+                >= s_idx * block_k + k_off)
+        if window is not None:
+            live = jnp.logical_and(
+                live, j * block_q + q_off
+                - ((s_idx + 1) * block_k - 1 + k_off) < window)
+        pl.when(live)(compute)
     else:
         compute()
 
@@ -454,7 +531,8 @@ def _bwd_fused_fits(tq, d, dtype, block_q, block_k, rate=0.0):
 
 
 def _flash_backward(q, k, v, out, lse, g, g_lse, seq_lens, offsets, seed,
-                    causal, scale, rate, block_q, block_k, interpret):
+                    causal, scale, rate, block_q, block_k, interpret,
+                    window=None):
     """dQ, dK, dV from the saved (out, lse). One kernel where its
     full-length dQ accumulator fits VMEM (``_bwd_fused_fits``, from the
     static shapes; blocks from ``pick_bwd_blocks``), the dQ and dK/dV
@@ -465,9 +543,10 @@ def _flash_backward(q, k, v, out, lse, g, g_lse, seq_lens, offsets, seed,
 
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
+    group = _kv_group(q, k, window, causal)
     qr = q.reshape(B * H, Tq, D)
-    kr = k.reshape(B * H, Tk, D)
-    vr = v.reshape(B * H, Tk, D)
+    kr = k.reshape(B * H // group, Tk, D)
+    vr = v.reshape(B * H // group, Tk, D)
     do = g.reshape(B * H, Tq, D)
     bq, bk = min(block_q, Tq), min(block_k, Tk)
     fused_blocks = pick_bwd_blocks(Tq, Tk, q.dtype, (bq, bk))
@@ -494,12 +573,13 @@ def _flash_backward(q, k, v, out, lse, g, g_lse, seq_lens, offsets, seed,
         delta = delta - g_lse.reshape(B * H, Tq).astype(jnp.float32)
     delta = jnp.broadcast_to(delta[..., None], (B * H, Tq, _LSE_LANES))
     args = (lens, seed_arr, off_arr, qr, kr, vr, do, lse, delta)
-    static = dict(causal=causal, scale=scale, rate=rate, masked=masked)
+    static = dict(causal=causal, scale=scale, rate=rate, masked=masked,
+                  **_window_kw(window))
 
     if fused:
         bq, bk = fused_blocks
     else:
-        _kvmap = _stream_kvmap(bq, bk, causal, offsets)
+        _kvmap = _stream_kvmap(bq, bk, causal, offsets, window, group)
 
         def qspec(lanes):
             return pl.BlockSpec((1, bq, lanes), lambda b, j, s: (b, j, 0))
@@ -540,14 +620,24 @@ def _flash_backward(q, k, v, out, lse, g, g_lse, seq_lens, offsets, seed,
             # lower-clamp to the causal frontier, upper-clamp to the last
             # real Q block (Tk > Tq puts whole k blocks past every q —
             # the body is skipped there, but the fetch must stay in range)
-            return (b, jnp.minimum(jnp.maximum(j, (s * bk) // bq), nq - 1),
+            # and, under a window, to the last Q block that sees K block s
+            last = nq - 1
+            if window is not None:
+                last = jnp.minimum(
+                    last, _window_last_q_block(s, bq, bk, window))
+            return (b, jnp.minimum(jnp.maximum(j, (s * bk) // bq), last),
                     0)
     else:
         def _qmap(b, s, j):
             return (b, j, 0)
     kspec = pl.BlockSpec((1, bk, D), lambda b, s, j: (b, s, 0))
-    kv_shapes = [jax.ShapeDtypeStruct(kr.shape, k.dtype),
-                 jax.ShapeDtypeStruct(vr.shape, v.dtype)]
+    # grouped heads: every Q head reads its group's K/V tile and writes a
+    # dK/dV of its own (one kernel body for both cases); the group's sum
+    # is taken below, in XLA
+    kin = kspec if group == 1 else pl.BlockSpec(
+        (1, bk, D), lambda b, s, j: (b // group, s, 0))
+    kv_shapes = [jax.ShapeDtypeStruct((B * H, Tk, D), k.dtype),
+                 jax.ShapeDtypeStruct((B * H, Tk, D), v.dtype)]
     kv_scratch = [pltpu.VMEM((bk, D), jnp.float32),
                   pltpu.VMEM((bk, D), jnp.float32)]
     call = functools.partial(
@@ -560,8 +650,8 @@ def _flash_backward(q, k, v, out, lse, g, g_lse, seq_lens, offsets, seed,
             _smem_spec(),
             _smem_spec(),
             pl.BlockSpec((1, bq, D), _qmap),
-            kspec,
-            kspec,
+            kin,
+            kin,
             pl.BlockSpec((1, bq, D), _qmap),
             pl.BlockSpec((1, bq, _LSE_LANES), _qmap),
             pl.BlockSpec((1, bq, _LSE_LANES), _qmap),
@@ -580,18 +670,31 @@ def _flash_backward(q, k, v, out, lse, g, g_lse, seq_lens, offsets, seed,
         dk, dv = call(out_shape=kv_shapes, out_specs=[kspec, kspec],
                       scratch_shapes=kv_scratch)(*args)
 
-    return (dq.reshape(B, H, Tq, D), dk.reshape(B, H, Tk, D),
-            dv.reshape(B, H, Tk, D))
+    if group > 1:
+        dk, dv = (x.reshape(B, H // group, group, Tk, D).astype(
+            jnp.float32).sum(axis=2).astype(x.dtype) for x in (dk, dv))
+    return (dq.reshape(B, H, Tq, D), dk.reshape(B, H // group, Tk, D),
+            dv.reshape(B, H // group, Tk, D))
 
 
-def _xla_scores(q, k, causal, scale, seq_lens):
+def _repeat_kv(x, heads):
+    """K or V with each head repeated for the Q heads of its group."""
+    group = heads // x.shape[1]
+    return x if group == 1 else jnp.repeat(x, group, axis=1)
+
+
+def _xla_scores(q, k, causal, scale, seq_lens, window=None):
     """Masked, scaled [B, H, Tq, Tk] scores of the unfused composition."""
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                   k.astype(jnp.float32)) * scale
+                   _repeat_kv(k, q.shape[1]).astype(jnp.float32)) * scale
     Tq, Tk = q.shape[2], k.shape[2]
     if causal:
         mask = jnp.tril(jnp.ones((Tq, Tk), bool))
+        if window is not None:
+            mask = jnp.logical_and(mask, ~jnp.tril(mask, -int(window)))
         s = jnp.where(mask[None, None], s, _NEG)
+    elif window is not None:
+        raise ValueError("a window needs causal attention")
     if seq_lens is not None:
         k_pos = jnp.arange(Tk)[None, None, None, :]
         valid = k_pos < jnp.maximum(seq_lens.astype(jnp.int32), 1).reshape(
@@ -601,14 +704,14 @@ def _xla_scores(q, k, causal, scale, seq_lens):
 
 
 def _xla_attention_lse(q, k, v, causal, scale, seq_lens=None, rate=0.0,
-                       rng_key=None):
+                       rng_key=None, window=None):
     """(out, lse) in plain XLA — the differentiable fallback matching
     ``flash_attention_lse``'s two outputs (the PADDLE_TPU_FLASH_BWD
     escape hatch and the op lowering's non-TPU branch, which must bind
     the program's Lse output). With dropout it draws its own jax.random
     mask — statistically, not bitwise, equivalent to the kernel's hash
     RNG; the lse is of the pre-dropout softmax, as in the kernel."""
-    s = _xla_scores(q, k, causal, scale, seq_lens)
+    s = _xla_scores(q, k, causal, scale, seq_lens, window)
     lse = jax.scipy.special.logsumexp(s, axis=-1)
     w = jnp.exp(s - lse[..., None])
     if rate > 0.0:
@@ -618,15 +721,16 @@ def _xla_attention_lse(q, k, v, causal, scale, seq_lens=None, rate=0.0,
             rng_key = jax.random.PRNGKey(0)
         keep = hash_keep_mask(rng_key, w.shape, rate)
         w = jnp.where(keep, w / (1.0 - rate), 0.0)
-    out = jnp.einsum("bhqk,bhkd->bhqd", w, v.astype(jnp.float32))
+    out = jnp.einsum("bhqk,bhkd->bhqd", w,
+                     _repeat_kv(v, q.shape[1]).astype(jnp.float32))
     return out.astype(q.dtype), lse
 
 
 def _xla_attention(q, k, v, causal, scale, seq_lens=None, rate=0.0,
-                   rng_key=None):
+                   rng_key=None, window=None):
     """Unfused reference composition (and the off-TPU fallback)."""
     return _xla_attention_lse(q, k, v, causal, scale, seq_lens, rate,
-                              rng_key)[0]
+                              rng_key, window)[0]
 
 
 def _check_tileable(q, k, block_q, block_k):
@@ -709,10 +813,11 @@ def pick_bwd_blocks(tq, tk, dtype, default):
     return default
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9, 10, 11))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(6, 7, 8, 9, 10, 11, 12))
 def flash_attention_lse(q, k, v, seq_lens=None, offsets=None, seed=0,
                         causal=False, scale=None, rate=0.0, block_q=128,
-                        block_k=128, interpret=False):
+                        block_k=128, interpret=False, window=None):
     """[B, H, T, D] attention via the Pallas kernels, returning
     ``(out, lse)`` where ``lse`` is the per-row logsumexp of the scaled
     (and masked) scores, [B, H, Tq] float32.
@@ -735,19 +840,24 @@ def flash_attention_lse(q, k, v, seq_lens=None, offsets=None, seed=0,
     in-kernel attention-weight dropout reproduced exactly in the backward
     kernels from ``seed``. Tq/Tk must divide by the (clamped) block sizes
     (ValueError otherwise — ``fused_attention`` handles the fallback).
+    ``window`` (static, causal only) keeps to each query its last
+    ``window`` keys; K and V may come with fewer heads than Q, each
+    shared by a group of consecutive Q heads.
     """
     out, lse = _fa_fwd(q, k, v, seq_lens, offsets, seed, causal, scale,
-                       rate, block_q, block_k, interpret)[0]
+                       rate, block_q, block_k, interpret, window)[0]
     return out, lse
 
 
 def flash_attention(q, k, v, seq_lens=None, seed=0, causal=False, scale=None,
-                    rate=0.0, block_q=128, block_k=128, interpret=False):
+                    rate=0.0, block_q=128, block_k=128, interpret=False,
+                    window=None):
     """[B, H, T, D] attention via the Pallas kernels (output only — see
     ``flash_attention_lse`` for semantics; this keeps the historical
     signature used by the op lowerings and the benchmarks)."""
     out, _ = flash_attention_lse(q, k, v, seq_lens, None, seed, causal,
-                                 scale, rate, block_q, block_k, interpret)
+                                 scale, rate, block_q, block_k, interpret,
+                                 window)
     return out
 
 
@@ -758,18 +868,20 @@ def _use_xla_bwd():
 
 
 def _fa_fwd(q, k, v, seq_lens, offsets, seed, causal, scale, rate, block_q,
-            block_k, interpret):
+            block_k, interpret, window=None):
     _check_tileable(q, k, block_q, block_k)
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     out, lse = _flash_forward(q, k, v, seq_lens, offsets, seed, causal,
-                              scale, rate, block_q, block_k, interpret)
+                              scale, rate, block_q, block_k, interpret,
+                              window)
     B, H, Tq = q.shape[0], q.shape[1], q.shape[2]
     lse_pub = lse[..., 0].reshape(B, H, Tq)
     return (out, lse_pub), (q, k, v, out, lse, seq_lens, offsets, seed)
 
 
 def _fa_bwd_core(q, k, v, out, lse_k, g_out, g_lse, seq_lens, offsets,
-                 seed, causal, scale, rate, block_q, block_k, interpret):
+                 seed, causal, scale, rate, block_q, block_k, interpret,
+                 window=None):
     """Shared backward preamble for both custom_vjps: the
     PADDLE_TPU_FLASH_BWD=xla escape hatch (with its dropout/offset
     guards) and the _flash_backward dispatch. ``lse_k`` is the kernel-layout
@@ -795,30 +907,31 @@ def _fa_bwd_core(q, k, v, out, lse_k, g_out, g_lse, seq_lens, offsets,
         gl = (g_lse if g_lse is not None
               else jnp.zeros((B, H, Tq), jnp.float32))
         _, vjp = jax.vjp(
-            lambda q_, k_, v_: _xla_attention_lse(q_, k_, v_, causal,
-                                                  scale_, seq_lens),
+            lambda q_, k_, v_: _xla_attention_lse(
+                q_, k_, v_, causal, scale_, seq_lens, window=window),
             q, k, v)
         return vjp((g_out, gl))
     return _flash_backward(q, k, v, out, lse_k, g_out, g_lse, seq_lens,
                            offsets, seed, causal, scale_, rate, block_q,
-                           block_k, interpret)
+                           block_k, interpret, window)
 
 
-def _fa_bwd(causal, scale, rate, block_q, block_k, interpret, res, g):
+def _fa_bwd(causal, scale, rate, block_q, block_k, interpret, window, res,
+            g):
     q, k, v, out, lse, seq_lens, offsets, seed = res
     g_out, g_lse = g
     dq, dk, dv = _fa_bwd_core(q, k, v, out, lse, g_out, g_lse, seq_lens,
                               offsets, seed, causal, scale, rate, block_q,
-                              block_k, interpret)
+                              block_k, interpret, window)
     return dq, dk, dv, None, None, None
 
 
 flash_attention_lse.defvjp(_fa_fwd, _fa_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9, 10, 11))
 def flash_attention_raw_lse(q, k, v, seq_lens, seed, causal, scale, rate,
-                            block_q, block_k, interpret):
+                            block_q, block_k, interpret, window=None):
     """``flash_attention_lse`` with the logsumexp kept in the kernel's
     native [B, H, Tq, _LSE_LANES] tiling (the form the fused_attention op
     saves so the backward read is relayout-free). Carrying its own
@@ -827,21 +940,23 @@ def flash_attention_raw_lse(q, k, v, seq_lens, seed, causal, scale, rate,
     the composed forward instead of running the registered grad op, so
     the pallas_call must not be left to jax's default jvp."""
     out, lse = _flash_forward(q, k, v, seq_lens, None, seed, causal,
-                              scale, rate, block_q, block_k, interpret)
+                              scale, rate, block_q, block_k, interpret,
+                              window)
     B, H, Tq = q.shape[0], q.shape[1], q.shape[2]
     return out, lse.reshape(B, H, Tq, -1)
 
 
 def _fa_raw_fwd(q, k, v, seq_lens, seed, causal, scale, rate, block_q,
-                block_k, interpret):
+                block_k, interpret, window=None):
     out, lse = _flash_forward(q, k, v, seq_lens, None, seed, causal,
-                              scale, rate, block_q, block_k, interpret)
+                              scale, rate, block_q, block_k, interpret,
+                              window)
     B, H, Tq = q.shape[0], q.shape[1], q.shape[2]
     lse_raw = lse.reshape(B, H, Tq, -1)
     return (out, lse_raw), (q, k, v, out, lse_raw, seq_lens, seed)
 
 
-def _fa_raw_bwd(causal, scale, rate, block_q, block_k, interpret,
+def _fa_raw_bwd(causal, scale, rate, block_q, block_k, interpret, window,
                 res, g):
     q, k, v, out, lse_raw, seq_lens, seed = res
     g_out, g_lse_raw = g
@@ -852,7 +967,7 @@ def _fa_raw_bwd(causal, scale, rate, block_q, block_k, interpret,
     lse_k = lse_raw.reshape(B * H, Tq, -1)
     dq, dk, dv = _fa_bwd_core(q, k, v, out, lse_k, g_out, g_lse, seq_lens,
                               None, seed, causal, scale, rate, block_q,
-                              block_k, interpret)
+                              block_k, interpret, window)
     return dq, dk, dv, None, None
 
 
@@ -952,7 +1067,7 @@ def _shard_seed(seed, mesh, batch_axes, head_axis):
 
 
 def _dispatch_local(q, k, v, causal, scale, seq_lens, dropout_rate, seed,
-                    force_pallas, raw_lse):
+                    force_pallas, raw_lse, window=None):
     """Single-device (or per-shard) dispatch core of
     ``dispatch_attention_lse``."""
     Tq, Tk = q.shape[2], k.shape[2]
@@ -966,13 +1081,13 @@ def _dispatch_local(q, k, v, causal, scale, seq_lens, dropout_rate, seed,
             _check_tileable(q, k, bq, bk)
             return flash_attention_raw_lse(
                 q, k, v, seq_lens, seed, causal, scale_,
-                dropout_rate, bq, bk, not _on_tpu())
+                dropout_rate, bq, bk, not _on_tpu(), window)
         return flash_attention_lse(q, k, v, seq_lens, None, seed, causal,
                                    scale_, dropout_rate, bq, bk,
-                                   not _on_tpu())
+                                   not _on_tpu(), window)
     key = jax.random.PRNGKey(seed) if dropout_rate > 0.0 else None
     out, lse = _xla_attention_lse(q, k, v, causal, scale_, seq_lens,
-                                  dropout_rate, key)
+                                  dropout_rate, key, window)
     if raw_lse:
         lse = jnp.broadcast_to(lse[..., None], (B, H, Tq, _LSE_LANES))
     return out, lse
@@ -980,7 +1095,7 @@ def _dispatch_local(q, k, v, causal, scale, seq_lens, dropout_rate, seed,
 
 def dispatch_attention_lse(q, k, v, causal=False, scale=None, seq_lens=None,
                            dropout_rate=0.0, seed=0, force_pallas=None,
-                           raw_lse=False):
+                           raw_lse=False, window=None):
     """THE shared (out, lse) attention dispatch: the Pallas kernels when
     ``flash_dispatch_ok`` (block table + interpret flag resolved here, in
     exactly one place), the XLA composition otherwise. ``fused_attention``,
@@ -1006,10 +1121,13 @@ def dispatch_attention_lse(q, k, v, causal=False, scale=None, seq_lens=None,
     the round-5 seq-2048 trace showed 12 x ~0.08 ms/step of lse layout
     copies). Only meaningful on the forward-only (op) path — the
     custom_vjp keeps the public form."""
-    spmd = _spmd_attention_axes(q.shape[0], q.shape[1])
+    # the head axis splits K/V too, so it is their head count that has to
+    # divide (Q's is a multiple of it)
+    spmd = _spmd_attention_axes(q.shape[0], k.shape[1])
     if spmd is None:
         return _dispatch_local(q, k, v, causal, scale, seq_lens,
-                               dropout_rate, seed, force_pallas, raw_lse)
+                               dropout_rate, seed, force_pallas, raw_lse,
+                               window)
     mesh, batch_axes, head_axis = spmd
     from jax.sharding import PartitionSpec as P
 
@@ -1024,7 +1142,8 @@ def dispatch_attention_lse(q, k, v, causal=False, scale=None, seq_lens=None,
         if dropout_rate > 0.0:
             seed_ = _shard_seed(seed_, mesh, batch_axes, head_axis)
         return _dispatch_local(q_, k_, v_, causal, scale, lens_,
-                               dropout_rate, seed_, force_pallas, raw_lse)
+                               dropout_rate, seed_, force_pallas, raw_lse,
+                               window)
 
     if seq_lens is not None:
         fn = _shard_map(
@@ -1040,7 +1159,8 @@ def dispatch_attention_lse(q, k, v, causal=False, scale=None, seq_lens=None,
 
 
 def flash_backward_spmd(q, k, v, out, lse_k, g, seq_lens, seed, causal,
-                        scale, rate, block_q, block_k, interpret):
+                        scale, rate, block_q, block_k, interpret,
+                        window=None):
     """``_flash_backward`` for the registered grad op, shard_mapped over
     the active mesh's data/tp axes when an SPMD lowering context is up
     (per-(batch, head) independence makes the wrap exact — the same
@@ -1050,11 +1170,11 @@ def flash_backward_spmd(q, k, v, out, lse_k, g, seq_lens, seed, causal,
     [B, H, Tq, LANES] (metadata-only) to shard batch and heads, and
     re-flattens per shard."""
     B, H, Tq, _D = q.shape
-    spmd = _spmd_attention_axes(B, H)
+    spmd = _spmd_attention_axes(B, k.shape[1])
     if spmd is None:
         return _flash_backward(q, k, v, out, lse_k, g, None, seq_lens,
                                None, seed, causal, scale, rate, block_q,
-                               block_k, interpret)
+                               block_k, interpret, window)
     mesh, batch_axes, head_axis = spmd
     from jax.sharding import PartitionSpec as P
 
@@ -1070,7 +1190,7 @@ def flash_backward_spmd(q, k, v, out, lse_k, g, seq_lens, seed, causal,
         return _flash_backward(
             q_, k_, v_, out_, lse4_.reshape(Bl * Hl, Tq, -1), g_, None,
             lens_, None, seed_, causal, scale, rate, block_q, block_k,
-            interpret)
+            interpret, window)
 
     out_specs = (qspec, qspec, qspec)
     if seq_lens is not None:
@@ -1090,7 +1210,7 @@ def flash_backward_spmd(q, k, v, out, lse_k, g, seq_lens, seed, causal,
 
 
 def fused_attention(q, k, v, causal=False, scale=None, seq_lens=None,
-                    dropout_rate=0.0, seed=0, force_pallas=None):
+                    dropout_rate=0.0, seed=0, force_pallas=None, window=None):
     """Dispatch point for whole-attention fusion: the Pallas flash kernels
     on TPU for sequences of at least PADDLE_TPU_FLASH_MIN_SEQ (default
     256) keys, the plain-XLA composition elsewhere (short sequences, odd
@@ -1105,4 +1225,5 @@ def fused_attention(q, k, v, causal=False, scale=None, seq_lens=None,
     clamped to >= 1 (see flash_attention). ``force_pallas=True`` runs the
     kernel in interpreter mode off-TPU (tests)."""
     return dispatch_attention_lse(q, k, v, causal, scale, seq_lens,
-                                  dropout_rate, seed, force_pallas)[0]
+                                  dropout_rate, seed, force_pallas,
+                                  window=window)[0]

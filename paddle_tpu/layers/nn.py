@@ -2429,7 +2429,7 @@ def similarity_focus(input, axis, indexes, name=None):
 
 def fused_attention(q, k, v, causal=False, scale=None, seq_lens=None,
                     dropout_rate=0.0, name=None, sequence_parallel=False,
-                    sp_axis="sp", sp_batch_axis=None):
+                    sp_axis="sp", sp_batch_axis=None, window=None):
     """Whole-attention fusion over [B, H, T, D] inputs: the Pallas
     flash-attention kernel on TPU, plain-XLA composition elsewhere.
 
@@ -2439,9 +2439,14 @@ def fused_attention(q, k, v, causal=False, scale=None, seq_lens=None,
     replaces the reference's additive [B, H, T, T] padding masks with
     per-sequence valid lengths; ``causal`` is a static flag;
     ``dropout_rate`` is attention-weight dropout executed inside the
-    kernel. Not part of the fluid.layers golden surface (kept out of
-    __all__); models reach it via this module directly.
+    kernel. ``window`` (causal only) keeps to each query its last
+    ``window`` keys; K and V may have fewer heads than Q (grouped-query
+    attention: Q head ``h`` reads K/V head ``h // (Hq // Hkv)``). Not part
+    of the fluid.layers golden surface (kept out of __all__); models reach
+    it via this module directly.
     """
+    if window is not None and (not causal or sequence_parallel):
+        raise ValueError("a window needs causal attention off the ring path")
     helper = LayerHelper("fused_attention", name=name)
     out = helper.create_variable_for_type_inference(dtype=q.dtype)
     inputs = {"Q": [q], "K": [k], "V": [v]}
@@ -2465,6 +2470,8 @@ def fused_attention(q, k, v, causal=False, scale=None, seq_lens=None,
             helper.create_variable_for_type_inference(dtype="float32")]
     if scale is not None:
         attrs["scale"] = float(scale)
+    if window is not None:
+        attrs["window"] = int(window)
     helper.append_op(type="fused_attention", inputs=inputs,
                      outputs=outputs, attrs=attrs)
     return out
@@ -2522,3 +2529,108 @@ def tree_conv(nodes_vector, edge_set, output_size, num_filters=1,
     else:
         pre_activation = out
     return helper.append_activation(pre_activation)
+
+
+# -- decoder-LM building blocks (beyond-reference, kept out of __all__ like
+# fused_attention; models/decoder_lm.py reaches them via this module) -------
+
+def rms_norm(input, epsilon=1e-6, param_attr=None, name=None):
+    """RMSNorm over the last axis with a learned scale (initialised 1)."""
+    helper = LayerHelper("rms_norm", name=name)
+    scale = helper.create_parameter(
+        attr=param_attr, shape=[int(input.shape[-1])], dtype=input.dtype,
+        default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(type="rms_norm",
+                     inputs={"X": [input], "Scale": [scale]},
+                     outputs={"Y": [out]}, attrs={"epsilon": float(epsilon)})
+    return out
+
+
+def rotary_embedding(xs, rope_theta=10000.0, rope_type="default", name=None,
+                     **rope):
+    """Rotary position embedding of every ``[B, H, T, D]`` tensor in ``xs``
+    (Q and K of one attention) at positions ``0..T-1``, over the whole
+    head, halves convention. ``rope_type`` ``yarn`` takes ``factor``,
+    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow`` and
+    ``attention_factor`` (ops/nn_ops.py ``rope_inv_freq``)."""
+    helper = LayerHelper("rotary_embedding", name=name)
+    outs = [helper.create_variable_for_type_inference(x.dtype) for x in xs]
+    attrs = dict(rope, rope_theta=float(rope_theta), rope_type=rope_type)
+    helper.append_op(type="rotary_embedding", inputs={"X": list(xs)},
+                     outputs={"Out": outs}, attrs=attrs)
+    return outs
+
+
+def moe_router(input, num_experts, k, param_attr=None, name=None):
+    """Softmax router over ``num_experts`` in float32 and its top ``k``:
+    -> (weights [N, k] float32, renormalised over the k; ids [N, k] int32)
+    for the ``N`` tokens of ``input`` [..., d]."""
+    helper = LayerHelper("moe_router", name=name)
+    w = helper.create_parameter(
+        attr=param_attr, shape=[int(input.shape[-1]), int(num_experts)],
+        dtype="float32", is_bias=False)
+    weight = helper.create_variable_for_type_inference("float32")
+    ids = helper.create_variable_for_type_inference("int32",
+                                                    stop_gradient=True)
+    helper.append_op(
+        type="moe_router", inputs={"X": [input], "Weight": [w]},
+        outputs={"TopkWeight": [weight], "TopkIds": [ids]},
+        attrs={"k": int(k)})
+    return weight, ids
+
+
+def moe_experts(input, topk_weight, topk_ids, experts_held, expert_offset,
+                width, gate_attr=None, up_attr=None, down_attr=None,
+                name=None):
+    """The part of a mixture-of-experts layer that the ``experts_held``
+    experts from ``expert_offset`` give: dispatch by expert (dropless: a
+    buffer row for every token-expert pair), the gated SiLU expert MLP as
+    three grouped matmuls (weights stacked ``[experts_held, d, width]`` /
+    ``[experts_held, width, d]``; the pair's weight goes in before the
+    down projection), combine. ``input`` [N, d] ->
+    (out [N, d] float32, counts [experts_held] int32: the tokens each held
+    expert received). Four op types (``moe_dispatch``, ``moe_expert_mlp``,
+    ``moe_combine`` beside the router's), so that a trace tells the
+    matmuls from the gathers."""
+    helper = LayerHelper("moe_experts", name=name)
+    d = int(input.shape[-1])
+    share = {"experts_held": int(experts_held),
+             "expert_offset": int(expert_offset)}
+
+    def ints():
+        return helper.create_variable_for_type_inference(
+            "int32", stop_gradient=True)
+
+    rows = helper.create_variable_for_type_inference(input.dtype)
+    row_weight = helper.create_variable_for_type_inference("float32")
+    counts, row_of_pair, pair_of_row = ints(), ints(), ints()
+    helper.append_op(
+        type="moe_dispatch",
+        inputs={"X": [input], "TopkWeight": [topk_weight],
+                "TopkIds": [topk_ids]},
+        outputs={"Rows": [rows], "Counts": [counts],
+                 "RowWeight": [row_weight], "RowOfPair": [row_of_pair],
+                 "PairOfRow": [pair_of_row]},
+        attrs=dict(share))
+    weights = [helper.create_parameter(attr=attr, shape=shape,
+                                       dtype="float32", is_bias=False)
+               for attr, shape in ((gate_attr, [experts_held, d, width]),
+                                   (up_attr, [experts_held, d, width]),
+                                   (down_attr, [experts_held, width, d]))]
+    mlp = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(
+        type="moe_expert_mlp",
+        inputs={"Rows": [rows], "RowWeight": [row_weight],
+                "Counts": [counts],
+                "GateWeight": [weights[0]], "UpWeight": [weights[1]],
+                "DownWeight": [weights[2]]},
+        outputs={"Out": [mlp]}, attrs={})
+    out = helper.create_variable_for_type_inference("float32")
+    helper.append_op(
+        type="moe_combine",
+        inputs={"Rows": [mlp], "TopkIds": [topk_ids],
+                "RowOfPair": [row_of_pair],
+                "PairOfRow": [pair_of_row], "Counts": [counts]},
+        outputs={"Out": [out]}, attrs=dict(share))
+    return out, counts

@@ -22,3 +22,4 @@ from paddle_tpu.ops import beam_search_ops  # noqa: F401
 from paddle_tpu.ops import distributed_ops  # noqa: F401
 from paddle_tpu.ops import detection_ops  # noqa: F401
 from paddle_tpu.ops import misc_ops  # noqa: F401
+from paddle_tpu.ops import moe_ops  # noqa: F401

@@ -1,0 +1,193 @@
+"""A mixture-of-experts layer as four ops, for a chip that holds some of
+the experts (``experts_held`` of ``num_experts``, from ``expert_offset``):
+
+    moe_router      probabilities over ALL experts in float32, top-k,
+                    weights renormalised over the k chosen
+    moe_dispatch    the token-expert pairs that fall on held experts,
+                    sorted by expert, their token rows (and weights)
+                    gathered into a buffer; second output: tokens each
+                    held expert received
+    moe_expert_mlp  down(w * silu(gate(x)) * up(x)) per expert: three
+                    grouped matmuls over the buffer
+    moe_combine     each token's sum over its held pairs' rows
+
+Dropless: the buffer has a row for every pair (tokens x k; a token's k
+experts are distinct and up to all of them may be held here), so no
+routing can overflow it; the grouped matmuls visit only the rows in use.
+What the experts held elsewhere would add is left out: on one chip there
+is no exchange and nothing stands in for it. A token's weights are
+renormalised over all k of its experts, held or not.
+
+The pair's weight multiplies the row before the down projection (which is
+linear, so the sum is the weighted combine): the combine's backward then
+needs nothing saved, and the weight's gradient falls out of the MLP's
+backward over ``width`` columns instead of ``d``.
+
+Both directions of the token <-> buffer traffic are gathers (a row's token
+forward, a pair's row backward), never a scatter-add: each pair has one
+row, so the inverse permutation is known. Unused rows of a grouped
+matmul's result are unspecified, so whatever leaves the buffer is picked
+with ``where``, never by a product with zero.
+"""
+
+import numpy as np
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from paddle_tpu.core.registry import register_op
+from paddle_tpu.ops.common import amp_cast, single
+
+
+@register_op("moe_router")
+def moe_router(ctx, ins, attrs):
+    """X [..., d], Weight [d, E] -> TopkWeight [N, k] float32, TopkIds
+    [N, k] int32. Logits and softmax in float32 whatever the program's
+    precision: the top-k is a discontinuity, and a rounded probability
+    flips it."""
+    x, w = single(ins, "X"), single(ins, "Weight")
+    k = int(attrs["k"])
+    x2 = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
+    logits = jnp.dot(x2, w.astype(jnp.float32),
+                     precision=lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top, ids = lax.top_k(probs, k)
+    top = top / jnp.sum(top, axis=-1, keepdims=True)
+    return {"TopkWeight": [top], "TopkIds": [ids.astype(jnp.int32)]}
+
+
+def _held(ids, attrs):
+    """(pair is held here [N, k] bool, its local expert [N, k] int32)."""
+    local = ids - int(attrs.get("expert_offset", 0))
+    return (local >= 0) & (local < int(attrs["experts_held"])), local
+
+
+@jax.custom_vjp
+def _to_rows(x, weight, pair_of_row, row_live, row_of_pair, pair_held):
+    """(rows[r] = x[token of row r], its pair's weight) where the row is
+    in use, else 0."""
+    k = weight.shape[1]
+    rows = jnp.where(row_live[:, None], x[pair_of_row // k], 0)
+    return rows, jnp.where(row_live, weight.reshape(-1)[pair_of_row], 0)
+
+
+def _to_rows_fwd(x, weight, pair_of_row, row_live, row_of_pair, pair_held):
+    return (_to_rows(x, weight, pair_of_row, row_live, row_of_pair,
+                     pair_held), (row_of_pair, pair_held))
+
+
+def _to_rows_bwd(res, g):
+    row_of_pair, pair_held = res
+    g_rows, g_weight = g
+    # a token's gradient is the sum over its held pairs' rows: a gather by
+    # the inverse permutation
+    dx = jnp.sum(jnp.where(pair_held[..., None], g_rows[row_of_pair], 0)
+                 .astype(jnp.float32), axis=1).astype(g_rows.dtype)
+    return (dx, jnp.where(pair_held, g_weight[row_of_pair], 0), None, None,
+            None, None)
+
+
+_to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
+
+
+@register_op("moe_dispatch", no_grad_inputs=("TopkIds",))
+def moe_dispatch(ctx, ins, attrs):
+    """X [N, d], TopkWeight and TopkIds [N, k] -> Rows [N*k, d] (the held
+    pairs' token rows, sorted by expert, in the program's compute dtype),
+    Counts [experts_held] int32 (tokens each held expert received),
+    RowWeight [N*k] float32 (each row's pair's weight), RowOfPair [N, k]
+    and PairOfRow [N*k] int32 (the permutation and its inverse; pair
+    ``t * k + j`` is token ``t``'s ``j``-th expert)."""
+    from paddle_tpu import observability as obs
+
+    x, ids = single(ins, "X"), single(ins, "TopkIds")
+    held_n = int(attrs["experts_held"])
+    n, k = ids.shape
+    held, local = _held(ids, attrs)
+    # held pairs first, by expert; the others after them in any order
+    key = jnp.where(held, local, held_n).reshape(-1)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    row_of_pair = jnp.argsort(order).astype(jnp.int32).reshape(n, k)
+    counts = jnp.sum(
+        key[:, None] == jnp.arange(held_n, dtype=jnp.int32)[None, :],
+        axis=0, dtype=jnp.int32)
+    row_live = jnp.arange(n * k, dtype=jnp.int32) < jnp.sum(counts)
+    rows, row_weight = _to_rows(amp_cast(x), single(ins, "TopkWeight"),
+                                order, row_live, row_of_pair, held)
+    if ctx.op.type == "moe_dispatch" and obs.enabled():
+        obs.inc("moe.layers")
+        obs.set_gauge("moe.buffer_rows", n * k)
+        obs.set_gauge("moe.experts_held", held_n)
+        jax.debug.callback(_publish_load, counts)
+    return {"Rows": [rows], "Counts": [counts], "RowWeight": [row_weight],
+            "RowOfPair": [row_of_pair], "PairOfRow": [order]}
+
+
+def _publish_load(counts):
+    """Per step, under the ``metrics`` flag: pairs that fell on held
+    experts and the busiest held expert's load over the mean."""
+    from paddle_tpu import observability as obs
+
+    counts = np.asarray(counts)
+    obs.set_gauge("moe.pairs_held", int(counts.sum()))
+    obs.set_gauge("moe.load_max_over_mean",
+                  float(counts.max() / max(counts.mean(), 1e-9)))
+
+
+@register_op("moe_expert_mlp", no_grad_inputs=("Counts",))
+def moe_expert_mlp(ctx, ins, attrs):
+    """Rows [R, d] sorted by expert, RowWeight [R], Counts [G], GateWeight
+    / UpWeight [G, d, w], DownWeight [G, w, d] -> Out [R, d]: the gated
+    SiLU MLP of each row's expert, times the row's weight. Rows past
+    sum(Counts) are unspecified."""
+    from paddle_tpu.kernels.grouped_matmul import grouped_matmul
+
+    rows, counts = single(ins, "Rows"), single(ins, "Counts")
+    rows, wg, wu, wd = amp_cast(rows, single(ins, "GateWeight"),
+                                single(ins, "UpWeight"),
+                                single(ins, "DownWeight"))
+    gate = grouped_matmul(rows, wg, counts)
+    up = grouped_matmul(rows, wu, counts)
+    hidden = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+              * single(ins, "RowWeight")[:, None]).astype(rows.dtype)
+    return {"Out": [grouped_matmul(hidden, wd, counts)]}
+
+
+@jax.custom_vjp
+def _sum_of_rows(rows, row_of_pair, pair_held, pair_of_row, row_live):
+    """out[t] = sum of the rows of t's held pairs, float32."""
+    return jnp.sum(jnp.where(pair_held[..., None], rows[row_of_pair], 0)
+                   .astype(jnp.float32), axis=1)
+
+
+def _sum_fwd(rows, row_of_pair, pair_held, pair_of_row, row_live):
+    return (_sum_of_rows(rows, row_of_pair, pair_held, pair_of_row,
+                         row_live),
+            # (an empty array carries the rows' dtype to the backward)
+            (pair_of_row, row_live, jnp.zeros((0,), rows.dtype)))
+
+
+def _sum_bwd(res, g):
+    pair_of_row, row_live, like = res
+    k = pair_of_row.shape[0] // g.shape[0]
+    # a row's gradient is its token's: a gather
+    d_rows = jnp.where(row_live[:, None], g[pair_of_row // k], 0)
+    return d_rows.astype(like.dtype), None, None, None, None
+
+
+_sum_of_rows.defvjp(_sum_fwd, _sum_bwd)
+
+
+@register_op("moe_combine",
+             no_grad_inputs=("TopkIds", "RowOfPair", "PairOfRow", "Counts"))
+def moe_combine(ctx, ins, attrs):
+    """Rows [N*k, d] (the expert MLP's weighted result), TopkIds,
+    RowOfPair, PairOfRow, Counts -> Out [N, d] float32: every token's sum
+    over the pairs held here (zero where none is)."""
+    rows = single(ins, "Rows")
+    held, _ = _held(single(ins, "TopkIds"), attrs)
+    row_live = (jnp.arange(rows.shape[0], dtype=jnp.int32)
+                < jnp.sum(single(ins, "Counts")))
+    return {"Out": [_sum_of_rows(rows, single(ins, "RowOfPair"), held,
+                                 single(ins, "PairOfRow"), row_live)]}

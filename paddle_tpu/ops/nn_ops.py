@@ -408,7 +408,8 @@ def fused_attention_op(ctx, ins, attrs):
         # False the XLA composition. Not a "__" name: the engine strips
         # those before lowerings (lowering.clean_attrs)
         attrs.get("force_flash", None),
-        raw_lse=True)  # kernel-native layout: zero-relayout backward read
+        raw_lse=True,  # kernel-native layout: zero-relayout backward read
+        window=attrs.get("window", None))
     # the XLA branch's lse binds the program's Lse var too (the direct
     # grad op ignores it there and XLA DCEs it when nothing reads it)
     return {"Out": [out], "Lse": [lse]}
@@ -433,6 +434,7 @@ def fused_attention_grad_op(ctx, ins, attrs):
     q, k, v, lens, rate, seed = _fused_attention_args(ctx, ins, attrs)
     causal = bool(attrs.get("causal", False))
     scale = attrs.get("scale", None)
+    window = attrs.get("window", None)
     g = single(ins, "Out@GRAD")
     g = jnp.asarray(g, q.dtype).reshape(q.shape)
     if bool(attrs.get("sequence_parallel", False)):
@@ -469,14 +471,15 @@ def fused_attention_grad_op(ctx, ins, attrs):
         dq, dk, dv = flash_backward_spmd(
             q, k, v, out.astype(q.dtype), lse_k, g, lens,
             seed, causal, scale_, rate, min(bq, Tq), min(bk, Tk),
-            not _on_tpu())
+            not _on_tpu(), window)
         return {"Q@GRAD": [dq], "K@GRAD": [dk], "V@GRAD": [dv]}
 
     # program lacks the saved residuals (old desc) or took the XLA branch:
     # differentiate the SAME shared dispatch the forward ran
     _, vjp = jax.vjp(
         lambda q_, k_, v_: dispatch_attention_lse(
-            q_, k_, v_, causal, scale, lens, rate, seed, force)[0],
+            q_, k_, v_, causal, scale, lens, rate, seed, force,
+            window=window)[0],
         q, k, v)
     dq, dk, dv = vjp(g)
     return {"Q@GRAD": [dq], "K@GRAD": [dk], "V@GRAD": [dv]}
@@ -506,6 +509,75 @@ def layer_norm(ctx, ins, attrs):
         "Mean": [jnp.squeeze(mean)],
         "Variance": [jnp.squeeze(var)],
     }
+
+
+@register_op("rms_norm")
+def rms_norm(ctx, ins, attrs):
+    """Y = X / sqrt(mean(X^2) + eps) * Scale over the last axis, computed
+    in float32 and returned in X's dtype."""
+    x, scale = single(ins, "X"), single(ins, "Scale")
+    x32 = fp32_accum(x)
+    y = x32 * lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+                        + attrs.get("epsilon", 1e-6))
+    if scale is not None:
+        y = y * scale
+    return {"Y": [y.astype(x.dtype)]}
+
+
+def rope_inv_freq(head_dim, attrs):
+    """(inverse frequencies [head_dim / 2] float64, the factor on cos and
+    sin) of a rotary embedding: ``rope_type`` ``default`` is
+    theta^(-2i/d); ``yarn`` (Peng et al., arXiv:2309.00071) blends each
+    frequency with its ``factor``-fold interpolation by a linear ramp
+    between the dimensions that turn ``beta_fast`` and ``beta_slow`` times
+    over the original context."""
+    theta = float(attrs.get("rope_theta", 10000.0))
+    half = head_dim // 2
+    inv = theta ** (-2.0 * np.arange(half, dtype=np.float64) / head_dim)
+    kind = attrs.get("rope_type", "default")
+    if kind == "default":
+        return inv, 1.0
+    if kind != "yarn":
+        raise ValueError("unknown rope_type %r" % (kind,))
+    factor = float(attrs["factor"])
+    original = float(attrs["original_max_position_embeddings"])
+
+    def dim_of(turns):
+        return (head_dim * np.log(original / (2.0 * np.pi * turns))
+                / (2.0 * np.log(theta)))
+
+    low = max(np.floor(dim_of(float(attrs.get("beta_fast", 32.0)))), 0.0)
+    high = min(np.ceil(dim_of(float(attrs.get("beta_slow", 1.0)))),
+               head_dim - 1.0)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    scaling = attrs.get("attention_factor")
+    if scaling is None:
+        scaling = 0.1 * np.log(factor) + 1.0
+    return inv * ((1.0 - ramp) + ramp / factor), float(scaling)
+
+
+@register_op("rotary_embedding")
+def rotary_embedding(ctx, ins, attrs):
+    """Rotate every X [B, H, T, D] by its position 0..T-1 over the whole
+    head, halves convention: x*cos + [-x2, x1]*sin. The tables come from
+    the attributes (``rope_inv_freq``) in float64 at lowering; the
+    rotation is float32, the result X's dtype."""
+    outs, tables = [], {}
+    for x in ins["X"]:
+        t, d = x.shape[-2], x.shape[-1]
+        if (t, d) not in tables:      # Q and K share one pair of tables
+            inv, scaling = rope_inv_freq(d, attrs)
+            angle = np.arange(t, dtype=np.float64)[:, None] * inv[None, :]
+            tables[t, d] = (
+                jnp.asarray(np.cos(angle) * scaling, jnp.float32),
+                jnp.asarray(np.sin(angle) * scaling, jnp.float32))
+        cos, sin = tables[t, d]
+        x32 = fp32_accum(x)
+        x1, x2 = x32[..., :d // 2], x32[..., d // 2:]
+        outs.append(jnp.concatenate(
+            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).astype(x.dtype))
+    return {"Out": outs}
 
 
 @register_op("dropout", needs_rng=True)

@@ -266,3 +266,90 @@ def test_s8_matmul_compiles(one_chip):
         lambda x_, y_: jax.lax.dot(x_, y_,
                                    preferred_element_type=jnp.int32), x, y)
     assert "s32[8,1000]" in hlo
+
+
+# -- the decoder cell's kernels: grouped-query heads, a window, head size 128
+
+DECODER = dict(rows=2, q_heads=32, kv_heads=4, seq=4096, dim=128)
+
+
+def _decoder_args(one_chip):
+    b, t, d = DECODER["rows"], DECODER["seq"], DECODER["dim"]
+    q = jax.ShapeDtypeStruct((b, DECODER["q_heads"], t, d), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, DECODER["kv_heads"], t, d), jnp.bfloat16,
+                              sharding=one_chip)
+    lse = jax.ShapeDtypeStruct((b * DECODER["q_heads"], t, _LSE_LANES),
+                               jnp.float32, sharding=one_chip)
+    return q, kv, lse
+
+
+@pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
+def test_grouped_query_forward_compiles(one_chip, window):
+    q, kv, _ = _decoder_args(one_chip)
+    blk = pick_block(DECODER["seq"], jnp.bfloat16)
+
+    def fwd(q_, k_, v_):
+        return flash_attention_raw_lse(
+            q_, k_, v_, None, 0, True, DECODER["dim"] ** -0.5, 0.0, blk,
+            blk, False, window)
+
+    assert _compile(fwd, q, kv, kv).count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("form", ["fused", "split"])
+@pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
+def test_grouped_query_backward_compiles(one_chip, monkeypatch, window,
+                                         form):
+    """Both backward forms at [2, 32/4, 4096, 128] bf16: by the shapes this
+    attention takes the one kernel (its [4096, 128] dQ accumulator fits);
+    dK/dV come out per K/V head."""
+    import sys
+
+    q, kv, lse = _decoder_args(one_chip)
+    blk = pick_block(DECODER["seq"], jnp.bfloat16)
+    assert _bwd_fused_fits(DECODER["seq"], DECODER["dim"], jnp.bfloat16,
+                           blk, blk)
+    if form == "split":
+        monkeypatch.setattr(
+            sys.modules["paddle_tpu.kernels.flash_attention"],
+            "_bwd_fused_fits", lambda *a: False)
+
+    def bwd(q_, k_, v_, out, lse_, g):
+        return _flash_backward(
+            q_, k_, v_, out, lse_, g, None, None, None, 0, True,
+            DECODER["dim"] ** -0.5, 0.0, blk, blk, False, window)
+
+    assert _compile(bwd, q, kv, kv, q, lse, q).count("tpu_custom_call") == (
+        1 if form == "fused" else 2)
+    dq, dk, dv = jax.eval_shape(bwd, q, kv, kv, q, lse, q)
+    assert dq.shape == q.shape and dk.shape == dv.shape == kv.shape
+
+
+def test_grouped_matmuls_compile(one_chip, monkeypatch):
+    """The expert MLP's three products and their gradients at the decoder
+    cell's shapes ([65536, 2304] rows, 16 experts of width 896): nine
+    megablox kernels, each at the tiling ``_tilings`` reckons for it."""
+    import sys
+
+    from paddle_tpu.kernels import grouped_matmul as gm
+
+    monkeypatch.setattr(sys.modules["paddle_tpu.kernels.grouped_matmul"],
+                        "_on_tpu", lambda: True)
+    rows = jax.ShapeDtypeStruct((65536, 2304), jnp.bfloat16,
+                                sharding=one_chip)
+    up = jax.ShapeDtypeStruct((16, 2304, 896), jnp.bfloat16,
+                              sharding=one_chip)
+    down = jax.ShapeDtypeStruct((16, 896, 2304), jnp.bfloat16,
+                                sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+
+    def loss(rows_, gate_, up_, down_, sizes_):
+        hidden = (jax.nn.silu(gm.grouped_matmul(rows_, gate_, sizes_))
+                  * gm.grouped_matmul(rows_, up_, sizes_))
+        out = gm.grouped_matmul(hidden, down_, sizes_)
+        return jnp.sum(out[:1024].astype(jnp.float32))
+
+    hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)), rows, up,
+                   up, down, sizes)
+    assert hlo.count("tpu_custom_call") == 9

@@ -61,7 +61,11 @@ DEFAULT_SEQS = (256, 512, 1024, 2048, 4096, 8192, 16384)
 
 def sweep(seqs=DEFAULT_SEQS, blocks=DEFAULT_BLOCKS,
           dtypes=("bfloat16", "float32"), batch=4, heads=16, dim=64,
-          reps=3, target_signal_s=3.0, fresh=False):
+          reps=3, target_signal_s=3.0, fresh=False, kv_heads=None,
+          window=None, write=True):
+    """``kv_heads`` (grouped-query attention) and ``window`` sweep another
+    attention than the table's rows were made on; with ``write`` off the
+    readings are printed and the table is left as it is."""
     import jax
     import jax.numpy as jnp
 
@@ -85,7 +89,8 @@ def sweep(seqs=DEFAULT_SEQS, blocks=DEFAULT_BLOCKS,
             # long f32 runs blow HBM sooner; shrink batch at 4096
             b = batch if seq < 4096 else max(1, batch // 2)
             q, k, v = (jax.device_put(jnp.asarray(
-                rng.randn(b, heads, seq, dim), dtype)) for _ in range(3))
+                rng.randn(b, h, seq, dim), dtype))
+                for h in (heads, kv_heads or heads, kv_heads or heads))
             # fwd+bwd ~ 3.5 x 4*B*H*T^2*D FLOPs; assume >=20 TFLOP/s so
             # Δn errs toward a LONGER (higher-signal) window
             est_s = 3.5 * 4 * b * heads * seq * seq * dim / 20e12
@@ -100,7 +105,7 @@ def sweep(seqs=DEFAULT_SEQS, blocks=DEFAULT_BLOCKS,
                 g = jax.grad(
                     lambda a, c, d, _blk=blk: jnp.sum(flash_attention(
                         a, c, d, None, 0, True, None, 0.0, _blk, _blk,
-                        False).astype(jnp.float32)),
+                        False, window).astype(jnp.float32)),
                     argnums=(0, 1, 2))
                 try:
                     # compile-check the SHORT window only: VMEM fit
@@ -142,7 +147,9 @@ def sweep(seqs=DEFAULT_SEQS, blocks=DEFAULT_BLOCKS,
                 continue
             best = min(med, key=med.get)
             row = table[dtype].get(str(seq))
-            if isinstance(row, dict):       # keep the backward's own pair
+            if not write:
+                pass
+            elif isinstance(row, dict):     # keep the backward's own pair
                 row["fwd"] = best
             else:
                 table[dtype][str(seq)] = best
@@ -150,7 +157,8 @@ def sweep(seqs=DEFAULT_SEQS, blocks=DEFAULT_BLOCKS,
                 dtype, seq, dn, best,
                 " ".join("%d:%.3fms" % (b_, m * 1e3)
                          for b_, m in sorted(med.items()))), flush=True)
-            _dump(table)                             # incremental dump
+            if write:
+                _dump(table)                         # incremental dump
     return table
 
 
@@ -269,11 +277,23 @@ if __name__ == "__main__":
                     help="sweep the fused backward's (block_q, block_k) "
                          "of the rows in --seqs (batch 8, 12 heads: the "
                          "benchmark cell's attention) instead")
+    ap.add_argument("--batch", type=int, default=4)
+    ap.add_argument("--heads", type=int, default=16)
+    ap.add_argument("--kv-heads", type=int, default=None,
+                    help="K/V heads under --heads Q heads")
+    ap.add_argument("--dim", type=int, default=64)
+    ap.add_argument("--window", type=int, default=None,
+                    help="causal window of the forward+backward sweep")
+    ap.add_argument("--print-only", action="store_true",
+                    help="print the readings, leave the table as it is")
     a = ap.parse_args()
     if a.bwd:
         sweep_bwd(seqs=tuple(a.seqs), dtypes=tuple(a.dtypes), reps=a.reps,
                   blocks=tuple(a.blocks))
     else:
         sweep(seqs=tuple(a.seqs), dtypes=tuple(a.dtypes), reps=a.reps,
-              blocks=tuple(a.blocks), fresh=a.fresh)
-    print("wrote", OUT)
+              blocks=tuple(a.blocks), fresh=a.fresh, batch=a.batch,
+              heads=a.heads, dim=a.dim, kv_heads=a.kv_heads,
+              window=a.window, write=not a.print_only)
+    if not a.print_only:
+        print("wrote", OUT)
