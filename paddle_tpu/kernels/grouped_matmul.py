@@ -3,7 +3,9 @@
 says how many rows each group has. The rows past ``sum(group_sizes)`` (a
 dropless buffer is sized for the worst routing, and most of it is unused)
 are never read and what the result holds there is unspecified: callers
-mask by the row count, never multiply by zero.
+mask by the row count, never multiply by zero. How the rows get into that
+buffer and out of it again is the sibling module's, ``row_permute.py``
+(whole tiles on the MXU where these kernels run, XLA's gathers elsewhere).
 
 On the TPU these are the megablox Pallas kernels (``jax.experimental
 .pallas.ops.tpu.megablox``): their grids cover only the row tiles that

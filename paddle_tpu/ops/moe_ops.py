@@ -23,11 +23,21 @@ linear, so the sum is the weighted combine): the combine's backward then
 needs nothing saved, and the weight's gradient falls out of the MLP's
 backward over ``width`` columns instead of ``d``.
 
-Both directions of the token <-> buffer traffic are gathers (a row's token
-forward, a pair's row backward), never a scatter-add: each pair has one
-row, so the inverse permutation is known. Unused rows of a grouped
-matmul's result are unspecified, so whatever leaves the buffer is picked
-with ``where``, never by a product with zero.
+The token <-> buffer traffic is four movements a layer, two shapes:
+``_rows_of_tokens`` (a row's token: ``moe_dispatch`` forward,
+``moe_combine`` backward) and ``_sums_of_rows`` (a token's sum over its
+pairs' rows: ``moe_combine`` forward, ``moe_dispatch`` backward). On the
+TPU, for bf16 rows of whole lanes and whole tiles, both are the Pallas
+kernel of ``kernels/row_permute.py``: whole tiles fetched, the permutation
+inside a tile a 0/1 product on the MXU, only the tiles in use visited.
+Everywhere else (the CPU, float32 programs, odd sizes) both are XLA gathers
+(a row's token one way, a pair's row the other), never a scatter-add: each
+pair has one row, so the inverse permutation is known. Which of the two a
+call site was lowered as is counted (``moe.permute_kernel`` /
+``moe.permute_xla``). Unused rows of a grouped matmul's result are
+unspecified, so whatever leaves the buffer is picked with ``where``, never
+by a product with zero; what the kernel writes into the buffer's unused
+tiles is unspecified too.
 """
 
 import numpy as np
@@ -37,6 +47,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from paddle_tpu.core.registry import register_op
+from paddle_tpu.kernels import row_permute
 from paddle_tpu.ops.common import amp_cast, single
 
 
@@ -63,27 +74,77 @@ def _held(ids, attrs):
     return (local >= 0) & (local < int(attrs["experts_held"])), local
 
 
+def _by_kernel(like, row_of_pair):
+    """Whether ``row_permute`` moves rows like ``like`` ([., d]) for these
+    tokens x k pairs: decided by what the call site can see, not by a flag."""
+    tokens, k = row_of_pair.shape
+    return row_permute.applies(tokens, tokens * k, like.shape[1], like.dtype)
+
+
+def _lowered_into_a_step(ctx, op_type):
+    """Not the shape inference when the Program is built (no engine), nor
+    the forward's replay inside its grad op."""
+    return ctx.op.type == op_type and ctx.executor is not None
+
+
+def _count_form(by_kernel):
+    from paddle_tpu import observability as obs
+
+    obs.inc("moe.permute_kernel" if by_kernel else "moe.permute_xla")
+
+
+def _live(counts, rows):
+    return jnp.arange(rows, dtype=jnp.int32) < jnp.sum(counts)
+
+
+def _rows_of_tokens_xla(x, pair_of_row, counts, k):
+    return jnp.where(_live(counts, pair_of_row.shape[0])[:, None],
+                     x[pair_of_row // k], 0)
+
+
+def _rows_of_tokens(x, pair_of_row, counts, row_of_pair):
+    """rows[r] = x[token of row r] where the row is in use (else 0, or
+    unspecified in a tile the kernel never visits)."""
+    k = row_of_pair.shape[1]
+    if _by_kernel(x, row_of_pair):
+        return row_permute.expand(x, pair_of_row, counts, k)
+    return _rows_of_tokens_xla(x, pair_of_row, counts, k)
+
+
+def _sums_of_rows_xla(rows, row_of_pair, pair_held, dtype):
+    return jnp.sum(jnp.where(pair_held[..., None], rows[row_of_pair], 0)
+                   .astype(jnp.float32), axis=1).astype(dtype)
+
+
+def _sums_of_rows(rows, row_of_pair, pair_held, pair_of_row, counts, dtype):
+    """out[t] = the float32 sum of the rows of t's held pairs, as ``dtype``:
+    by the inverse permutation, no scatter-add."""
+    if _by_kernel(rows, row_of_pair):
+        return row_permute.reduce(rows, pair_of_row, counts,
+                                  row_of_pair.shape[1], dtype)
+    return _sums_of_rows_xla(rows, row_of_pair, pair_held, dtype)
+
+
 @jax.custom_vjp
-def _to_rows(x, weight, pair_of_row, row_live, row_of_pair, pair_held):
+def _to_rows(x, weight, pair_of_row, counts, row_of_pair, pair_held):
     """(rows[r] = x[token of row r], its pair's weight) where the row is
     in use, else 0."""
-    k = weight.shape[1]
-    rows = jnp.where(row_live[:, None], x[pair_of_row // k], 0)
-    return rows, jnp.where(row_live, weight.reshape(-1)[pair_of_row], 0)
+    return (_rows_of_tokens(x, pair_of_row, counts, row_of_pair),
+            jnp.where(_live(counts, pair_of_row.shape[0]),
+                      weight.reshape(-1)[pair_of_row], 0))
 
 
-def _to_rows_fwd(x, weight, pair_of_row, row_live, row_of_pair, pair_held):
-    return (_to_rows(x, weight, pair_of_row, row_live, row_of_pair,
-                     pair_held), (row_of_pair, pair_held))
+def _to_rows_fwd(x, weight, pair_of_row, counts, row_of_pair, pair_held):
+    return (_to_rows(x, weight, pair_of_row, counts, row_of_pair, pair_held),
+            (pair_of_row, counts, row_of_pair, pair_held))
 
 
 def _to_rows_bwd(res, g):
-    row_of_pair, pair_held = res
+    pair_of_row, counts, row_of_pair, pair_held = res
     g_rows, g_weight = g
-    # a token's gradient is the sum over its held pairs' rows: a gather by
-    # the inverse permutation
-    dx = jnp.sum(jnp.where(pair_held[..., None], g_rows[row_of_pair], 0)
-                 .astype(jnp.float32), axis=1).astype(g_rows.dtype)
+    _count_form(_by_kernel(g_rows, row_of_pair))
+    dx = _sums_of_rows(g_rows, row_of_pair, pair_held, pair_of_row, counts,
+                       g_rows.dtype)
     return (dx, jnp.where(pair_held, g_weight[row_of_pair], 0), None, None,
             None, None)
 
@@ -112,24 +173,31 @@ def moe_dispatch(ctx, ins, attrs):
     counts = jnp.sum(
         key[:, None] == jnp.arange(held_n, dtype=jnp.int32)[None, :],
         axis=0, dtype=jnp.int32)
-    row_live = jnp.arange(n * k, dtype=jnp.int32) < jnp.sum(counts)
     rows, row_weight = _to_rows(amp_cast(x), single(ins, "TopkWeight"),
-                                order, row_live, row_of_pair, held)
-    if ctx.op.type == "moe_dispatch" and obs.enabled():
+                                order, counts, row_of_pair, held)
+    if _lowered_into_a_step(ctx, "moe_dispatch") and obs.enabled():
+        by_kernel = _by_kernel(rows, row_of_pair)
+        _count_form(by_kernel)
         obs.inc("moe.layers")
         obs.set_gauge("moe.buffer_rows", n * k)
         obs.set_gauge("moe.experts_held", held_n)
-        jax.debug.callback(_publish_load, counts)
+        jax.debug.callback(
+            _publish_load, counts,
+            row_permute.visits(order, counts, k, n, False)[0]
+            if by_kernel else None)
     return {"Rows": [rows], "Counts": [counts], "RowWeight": [row_weight],
             "RowOfPair": [row_of_pair], "PairOfRow": [order]}
 
 
-def _publish_load(counts):
+def _publish_load(counts, visits):
     """Per step, under the ``metrics`` flag: pairs that fell on held
-    experts and the busiest held expert's load over the mean."""
+    experts, the busiest held expert's load over the mean and, where the
+    kernel moves the rows, the (tile, chunk) visits of one movement."""
     from paddle_tpu import observability as obs
 
     counts = np.asarray(counts)
+    if visits is not None:
+        obs.set_gauge("moe.permute_visits", int(np.asarray(visits)[0]))
     obs.set_gauge("moe.pairs_held", int(counts.sum()))
     obs.set_gauge("moe.load_max_over_mean",
                   float(counts.max() / max(counts.mean(), 1e-9)))
@@ -155,25 +223,27 @@ def moe_expert_mlp(ctx, ins, attrs):
 
 
 @jax.custom_vjp
-def _sum_of_rows(rows, row_of_pair, pair_held, pair_of_row, row_live):
+def _sum_of_rows(rows, row_of_pair, pair_held, pair_of_row, counts):
     """out[t] = sum of the rows of t's held pairs, float32."""
-    return jnp.sum(jnp.where(pair_held[..., None], rows[row_of_pair], 0)
-                   .astype(jnp.float32), axis=1)
+    return _sums_of_rows(rows, row_of_pair, pair_held, pair_of_row, counts,
+                         jnp.float32)
 
 
-def _sum_fwd(rows, row_of_pair, pair_held, pair_of_row, row_live):
-    return (_sum_of_rows(rows, row_of_pair, pair_held, pair_of_row,
-                         row_live),
+def _sum_fwd(rows, row_of_pair, pair_held, pair_of_row, counts):
+    return (_sum_of_rows(rows, row_of_pair, pair_held, pair_of_row, counts),
             # (an empty array carries the rows' dtype to the backward)
-            (pair_of_row, row_live, jnp.zeros((0,), rows.dtype)))
+            (pair_of_row, counts, row_of_pair,
+             jnp.zeros((0,), rows.dtype)))
 
 
 def _sum_bwd(res, g):
-    pair_of_row, row_live, like = res
-    k = pair_of_row.shape[0] // g.shape[0]
-    # a row's gradient is its token's: a gather
-    d_rows = jnp.where(row_live[:, None], g[pair_of_row // k], 0)
-    return d_rows.astype(like.dtype), None, None, None, None
+    pair_of_row, counts, row_of_pair, like = res
+    # a row's gradient is its token's, in the rows' dtype (the cast and the
+    # movement commute)
+    g = g.astype(like.dtype)
+    _count_form(_by_kernel(g, row_of_pair))
+    return (_rows_of_tokens(g, pair_of_row, counts, row_of_pair), None, None,
+            None, None)
 
 
 _sum_of_rows.defvjp(_sum_fwd, _sum_bwd)
@@ -185,9 +255,10 @@ def moe_combine(ctx, ins, attrs):
     """Rows [N*k, d] (the expert MLP's weighted result), TopkIds,
     RowOfPair, PairOfRow, Counts -> Out [N, d] float32: every token's sum
     over the pairs held here (zero where none is)."""
-    rows = single(ins, "Rows")
+    rows, row_of_pair = single(ins, "Rows"), single(ins, "RowOfPair")
     held, _ = _held(single(ins, "TopkIds"), attrs)
-    row_live = (jnp.arange(rows.shape[0], dtype=jnp.int32)
-                < jnp.sum(single(ins, "Counts")))
-    return {"Out": [_sum_of_rows(rows, single(ins, "RowOfPair"), held,
-                                 single(ins, "PairOfRow"), row_live)]}
+    if _lowered_into_a_step(ctx, "moe_combine"):
+        _count_form(_by_kernel(rows, row_of_pair))
+    return {"Out": [_sum_of_rows(rows, row_of_pair, held,
+                                 single(ins, "PairOfRow"),
+                                 single(ins, "Counts"))]}

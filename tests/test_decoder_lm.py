@@ -191,17 +191,23 @@ def test_the_grouped_matmul_kernel_agrees_with_ragged_dot():
 
 
 def test_the_expert_layer_counts_itself():
-    """Lowering-time counters and, under the metrics flag, the per-step
-    load of the held experts."""
+    """Lowering-time counters (the layer, and the form its two forward row
+    movements were lowered in: XLA's gathers on the CPU) and, under the
+    metrics flag, the per-step load of the held experts."""
     from paddle_tpu import observability as obs
 
     obs.set_enabled(True)
+    before = {name: obs.counter_value(name) for name in (
+        "moe.layers", "moe.permute_xla", "moe.permute_kernel")}
     rng = np.random.RandomState(3)
     _, counts = _expert_layer(rng.randn(16, D).astype(np.float32),
                               _params(rng), 2, 2)
     jax.effects_barrier()
-    assert obs.counter_value("moe.layers") >= 1
+    assert {name: obs.counter_value(name) - was
+            for name, was in before.items()} == {
+        "moe.layers": 1, "moe.permute_xla": 2, "moe.permute_kernel": 0}
     gauges = obs.snapshot()["gauges"]
+    assert "moe.permute_visits" not in gauges
     assert gauges["moe.buffer_rows"] == 16 * TOP
     assert gauges["moe.experts_held"] == 2
     assert gauges["moe.pairs_held"] == int(counts.sum())

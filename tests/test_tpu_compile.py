@@ -353,3 +353,70 @@ def test_grouped_matmuls_compile(one_chip, monkeypatch):
     hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)), rows, up,
                    up, down, sizes)
     assert hlo.count("tpu_custom_call") == 9
+
+
+# -- the expert layer's row movements (kernels/row_permute.py)
+
+@pytest.mark.parametrize("which", ["expand", "reduce_float32",
+                                   "reduce_bfloat16"])
+def test_row_permute_compiles(one_chip, which):
+    """Both directions at the decoder cell's shapes, [8192 x 8 -> 65536,
+    2304] bf16 with 16 experts held, at the module's tile and chunk: one
+    custom call each, the visit list round it in XLA."""
+    from paddle_tpu.kernels import row_permute as rp
+
+    tokens, k, d, held = 8192, 8, 2304, 16
+    order = jax.ShapeDtypeStruct((tokens * k,), jnp.int32, sharding=one_chip)
+    counts = jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip)
+    if which == "expand":
+        src = jax.ShapeDtypeStruct((tokens, d), jnp.bfloat16,
+                                   sharding=one_chip)
+        fn = lambda x, o, c: rp.expand(x, o, c, k)
+        out = (tokens * k, d), jnp.bfloat16
+    else:
+        src = jax.ShapeDtypeStruct((tokens * k, d), jnp.bfloat16,
+                                   sharding=one_chip)
+        dtype = jnp.dtype(which.split("_")[1])
+        fn = lambda r, o, c: rp.reduce(r, o, c, k, dtype)
+        out = (tokens, d), dtype
+    assert _compile(fn, src, order, counts).count("tpu_custom_call") == 1
+    got = jax.eval_shape(fn, src, order, counts)
+    assert (got.shape, got.dtype) == out
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_one_row_of_a_2d_hbm_ref_is_still_refused(one_chip, dtype):
+    """Why ``row_permute`` moves whole tiles: a DMA of single rows of a 2-D
+    array, as a row gather would issue them, does not compile for this
+    chip. When a later jax lifts the refusal this test says so, and a row
+    gather over the live tiles becomes worth a sweep."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(idx_ref, src_hbm, out_ref, sem):
+        def one(r, carry):
+            copy = pltpu.make_async_copy(
+                src_hbm.at[pl.ds(idx_ref[r], 1)], out_ref.at[pl.ds(r, 1)],
+                sem)
+            copy.start()
+            copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, 8, one, 0)
+
+    def gather(idx, src):
+        return pl.pallas_call(
+            kernel,
+            out_shape=jax.ShapeDtypeStruct((8, src.shape[1]), src.dtype),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(1,),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=pl.BlockSpec((8, src.shape[1]),
+                                       lambda i, idx: (0, 0)),
+                scratch_shapes=[pltpu.SemaphoreType.DMA(())]))(idx, src)
+
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _compile(gather,
+                 jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip),
+                 jax.ShapeDtypeStruct((8192, 2304), dtype,
+                                      sharding=one_chip))
