@@ -57,25 +57,18 @@ from paddle_tpu.analysis.spmd import (  # noqa: F401
     hlo_collectives,
     measured_collectives,
 )
-from paddle_tpu.analysis.layout import (  # noqa: F401
-    LayoutAssignPass,
-    LayoutPlan,
-    apply_layout,
-    plan_layout,
-    resolved_layout_mode,
-)
 
 __all__ = [
     "AnalysisContext", "DEFAULT_PASSES", "DiagnosticReport",
-    "DonationPlan", "Finding", "Graph", "LayoutAssignPass", "LayoutPlan",
-    "LivenessReport", "MemoryPlan", "OpNode", "PASS_REGISTRY", "Pass",
+    "DonationPlan", "Finding", "Graph", "LivenessReport", "MemoryPlan",
+    "OpNode", "PASS_REGISTRY", "Pass",
     "RematPlan", "Severity", "TRANSFORM_PIPELINE", "TransformContext",
     "TransformPass", "TransformReport", "VarNode", "VerificationError",
     "Collective", "SpmdReport", "analyze_spmd", "hlo_collectives",
     "measured_collectives",
-    "analyze_liveness", "apply_layout", "build_graph", "default_passes",
-    "optimize_program", "plan_donation", "plan_layout", "plan_memory",
+    "analyze_liveness", "build_graph", "default_passes",
+    "optimize_program", "plan_donation", "plan_memory",
     "plan_remat", "register_pass", "replan_segments",
-    "resolved_layout_mode", "transform_passes", "run_passes",
+    "transform_passes", "run_passes",
     "verify_graph", "verify_program",
 ]

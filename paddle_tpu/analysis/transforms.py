@@ -59,10 +59,6 @@ TRANSFORM_PIPELINE = (
     "fuse-elemwise-act",
     "fold-constants",
     "cse",
-    # last: the whole-program NHWC rewrite (analysis/layout.py) wants the
-    # final op set — fusions done, dead constants folded — before it
-    # partitions the def-use graph and bakes weight layouts
-    "layout-assign",
 )
 
 
@@ -153,6 +149,8 @@ def optimize_program(program_or_desc, level=None, feed_names=None,
         from paddle_tpu import flags
         level = int(flags.get_flag("opt_level"))
     level = int(level)
+    if level > 3:
+        raise ValueError("opt_level %d: valid levels are 0 to 3" % level)
     selected = transform_passes(level) if passes is None else list(passes)
     report = TransformReport(level)
     if level <= 0 or not selected:
@@ -945,7 +943,3 @@ class CSEPass(TransformPass):
                 if k not in _NONSEMANTIC_ATTRS and not k.startswith("__"))),
         )
 
-
-# Imported last so the layout pass can subclass TransformPass; the import
-# itself is what registers "layout-assign" in PASS_REGISTRY.
-from paddle_tpu.analysis import layout as _layout  # noqa: E402,F401

@@ -21,21 +21,6 @@ from paddle_tpu.engine.pipeline import (DeferredFetch, DispatchWindow,
 from paddle_tpu.resilience import faultinject
 
 
-def _auto_layout_format():
-    """The AUTO-layout Format when the opt-in applies, else None. Gated
-    to the TPU backend plus the auto_layout flag. jax.experimental.layout
-    exports only Format/Layout; the AUTO sentinel lives in the private
-    module on the installed jax."""
-    from paddle_tpu import flags
-
-    if not flags.get_flag("auto_layout") or jax.default_backend() != "tpu":
-        return None
-    from jax._src.layout import AutoLayout
-    from jax.experimental.layout import Format
-
-    return Format(AutoLayout())
-
-
 class CompiledBlock:
     def __init__(self, block_program, jitted, mutated_names, readonly_names,
                  in_shardings=None, memory_plan=None, remat_segments=0):
@@ -78,14 +63,12 @@ class CompiledBlock:
         # auto_remat_eligible mirrors the get_compiled auto-remat guard
         # (no mesh/accumulation/test program/manual segments); _rebuild
         # re-compiles with a new segment count; mem_budget is the HBM
-        # budget the plan was made against; _layout_scope pins the scope
-        # whose id() rides in the cache key under the layout pass
+        # budget the plan was made against
         self.replanned = False
         self.auto_remat_eligible = False
         self.mem_budget = None
         self._cache_key = None
         self._rebuild = None
-        self._layout_scope = None
 
 
 class Engine:
@@ -236,7 +219,7 @@ class Engine:
                 cache_key_extra=cache_key_extra, mesh=mesh,
                 shard_rules=shard_rules, data_axes=data_axes,
                 remat_segments=remat_segments, verify=verify,
-                opt_level=opt_level, sdc=sdc, scope=scope)
+                opt_level=opt_level, sdc=sdc)
 
         with obs.span("gather", step=step):
             feed_values, mutated, readonly = self._gather(
@@ -587,7 +570,7 @@ class Engine:
                      fetch_list, is_test, donate_state, amp,
                      accumulate_steps, cache_key_extra=None, mesh=None,
                      shard_rules=None, data_axes=("dp",), remat_segments=0,
-                     verify=None, opt_level=None, sdc=False, scope=None):
+                     verify=None, opt_level=None, sdc=False):
         """LRU-cached executable lookup/compile for one (program, feed
         signature) — shared by ``run_block`` and the Executor's
         ``cost_analysis`` so an analysis compiles exactly the executable
@@ -629,18 +612,6 @@ class Engine:
             from paddle_tpu.analysis import memory as memplan
 
             mem_budget = memplan.hbm_budget_bytes()
-        # The layout pass bakes weight values OIHW->HWIO in the SCOPE, so
-        # a layout-rewritten executable is only valid against the scope it
-        # was compiled for: key on (mode, scope identity). The compiled
-        # entry pins the scope object (below) so the id can never be
-        # recycled while the entry lives.
-        layout_key = None
-        if opt_level > 0:
-            from paddle_tpu.analysis.layout import resolved_layout_mode
-
-            mode = resolved_layout_mode(opt_level)
-            if mode is not None:
-                layout_key = (mode, id(scope) if scope is not None else None)
         key = (
             program_desc.cached_fingerprint(),
             block_idx,
@@ -657,7 +628,6 @@ class Engine:
             mesh_key,
             mem_budget,
             sdc,
-            layout_key,
             bool(flags.get_flag("opprof")),
         )
         compiled = self._cache.get(key)
@@ -691,8 +661,7 @@ class Engine:
 
                     run_desc, _report = optimize_program(
                         program_desc, level=opt_level,
-                        feed_names=feed_names, fetch_names=fetch_list,
-                        scope=scope)
+                        feed_names=feed_names, fetch_names=fetch_list)
                 memory_plan, auto_remat = None, 0
                 if opt_level >= 3:
                     # Memory planning on the POST-transform desc (the
@@ -787,7 +756,7 @@ class Engine:
             # measured-feedback re-planning metadata (_maybe_replan):
             # eligible exactly where auto-remat was legal, with a rebuild
             # closure that re-lowers the SAME post-transform desc at a
-            # new segment count — the layout/transform work is not redone
+            # new segment count — the transform work is not redone
             # Static SPMD plan on the POST-transform desc (mesh compiles
             # only), crash-isolated like the memory planner: the
             # predicted collective schedule rides on the executable and
@@ -827,8 +796,6 @@ class Engine:
                 and accumulate_steps <= 1 and mesh is None and not is_test)
             compiled.mem_budget = mem_budget
             compiled._cache_key = key
-            if layout_key is not None:
-                compiled._layout_scope = scope
 
             def _rebuild(new_segments, new_plan, _desc=run_desc):
                 return self._compile(
@@ -906,7 +873,6 @@ class Engine:
         fresh.mem_budget = compiled.mem_budget
         fresh._cache_key = compiled._cache_key
         fresh._rebuild = compiled._rebuild
-        fresh._layout_scope = compiled._layout_scope
         key = compiled._cache_key
         if self._cache.get(key) is compiled:
             self._cache[key] = fresh
@@ -1077,31 +1043,6 @@ class Engine:
 
         donate = (1,) if (donate_state and mutated) else ()
         jit_kwargs = {}
-        fmt = _auto_layout_format() if mesh is None else None
-        if fmt is not None:
-            # Opt-in AUTO entry/exit layouts for the STATE: XLA picks one
-            # layout per state var, input and output agree, donation
-            # aliases cleanly, and the state cycles through the jit with
-            # zero relayout. Measured a NULL lever on this round's
-            # benches (XLA's defaults already avoid per-step relayout) —
-            # see the auto_layout flag help. Feeds keep default layouts
-            # so host arrays feed them directly; mesh path unchanged
-            # (NamedShardings occupy the shardings slots there).
-            jit_kwargs["in_shardings"] = (
-                [None] * len(feed_values or []),
-                [fmt] * len(mutated),
-                [fmt] * len(readonly),
-                None,
-            )
-            # fetches are AUTO too: donation pairs inputs to ANY
-            # shape/dtype-compatible output (a [1] beta-pow accumulator
-            # can alias the loss fetch), and a donated-AUTO input may not
-            # alias a fixed-layout output; host reads are layout-agnostic
-            jit_kwargs["out_shardings"] = (
-                [fmt] * (len(bp.fetch_names) - len(sdc_grad_names)
-                         + (1 if sdc else 0)),
-                [fmt] * len(bp.state_out_names),
-            )
         if mesh is not None:
             # SPMD: batch-shard the feeds over the data axes and lay out
             # state per the declared sharding rules (replicated when no rule

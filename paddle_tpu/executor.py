@@ -116,8 +116,7 @@ class Executor:
             program.desc, 0, feed_names, feed_values, fetch_names,
             getattr(program, "_is_test", False), True,
             getattr(program, "_amp", False), accumulate_steps,
-            remat_segments=remat_segments, opt_level=opt_level,
-            scope=scope)
+            remat_segments=remat_segments, opt_level=opt_level)
         mutated = [self.engine._state_value(scope, n)
                    for n in compiled.mutated_names]
         readonly = [self.engine._state_value(scope, n)

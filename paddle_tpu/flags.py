@@ -39,18 +39,9 @@ DEFS = {
         "fusion, constant folding, and CSE, 3 = + memory planning "
         "(analysis/memory.py): liveness-driven state donation and "
         "automatic rematerialization under the HBM budget "
-        "(PADDLE_TPU_HBM_BUDGET_FRAC), 4 = + whole-program NHWC layout "
-        "assignment (analysis/layout.py) when PADDLE_TPU_LAYOUT is "
-        "'auto'. Rewrites operate on a clone; the program desc is never "
-        "mutated."),
-    "layout": (
-        str, "auto",
-        "Whole-program layout assignment (analysis/layout.py): rewrite "
-        "every conv/pool/batch_norm (and their grads) to NHWC, bake "
-        "OIHW filters to HWIO in the scope, and insert transpose2 seams "
-        "only at feed/fetch/flatten boundaries. 'auto' = on at opt_level "
-        ">= 4, 'nhwc' = on whenever transforms run, 'off' = never. The "
-        "engine keys its executable cache on the resolved value."),
+        "(PADDLE_TPU_HBM_BUDGET_FRAC); a level above 3 raises "
+        "ValueError. Rewrites operate on a clone; the program desc is "
+        "never mutated."),
     "replan_tolerance": (
         float, 0.0,
         "Measured-feedback memory re-planning: when the realized XLA "
@@ -132,13 +123,6 @@ DEFS = {
         float, 180000.0,
         "Deadline for pserver RPC replies; <=0 disables (reference: "
         "FLAGS_rpc_deadline)."),
-    "auto_layout": (
-        bool, False,
-        "Let XLA choose entry/exit buffer layouts for training state "
-        "(TPU only). Measured a NULL lever on BERT/ResNet in round 5 "
-        "(XLA's defaults already avoid per-step relayout; the suspected "
-        "optimizer-fusion slowness turned out to be the dW matmul fused "
-        "into the update) — kept as an opt-in knob for other models."),
     "flash_min_seq": (
         int, 256,
         "Minimum key length at which fused_attention dispatches to the "

@@ -155,18 +155,6 @@ class FoldBatchNormPass(TransformPass):
         eps = float(op.attrs.get("epsilon", 1e-5))
         alpha = vals["Scale"] / np.sqrt(vals["Variance"] + eps)
         if w.ndim == 4:            # conv OIHW: scale per output channel O
-            # a layout-enabled compile (analysis/layout.py) may have
-            # baked this filter HWIO in the scope; fold in OIHW and let
-            # the layout pass re-bake the .bnfold weight on its own
-            # terms when the frozen program compiles with layout on
-            w_vd0 = block.find_var_recursive(w_name)
-            declared = tuple(w_vd0.shape) \
-                if w_vd0 is not None and w_vd0.shape else tuple(w.shape)
-            hwio = tuple(declared[i] for i in (2, 3, 1, 0))
-            if (w_name in getattr(scope, "_layout_hwio", ())
-                    or (tuple(w.shape) == hwio
-                        and tuple(w.shape) != declared)):
-                w = np.transpose(w, (3, 2, 0, 1))  # HWIO -> OIHW
             if alpha.shape[0] != w.shape[0]:
                 return False
             w_f = w * alpha.reshape(-1, 1, 1, 1)

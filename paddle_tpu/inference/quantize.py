@@ -262,19 +262,6 @@ def quantize_desc(desc, scope, ranges, per_channel=True, skip_vars=()):
                 i += 1
                 continue
             w = np.asarray(w_val, np.float32)
-            if w.ndim == 4:
-                # a layout-enabled compile may have baked this filter
-                # HWIO in the scope (analysis/layout.py); quantize in
-                # OIHW — the layout pass re-bakes the .int8 weight when
-                # the quantized program compiles with layout on
-                w_vd = b.find_var_recursive(w_name)
-                declared = tuple(w_vd.shape) \
-                    if w_vd is not None and w_vd.shape else tuple(w.shape)
-                hwio = tuple(declared[i] for i in (2, 3, 1, 0))
-                if (w_name in getattr(scope, "_layout_hwio", ())
-                        or (tuple(w.shape) == hwio
-                            and tuple(w.shape) != declared)):
-                    w = np.transpose(w, (3, 2, 0, 1))
             if w.ndim not in (2, 4) or (
                     w.ndim != 4) == (op.type in ("conv2d",
                                                  "depthwise_conv2d")):

@@ -785,8 +785,7 @@ def pick_block(t, dtype=None):
     operators/jit/README.en.md). Lookup is by (dtype, nearest swept seq);
     the winning block is clamped to one that tiles ``t``. Heuristic
     fallback (256 when it tiles) if the table is absent. Shared by the
-    fused_attention dispatch and bench.py so the benchmark measures the
-    production configuration."""
+    fused_attention dispatch and chip_smoke.py's kernel check."""
     row = _table_row(t, dtype)
     if row is not None:
         if isinstance(row, dict):

@@ -10,8 +10,8 @@ not cover):
   rewrite-fire counts; the lowering records op counts.
 * a **span tracer** (tracing.py): RAII host spans (step → trace →
   transform/verify/lower → compile/run), exportable as chrome-trace
-  JSON that merges with the xplane device traces tools/timeline.py
-  converts.
+  JSON that merges with the xplane device traces
+  ``tracing.xplane_to_chrome_trace`` converts.
 
 Everything is gated by ``PADDLE_TPU_METRICS`` (flags.py): with the flag
 down every helper here is one module-bool check — no locks, no

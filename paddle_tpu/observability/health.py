@@ -94,8 +94,8 @@ EWMA_ALPHA = 0.3
 
 # -- the per-rank step counter the heartbeat reports ------------------------
 # Plain dict mutation under the GIL: these notes are the only calls on
-# the engine's step path and must stay in the ns regime (bench.py
-# counters.health proves it). Multi-step dispatch (engine/pipeline.py)
+# the engine's step path and must stay in the ns regime. Multi-step
+# dispatch (engine/pipeline.py)
 # splits "a step happened" into two edges: ENQUEUED when the host hands
 # the step to the device queue, RETIRED when its results materialize.
 # The hang classifier reads RETIRED ("step" in the heartbeat payload) —

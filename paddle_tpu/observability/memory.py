@@ -55,8 +55,8 @@ def _obs():
 
 
 def reset_peaks():
-    """Zero the watermark state (bench.py calls this between models so
-    ``peak_hbm_bytes()`` attributes per model)."""
+    """Zero the watermark state (``observability.reset()`` calls this,
+    so ``peak_hbm_bytes()`` attributes to what ran since)."""
     with _lock:
         _state["live_peak"] = 0
         _state["compile_peak"] = 0
@@ -65,9 +65,9 @@ def reset_peaks():
 
 def peak_hbm_bytes():
     """The high-watermark since the last ``reset_peaks()``: max of the
-    live-census peak and the compile-time peak estimate — the headline
-    "how much device memory did this model need" number bench.py
-    publishes per model."""
+    live-census peak and the compile-time peak estimate — the "how much
+    device memory did this need" number the heartbeat reports
+    (observability/health.py)."""
     with _lock:
         return max(_state["live_peak"], _state["compile_peak"])
 

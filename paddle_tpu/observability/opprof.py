@@ -32,8 +32,7 @@ granularity in three stages:
    process.
 
 Plane parsing (:func:`iter_planes`, :func:`top_ops`) lives HERE — the
-package must never import from ``tools/``; ``tools/xplane_top_ops.py``
-is a thin CLI shim over this module. Traces are read through
+package must never import from ``tools/``. Traces are read through
 ``jax.profiler.ProfileData``, with nothing but JAX.
 
 CPU-probe caveat: CPU xplane planes attribute coarsely (thread lines
@@ -439,13 +438,13 @@ def load_sidecar(trace_dir):
         return None
 
 
-# -- xplane parsing (hoisted from tools/xplane_top_ops.py) ------------------
+# -- xplane parsing ---------------------------------------------------------
 def iter_planes(trace_dir):
     """Yield every non-empty DISTINCT plane (``jax.profiler.ProfileData``
     planes: ``.name``, ``.lines`` of ``.events`` with ``.name``,
     ``.start_ns``, ``.duration_ns``, ``.stats``) from the .xplane.pb
-    files under ``trace_dir`` (shared by tools/xplane_top_ops.py,
-    tools/timeline.py and observability/tracing.py). Planes alike to the
+    files under ``trace_dir`` (shared by ``top_ops`` and
+    observability/tracing.py). Planes alike to the
     last event are skipped — some sessions embed the same device plane
     in more than one dump file, which would double every aggregate —
     while genuine multi-host planes (same name, different

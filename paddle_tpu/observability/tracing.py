@@ -2,10 +2,10 @@
 chrome-trace JSON.
 
 The host half of the reference's RecordEvent timeline (reference:
-platform/profiler.h:82 RecordEvent + tools/timeline.py chrome-trace
+platform/profiler.h:82 RecordEvent + its timeline tool's chrome-trace
 export): ``span("compile")`` records start + duration on exit, spans
 nest per thread, and ``chrome_trace()`` emits the same event schema
-tools/timeline.py produces from the jax xplane dump — complete
+``xplane_to_chrome_trace`` produces from the jax xplane dump — complete
 ("ph": "X") slices with microsecond timestamps — so a host dump and a
 device trace load side by side in chrome://tracing / perfetto and line
 up on the wall clock (both timebases are ns-since-epoch).
@@ -337,9 +337,8 @@ def xplane_to_chrome_trace(trace_dir, line_filter=None):
     with the device lanes are the ``pt.<name>`` events the session wrote
     on ``/host:CPU``, in the same file.
     ``line_filter`` (substring, e.g. "XLA Ops") keeps matching lines
-    only. Folded in from tools/timeline.py so the package owns ONE
-    trace-export entry point (``dump_chrome_trace(path, xplane_dir)``);
-    the tools CLI is now a thin shim over this."""
+    only. The package owns ONE trace-export entry point
+    (``dump_chrome_trace(path, xplane_dir)``)."""
     from paddle_tpu.observability.opprof import iter_planes
 
     events = []

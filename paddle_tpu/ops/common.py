@@ -72,6 +72,18 @@ def single(ins, slot, default=None):
     return vals[0] if vals else default
 
 
+def require_nchw(ctx, attrs):
+    """The conv / pool lowerings compute NCHW with OIHW filters. A loaded
+    program may carry ``data_format`` "AnyLayout" (the reference's
+    default); "NHWC" is refused, not computed as NCHW on NHWC data."""
+    fmt = attrs.get("data_format", "NCHW")
+    if fmt not in ("NCHW", "AnyLayout"):
+        raise ValueError(
+            "%s: data_format %r is not supported; the lowering is NCHW "
+            "(transpose the input, or leave the attribute out)"
+            % (ctx.op.type, fmt))
+
+
 def flatten_lookup_ids(ids):
     """lookup_table id normalization: a trailing dim of 1 is squeezed
     (reference: lookup_table_op.cc treats ids as a column of indices)."""

@@ -9,6 +9,11 @@ paddle/fluid/framework/data_layout_transform.cc becomes a no-op concern).
 Pre-round record (one v5e, July 2026): an end-to-end NHWC ResNet-50
 formulation timed within +0.3% of this NCHW lowering (tools/resnet_probe.py
 full-nhwc) — the logical layout is immaterial under XLA:TPU.
+PR 31's reading (one v5e, the resnet50.train_b256 cell, five pairs on
+shared seeds): the whole-program NHWC/HWIO rewrite read 2,555.1 samples/s
+against 2,544.6 for this lowering, +0.41% in every pair and under the
+cell's 1% bound; the pass, its flag and the NHWC branches here were
+deleted on that reading (PERF.md, Findings PR 31).
 """
 
 import numpy as np
@@ -17,19 +22,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from paddle_tpu.core.registry import register_op, register_no_grad_op
-from paddle_tpu.ops.common import amp_cast, fp32_accum, single
-
-
-def _conv_dn(ndim):
-    if ndim == 4:
-        return lax.conv_dimension_numbers(
-            (1, 1, 1, 1), (1, 1, 1, 1), ("NCHW", "OIHW", "NCHW")
-        )
-    raise NotImplementedError
+from paddle_tpu.ops.common import (amp_cast, fp32_accum, require_nchw,
+                                   single)
 
 
 @register_op("conv2d")
 def conv2d(ctx, ins, attrs):
+    require_nchw(ctx, attrs)
     x = single(ins, "Input")  # NCHW
     w = single(ins, "Filter")  # OIHW (I = C/groups)
     # Under AMP the conv runs wholly in bf16 (the MXU accumulates fp32
@@ -48,13 +47,8 @@ def _conv2d_apply(x, w, attrs):
     dilations = tuple(attrs.get("dilations", [1, 1]))
     groups = attrs.get("groups", 1)
     pad = [(paddings[0], paddings[0]), (paddings[1], paddings[1])]
-    # data_format NHWC = the layout-assignment pass (analysis/layout.py)
-    # rewrote this op; the filter arrives HWIO (baked into the scope)
-    if attrs.get("data_format", "NCHW") == "NHWC":
-        dims = ("NHWC", "HWIO", "NHWC")
-    else:
-        dims = ("NCHW", "OIHW", "NCHW")
-    dn = lax.conv_dimension_numbers(x.shape, w.shape, dims)
+    dn = lax.conv_dimension_numbers(
+        x.shape, w.shape, ("NCHW", "OIHW", "NCHW"))
     return lax.conv_general_dilated(
         x, w, window_strides=strides, padding=pad, rhs_dilation=dilations,
         dimension_numbers=dn, feature_group_count=groups,
@@ -71,6 +65,7 @@ def conv2d_grad(ctx, ins, attrs):
     with the other operand fixed — this emits ONLY the transposed
     convolution, never a recomputed forward primal for XLA to CSE away
     (the round-2 per-op jax.vjp residue)."""
+    require_nchw(ctx, attrs)
     x = single(ins, "Input")
     w = single(ins, "Filter")
     g = single(ins, "Output@GRAD")
@@ -85,17 +80,11 @@ def conv2d_grad(ctx, ins, attrs):
             "Filter@GRAD": [dw.astype(w.dtype)]}
 
 
-def _depthwise_groups(x, attrs):
-    # channel count lives last under the layout pass's NHWC rewrite
-    return x.shape[3] if attrs.get("data_format", "NCHW") == "NHWC" \
-        else x.shape[1]
-
-
 @register_no_grad_op("depthwise_conv2d_grad")
 def depthwise_conv2d_grad(ctx, ins, attrs):
     x = single(ins, "Input")
     attrs = dict(attrs)
-    attrs["groups"] = _depthwise_groups(x, attrs)
+    attrs["groups"] = x.shape[1]
     return conv2d_grad(ctx, ins, attrs)
 
 
@@ -103,7 +92,7 @@ def depthwise_conv2d_grad(ctx, ins, attrs):
 def depthwise_conv2d(ctx, ins, attrs):
     x = single(ins, "Input")
     attrs = dict(attrs)
-    attrs["groups"] = _depthwise_groups(x, attrs)
+    attrs["groups"] = x.shape[1]
     return conv2d(ctx, ins, attrs)
 
 
@@ -148,7 +137,8 @@ def conv2d_transpose(ctx, ins, attrs):
 
 @register_op("pool2d")
 def pool2d(ctx, ins, attrs):
-    x = single(ins, "X")  # NCHW, or NHWC after the layout pass
+    require_nchw(ctx, attrs)
+    x = single(ins, "X")  # NCHW
     ptype = attrs.get("pooling_type", "max")
     ksize = attrs.get("ksize", [2, 2])
     strides = attrs.get("strides", [1, 1])
@@ -157,24 +147,18 @@ def pool2d(ctx, ins, attrs):
     exclusive = attrs.get("exclusive", True)
     adaptive = attrs.get("adaptive", False)
     ceil_mode = attrs.get("ceil_mode", False)
-    nhwc = attrs.get("data_format", "NCHW") == "NHWC"
-    spatial = (1, 2) if nhwc else (2, 3)
 
     if global_pooling or (adaptive and list(ksize) == [1, 1]):
         if ptype == "max":
-            out = jnp.max(x, axis=spatial, keepdims=True)
+            out = jnp.max(x, axis=(2, 3), keepdims=True)
         else:
             # fp32 accumulation for low-precision (H*W-element sums)
-            out = jnp.mean(fp32_accum(x), axis=spatial,
+            out = jnp.mean(fp32_accum(x), axis=(2, 3),
                            keepdims=True).astype(x.dtype)
         return {"Out": [out]}
 
-    if nhwc:
-        window = (1, ksize[0], ksize[1], 1)
-        strides_ = (1, strides[0], strides[1], 1)
-    else:
-        window = (1, 1, ksize[0], ksize[1])
-        strides_ = (1, 1, strides[0], strides[1])
+    window = (1, 1, ksize[0], ksize[1])
+    strides_ = (1, 1, strides[0], strides[1])
     if ceil_mode:
         # pad right/bottom enough that the last partial window is included
         def _extra(in_sz, k, s, p):
@@ -182,13 +166,12 @@ def pool2d(ctx, ins, attrs):
             needed = (out_sz - 1) * s + k - in_sz - p
             return max(needed, p)
 
-        eh = _extra(x.shape[spatial[0]], ksize[0], strides[0], paddings[0])
-        ew = _extra(x.shape[spatial[1]], ksize[1], strides[1], paddings[1])
+        eh = _extra(x.shape[2], ksize[0], strides[0], paddings[0])
+        ew = _extra(x.shape[3], ksize[1], strides[1], paddings[1])
         sp = ((paddings[0], eh), (paddings[1], ew))
     else:
         sp = ((paddings[0], paddings[0]), (paddings[1], paddings[1]))
-    pads = ((0, 0), sp[0], sp[1], (0, 0)) if nhwc \
-        else ((0, 0), (0, 0), sp[0], sp[1])
+    pads = ((0, 0), (0, 0), sp[0], sp[1])
 
     if ptype == "max":
         init = -jnp.inf if jnp.issubdtype(x.dtype, jnp.floating) else jnp.iinfo(x.dtype).min

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from paddle_tpu.core.registry import register_op, register_no_grad_op
-from paddle_tpu.ops.common import single
+from paddle_tpu.ops.common import require_nchw, single
 
 
 def _qrange(bits):
@@ -138,18 +138,18 @@ def quantized_matmul(ctx, ins, attrs):
 
 @register_no_grad_op("quantized_conv2d")
 def quantized_conv2d(ctx, ins, attrs):
-    x = single(ins, "Input")   # int8 NCHW (NHWC after the layout pass)
-    w = single(ins, "Filter")  # int8 OIHW (HWIO after the layout pass)
+    require_nchw(ctx, attrs)
+    x = single(ins, "Input")   # int8 NCHW
+    w = single(ins, "Filter")  # int8 OIHW
     sx = float(attrs.get("scale_x", 1.0))
     sw = _scale_param(attrs, "scale_w")  # scalar or [O] per-channel
     strides = tuple(attrs.get("strides", [1, 1]))
     paddings = attrs.get("paddings", [0, 0])
     dilations = tuple(attrs.get("dilations", [1, 1]))
     groups = int(attrs.get("groups", 1))
-    nhwc = attrs.get("data_format", "NCHW") == "NHWC"
     pad = [(paddings[0], paddings[0]), (paddings[1], paddings[1])]
-    dims = ("NHWC", "HWIO", "NHWC") if nhwc else ("NCHW", "OIHW", "NCHW")
-    dn = lax.conv_dimension_numbers(x.shape, w.shape, dims)
+    dn = lax.conv_dimension_numbers(
+        x.shape, w.shape, ("NCHW", "OIHW", "NCHW"))
     if _native_int8():
         acc = lax.conv_general_dilated(
             x.astype(jnp.int8), w.astype(jnp.int8),
@@ -163,6 +163,5 @@ def quantized_conv2d(ctx, ins, attrs):
             window_strides=strides, padding=pad, rhs_dilation=dilations,
             dimension_numbers=dn, feature_group_count=groups)
     if isinstance(sw, jnp.ndarray):
-        # per-O scale over the channel dim (last under NHWC)
-        sw = sw.reshape((1, 1, 1, -1) if nhwc else (1, -1, 1, 1))
+        sw = sw.reshape((1, -1, 1, 1))  # per-O scale over the channels
     return {"Output": [out / (sx * sw)]}

@@ -75,8 +75,9 @@ def cuda_device_count():
 
 def use_compilation_cache():
     """Point JAX's persistent compilation cache somewhere stable, for
-    entry points that pay large compiles (chip_smoke.py, bench.py) —
-    never at import. Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX has
+    entry points that pay large compiles (chip_smoke.py,
+    benchmarks/drivers/train.py) — never at import. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set JAX has
     already taken the directory from it and no path is set in code;
     otherwise the cache is ``<checkout>/.jax_cache``, a fixed path (the
     path is part of the cache key, so one that moves never hits).

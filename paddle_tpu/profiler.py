@@ -5,8 +5,8 @@ Drives BOTH halves of the telemetry stack together:
 
 * the **device** half: the JAX profiler, whose xplane traces load in
   TensorBoard/XProf and convert to chrome-trace JSON via
-  tools/timeline.py (the CUPTI + chrome-trace pipeline of the
-  reference, SURVEY.md §5);
+  ``observability.tracing.xplane_to_chrome_trace`` (the CUPTI +
+  chrome-trace pipeline of the reference, SURVEY.md §5);
 * the **host** half: paddle_tpu.observability spans (step → trace →
   transform/lower → compile/run) and the metrics registry. The session
   itself switches the spans on, and they land in the device trace too
@@ -194,7 +194,7 @@ def stop_profiler(sorted_key=None, profile_path="/tmp/profile"):
     observability.set_enabled(None)  # back to the PADDLE_TPU_METRICS gate
     if _trace_dir:
         print("profiler: device trace in %s (TensorBoard/XProf; "
-              "tools/timeline.py converts to chrome-trace), host summary "
+              "observability.dump_chrome_trace converts), host summary "
               "in %s" % (_trace_dir, profile_path))
 
 

@@ -232,10 +232,10 @@ class TestProfiler:
         assert x == 4
 
     def test_chrome_trace_timeline_export(self, tmp_path):
-        """tools/timeline.py converts a jax profiler xplane dump into
-        chrome://tracing JSON (capability parity with the reference
-        repo's tools/timeline.py — same workflow: profile, convert,
-        open in the trace viewer). The dump is read through
+        """``xplane_to_chrome_trace`` converts a jax profiler xplane
+        dump into chrome://tracing JSON (capability parity with the
+        reference repo's timeline tool — same workflow: profile,
+        convert, open in the trace viewer). The dump is read through
         ``jax.profiler.ProfileData``: no TensorFlow is needed."""
         import json
 
@@ -250,7 +250,8 @@ class TestProfiler:
         finally:
             jax.profiler.stop_trace()
 
-        from tools.timeline import xplane_to_chrome_trace
+        from paddle_tpu.observability.tracing import (
+            xplane_to_chrome_trace)
 
         trace = xplane_to_chrome_trace(tdir)
         evs = trace["traceEvents"]

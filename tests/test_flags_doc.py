@@ -5,6 +5,8 @@ in ``tools/lint_program.py --flags`` (same helper)."""
 
 import os
 
+import pytest
+
 from paddle_tpu import flags
 
 README = os.path.join(os.path.dirname(os.path.dirname(
@@ -30,3 +32,12 @@ def test_drift_is_detected(tmp_path):
     assert "no_such_flag_ever" in text    # stale row
     assert "2 times" in text              # duplicate row
     assert flags.flags_doc_issues(str(tmp_path / "absent.md"))
+
+
+def test_layout_flags_are_gone():
+    # the registry is what the README table and --flags are checked
+    # against: a removed flag is unknown to it, not silently ignored
+    assert len(flags.DEFS) == 59
+    assert not [name for name in flags.DEFS if "layout" in name]
+    with pytest.raises(KeyError):
+        flags.set_flags({"layout": "nhwc"})

@@ -2,8 +2,7 @@
 provenance scope tags, HLO op_metadata parsing with the dominant-fusion
 policy, xplane -> framework-op attribution on a real profiled MLP run,
 roofline classification, fused-op source lists at opt 2, the gate
-predicate, bench_diff directions for the new counters, and the
-bit-exactness guarantee — named_scope is metadata-only, so the
+predicate, and the bit-exactness guarantee — named_scope is metadata-only, so the
 instrumented lowering emits the same computation as the plain one.
 """
 
@@ -136,15 +135,6 @@ def test_gate_issues():
                 "expected_collective_instances": 2}
     issues = opprof.gate_issues(bad_comm)
     assert issues and "collective" in issues[0]
-
-
-def test_bench_diff_directions_for_opprof_keys():
-    from tools.bench_diff import direction
-
-    assert direction("opprof.pt.mul.0_3_ms") == "lower"
-    assert direction("opprof.unattributed_ms") == "lower"
-    assert direction("opprof.unattributed_frac") == "lower"
-    assert direction("opprof.attributed_frac") == "higher"
 
 
 # -- fused-op source lists ----------------------------------------------

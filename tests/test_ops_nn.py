@@ -138,6 +138,53 @@ class TestConv2d:
                                    atol=1e-5, rtol=1e-4)
 
 
+def _layout_case(op_type):
+    """One small call of an op whose lowering is NCHW only:
+    (inputs, output slot, attrs)."""
+    rng = np.random.RandomState(11)
+    if op_type == "pool2d":
+        x = rng.randn(2, 3, 8, 8).astype(np.float32)
+        return ({"X": [("x", x)]}, "Out",
+                {"pooling_type": "max", "ksize": [2, 2], "strides": [2, 2],
+                 "paddings": [0, 0]})
+    conv = {"strides": [1, 1], "paddings": [1, 1], "dilations": [1, 1],
+            "groups": 1}
+    if op_type == "conv2d":
+        x = rng.randn(2, 4, 8, 8).astype(np.float32)
+        w = rng.randn(6, 4, 3, 3).astype(np.float32)
+        return ({"Input": [("x", x)], "Filter": [("w", w)]}, "Output", conv)
+    x = rng.randint(-127, 128, (2, 4, 8, 8)).astype(np.int8)
+    w = rng.randint(-127, 128, (6, 4, 3, 3)).astype(np.int8)
+    return ({"Input": [("x", x)], "Filter": [("w", w)]}, "Output",
+            dict(conv, scale_x=4.0, scale_w=[float(i + 1) for i in range(6)]))
+
+
+class TestDataFormat:
+    """``data_format`` on conv2d / pool2d / quantized_conv2d: a loaded
+    program may say "AnyLayout" (the reference's default), which is
+    NCHW; "NHWC" is refused with the op's name, not computed as NCHW on
+    NHWC data."""
+
+    @pytest.mark.parametrize(
+        "op_type", ["conv2d", "pool2d", "quantized_conv2d"])
+    def test_nhwc_is_refused(self, op_type):
+        inputs, slot, attrs = _layout_case(op_type)
+        with pytest.raises(ValueError, match="%s: data_format 'NHWC'"
+                           % op_type):
+            run_single_op(op_type, inputs, [slot],
+                          attrs=dict(attrs, data_format="NHWC"))
+
+    @pytest.mark.parametrize(
+        "op_type", ["conv2d", "pool2d", "quantized_conv2d"])
+    def test_anylayout_is_nchw(self, op_type):
+        inputs, slot, attrs = _layout_case(op_type)
+        fetch = "out_%s" % slot.lower()
+        want = run_single_op(op_type, inputs, [slot], attrs=attrs)[fetch]
+        got = run_single_op(op_type, inputs, [slot],
+                            attrs=dict(attrs, data_format="AnyLayout"))[fetch]
+        np.testing.assert_array_equal(got, want)
+
+
 class TestPool2d:
     @pytest.mark.parametrize("ptype", ["max", "avg"])
     def test_forward_backward(self, ptype):
@@ -243,6 +290,51 @@ class TestBatchNorm:
         ref = (x - mean0.reshape(1, 3, 1, 1)) / np.sqrt(
             var0.reshape(1, 3, 1, 1) + 1e-5)
         np.testing.assert_allclose(got["out_y"], ref, atol=1e-4, rtol=1e-4)
+
+
+    @pytest.mark.parametrize("is_test", [False, True])
+    def test_layer_nhwc_equals_nchw_on_transposed_input(self, is_test):
+        """``layers.batch_norm(data_layout="NHWC")``: output, and in
+        training the gradients of scale and bias, equal the NCHW layer's
+        on the transposed input."""
+        rng = np.random.RandomState(12)
+        x = rng.randn(4, 3, 5, 6).astype(np.float32)
+        scale = rng.rand(3).astype(np.float32) + 0.5
+        bias = rng.randn(3).astype(np.float32)
+        got = {}
+        for layout in ("NCHW", "NHWC"):
+            main, startup = Program(), Program()
+            with program_guard(main, startup):
+                shape = [3, 5, 6] if layout == "NCHW" else [5, 6, 3]
+                inp = fluid.layers.data(name="x", shape=shape,
+                                        dtype="float32")
+                inp.stop_gradient = False
+                y = fluid.layers.batch_norm(
+                    inp, is_test=is_test, data_layout=layout,
+                    param_attr=fluid.ParamAttr(name="bn_scale"),
+                    bias_attr=fluid.ParamAttr(name="bn_bias"))
+                # a loss that is not symmetric in the positions
+                loss = fluid.layers.mean(y * y * inp)
+                fetch = [y]
+                if not is_test:
+                    fluid.append_backward(loss)
+                    fetch += ["bn_scale@GRAD", "bn_bias@GRAD", "x@GRAD"]
+            exe = fluid.Executor(fluid.CPUPlace())
+            scope = fluid.Scope()
+            exe.run(startup, scope=scope)
+            scope.set("bn_scale", scale)
+            scope.set("bn_bias", bias)
+            feed = x if layout == "NCHW" else x.transpose(0, 2, 3, 1)
+            out = exe.run(main, feed={"x": feed}, fetch_list=fetch,
+                          scope=scope)
+            if layout == "NHWC":  # back to NCHW to compare
+                out[0] = out[0].transpose(0, 3, 1, 2)
+                if not is_test:
+                    out[3] = out[3].transpose(0, 3, 1, 2)
+            got[layout] = out
+        assert len(got["NCHW"]) == (1 if is_test else 4)
+        for a, b in zip(got["NHWC"], got["NCHW"]):
+            np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-4)
 
 
 class TestLayerNorm:

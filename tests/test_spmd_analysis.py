@@ -308,7 +308,7 @@ def test_predicted_schedule_matches_compiled_hlo(which, axes):
         compiled = eng.get_compiled(
             main.desc, 0, feed_names, feed_values, [loss.name], False,
             True, False, 1, mesh=mesh, shard_rules=ShardingRules(),
-            opt_level=0, scope=scope)
+            opt_level=0)
         plan = compiled.spmd_plan  # the engine seam attached it
         assert plan is not None and not plan.empty
         mutated = [eng._state_value(scope, n)
@@ -383,7 +383,7 @@ def _compiled_schedule(main, startup, loss, feed, axes):
         compiled = eng.get_compiled(
             main.desc, 0, feed_names, feed_values, [loss.name], False,
             True, False, 1, mesh=mesh, shard_rules=ShardingRules(),
-            opt_level=0, scope=scope)
+            opt_level=0)
         plan = compiled.spmd_plan
         assert plan is not None and not plan.empty
         mutated = [eng._state_value(scope, n)

@@ -2,13 +2,18 @@
 target composition (must-rewrite) and leaves a near-miss alone; the
 attention rewrite fires on the real bert/transformer programs; a
 bert-style program trains to the same loss at opt level 0 and 2; every
-transformed desc passes the static verifier with zero errors; and the
-engine's executable cache evicts by capacity and recency."""
+transformed desc passes the static verifier with zero errors; the
+engine's executable cache evicts by capacity and recency, and its key
+holds the program and the call, never the state."""
+
+import pickle
 
 import numpy as np
+import pytest
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu import flags, models
+from paddle_tpu import observability as obs
 from paddle_tpu.analysis import optimize_program, verify_program
 from paddle_tpu.analysis.transforms import (
     AttentionFusePass,
@@ -328,3 +333,80 @@ def test_engine_cache_lru_capacity_and_recency():
             assert key_b not in engine._cache
     finally:
         flags.reset_flag("executable_cache_size")
+
+
+def _two_layer_regression():
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup):
+        x = fluid.layers.data(name="x", shape=[4], dtype="float32")
+        y = fluid.layers.data(name="y", shape=[1], dtype="float32")
+        h = fluid.layers.fc(input=x, size=8, act="relu")
+        loss = fluid.layers.mean(fluid.layers.square_error_cost(
+            fluid.layers.fc(input=h, size=1), y))
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    feed = {"x": np.ones((2, 4), np.float32),
+            "y": np.ones((2, 1), np.float32)}
+    return main, startup, loss, feed
+
+
+@pytest.mark.parametrize("level", [1, 2, 3])
+def test_one_executable_serves_two_scopes(level):
+    """The executable cache is keyed on the program and the call, never
+    on the state: the same Program run against a second Scope is a cache
+    hit at every opt level."""
+    main, startup, loss, feed = _two_layer_regression()
+    exe = fluid.Executor()
+    scopes = [fluid.Scope(), fluid.Scope()]
+    for scope in scopes:
+        exe.run(startup, scope=scope)
+    obs.set_enabled(True)
+    obs.reset()
+    for scope in scopes:
+        exe.run(main, feed=feed, fetch_list=[loss], scope=scope,
+                opt_level=level)
+    counters = obs.snapshot()["counters"]
+    assert counters["engine.cache_miss"] == 1
+    assert counters["engine.cache_hit"] == 1
+
+
+def test_cache_key_pickles_and_holds_no_object_identity():
+    """What a persistent executable cache needs of the key: it survives
+    a round trip through pickle equal to itself, and no part of it is
+    the id() of the scope the step ran against."""
+    main, startup, loss, feed = _two_layer_regression()
+    exe = fluid.Executor()
+    scope = fluid.Scope()
+    exe.run(startup, scope=scope)
+    exe.run(main, feed=feed, fetch_list=[loss], scope=scope)
+
+    def ints(part):
+        if isinstance(part, (tuple, list)):
+            for p in part:
+                yield from ints(p)
+        elif isinstance(part, int) and not isinstance(part, bool):
+            yield part
+
+    keys = [c._cache_key for c in exe.engine._cache.values()]
+    assert len(keys) == 2  # startup and main
+    for key in keys:
+        assert pickle.loads(pickle.dumps(key)) == key
+        assert id(scope) not in set(ints(key))
+
+
+@pytest.mark.parametrize("how", ["flag", "argument"])
+def test_opt_level_above_3_raises(how):
+    main, startup, loss, feed = _two_layer_regression()
+    exe = fluid.Executor()
+    scope = fluid.Scope()
+    exe.run(startup, scope=scope)
+    kwargs = {}
+    if how == "flag":
+        flags.set_flags({"opt_level": 4})
+    else:
+        kwargs["opt_level"] = 4
+    try:
+        with pytest.raises(ValueError, match="0 to 3"):
+            exe.run(main, feed=feed, fetch_list=[loss], scope=scope,
+                    **kwargs)
+    finally:
+        flags.reset_flag("opt_level")

@@ -18,9 +18,6 @@ reviewer) runs to prove the whole failure-domain story at once:
               persistent)                      vote, blame, quarantine
     preempt   SIGTERM eviction                 drain + checkpoint + free
                                                restart (no budget spent)
-    layout    rank loss mid-window with the    shrink + replay stays
-              NHWC layout pass rewriting the   bit-exact with HWIO-baked
-              conv probe (PADDLE_TPU_LAYOUT)   weights in the checkpoints
     zero1     permanent rank loss with the     mesh shrink reshards the
               ZeRO-1 sharded Momentum update   partitioned velocity
               on the dp mesh (PADDLE_TPU_ZERO) slots; survivors keep
@@ -77,12 +74,6 @@ GATES = [
                            "worker_kill@rank0:step14"]),
     ("sdc", CHAOS_RUN, ["--sdc"]),
     ("preempt", CHAOS_RUN, ["--preempt"]),
-    # conv probe + whole-program NHWC rewrite (analysis/layout.py): the
-    # baked-HWIO filter rides the checkpoints through a permanent rank
-    # loss mid dispatch window — the layout pass may not perturb
-    # bit-exact replay under any recovery path
-    ("layout", CHAOS_RUN, ["--layout", "--shrink",
-                           "--dispatch-steps", "4"]),
     # the ZeRO-1 sharded weight update on the dp mesh: the permanent
     # rank loss shrinks the workers' mesh while the Momentum velocity
     # slots live dp-sharded — the reshard-on-shrink seam must migrate

@@ -61,15 +61,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
 CKPT_INTERVAL = 5
 
 
-def _layout_mode():
-    """--layout gate: both the supervisor's in-process reference run and
-    the workers read the SAME env var, so the probe model (and the
-    layout pass over it) is identical on both sides of the parity
-    check."""
-    return os.environ.get("PADDLE_TPU_LAYOUT", "").strip().lower() \
-        == "nhwc"
-
-
 def _zero1_mode():
     """--zero1 gate: reads the engine's own PADDLE_TPU_ZERO flag env so
     the probe model switches to Momentum (slot state for the sharded
@@ -88,24 +79,10 @@ def build(lr=0.1):
 
     main, startup = Program(), Program()
     with program_guard(main, startup):
-        if _layout_mode():
-            # under --layout the probe grows a conv stem so the NHWC
-            # pass has an anchor to rewrite (and a filter to bake HWIO
-            # into the checkpointed scope — restart-after-bake is
-            # exactly the reconciliation path worth chaosing)
-            x = fluid.layers.data(name="x", shape=[1, 4, 4],
-                                  dtype="float32")
-            c = fluid.layers.conv2d(
-                x, num_filters=4, filter_size=3, padding=1, act="relu",
-                param_attr=fluid.ParamAttr(name="cw0"), bias_attr=False)
-            h = fluid.layers.fc(input=c, size=16, act="relu",
-                                param_attr=fluid.ParamAttr(name="cw1"),
-                                bias_attr=False)
-        else:
-            x = fluid.layers.data(name="x", shape=[16], dtype="float32")
-            h = fluid.layers.fc(input=x, size=16, act="relu",
-                                param_attr=fluid.ParamAttr(name="cw1"),
-                                bias_attr=False)
+        x = fluid.layers.data(name="x", shape=[16], dtype="float32")
+        h = fluid.layers.fc(input=x, size=16, act="relu",
+                            param_attr=fluid.ParamAttr(name="cw1"),
+                            bias_attr=False)
         y = fluid.layers.data(name="y", shape=[1], dtype="int64")
         pred = fluid.layers.fc(input=h, size=4,
                                param_attr=fluid.ParamAttr(name="cw2"),
@@ -118,17 +95,11 @@ def build(lr=0.1):
         else:
             fluid.optimizer.SGD(learning_rate=lr).minimize(loss)
     init = {
+        "cw1": np.linspace(-0.4, 0.4, 16 * 16).astype(
+            np.float32).reshape(16, 16),
         "cw2": np.linspace(0.3, -0.3, 16 * 4).astype(
             np.float32).reshape(16, 4),
     }
-    if _layout_mode():
-        init["cw0"] = np.linspace(-0.2, 0.2, 4 * 1 * 3 * 3).astype(
-            np.float32).reshape(4, 1, 3, 3)
-        init["cw1"] = np.linspace(-0.4, 0.4, 64 * 16).astype(
-            np.float32).reshape(64, 16)
-    else:
-        init["cw1"] = np.linspace(-0.4, 0.4, 16 * 16).astype(
-            np.float32).reshape(16, 16)
     return main, startup, loss, init
 
 
@@ -141,8 +112,6 @@ def batch_fn(step, batch=16, seed=0):
     rng = np.random.RandomState(seed * 100003 + step)
     xv = rng.randn(batch, 16).astype(np.float32)
     yv = np.argmax(xv @ W, 1).astype(np.int64).reshape(-1, 1)
-    if _layout_mode():
-        xv = xv.reshape(batch, 1, 4, 4)
     return {"x": xv, "y": yv}
 
 
@@ -376,8 +345,6 @@ def run_supervisor(args):
         # exports PADDLE_TPU_TRACE_ID to every incarnation — a
         # restarted worker's spans join the same trace (verdict below)
         env_extra["PADDLE_TPU_TRACE_SAMPLE"] = "1"
-    if args.layout:
-        env_extra["PADDLE_TPU_LAYOUT"] = "nhwc"
     if args.zero1:
         env_extra["PADDLE_TPU_ZERO"] = "1"
     if args.ckpt_replicas:
@@ -897,13 +864,6 @@ def main():
                              "path (2 virtual devices each) — proves the "
                              "mesh data-parallel path survives "
                              "worker_kill under the gang supervisor")
-    parser.add_argument("--layout", action="store_true",
-                        help="run everything with PADDLE_TPU_LAYOUT=nhwc "
-                             "and a conv stem on the probe model: the "
-                             "NHWC pass rewrites the step, the filter is "
-                             "baked HWIO into the checkpointed scope, "
-                             "and restart/rollback must still replay to "
-                             "bit-exact fault-free parity")
     parser.add_argument("--zero1", action="store_true",
                         help="run everything with PADDLE_TPU_ZERO=1 and "
                              "a Momentum probe optimizer: the workers' "
@@ -925,14 +885,10 @@ def main():
     parser.add_argument("--no-check-parity", dest="check_parity",
                         action="store_false")
     args = parser.parse_args()
-    if args.layout:
-        # in os.environ (not just env_extra) so the supervisor's OWN
-        # in-process parity reference builds the same conv probe and
-        # runs the same NHWC-rewritten executable as the workers
-        os.environ["PADDLE_TPU_LAYOUT"] = "nhwc"
     if args.zero1:
-        # same reasoning: the parity reference must build the Momentum
-        # probe; the zero flag itself is inert there (no mesh)
+        # in os.environ (not just env_extra) so the supervisor's OWN
+        # in-process parity reference builds the Momentum probe; the
+        # zero flag itself is inert there (no mesh)
         os.environ["PADDLE_TPU_ZERO"] = "1"
     if args.worker:
         return run_worker(args)

@@ -5,8 +5,8 @@ replace the one-off hand tune with a table from a reproducible sweep;
 the discipline of the reference's jit kernel benchmarks,
 benchmark/paddle/fluid/operators/jit/README.en.md).
 
-Protocol: the same MARGINAL-cost measurement as ``bench.py``'s flash
-bench — a single drained window carries a fixed dispatch/readback
+Protocol: the MARGINAL-cost measurement of ``tools/marginal_timing.py``
+— a single drained window carries a fixed dispatch/readback
 overhead next to the ms-scale kernels (its size on the sealed chip
 machine: not measured), so each (dtype, seq, block) config runs as one jitted
 ``lax.fori_loop`` of chained fwd+bwd steps at TWO loop counts; per-step

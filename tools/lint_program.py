@@ -23,10 +23,7 @@ reads "off"). ``--freeze`` additionally runs each built model through
 the inference freeze + INT8 post-training-quantization pipeline
 (paddle_tpu.inference) and prints the op/var counts before/after, the
 batch-norm folds, and the quantized-vs-skipped table with per-op
-calibrated ranges. ``--layout`` additionally prints each program's NHWC
-layout-assignment plan (analysis/layout.py, dry run): the ops assigned
-NHWC, every transpose2 seam and where it lands, and the weights that
-would be re-laid-out OIHW->HWIO. ``--spmd`` additionally prints each
+calibrated ranges. ``--spmd`` additionally prints each
 program's static SPMD report (analysis/spmd.py) under the --mesh/--rule
 table: sharding table, predicted collective schedule with bytes,
 per-device peak vs replicated peak, and the replicated-optimizer-state
@@ -183,20 +180,6 @@ def _print_memory_plan(program_or_desc, args, fetch_names=None):
     print(plan.render())
 
 
-def _print_layout_plan(program_or_desc, feed_names=None, fetch_names=None):
-    """The --layout report: dry-run the NHWC layout-assignment partition
-    (analysis/layout.py plan_layout — no desc mutation, no scope) and
-    print what the engine's opt-level-4 compile would do: which ops take
-    NHWC, every transpose2 seam and the op it feeds, and the weights
-    that would be re-laid-out OIHW->HWIO."""
-    from paddle_tpu.analysis.layout import plan_layout
-
-    plan = plan_layout(program_or_desc, feed_names=feed_names or (),
-                       fetch_names=fetch_names or ())
-    print("-- layout report (NHWC assignment, dry run) --")
-    print(plan.render())
-
-
 def _print_spmd_report(program_or_desc, args, feed_names=None,
                        fetch_names=None):
     """The --spmd report: the static SPMD analysis (analysis/spmd.py)
@@ -257,8 +240,8 @@ def _provenance_lint():
         compiled HLO op_metadata, and that the opprof registry has a
         cost row for every provenance tag.
     (c) Layering: no module under paddle_tpu/ imports from tools/ (the
-        library must never depend on the CLI layer — tools/ shims like
-        xplane_top_ops.py point the other way).
+        library must never depend on the CLI layer — tools/ imports
+        the library, never the other way).
 
     Exit 1 on any failure.
     """
@@ -445,9 +428,6 @@ def _lint_built_model(name, builder, args):
         report.extend(startup_report.findings)
         if args.memory:
             _print_memory_plan(main_desc, args, fetch_names=fetches)
-        if args.layout:
-            _print_layout_plan(main_desc, feed_names=feeds,
-                               fetch_names=fetches)
         if args.spmd:
             _print_spmd_report(main_desc, args, feed_names=feeds,
                                fetch_names=fetches)
@@ -490,8 +470,6 @@ def _lint_file(path, args):
                             shard_rules=_parse_rules(args.rule))
     if args.memory:
         _print_memory_plan(program, args)
-    if args.layout:
-        _print_layout_plan(program)
     if args.spmd:
         _print_spmd_report(program, args)
     min_sev = Severity.INFO if args.verbose else Severity.WARNING
@@ -531,12 +509,6 @@ def main(argv=None):
                         help="HBM budget for the --memory remat policy "
                              "(default: device limit x "
                              "PADDLE_TPU_HBM_BUDGET_FRAC, if knowable)")
-    parser.add_argument("--layout", action="store_true",
-                        help="print each program's NHWC layout-"
-                             "assignment plan (analysis/layout.py dry "
-                             "run): ops assigned NHWC, transpose seams "
-                             "and where they land, weights re-laid-out "
-                             "OIHW->HWIO")
     parser.add_argument("--freeze", action="store_true",
                         help="after linting each built model, run the "
                              "inference freeze + INT8 PTQ pipeline over "

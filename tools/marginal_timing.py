@@ -1,6 +1,6 @@
-"""Shared core of the marginal-cost timing protocol (used by bench.py's
-flash bench and tools/flash_block_sweep.py — one implementation so the
-sweep table and the benchmark that cites it measure the same thing).
+"""Core of the marginal-cost timing protocol (used by
+tools/flash_block_sweep.py, which fills the block table ``pick_block``
+reads).
 
 A single dispatch carries a fixed host overhead next to ms-scale
 kernels (its size on the sealed chip machine: not measured); the protocol

@@ -6,7 +6,7 @@ Each device count runs in its OWN subprocess with ``JAX_PLATFORMS=cpu`` and
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` — XLA fixes the
 device count at backend init, so a single process cannot sweep it. The
 child trains through the real mesh path (``Executor.run(mesh=...)`` →
-engine GSPMD jit, the exact seam bench.py and production use) with a
+engine GSPMD jit, the exact seam production uses) with a
 weak-scaling batch (``--batch-per-device × N``) and publishes its
 throughput as ``probe.samples_per_sec``/``probe.devices`` gauges into a
 per-run telemetry sink (observability JsonlSink); the parent assembles the
@@ -19,8 +19,9 @@ definition tput(N)/(N×tput(1)) could never exceed ~1/N no matter how
 good the graph is — whereas against flat capacity, healthy weak scaling
 (same total FLOPs/sec, partitioning overhead only) sits near 1.0 and a
 broken graph (state gathered to host every step, per-count recompiles,
-unsharded fallbacks) craters well below it. bench.py's real-device
-path uses the per-device normalization; this probe is the
+unsharded fallbacks) craters well below it. A run on real devices
+would use the per-device normalization (no benchmark cell spans chips
+yet: PERF.md, Open questions row 2); this probe is the
 shared-capacity stand-in. ``--efficiency-floor F`` exits non-zero when
 the largest-N efficiency lands below F — the CI guard for "the psum
 path stopped scaling".
@@ -37,15 +38,13 @@ collective bytes miss by more than the relative tolerance.
 ``--zero1`` flips every child onto the ZeRO-1 sharded weight update
 (PADDLE_TPU_ZERO=1; optionally ``--bucket-mb N`` for bucketed gradient
 reduction) so two invocations give the replicated-vs-sharded scaling
-A/B that bench.py's multichip section automates.
+A/B.
 
 Usage:
   python tools/multichip_probe.py --model mlp --devices 1,2,4,8
   python tools/multichip_probe.py --model bert --efficiency-floor 0.6
   python tools/multichip_probe.py --predict --predict-tolerance 0.1
   python tools/multichip_probe.py --model mlp --zero1 --bucket-mb 4
-Bench integration: ``PADDLE_TPU_BENCH=multichip python bench.py`` calls
-``probe_scaling()`` when fewer than 2 real devices exist.
 """
 
 import argparse
@@ -185,8 +184,8 @@ def probe_scaling(model="mlp", devices=(1, 2, 4, 8), batch_per_device=64,
     {n: prediction_delta args} when ``predict``). Parent-side only.
     ``zero1``/``bucket_mb`` turn on the ZeRO-1 sharded weight update
     (PADDLE_TPU_ZERO) and bucketed gradient reduction
-    (PADDLE_TPU_GRAD_BUCKET_MB) in every child — the A/B lever bench.py
-    sweeps to price the sharded update against the replicated one."""
+    (PADDLE_TPU_GRAD_BUCKET_MB) in every child — the A/B lever that
+    prices the sharded update against the replicated one."""
     results = {}
     predictions = {}
     own_tmp = sink_dir is None

@@ -8,7 +8,7 @@ Single-host mode: the host side comes from
 ``<profile_path>.trace.json`` stop_profiler writes): every engine step
 is a "step" slice with its trace/transform/lower/compile/run children.
 The device side comes from the jax profiler's xplane dump, aggregated
-per op by tools/xplane_top_ops.py. Together they answer the question
+per op by ``observability.opprof.top_ops``. Together they answer the question
 the throughput number alone cannot: where did each step's wall time go
 — host build (trace/transform/lower), XLA compile, dispatch, or device
 kernels.

@@ -21,8 +21,7 @@ removes is the per-step host materialization, which on a local CPU
 device is ~tens of µs — so healthy speedups sit at a few percent here
 (what it removes on the chip: not measured). The
 ``--floor`` gate therefore defaults just under 1.0 (no-REGRESSION, with
-room for scheduler noise), not to a speedup target; bench.py's pipeline
-block carries the headline ratios.
+room for scheduler noise), not to a speedup target.
 
 Usage:
   JAX_PLATFORMS=cpu python tools/pipeline_probe.py
