@@ -72,6 +72,13 @@ def single(ins, slot, default=None):
     return vals[0] if vals else default
 
 
+def lowered_into_a_step(ctx, op_type):
+    """Not the shape inference when the Program is built (no engine), nor
+    the forward's replay inside its grad op: where a lowering counts what
+    a step holds."""
+    return ctx.op.type == op_type and ctx.executor is not None
+
+
 def require_nchw(ctx, attrs):
     """The conv / pool lowerings compute NCHW with OIHW filters. A loaded
     program may carry ``data_format`` "AnyLayout" (the reference's

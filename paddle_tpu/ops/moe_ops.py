@@ -48,7 +48,7 @@ from jax import lax
 
 from paddle_tpu.core.registry import register_op
 from paddle_tpu.kernels import row_permute
-from paddle_tpu.ops.common import amp_cast, single
+from paddle_tpu.ops.common import amp_cast, lowered_into_a_step, single
 
 
 @register_op("moe_router")
@@ -79,12 +79,6 @@ def _by_kernel(like, row_of_pair):
     tokens x k pairs: decided by what the call site can see, not by a flag."""
     tokens, k = row_of_pair.shape
     return row_permute.applies(tokens, tokens * k, like.shape[1], like.dtype)
-
-
-def _lowered_into_a_step(ctx, op_type):
-    """Not the shape inference when the Program is built (no engine), nor
-    the forward's replay inside its grad op."""
-    return ctx.op.type == op_type and ctx.executor is not None
 
 
 def _count_form(by_kernel):
@@ -175,7 +169,7 @@ def moe_dispatch(ctx, ins, attrs):
         axis=0, dtype=jnp.int32)
     rows, row_weight = _to_rows(amp_cast(x), single(ins, "TopkWeight"),
                                 order, counts, row_of_pair, held)
-    if _lowered_into_a_step(ctx, "moe_dispatch") and obs.enabled():
+    if lowered_into_a_step(ctx, "moe_dispatch") and obs.enabled():
         by_kernel = _by_kernel(rows, row_of_pair)
         _count_form(by_kernel)
         obs.inc("moe.layers")
@@ -257,7 +251,7 @@ def moe_combine(ctx, ins, attrs):
     over the pairs held here (zero where none is)."""
     rows, row_of_pair = single(ins, "Rows"), single(ins, "RowOfPair")
     held, _ = _held(single(ins, "TopkIds"), attrs)
-    if _lowered_into_a_step(ctx, "moe_combine"):
+    if lowered_into_a_step(ctx, "moe_combine"):
         _count_form(_by_kernel(rows, row_of_pair))
     return {"Out": [_sum_of_rows(rows, row_of_pair, held,
                                  single(ins, "PairOfRow"),

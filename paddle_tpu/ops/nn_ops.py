@@ -22,8 +22,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from paddle_tpu.core.registry import register_op, register_no_grad_op
-from paddle_tpu.ops.common import (amp_cast, fp32_accum, require_nchw,
-                                   single)
+from paddle_tpu.ops.common import (amp_cast, fp32_accum,
+                                   lowered_into_a_step, require_nchw, single)
 
 
 @register_op("conv2d")
@@ -540,26 +540,56 @@ def rope_inv_freq(head_dim, attrs):
     return inv * ((1.0 - ramp) + ramp / factor), float(scaling)
 
 
+def _rotation(t, d, dtype, attrs):
+    """rotate(x) = x*cos + (x @ R)*sin for x [..., t, d] of ``dtype``: R
+    the [d, d] matrix of 0 / +-1 with x @ R = [-x2, x1], cos and sin the
+    [t, d] float32 tables, each half twice. The product only selects, so
+    it is exact: bf16 operands accumulate in float32, float32 operands
+    ask for HIGHEST so that the chip does not round them to bf16. Its
+    cotangent is the rotation by the negative angle, g*cos - (g @ R)*sin,
+    so each way is one pass over the tensor (PERF.md, Findings PR 32)."""
+    inv, scaling = rope_inv_freq(d, attrs)
+    angle = np.arange(t, dtype=np.float64)[:, None] * inv[None, :]
+    cos = jnp.asarray(np.tile(np.cos(angle) * scaling, 2), jnp.float32)
+    sin = jnp.asarray(np.tile(np.sin(angle) * scaling, 2), jnp.float32)
+    half = d // 2
+    r = np.zeros((d, d), np.float32)
+    r[half:, :half] = -np.eye(half)
+    r[:half, half:] = np.eye(half)
+    r = jnp.asarray(r, dtype)
+    precision = lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+    def turn(x, combine):
+        turned = jnp.dot(x, r, precision=precision,
+                         preferred_element_type=jnp.float32)
+        return combine(fp32_accum(x) * cos, turned * sin).astype(x.dtype)
+
+    @jax.custom_vjp
+    def rotate(x):
+        return turn(x, jnp.add)
+
+    rotate.defvjp(lambda x: (turn(x, jnp.add), None),
+                  lambda _, g: (turn(g, jnp.subtract),))
+    return rotate
+
+
 @register_op("rotary_embedding")
 def rotary_embedding(ctx, ins, attrs):
     """Rotate every X [B, H, T, D] by its position 0..T-1 over the whole
     head, halves convention: x*cos + [-x2, x1]*sin. The tables come from
     the attributes (``rope_inv_freq``) in float64 at lowering; the
-    rotation is float32, the result X's dtype."""
-    outs, tables = [], {}
+    rotation is float32, the result X's dtype. ``rope.rotations``
+    (``metrics`` flag) counts the tensors rotated in a lowered step."""
+    from paddle_tpu import observability as obs
+
+    outs, rotations = [], {}
     for x in ins["X"]:
-        t, d = x.shape[-2], x.shape[-1]
-        if (t, d) not in tables:      # Q and K share one pair of tables
-            inv, scaling = rope_inv_freq(d, attrs)
-            angle = np.arange(t, dtype=np.float64)[:, None] * inv[None, :]
-            tables[t, d] = (
-                jnp.asarray(np.cos(angle) * scaling, jnp.float32),
-                jnp.asarray(np.sin(angle) * scaling, jnp.float32))
-        cos, sin = tables[t, d]
-        x32 = fp32_accum(x)
-        x1, x2 = x32[..., :d // 2], x32[..., d // 2:]
-        outs.append(jnp.concatenate(
-            [x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).astype(x.dtype))
+        key = (x.shape[-2], x.shape[-1], x.dtype)
+        if key not in rotations:      # Q and K share one pair of tables
+            rotations[key] = _rotation(*key, attrs)
+        outs.append(rotations[key](x))
+    if lowered_into_a_step(ctx, "rotary_embedding"):
+        obs.inc("rope.rotations", len(outs))
     return {"Out": outs}
 
 

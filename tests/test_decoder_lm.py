@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu.fluid as fluid
+from paddle_tpu.core.registry import LowerContext, OpRegistry
 from paddle_tpu.layers import nn as _nn
 from paddle_tpu.ops.nn_ops import rope_inv_freq
 
@@ -277,3 +278,76 @@ def test_rotary_embedding_matches_the_reference(rope):
         want = mellum2._rope(jnp.asarray(x).transpose(0, 2, 1, 3), cos, sin)
         np.testing.assert_allclose(y, np.asarray(want).transpose(0, 2, 1, 3),
                                    atol=1e-5, rtol=1e-5)
+
+
+def _rotate_by_slices(x, attrs):
+    """The rotation as the program lowered it until PR 32, in plain
+    ``jax.numpy``: the head sliced at its middle, float32 halves, a
+    concatenate. The lowering is one product now and has to agree."""
+    t, d = x.shape[-2:]
+    inv, scaling = rope_inv_freq(d, attrs)
+    angle = np.arange(t, dtype=np.float64)[:, None] * inv[None, :]
+    cos = jnp.asarray(np.cos(angle) * scaling, jnp.float32)
+    sin = jnp.asarray(np.sin(angle) * scaling, jnp.float32)
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :d // 2], x32[..., d // 2:]
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).astype(x.dtype)
+
+
+def _rotate_by_the_lowering(xs, attrs):
+    op = fluid.Program().global_block().append_op(
+        type="rotary_embedding", inputs={}, outputs={}, attrs=dict(attrs))
+    return OpRegistry.get("rotary_embedding").lower(
+        LowerContext(op, None), {"X": list(xs)}, attrs)["Out"]
+
+
+@pytest.mark.parametrize("head", [128, 64])
+@pytest.mark.parametrize("rope", [DEFAULT, YARN], ids=["default", "yarn"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_the_rotation_as_one_product_equals_the_slices(dtype, rope, head):
+    """Q and K through the lowering (x*cos + (x @ R)*sin under its own
+    ``custom_vjp``) and through the slices, result and ``jax.vjp``
+    cotangent: the product only selects and a - b is a + (-b), so op by
+    op on the CPU they are equal bit for bit. (Inside one jitted
+    computation the CPU's compiler contracts the two backward forms'
+    multiply-adds differently, a float32 ulp apart.)"""
+    rng = np.random.RandomState(6)
+    xs = [jnp.asarray(rng.randn(2, heads, 48, head), dtype)
+          for heads in (4, 2)]
+    gs = [jnp.asarray(rng.randn(*x.shape), dtype) for x in xs]
+    got, got_vjp = jax.vjp(lambda *a: _rotate_by_the_lowering(a, rope), *xs)
+    want, want_vjp = jax.vjp(
+        lambda *a: [_rotate_by_slices(x, rope) for x in a], *xs)
+    for a, b in zip(list(got) + list(got_vjp(gs)),
+                    list(want) + list(want_vjp(gs))):
+        assert a.dtype == b.dtype == jnp.dtype(dtype)
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+def test_the_rotation_counts_its_tensors_once():
+    """``rope.rotations`` under the metrics flag: 2 for one attention's Q
+    and K in a step that holds the grad op too, whose replay of the
+    forward inside ``jax.vjp`` counts nothing."""
+    from paddle_tpu import observability as obs
+
+    rng = np.random.RandomState(7)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        qd = fluid.layers.data(name="q", shape=[4, 24, 16], dtype="float32")
+        kd = fluid.layers.data(name="k", shape=[2, 24, 16], dtype="float32")
+        w = fluid.layers.create_parameter([16], "float32", name="w")
+        q, k = _nn.rotary_embedding([qd * w, kd * w], **YARN)
+        loss = fluid.layers.reduce_mean(q) + fluid.layers.reduce_mean(k)
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    assert "rotary_embedding_grad" in [
+        op.type for op in main.global_block().ops]
+    exe = fluid.Executor()
+    exe.run(startup)
+    obs.set_enabled(True)
+    before = obs.counter_value("rope.rotations")
+    exe.run(main, feed={"q": rng.randn(2, 4, 24, 16).astype(np.float32),
+                        "k": rng.randn(2, 2, 24, 16).astype(np.float32)},
+            fetch_list=[loss])
+    assert obs.counter_value("rope.rotations") - before == 2

@@ -140,9 +140,12 @@ def test_subprocess_async_cluster_converges():
     the GIL-threaded in-process test can't catch races in the
     apply-as-grads-arrive path (reference: listen_and_serv_op.cc
     RunAsyncLoop; test discipline of test_dist_base.py:213)."""
-    results = _run_cluster("async", n_steps=10)
+    results = _run_cluster("async", n_steps=100)
     for losses in results:
-        assert losses[-1] < losses[0], losses
+        # every step draws a fresh batch of 16 at a loss near ln 4, so one
+        # step against another is a coin the race order flips (10 steps:
+        # 1 failure in 6 under load, PR 32); ten steps' mean is not
+        assert np.mean(losses[-10:]) < np.mean(losses[:10]), losses
         assert all(np.isfinite(l) for l in losses), losses
 
 

@@ -11,6 +11,7 @@ in this process, and in this one file, for the same reason.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -353,6 +354,42 @@ def test_grouped_matmuls_compile(one_chip, monkeypatch):
     hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3)), rows, up,
                    up, down, sizes)
     assert hlo.count("tpu_custom_call") == 9
+
+
+# -- the rotation of Q and K (ops/nn_ops.py rotary_embedding)
+
+@pytest.mark.parametrize("which", ["q", "k"])
+def test_the_rotation_is_one_pass_each_way(one_chip, which):
+    """What the chip's compiler makes of x*cos + (x @ R)*sin at the
+    decoder cell's shapes, forward and cotangent: one output fusion, the
+    [128, 128] product with the multiply-add as its epilogue, and no
+    float32 copy of the tensor or of its halves between instructions (the
+    sliced form left five passes with both; PERF.md, Findings PR 32)."""
+    from paddle_tpu.ops.nn_ops import _rotation
+
+    q, kv, _ = _decoder_args(one_chip)
+    x = q if which == "q" else kv
+    d = DECODER["dim"]
+    rotate = _rotation(DECODER["seq"], d, x.dtype, {
+        "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+        "original_max_position_embeddings": 8192})
+
+    def cotangent(x_, g):
+        return jax.vjp(rotate, x_)[1](g)[0]
+
+    for fn, args in ((rotate, (x,)), (cotangent, (x, x))):
+        hlo = _compile(fn, *args)
+        entry = hlo[hlo.index("\nENTRY "):].splitlines()
+        wide = "f32[%s," % ",".join(str(n) for n in x.shape[:3])
+        assert not [line for line in entry if " = " + wide in line]
+        (matrix,) = [line.split(" = ")[0].strip() for line in entry
+                     if " = bf16[%d,%d]" % (d, d) in line
+                     and " constant(" in line]
+        (reader,) = [line for line in entry if " fusion(" in line
+                     and re.search(re.escape(matrix) + "[,)]", line)]
+        assert "kind=kOutput" in reader
+        got = jax.eval_shape(fn, *args)
+        assert (got.shape, got.dtype) == (x.shape, x.dtype)
 
 
 # -- the expert layer's row movements (kernels/row_permute.py)
