@@ -2562,10 +2562,20 @@ def rotary_embedding(xs, rope_theta=10000.0, rope_type="default", name=None,
     return outs
 
 
-def moe_router(input, num_experts, k, param_attr=None, name=None):
-    """Softmax router over ``num_experts`` in float32 and its top ``k``:
-    -> (weights [N, k] float32, renormalised over the k; ids [N, k] int32)
-    for the ``N`` tokens of ``input`` [..., d]."""
+def moe_router(input, num_experts, k, param_attr=None, name=None,
+               score_func="softmax", route_scale=1.0, bias_name=None):
+    """Router over ``num_experts`` in float32 and its top ``k``, for the
+    ``N`` tokens of ``input`` [..., d]: scores by ``score_func``
+    (``softmax`` over all of them, or a ``sigmoid`` each); the weights are
+    the chosen scores renormalised over the k, times ``route_scale``.
+    -> (weights [N, k] float32, ids [N, k] int32).
+
+    ``bias_name`` names a balancing bias [num_experts]: persistable state
+    that starts at 0, is added to the scores for the choice of the k alone
+    (the weights stay the scores'; no gradient reaches it) and that
+    ``moe_bias_update`` moves from the loads. Then
+    -> (weights, ids, load [num_experts] int32: the selections that fell on
+    each router output, the bias variable)."""
     helper = LayerHelper("moe_router", name=name)
     w = helper.create_parameter(
         attr=param_attr, shape=[int(input.shape[-1]), int(num_experts)],
@@ -2573,11 +2583,65 @@ def moe_router(input, num_experts, k, param_attr=None, name=None):
     weight = helper.create_variable_for_type_inference("float32")
     ids = helper.create_variable_for_type_inference("int32",
                                                     stop_gradient=True)
-    helper.append_op(
-        type="moe_router", inputs={"X": [input], "Weight": [w]},
-        outputs={"TopkWeight": [weight], "TopkIds": [ids]},
-        attrs={"k": int(k)})
-    return weight, ids
+    inputs = {"X": [input], "Weight": [w]}
+    outputs = {"TopkWeight": [weight], "TopkIds": [ids]}
+    # (defaults stay out of the desc: a softmax router's op, and with it the
+    # program's fingerprint and its cached executables, is what it was)
+    attrs = {"k": int(k)}
+    if score_func != "softmax":
+        attrs["score_func"] = score_func
+    if float(route_scale) != 1.0:
+        attrs["route_scale"] = float(route_scale)
+    if bias_name is None:
+        helper.append_op(type="moe_router", inputs=inputs, outputs=outputs,
+                         attrs=attrs)
+        return weight, ids
+    bias = helper.create_global_variable(
+        name=bias_name, shape=[int(num_experts)], dtype="float32",
+        persistable=True)
+    helper.set_variable_initializer(bias, ConstantInitializer(0.0))
+    load = helper.create_variable_for_type_inference("int32",
+                                                     stop_gradient=True)
+    helper.append_op(type="moe_router", inputs=dict(inputs, Bias=[bias]),
+                     outputs=dict(outputs, Load=[load]), attrs=attrs)
+    return weight, ids, load, bias
+
+
+def moe_bias_update(bias, load, coeff, name=None):
+    """Move a router's balancing ``bias`` by ``coeff * sign(mean(load) -
+    load)`` less its mean (ops/moe_ops.py), in place. Call it after
+    ``minimize``: the op carries ``op_role`` Optimize whatever the
+    program's current role, so that it runs after the backward (whose
+    replay of the router must see the bias the forward saw) and a clone
+    for test drops it."""
+    from paddle_tpu.framework import OpRole
+
+    helper = LayerHelper("moe_bias_update", name=name)
+    with helper.main_program._op_role_guard(OpRole.Optimize):
+        helper.append_op(type="moe_bias_update",
+                         inputs={"Bias": [bias], "Load": [load]},
+                         outputs={"BiasOut": [bias]},
+                         attrs={"coeff": float(coeff)})
+    return bias
+
+
+def gated_mlp(input, width, gate_attr=None, up_attr=None, down_attr=None,
+              name=None):
+    """(silu(x Wgate) * (x Wup)) Wdown over the last axis of ``input``
+    [..., d], ``width`` wide, no biases: one op (``gated_mlp``)."""
+    helper = LayerHelper("gated_mlp", name=name)
+    d = int(input.shape[-1])
+    gate, up, down = (
+        helper.create_parameter(attr=attr, shape=shape, dtype="float32",
+                                is_bias=False)
+        for attr, shape in ((gate_attr, [d, width]), (up_attr, [d, width]),
+                            (down_attr, [width, d])))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(type="gated_mlp",
+                     inputs={"X": [input], "Gate": [gate], "Up": [up],
+                             "Down": [down]},
+                     outputs={"Out": [out]}, attrs={})
+    return out
 
 
 def moe_experts(input, topk_weight, topk_ids, experts_held, expert_offset,

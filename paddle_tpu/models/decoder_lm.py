@@ -1,6 +1,7 @@
-"""A pre-norm decoder language model with a per-layer attention pattern and
-a mixture-of-experts feed-forward, for the chip's share of an
-expert-parallel deployment:
+"""A decoder language model with a per-layer attention pattern and a
+mixture-of-experts feed-forward, for the chip's share of an
+expert-parallel deployment. One builder, two families of block; every
+argument past ``rms_norm_eps`` defaults to the plain pre-norm form:
 
     h = x + Wo . Attn(RoPE(Wq n1), RoPE(Wk n1), Wv n1)       n1 = RMSNorm(x)
     y = h + sum over the token's top-k experts HELD HERE of
@@ -8,15 +9,37 @@ expert-parallel deployment:
 
 grouped-query attention (``num_key_value_heads`` under
 ``num_attention_heads``), causal, ``sliding_attention`` layers with a
-window and ``full_attention`` layers without, each kind with its own
-rotary parameters; a softmax router over ``router_experts`` in float32,
-top ``num_experts_per_tok``, weights renormalised over all of them, of
-which this chip computes the ``experts_held`` from ``expert_offset``; final
+window and ``full_attention`` layers without; each kind with its own
+rotary parameters, and a kind that ``rope_parameters`` does not list is
+not rotated at all; a router over ``router_experts`` in float32, top
+``num_experts_per_tok``, weights renormalised over all of them, of which
+this chip computes the ``experts_held`` from ``expert_offset``; final
 RMSNorm, untied head, mean next-token cross-entropy over the vocabulary
 held. No biases. Every parameter has an explicit name.
+
+What the arguments add, each alone:
+
+    scale_embedding    the embedding times sqrt(hidden_size)
+    qk_norm            RMSNorm over each head of q and of k (one scale of
+                       ``head_dim`` each a layer), before the rotation
+    attention_gate     Wo . (Attn(...) * sigmoid(Wg n1))
+    post_norms         x + RMSNorm(Wo ...) and h + RMSNorm(MLP(n2)): four
+                       norms a layer, the residual adding the normed output
+    mlp_layer_types    ``dense`` layers: a gated SiLU MLP ``intermediate_size``
+                       wide in place of the experts (``sparse``)
+    num_shared_experts a gated SiLU MLP of that many expert widths that
+                       every token passes, added to the held experts' part
+    score_func, route_scale   the router's scores (``softmax`` over all, or
+                       a ``sigmoid`` each) and a factor on the top-k weights
+    load_balance_coeff above 0: a bias a router output that ranks the
+                       experts and is no weight: state
+                       (``layerN.expert_bias``) that the step itself moves,
+                       after the backward, by that much against each
+                       output's load (layers/nn.py ``moe_bias_update``)
 """
 
 import paddle_tpu.fluid as fluid
+from paddle_tpu import observability as obs
 from paddle_tpu.layers import nn as _nn
 
 
@@ -26,51 +49,87 @@ def _proj(x, size, name):
                            param_attr=fluid.ParamAttr(name=name))
 
 
-def attention(x, prefix, num_heads, num_kv_heads, head_dim, window, rope):
-    def heads(y, n):
+def _norm(x, eps, name):
+    return _nn.rms_norm(x, eps, fluid.ParamAttr(name=name))
+
+
+def attention(x, prefix, num_heads, num_kv_heads, head_dim, window, rope,
+              rms_norm_eps=None, qk_norm=False, gate=False):
+    """``rope`` None: q and k are not rotated. The counters (``metrics``
+    flag) say what the Program was built with: compositions of ops that
+    know nothing of attention have no lowering to count in."""
+    def heads(y, n, norm=None):
         y = fluid.layers.reshape(y, shape=[0, 0, n, head_dim])
+        if norm:
+            y = _norm(y, rms_norm_eps, prefix + norm)
         return fluid.layers.transpose(y, perm=[0, 2, 1, 3])  # [B, H, T, D]
 
-    q = heads(_proj(x, num_heads * head_dim, prefix + "q_proj"), num_heads)
+    q = heads(_proj(x, num_heads * head_dim, prefix + "q_proj"), num_heads,
+              "q_norm" if qk_norm else None)
     k = heads(_proj(x, num_kv_heads * head_dim, prefix + "k_proj"),
-              num_kv_heads)
+              num_kv_heads, "k_norm" if qk_norm else None)
     v = heads(_proj(x, num_kv_heads * head_dim, prefix + "v_proj"),
               num_kv_heads)
-    q, k = _nn.rotary_embedding([q, k], **rope)
+    if rope is not None:
+        q, k = _nn.rotary_embedding([q, k], **rope)
     ctx = _nn.fused_attention(q, k, v, causal=True, scale=head_dim ** -0.5,
                               window=window)
     ctx = fluid.layers.transpose(ctx, perm=[0, 2, 1, 3])
     ctx = fluid.layers.reshape(ctx, shape=[0, 0, num_heads * head_dim])
+    if gate:
+        ctx = fluid.layers.elementwise_mul(ctx, fluid.layers.sigmoid(
+            _proj(x, num_heads * head_dim, prefix + "gate_proj")))
+    for name, built in (("attn.qk_norm", qk_norm), ("attn.gated", gate),
+                        ("attn.unrotated_layers", rope is None)):
+        if built:
+            obs.inc(name)
     return _proj(ctx, int(x.shape[-1]), prefix + "o_proj")
 
 
+def gated_mlp(x, prefix, width):
+    return _nn.gated_mlp(
+        x, width, gate_attr=fluid.ParamAttr(name=prefix + "gate"),
+        up_attr=fluid.ParamAttr(name=prefix + "up"),
+        down_attr=fluid.ParamAttr(name=prefix + "down"))
+
+
 def moe(x, prefix, router_experts, experts_held, expert_offset,
-        experts_per_token, width):
+        experts_per_token, width, score_func="softmax", route_scale=1.0,
+        biased=False):
     """-> (the held experts' part of the layer [B, T, d], tokens each held
-    expert received)."""
+    expert received, the router's [load, bias] with ``biased`` or [])."""
     d = int(x.shape[-1])
     flat = fluid.layers.reshape(x, shape=[-1, d])
-    weight, ids = _nn.moe_router(
+    weight, ids, *balance = _nn.moe_router(
         flat, router_experts, experts_per_token,
-        param_attr=fluid.ParamAttr(name=prefix + "router"))
+        param_attr=fluid.ParamAttr(name=prefix + "router"),
+        score_func=score_func, route_scale=route_scale,
+        bias_name=prefix + "expert_bias" if biased else None)
     out, counts = _nn.moe_experts(
         flat, weight, ids, experts_held, expert_offset, width,
         gate_attr=fluid.ParamAttr(name=prefix + "experts_gate"),
         up_attr=fluid.ParamAttr(name=prefix + "experts_up"),
         down_attr=fluid.ParamAttr(name=prefix + "experts_down"))
-    return fluid.layers.reshape(out, shape=[-1, int(x.shape[1]), d]), counts
+    return (fluid.layers.reshape(out, shape=[-1, int(x.shape[1]), d]), counts,
+            balance)
 
 
 def get_model(batch_size, seq_len, vocab_size, hidden_size, num_hidden_layers,
               num_attention_heads, num_key_value_heads, head_dim,
               router_experts, experts_held, expert_offset,
               num_experts_per_tok, moe_intermediate_size, sliding_window,
-              layer_types, rope_parameters, rms_norm_eps, lr, is_train=True):
+              layer_types, rope_parameters, rms_norm_eps, lr, is_train=True,
+              mlp_layer_types=None, intermediate_size=None,
+              num_shared_experts=0, score_func="softmax", route_scale=1.0,
+              load_balance_coeff=0.0, qk_norm=False, attention_gate=False,
+              post_norms=False, scale_embedding=False):
     """Next-token pre-training program; the configuration gives every size
     (``batch_size`` is the feed's own: the batch axis stays open).
     ``layer_types`` names each layer ``sliding_attention`` or
     ``full_attention`` (the first ``num_hidden_layers`` entries are used);
-    ``rope_parameters`` gives each kind its rotary attributes."""
+    ``rope_parameters`` gives each kind that is rotated its rotary
+    attributes; ``mlp_layer_types`` names each layer ``sparse`` (the
+    default) or ``dense``. The rest: the module's docstring."""
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):
         ids = fluid.layers.data(name="ids", shape=[seq_len], dtype="int64")
@@ -79,32 +138,48 @@ def get_model(batch_size, seq_len, vocab_size, hidden_size, num_hidden_layers,
         h = fluid.layers.embedding(
             input=ids, size=[vocab_size, hidden_size],
             param_attr=fluid.ParamAttr(name="tok_embedding"))
-        loads = []
+        if scale_embedding:
+            h = fluid.layers.scale(h, scale=float(hidden_size) ** 0.5)
+        loads, balances = [], []
         for i in range(num_hidden_layers):
             prefix, kind = "layer%d." % i, layer_types[i]
-            n1 = _nn.rms_norm(h, rms_norm_eps,
-                              fluid.ParamAttr(name=prefix + "attn_norm"))
+            rope = rope_parameters.get(kind)
             attn = attention(
-                n1, prefix, num_attention_heads, num_key_value_heads,
-                head_dim,
+                _norm(h, rms_norm_eps, prefix + "attn_norm"), prefix,
+                num_attention_heads, num_key_value_heads, head_dim,
                 sliding_window if kind == "sliding_attention" else None,
-                dict(rope_parameters.get(kind, {})))
+                None if rope is None else dict(rope), rms_norm_eps, qk_norm,
+                attention_gate)
+            if post_norms:
+                attn = _norm(attn, rms_norm_eps, prefix + "post_attn_norm")
             h = fluid.layers.elementwise_add(h, attn)
-            n2 = _nn.rms_norm(h, rms_norm_eps,
-                              fluid.ParamAttr(name=prefix + "mlp_norm"))
-            part, counts = moe(n2, prefix, router_experts, experts_held,
-                               expert_offset, num_experts_per_tok,
-                               moe_intermediate_size)
-            loads.append(counts)
+            n2 = _norm(h, rms_norm_eps, prefix + "mlp_norm")
+            if mlp_layer_types and mlp_layer_types[i] == "dense":
+                part = gated_mlp(n2, prefix + "mlp_", intermediate_size)
+            else:
+                part, counts, balance = moe(
+                    n2, prefix, router_experts, experts_held, expert_offset,
+                    num_experts_per_tok, moe_intermediate_size, score_func,
+                    route_scale, load_balance_coeff > 0)
+                loads.append(counts)
+                if balance:
+                    balances.append(balance)
+                if num_shared_experts:
+                    part = fluid.layers.elementwise_add(part, gated_mlp(
+                        n2, prefix + "shared_",
+                        num_shared_experts * moe_intermediate_size))
+                    obs.inc("moe.shared_experts", num_shared_experts)
+            if post_norms:
+                part = _norm(part, rms_norm_eps, prefix + "post_mlp_norm")
             h = fluid.layers.elementwise_add(h, part)
-        h = _nn.rms_norm(h, rms_norm_eps,
-                         fluid.ParamAttr(name="final_norm"))
+        h = _norm(h, rms_norm_eps, "final_norm")
         logits = _proj(h, vocab_size, "lm_head")
         loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
             logits=fluid.layers.reshape(logits, shape=[-1, vocab_size]),
             label=fluid.layers.reshape(labels, shape=[-1, 1])))
         if is_train:
             fluid.optimizer.Adam(learning_rate=lr).minimize(loss)
+            for load, bias in balances:
+                _nn.moe_bias_update(bias, load, load_balance_coeff)
     return main, startup, {"feeds": {"ids": ids, "labels": labels},
                            "loss": loss, "expert_loads": loads}
-

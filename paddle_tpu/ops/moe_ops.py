@@ -1,8 +1,12 @@
 """A mixture-of-experts layer as four ops, for a chip that holds some of
 the experts (``experts_held`` of ``num_experts``, from ``expert_offset``):
 
-    moe_router      probabilities over ALL experts in float32, top-k,
-                    weights renormalised over the k chosen
+    moe_router      scores over ALL experts in float32 (softmax, or a
+                    sigmoid each), top-k (by score + a bias that takes no
+                    gradient, where one is given), weights from the scores
+                    alone, renormalised over the k chosen, times a scale
+    moe_bias_update the balancing bias as state: after the backward, once a
+                    step, from the loads the router counted
     moe_dispatch    the token-expert pairs that fall on held experts,
                     sorted by expert, their token rows (and weights)
                     gathered into a buffer; second output: tokens each
@@ -46,26 +50,87 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from paddle_tpu.core.registry import register_op
+from paddle_tpu.core.registry import register_no_grad_op, register_op
 from paddle_tpu.kernels import row_permute
 from paddle_tpu.ops.common import amp_cast, lowered_into_a_step, single
 
 
-@register_op("moe_router")
+@register_op("moe_router", no_grad_inputs=("Bias",))
 def moe_router(ctx, ins, attrs):
-    """X [..., d], Weight [d, E] -> TopkWeight [N, k] float32, TopkIds
-    [N, k] int32. Logits and softmax in float32 whatever the program's
-    precision: the top-k is a discontinuity, and a rounded probability
-    flips it."""
-    x, w = single(ins, "X"), single(ins, "Weight")
+    """X [..., d], Weight [d, E], optionally Bias [E] -> TopkWeight [N, k]
+    float32, TopkIds [N, k] int32 and, where a Bias is given, Load [E]
+    int32: how many of the N * k selections fell on each router output,
+    held here or not. ``score_func`` ``softmax`` (the default): softmax
+    over all E; ``sigmoid``: a sigmoid each. The k are the largest of score
+    + Bias (the bias ranks and takes no gradient); their weights are the
+    scores alone, renormalised over the k and times ``route_scale``.
+    Logits and scores in float32 whatever the program's precision: the
+    top-k is a discontinuity, and a rounded score flips it."""
+    from paddle_tpu import observability as obs
+
+    x, w, bias = single(ins, "X"), single(ins, "Weight"), single(ins, "Bias")
     k = int(attrs["k"])
+    sigmoid = attrs.get("score_func", "softmax") == "sigmoid"
     x2 = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
     logits = jnp.dot(x2, w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top, ids = lax.top_k(probs, k)
-    top = top / jnp.sum(top, axis=-1, keepdims=True)
-    return {"TopkWeight": [top], "TopkIds": [ids.astype(jnp.int32)]}
+    if sigmoid:
+        probs = jax.nn.sigmoid(logits)
+    else:
+        probs = jax.nn.softmax(logits, axis=-1)
+    if bias is None:
+        top, ids = lax.top_k(probs, k)
+    else:
+        _, ids = lax.top_k(probs + lax.stop_gradient(bias), k)
+        top = jnp.take_along_axis(probs, ids, axis=-1)
+    if sigmoid:     # (sigmoids can all be small; a softmax's top-k cannot)
+        top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+    else:
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+    scale = float(attrs.get("route_scale", 1.0))
+    if scale != 1.0:
+        top = top * scale
+    outs = {"TopkWeight": [top], "TopkIds": [ids.astype(jnp.int32)]}
+    if bias is not None:
+        load = jnp.sum(ids.reshape(-1)[:, None] == jnp.arange(
+            probs.shape[-1], dtype=ids.dtype)[None, :], axis=0,
+            dtype=jnp.int32)
+        outs["Load"] = [load]
+    if lowered_into_a_step(ctx, "moe_router") and obs.enabled():
+        if sigmoid:
+            obs.inc("moe.router_sigmoid")
+        if bias is not None:
+            jax.debug.callback(_publish_router_load, load)
+    return outs
+
+
+def _publish_router_load(load):
+    """Per step, under the ``metrics`` flag: the busiest router output's
+    selections over the mean, over ALL outputs (``moe.load_max_over_mean``
+    is over the experts held)."""
+    from paddle_tpu import observability as obs
+
+    load = np.asarray(load)
+    obs.set_gauge("moe.router_load_max_over_mean",
+                  float(load.max() / max(load.mean(), 1e-9)))
+
+
+@register_no_grad_op("moe_bias_update", inplace_map={"BiasOut": "Bias"})
+def moe_bias_update(ctx, ins, attrs):
+    """Bias [E] float32, Load [E] -> BiasOut [E]: the auxiliary-loss-free
+    balancing of Wang et al. (arXiv:2408.15664), the mean taken out:
+    ``delta = coeff * sign(mean(load) - load)``, ``delta -= mean(delta)``,
+    ``bias += delta``. State that the step updates itself, not through the
+    optimizer; the op carries ``op_role`` Optimize, so that it runs once a
+    step after the backward and the next step selects with what it left."""
+    from paddle_tpu import observability as obs
+
+    bias = single(ins, "Bias")
+    load = single(ins, "Load").astype(jnp.float32)
+    delta = float(attrs["coeff"]) * jnp.sign(jnp.mean(load) - load)
+    if lowered_into_a_step(ctx, "moe_bias_update"):
+        obs.inc("moe.bias_updates")
+    return {"BiasOut": [bias + (delta - jnp.mean(delta)).astype(bias.dtype)]}
 
 
 def _held(ids, attrs):

@@ -507,6 +507,31 @@ def rms_norm(ctx, ins, attrs):
     return {"Y": [y.astype(x.dtype)]}
 
 
+@register_op("gated_mlp")
+def gated_mlp(ctx, ins, attrs):
+    """Out = (silu(X Gate) * (X Up)) Down over the last axis: X [..., d],
+    Gate and Up [d, w], Down [w, d]. One op type for a dense layer's MLP
+    and a shared expert, so that a trace reads their time by type. bf16
+    operands under AMP, the gate's product in float32; the gradient is the
+    engine's generic ``jax.vjp`` of this lowering. ``gated_mlp.calls``
+    (``metrics`` flag) counts the ops lowered into a step."""
+    from paddle_tpu import observability as obs
+
+    x = single(ins, "X")
+    x2, wg, wu, wd = amp_cast(x.reshape(-1, x.shape[-1]),
+                              single(ins, "Gate"), single(ins, "Up"),
+                              single(ins, "Down"))
+    pet = None if x2.dtype == jnp.bfloat16 else jnp.float32
+    gate = jnp.matmul(x2, wg, preferred_element_type=pet)
+    up = jnp.matmul(x2, wu, preferred_element_type=pet)
+    hidden = (jax.nn.silu(gate.astype(jnp.float32))
+              * up.astype(jnp.float32)).astype(x2.dtype)
+    out = jnp.matmul(hidden, wd, preferred_element_type=pet)
+    if lowered_into_a_step(ctx, "gated_mlp"):
+        obs.inc("gated_mlp.calls")
+    return {"Out": [out.reshape(x.shape[:-1] + (wd.shape[-1],))]}
+
+
 def rope_inv_freq(head_dim, attrs):
     """(inverse frequencies [head_dim / 2] float64, the factor on cos and
     sin) of a rotary embedding: ``rope_type`` ``default`` is

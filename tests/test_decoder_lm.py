@@ -1,11 +1,13 @@
 """The decoder language model (``models/decoder_lm.py``) and the ops it
-brought — ``rms_norm``, ``rotary_embedding``, the four expert-layer ops —
-through ``Executor.run`` on the CPU at small sizes, against the benchmark's
-plain reference (``benchmarks/reference/mellum2.py``, which imports nothing
-of the program)."""
+brought — ``rms_norm``, ``rotary_embedding``, the expert-layer ops,
+``gated_mlp`` — through ``Executor.run`` on the CPU at small sizes,
+against the benchmark's plain references (``benchmarks/reference/mellum2.py``
+and ``trinity_mini.py``, which import nothing of the program)."""
 
+import hashlib
 import json
 import os
+import re
 
 import numpy as np
 import pytest
@@ -15,12 +17,13 @@ import jax.numpy as jnp
 
 import paddle_tpu.fluid as fluid
 from paddle_tpu.core.registry import LowerContext, OpRegistry
+from paddle_tpu.framework import OpRole
 from paddle_tpu.layers import nn as _nn
 from paddle_tpu.ops.nn_ops import rope_inv_freq
 
 from benchmarks import compare
 from benchmarks.drivers import train
-from benchmarks.reference import common, mellum2
+from benchmarks.reference import common, mellum2, trinity_mini
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CELL = "mellum2_12b.pretrain_s4096_b2"
@@ -30,9 +33,9 @@ YARN = {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
 DEFAULT = {"rope_type": "default", "rope_theta": 500000}
 
 
-def _cell():
+def _cell(cell=CELL):
     with open(os.path.join(ROOT, "benchmarks", "workloads",
-                           CELL + ".json")) as f:
+                           cell + ".json")) as f:
         workload = json.load(f)
     with open(os.path.join(ROOT, "benchmarks", "configs",
                            workload["config"] + ".json")) as f:
@@ -351,3 +354,334 @@ def test_the_rotation_counts_its_tensors_once():
                         "k": rng.randn(2, 2, 24, 16).astype(np.float32)},
             fetch_list=[loss])
     assert obs.counter_value("rope.rotations") - before == 2
+
+
+# -- the sandwich-norm, sigmoid-routed family (the trinity_mini cell) ----------
+
+TRINITY = "trinity_mini.pretrain_b2"
+TRINITY_SEED = 3000000007
+
+
+@pytest.fixture(scope="module")
+def trinity():
+    """The cell's program at the rehearsal size, built and driven through
+    its first three steps once, with the metrics flag up."""
+    from paddle_tpu import observability as obs
+
+    workload, cfg, rows = _cell(TRINITY)
+    names = ("moe.shared_experts", "moe.router_sigmoid", "moe.bias_updates",
+             "gated_mlp.calls", "attn.gated", "attn.qk_norm",
+             "attn.unrotated_layers")
+    obs.set_enabled(True)
+    before = {name: obs.counter_value(name) for name in names}
+    with fluid.unique_name.guard():
+        trainer = train.Trainer(cfg, rows, workload, rehearse=True)
+    trainer.start(TRINITY_SEED)
+    program, _ = trainer.warm_up()
+    jax.effects_barrier()
+    counted = {name: obs.counter_value(name) - was
+               for name, was in before.items()}
+    yield dict(workload=workload, cfg=cfg, rows=rows, trainer=trainer,
+               program=program, counted=counted,
+               gauges=obs.snapshot()["gauges"])
+    trainer.exe.close()
+
+
+def test_the_trinity_program_follows_its_reference(trinity):
+    """get_model with every new argument -> enable_bf16 -> Executor.run
+    against the float32 reference through three steps, under the cell's
+    rehearsal limits: losses, first gradients, changes, the bias state's
+    change among them."""
+    cfg, trainer = trinity["cfg"], trinity["trainer"]
+    reference = common.follow(trinity_mini, cfg, trinity["rows"],
+                              TRINITY_SEED)
+    correct, compared = compare.judge(
+        trinity["program"], reference,
+        train.limits(trinity["workload"], rehearse=True))
+    assert correct, compared
+    assert compared["loss_gap"]["value"] < 1e-3
+    ops = trainer.main.desc.global_block().ops
+    kinds = [op.type for op in ops]
+    assert kinds.count("gated_mlp") == 5 and kinds.count("moe_router") == 4
+    assert kinds.count("rotary_embedding") == 4     # none on the full layer
+    assert kinds.count("moe_bias_update") == 4
+    assert kinds.index("moe_bias_update") > max(
+        i for i, kind in enumerate(kinds) if kind.endswith("_grad"))
+    m = cfg["model"]
+    assert [op.attrs.get("window") for op in ops
+            if op.type == "fused_attention"] == [m["sliding_window"]] * 4 + [
+                None]
+    biases = sorted(trinity_mini.state_specs(cfg))
+    assert biases == ["layer%d.expert_bias" % i for i in (1, 2, 3, 4)]
+    for name in biases:
+        moved = reference["change_norms"][name]
+        assert moved > 0
+        assert trinity["program"]["change_norms"][name] == pytest.approx(
+            moved, rel=0.35)
+        bias = np.asarray(trainer.scope.get(name))
+        assert abs(bias.sum()) < 1e-6 and np.abs(bias).max() <= 6.1e-3
+
+
+def test_the_trinity_program_counts_what_it_was_built_with(trinity):
+    """The new counters under the metrics flag: one dense and four sparse
+    layers, each with a gated, q/k-normed attention, the fifth unrotated;
+    and the step's gauge over all router outputs."""
+    assert trinity["counted"] == {
+        "moe.shared_experts": 4, "moe.router_sigmoid": 4,
+        "moe.bias_updates": 4, "gated_mlp.calls": 5, "attn.gated": 5,
+        "attn.qk_norm": 5, "attn.unrotated_layers": 1}
+    assert trinity["gauges"]["moe.router_load_max_over_mean"] >= 1.0
+
+
+def _router(x, params, bias, fetch_grad=False):
+    """TopkWeight, TopkIds and Load of a sigmoid router with a bias, scale
+    2.5, through Executor.run; with ``fetch_grad`` the router weight's
+    gradient of sum(TopkWeight * arange) too."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        data = fluid.layers.data(name="x", shape=[D], dtype="float32")
+        weight, ids, load, bias_var = _nn.moe_router(
+            data, EXPERTS, TOP, param_attr=fluid.ParamAttr(name="router"),
+            score_func="sigmoid", route_scale=2.5, bias_name="bias")
+        fetch = [weight, ids, load]
+        if fetch_grad:
+            ramp = fluid.layers.assign(
+                np.arange(1, TOP + 1, dtype=np.float32))
+            loss = fluid.layers.reduce_sum(weight * ramp)
+            fluid.optimizer.SGD(learning_rate=1.0).minimize(loss)
+            fetch.append(main.global_block().var("router@GRAD"))
+    assert bias_var.persistable and bias_var.stop_gradient
+    assert not any("bias@GRAD" in name for op in main.global_block().ops
+                   for name in op.desc.output_arg_names())
+    exe, scope = fluid.Executor(), fluid.Scope()
+    exe.run(startup, scope=scope)
+    assert np.all(np.asarray(scope.get("bias")) == 0)
+    scope.set("router", jnp.asarray(params))
+    scope.set("bias", jnp.asarray(bias))
+    got = exe.run(main, feed={"x": x}, fetch_list=fetch, scope=scope)
+    exe.close()
+    return got
+
+
+def _numpy_router(x, params, bias, scale=2.5):
+    scores = 1.0 / (1.0 + np.exp(-(x.astype(np.float64) @ params)))
+    ids = np.argsort(-(scores + bias), axis=1, kind="stable")[:, :TOP]
+    top = np.take_along_axis(scores, ids, 1)
+    return scale * top / (top.sum(1, keepdims=True) + 1e-20), ids
+
+
+def test_the_sigmoid_router_selects_by_score_plus_bias_and_weighs_by_score():
+    """Against numpy: the k largest of sigmoid + bias (a bias large enough
+    to push experts 6 and 7 into every token's choice), the weights from
+    the sigmoids alone, renormalised, times the scale; Load counts every
+    selection; the router's gradient is that of the weights with the
+    choice held fixed, and nothing flows to the bias."""
+    rng = np.random.RandomState(8)
+    x = rng.randn(48, D).astype(np.float32)
+    params = (rng.randn(D, EXPERTS) * 0.3).astype(np.float32)
+    bias = np.zeros(EXPERTS, np.float32)
+    bias[6:] = (3.0, 1.5)
+    weight, ids, load, grad = _router(x, params, bias, fetch_grad=True)
+    want_weight, want_ids = _numpy_router(x, params, bias)
+    np.testing.assert_array_equal(ids, want_ids)
+    assert (ids[:, 0] == 6).all() and (ids[:, 1] == 7).all()
+    np.testing.assert_allclose(weight, want_weight, rtol=2e-5)
+    np.testing.assert_allclose(weight.sum(1), 2.5, rtol=1e-5)
+    assert load.tolist() == np.bincount(want_ids.ravel(),
+                                        minlength=EXPERTS).tolist()
+    assert load.sum() == 48 * TOP
+    unbiased, free_ids, _ = _router(x, params, np.zeros(EXPERTS, np.float32))
+    assert (free_ids != ids).any()
+
+    def loss(w):
+        scores = jax.nn.sigmoid(jnp.dot(
+            jnp.asarray(x), w, precision=jax.lax.Precision.HIGHEST))
+        top = jnp.take_along_axis(scores, jnp.asarray(want_ids), 1)
+        top = 2.5 * top / (top.sum(1, keepdims=True) + 1e-20)
+        return jnp.sum(top * jnp.arange(1, TOP + 1, dtype=jnp.float32))
+
+    np.testing.assert_allclose(grad, jax.grad(loss)(jnp.asarray(params)),
+                               atol=1e-5, rtol=1e-4)
+
+
+def test_the_bias_update_on_a_hand_made_load():
+    """coeff * sign(mean - load), its mean taken out, added in place: loads
+    (9, 1, 5, 5) have mean 5: signs (-1, +1, 0, 0), mean 0; loads (8, 0, 2,
+    2) have mean 3: signs (-1, +1, +1, +1), mean 1/2. The reference's own
+    update reads the same."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        load = fluid.layers.data(name="load", shape=[4], dtype="int32",
+                                 append_batch_size=False)
+        bias = main.global_block().create_var(
+            name="bias", shape=[4], dtype="float32", persistable=True)
+        _nn.moe_bias_update(bias, load, 0.01)
+    (op,) = [op for op in main.global_block().ops]
+    assert op.type == "moe_bias_update"
+    assert int(op.attr("op_role")) & int(OpRole.Optimize)
+    exe, scope = fluid.Executor(), fluid.Scope()
+    scope.set("bias", jnp.asarray([0.5, 0.0, -0.5, 0.0], jnp.float32))
+    exe.run(main, feed={"load": np.asarray([9, 1, 5, 5], np.int32)},
+            scope=scope)
+    np.testing.assert_allclose(np.asarray(scope.get("bias")),
+                               [0.49, 0.01, -0.5, 0.0], atol=1e-7)
+    exe.run(main, feed={"load": np.asarray([8, 0, 2, 2], np.int32)},
+            scope=scope)
+    want = np.asarray([0.49, 0.01, -0.5, 0.0]) + 0.01 * (
+        np.asarray([-1.0, 1.0, 1.0, 1.0]) - 0.5)
+    np.testing.assert_allclose(np.asarray(scope.get("bias")), want,
+                               atol=1e-7)
+    np.testing.assert_allclose(trinity_mini.update_bias(
+        jnp.asarray([0.49, 0.01, -0.5, 0.0]), jnp.asarray([8, 0, 2, 2]),
+        0.01), want, atol=1e-7)
+    exe.close()
+
+
+def test_gated_mlp_and_its_gradient_match_numpy():
+    """(silu(x Wg) * (x Wu)) Wd on [3, 5, D] rows, and the four gradients
+    of sum(out * c) by the chain rule in numpy (float64)."""
+    rng = np.random.RandomState(9)
+    x = rng.randn(3, 5, D).astype(np.float32)
+    c = rng.randn(3, 5, D).astype(np.float32)
+    w = {"g": rng.randn(D, WIDTH) * 0.3, "u": rng.randn(D, WIDTH) * 0.3,
+         "d": rng.randn(WIDTH, D) * 0.3}
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        data = fluid.layers.data(name="x", shape=[5, D], dtype="float32")
+        data.stop_gradient = False
+        coef = fluid.layers.data(name="c", shape=[5, D], dtype="float32")
+        out = _nn.gated_mlp(data, WIDTH, *(fluid.ParamAttr(name=n)
+                                           for n in "gud"))
+        loss = fluid.layers.reduce_sum(out * coef)
+        fluid.optimizer.SGD(learning_rate=1.0).minimize(loss)
+        grads = [main.global_block().var(n + "@GRAD") for n in "xgud"]
+    assert [op.type for op in main.global_block().ops].count(
+        "gated_mlp_grad") == 1
+    exe, scope = fluid.Executor(), fluid.Scope()
+    exe.run(startup, scope=scope)
+    for name, value in w.items():
+        scope.set(name, jnp.asarray(value, jnp.float32))
+    got = exe.run(main, feed={"x": x, "c": c}, fetch_list=[out] + grads,
+                  scope=scope)
+    exe.close()
+    x2, c2 = x.reshape(-1, D).astype(np.float64), c.reshape(-1, D)
+    gate, up = x2 @ w["g"], x2 @ w["u"]
+    sig = 1.0 / (1.0 + np.exp(-gate))
+    hidden = gate * sig * up
+    d_hidden = c2 @ w["d"].T
+    d_gate = d_hidden * up * (sig + gate * sig * (1.0 - sig))
+    d_up = d_hidden * gate * sig
+    want = [hidden @ w["d"], d_gate @ w["g"].T + d_up @ w["u"].T,
+            x2.T @ d_gate, x2.T @ d_up, hidden.T @ c2]
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a).reshape(b.shape), b,
+                                   atol=2e-4, rtol=2e-4)
+
+
+def _sigmoid_layer(x, params, held, offset, bias):
+    """The routed part (for the experts ``offset .. offset + held``) and
+    the shared expert of the program's sigmoid-routed layer."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        data = fluid.layers.data(name="x", shape=[D], dtype="float32")
+        weight, ids, _, _ = _nn.moe_router(
+            data, EXPERTS, TOP, param_attr=fluid.ParamAttr(name="router"),
+            score_func="sigmoid", route_scale=2.5, bias_name="bias")
+        routed, _ = _nn.moe_experts(
+            data, weight, ids, held, offset, WIDTH,
+            gate_attr=fluid.ParamAttr(name="gate"),
+            up_attr=fluid.ParamAttr(name="up"),
+            down_attr=fluid.ParamAttr(name="down"))
+        shared = _nn.gated_mlp(data, WIDTH, *(fluid.ParamAttr(name=n) for n
+                                              in ("sg", "su", "sd")))
+    exe, scope = fluid.Executor(), fluid.Scope()
+    exe.run(startup, scope=scope)
+    scope.set("router", jnp.asarray(params["router"]))
+    scope.set("bias", jnp.asarray(bias))
+    for name in ("gate", "up", "down"):
+        scope.set(name, jnp.asarray(params[name][offset:offset + held]))
+    for name in ("sg", "su", "sd"):
+        scope.set(name, jnp.asarray(params[name]))
+    got = exe.run(main, feed={"x": x}, fetch_list=[routed, shared],
+                  scope=scope)
+    exe.close()
+    return got
+
+
+def test_the_sigmoid_shares_and_the_shared_expert_add_up_to_the_uncut_layer():
+    """8 experts in 4 shares of 2, 3 a token, a bias that moves the choice:
+    the four shares' routed parts and the shared expert counted ONCE add up
+    to what the reference gives for the whole layer (all 8 held), and each
+    share with the shared expert is the reference's own share."""
+    rng = np.random.RandomState(10)
+    x = rng.randn(40, D).astype(np.float32)
+    params = _params(rng, (rng.randn(D, EXPERTS) * 0.3).astype(np.float32))
+    params.update(sg=(rng.randn(D, WIDTH) * 0.3).astype(np.float32),
+                  su=(rng.randn(D, WIDTH) * 0.3).astype(np.float32),
+                  sd=(rng.randn(WIDTH, D) * 0.3).astype(np.float32))
+    bias = (rng.randn(EXPERTS) * 0.2).astype(np.float32)
+
+    def reference(held, offset):
+        m = {"num_experts_per_tok": TOP, "experts_held": held,
+             "expert_offset": offset, "route_scale": 2.5}
+        p = {"l.router": params["router"], "l.shared_gate": params["sg"],
+             "l.shared_up": params["su"], "l.shared_down": params["sd"]}
+        for name in ("gate", "up", "down"):
+            p["l.experts_" + name] = params[name][offset:offset + held]
+        with jax.default_matmul_precision("highest"):
+            out, load = trinity_mini._experts(
+                common.Matmuls("f32"), m,
+                {k: jnp.asarray(v) for k, v in p.items()}, "l.",
+                jnp.asarray(x), jnp.asarray(bias))
+        assert int(load.sum()) == x.shape[0] * TOP
+        return np.asarray(out)
+
+    total = 0.0
+    for offset in range(0, EXPERTS, 2):
+        routed, shared = _sigmoid_layer(x, params, 2, offset, bias)
+        np.testing.assert_allclose(routed + shared, reference(2, offset),
+                                   atol=5e-5, rtol=2e-4)
+        total = total + routed
+    np.testing.assert_allclose(total + shared, reference(EXPERTS, 0),
+                               atol=1e-4, rtol=2e-4)
+
+
+def test_the_mellum2_step_is_lowered_as_before():
+    """With every new argument of the builder at its default the
+    ``mellum2_12b`` step (the configuration's own sizes, bf16, the CPU's
+    path) traces to the jaxpr it had at the parent commit (PR 32, 7296293):
+    a digest of the jaxpr text, source positions struck, taken there."""
+    with open(os.path.join(ROOT, "benchmarks", "configs",
+                           "mellum2_12b.json")) as f:
+        cfg = json.load(f)
+    from paddle_tpu.core.types import convert_dtype_to_np
+    from paddle_tpu.models import decoder_lm
+
+    with fluid.unique_name.guard():
+        main, _, handle = decoder_lm.get_model(batch_size=2, lr=1e-4,
+                                               **cfg["model"])
+    fluid.contrib.mixed_precision.enable_bf16(main)
+    exe, block = fluid.Executor(), main.desc.block(0)
+    seq = cfg["model"]["seq_len"]
+    names, values = exe.engine._coerce_feed(block, {
+        "ids": np.zeros((2, seq), np.int64),
+        "labels": np.zeros((2, seq), np.int64)})
+    step = exe.engine.get_compiled(main.desc, 0, names, values,
+                                   [handle["loss"].name], False, True, True,
+                                   1)
+
+    def shape(name):
+        var = block.find_var_recursive(name)
+        return jax.ShapeDtypeStruct(tuple(var.shape),
+                                    convert_dtype_to_np(var.dtype))
+
+    text = str(jax.make_jaxpr(step.jitted)(
+        [jax.ShapeDtypeStruct(v.shape, v.dtype) for v in values],
+        [shape(n) for n in step.mutated_names],
+        [shape(n) for n in step.readonly_names],
+        (np.uint32(0), np.uint32(1))))
+    text = re.sub(r" at 0x[0-9a-f]+", "", text)
+    text = re.sub(r"\S+\.py:\d+", "F", text)
+    exe.close()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        "392233118762b739"

@@ -272,10 +272,18 @@ def test_s8_matmul_compiles(one_chip):
 # -- the decoder cell's kernels: grouped-query heads, a window, head size 128
 
 DECODER = dict(rows=2, q_heads=32, kv_heads=4, seq=4096, dim=128)
+# (positions, window) of the decoder cells' attention layers: the first
+# cell's 4096 under 1024 and under none, the second's 3072 under 2048 and
+# under none; (tokens x 8 rows, d, width) of their expert layers, 16 held
+ATTENTION_SHAPES = {"window": (4096, 1024), "full": (4096, None),
+                    "s3072-window2048": (3072, 2048),
+                    "s3072-full": (3072, None)}
+EXPERT_SHAPES = {"d2304-w896": (8192, 2304, 896),
+                 "d2048-w1024": (6144, 2048, 1024)}
 
 
-def _decoder_args(one_chip):
-    b, t, d = DECODER["rows"], DECODER["seq"], DECODER["dim"]
+def _decoder_args(one_chip, seq=None):
+    b, t, d = DECODER["rows"], seq or DECODER["seq"], DECODER["dim"]
     q = jax.ShapeDtypeStruct((b, DECODER["q_heads"], t, d), jnp.bfloat16,
                              sharding=one_chip)
     kv = jax.ShapeDtypeStruct((b, DECODER["kv_heads"], t, d), jnp.bfloat16,
@@ -285,10 +293,11 @@ def _decoder_args(one_chip):
     return q, kv, lse
 
 
-@pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
-def test_grouped_query_forward_compiles(one_chip, window):
-    q, kv, _ = _decoder_args(one_chip)
-    blk = pick_block(DECODER["seq"], jnp.bfloat16)
+@pytest.mark.parametrize("shape", list(ATTENTION_SHAPES))
+def test_grouped_query_forward_compiles(one_chip, shape):
+    seq, window = ATTENTION_SHAPES[shape]
+    q, kv, _ = _decoder_args(one_chip, seq)
+    blk = pick_block(seq, jnp.bfloat16)
 
     def fwd(q_, k_, v_):
         return flash_attention_raw_lse(
@@ -299,18 +308,18 @@ def test_grouped_query_forward_compiles(one_chip, window):
 
 
 @pytest.mark.parametrize("form", ["fused", "split"])
-@pytest.mark.parametrize("window", [1024, None], ids=["window", "full"])
-def test_grouped_query_backward_compiles(one_chip, monkeypatch, window,
+@pytest.mark.parametrize("shape", list(ATTENTION_SHAPES))
+def test_grouped_query_backward_compiles(one_chip, monkeypatch, shape,
                                          form):
-    """Both backward forms at [2, 32/4, 4096, 128] bf16: by the shapes this
-    attention takes the one kernel (its [4096, 128] dQ accumulator fits);
-    dK/dV come out per K/V head."""
+    """Both backward forms at [2, 32/4, positions, 128] bf16: by the shapes
+    this attention takes the one kernel (its [positions, 128] dQ
+    accumulator fits); dK/dV come out per K/V head."""
     import sys
 
-    q, kv, lse = _decoder_args(one_chip)
-    blk = pick_block(DECODER["seq"], jnp.bfloat16)
-    assert _bwd_fused_fits(DECODER["seq"], DECODER["dim"], jnp.bfloat16,
-                           blk, blk)
+    seq, window = ATTENTION_SHAPES[shape]
+    q, kv, lse = _decoder_args(one_chip, seq)
+    blk = pick_block(seq, jnp.bfloat16)
+    assert _bwd_fused_fits(seq, DECODER["dim"], jnp.bfloat16, blk, blk)
     if form == "split":
         monkeypatch.setattr(
             sys.modules["paddle_tpu.kernels.flash_attention"],
@@ -327,21 +336,24 @@ def test_grouped_query_backward_compiles(one_chip, monkeypatch, window,
     assert dq.shape == q.shape and dk.shape == dv.shape == kv.shape
 
 
-def test_grouped_matmuls_compile(one_chip, monkeypatch):
+@pytest.mark.parametrize("shape", list(EXPERT_SHAPES))
+def test_grouped_matmuls_compile(one_chip, monkeypatch, shape):
     """The expert MLP's three products and their gradients at the decoder
-    cell's shapes ([65536, 2304] rows, 16 experts of width 896): nine
-    megablox kernels, each at the tiling ``_tilings`` reckons for it."""
+    cells' shapes ([65536, 2304] rows, 16 experts of width 896; [49152,
+    2048] rows, 16 of width 1024): nine megablox kernels, each at the
+    tiling ``_tilings`` reckons for it."""
     import sys
 
     from paddle_tpu.kernels import grouped_matmul as gm
 
     monkeypatch.setattr(sys.modules["paddle_tpu.kernels.grouped_matmul"],
                         "_on_tpu", lambda: True)
-    rows = jax.ShapeDtypeStruct((65536, 2304), jnp.bfloat16,
+    tokens, d, width = EXPERT_SHAPES[shape]
+    rows = jax.ShapeDtypeStruct((tokens * 8, d), jnp.bfloat16,
                                 sharding=one_chip)
-    up = jax.ShapeDtypeStruct((16, 2304, 896), jnp.bfloat16,
+    up = jax.ShapeDtypeStruct((16, d, width), jnp.bfloat16,
                               sharding=one_chip)
-    down = jax.ShapeDtypeStruct((16, 896, 2304), jnp.bfloat16,
+    down = jax.ShapeDtypeStruct((16, width, d), jnp.bfloat16,
                                 sharding=one_chip)
     sizes = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
 
@@ -394,15 +406,17 @@ def test_the_rotation_is_one_pass_each_way(one_chip, which):
 
 # -- the expert layer's row movements (kernels/row_permute.py)
 
+@pytest.mark.parametrize("shape", list(EXPERT_SHAPES))
 @pytest.mark.parametrize("which", ["expand", "reduce_float32",
                                    "reduce_bfloat16"])
-def test_row_permute_compiles(one_chip, which):
-    """Both directions at the decoder cell's shapes, [8192 x 8 -> 65536,
-    2304] bf16 with 16 experts held, at the module's tile and chunk: one
-    custom call each, the visit list round it in XLA."""
+def test_row_permute_compiles(one_chip, which, shape):
+    """Both directions at the decoder cells' shapes, [8192 x 8 -> 65536,
+    2304] and [6144 x 8 -> 49152, 2048] bf16 with 16 experts held, at the
+    module's tile and chunk: one custom call each, the visit list round it
+    in XLA."""
     from paddle_tpu.kernels import row_permute as rp
 
-    tokens, k, d, held = 8192, 8, 2304, 16
+    (tokens, d, _), k, held = EXPERT_SHAPES[shape], 8, 16
     order = jax.ShapeDtypeStruct((tokens * k,), jnp.int32, sharding=one_chip)
     counts = jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip)
     if which == "expand":
