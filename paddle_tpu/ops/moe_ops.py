@@ -12,7 +12,9 @@ the experts (``experts_held`` of ``num_experts``, from ``expert_offset``):
                     gathered into a buffer; second output: tokens each
                     held expert received
     moe_expert_mlp  down(w * silu(gate(x)) * up(x)) per expert: three
-                    grouped matmuls over the buffer
+                    grouped matmuls over the buffer, and a backward of
+                    its own (``_expert_mlp``): five more, the rows'
+                    gradient from gate and up in one of them
     moe_combine     each token's sum over its held pairs' rows
 
 Dropless: the buffer has a row for every pair (tokens x k; a token's k
@@ -38,11 +40,15 @@ Everywhere else (the CPU, float32 programs, odd sizes) both are XLA gathers
 (a row's token one way, a pair's row the other), never a scatter-add: each
 pair has one row, so the inverse permutation is known. Which of the two a
 call site was lowered as is counted (``moe.permute_kernel`` /
-``moe.permute_xla``). Unused rows of a grouped matmul's result are
-unspecified, so whatever leaves the buffer is picked with ``where``, never
-by a product with zero; what the kernel writes into the buffer's unused
-tiles is unspecified too.
+``moe.permute_xla``), and so is the form of the expert MLP's two-pair
+product (``moe.gmm_pair_kernel`` / ``moe.gmm_pair_xla``: one call site a
+layer, counted where the backward is traced). Unused rows of a grouped
+matmul's result are unspecified, so whatever leaves the buffer is picked
+with ``where``, never by a product with zero; what the kernel writes into
+the buffer's unused tiles is unspecified too.
 """
+
+import functools
 
 import numpy as np
 
@@ -51,6 +57,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from paddle_tpu.core.registry import register_no_grad_op, register_op
+from paddle_tpu.kernels import grouped_matmul as gm
 from paddle_tpu.kernels import row_permute
 from paddle_tpu.ops.common import amp_cast, lowered_into_a_step, single
 
@@ -262,23 +269,68 @@ def _publish_load(counts, visits):
                   float(counts.max() / max(counts.mean(), 1e-9)))
 
 
+def _gated(gate, up, row_weight):
+    """The middle of the expert MLP, XLA's: w * silu(gate) * up in
+    float32, as the rows' dtype."""
+    return (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+            * row_weight[:, None]).astype(gate.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
+def _expert_mlp(rows, row_weight, counts, wg, wu, wd, interpret=False):
+    """down(w * silu(gate(x)) * up(x)) per expert, with a backward of its
+    own: three grouped matmuls forward; backward the down projection's
+    transposed product, XLA's transpose of ``_gated``, the rows' gradient
+    from gate and up in ONE kernel that adds both products in its float32
+    accumulator (autodiff would round each to the rows' dtype and add them
+    in a pass over all buffer rows), and three weights' gradients. The
+    weights arrive in the rows' dtype (the caller casts the masters, so the
+    cast's transpose rides in whatever reads the gradient)."""
+    return _expert_mlp_fwd(rows, row_weight, counts, wg, wu, wd,
+                           interpret)[0]
+
+
+def _expert_mlp_fwd(rows, row_weight, counts, wg, wu, wd, interpret):
+    gate = gm.grouped_matmul(rows, wg, counts, interpret)
+    up = gm.grouped_matmul(rows, wu, counts, interpret)
+    hidden = _gated(gate, up, row_weight)
+    return (gm.grouped_matmul(hidden, wd, counts, interpret),
+            (rows, row_weight, counts, wg, wu, wd, gate, up, hidden))
+
+
+def _expert_mlp_bwd(interpret, res, g):
+    from paddle_tpu import observability as obs
+
+    rows, row_weight, counts, wg, wu, wd, gate, up, hidden = res
+    obs.inc("moe.gmm_pair_kernel" if gm.pair_by_kernel(
+        rows.shape[0], wg.shape[1], wg.shape[2], interpret)
+        else "moe.gmm_pair_xla")
+    d_hidden = gm.grouped_matmul_t(g, wd, counts, interpret)
+    d_gate, d_up, d_weight = jax.vjp(_gated, gate, up, row_weight)[1](
+        d_hidden)
+    d_rows = gm.grouped_matmul_pair_t(d_gate, d_up, wg, wu, counts,
+                                      interpret)
+    return (d_rows, d_weight, None,
+            gm.grouped_weight_gradient(rows, d_gate, counts, interpret),
+            gm.grouped_weight_gradient(rows, d_up, counts, interpret),
+            gm.grouped_weight_gradient(hidden, g, counts, interpret))
+
+
+_expert_mlp.defvjp(_expert_mlp_fwd, _expert_mlp_bwd)
+
+
 @register_op("moe_expert_mlp", no_grad_inputs=("Counts",))
 def moe_expert_mlp(ctx, ins, attrs):
     """Rows [R, d] sorted by expert, RowWeight [R], Counts [G], GateWeight
     / UpWeight [G, d, w], DownWeight [G, w, d] -> Out [R, d]: the gated
     SiLU MLP of each row's expert, times the row's weight. Rows past
     sum(Counts) are unspecified."""
-    from paddle_tpu.kernels.grouped_matmul import grouped_matmul
-
-    rows, counts = single(ins, "Rows"), single(ins, "Counts")
-    rows, wg, wu, wd = amp_cast(rows, single(ins, "GateWeight"),
+    rows, wg, wu, wd = amp_cast(single(ins, "Rows"),
+                                single(ins, "GateWeight"),
                                 single(ins, "UpWeight"),
                                 single(ins, "DownWeight"))
-    gate = grouped_matmul(rows, wg, counts)
-    up = grouped_matmul(rows, wu, counts)
-    hidden = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-              * single(ins, "RowWeight")[:, None]).astype(rows.dtype)
-    return {"Out": [grouped_matmul(hidden, wd, counts)]}
+    return {"Out": [_expert_mlp(rows, single(ins, "RowWeight"),
+                                single(ins, "Counts"), wg, wu, wd)]}
 
 
 @jax.custom_vjp

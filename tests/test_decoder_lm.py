@@ -194,6 +194,162 @@ def test_the_grouped_matmul_kernel_agrees_with_ragged_dot():
                                    atol=1e-3, rtol=1e-3)
 
 
+def _ragged(lhs, rhs, sizes):
+    return jax.lax.ragged_dot(lhs, rhs, sizes,
+                              preferred_element_type=jnp.float32)
+
+
+@pytest.mark.parametrize("sizes", [[40, 0, 77, 19], [256, 0, 0, 130],
+                                   [0, 3, 0, 0], [300, 100, 60, 52]])
+def test_the_pair_kernel_adds_both_products_over_the_live_rows(sizes):
+    """``g_a @ Waᵀ + g_b @ Wbᵀ`` in one kernel (interpret mode) against
+    two float32 ``ragged_dot``s and an add, over the live rows only (the
+    dead ones are unspecified): an empty group, groups that end inside a
+    tile, live rows well short of the buffer and a buffer that is full;
+    two column tiles, so a left tile is fetched for each."""
+    from paddle_tpu.kernels import grouped_matmul as gm
+
+    rng = np.random.RandomState(5)
+    rows, k, n = 512, 256, 128
+    g_a, g_b = (jnp.asarray(rng.randn(rows, n), jnp.float32)
+                for _ in range(2))
+    w_a, w_b = (jnp.asarray(rng.randn(4, k, n) * 0.1, jnp.float32)
+                for _ in range(2))
+    sizes = jnp.asarray(sizes, jnp.int32)
+    live = int(sizes.sum())
+    want = (_ragged(g_a, w_a.swapaxes(1, 2), sizes)
+            + _ragged(g_b, w_b.swapaxes(1, 2), sizes))
+    assert gm.pair_by_kernel(rows, k, n, interpret=True)
+    assert not gm.pair_by_kernel(rows, k, n)        # the CPU, no interpreter
+    assert not gm.pair_by_kernel(rows, k, 100, interpret=True)
+    for tn in (256, 128):
+        got = gm._pair_gmm_t(g_a, g_b, w_a, w_b, sizes, (256, n, tn), True)
+        np.testing.assert_allclose(np.asarray(got)[:live],
+                                   np.asarray(want)[:live],
+                                   atol=1e-3, rtol=1e-3)
+    got = gm.grouped_matmul_pair_t(g_a, g_b, w_a, w_b, sizes, interpret=True)
+    np.testing.assert_allclose(np.asarray(got)[:live],
+                               np.asarray(want)[:live], atol=1e-3, rtol=1e-3)
+    xla = gm.grouped_matmul_pair_t(g_a, g_b, w_a, w_b, sizes)
+    np.testing.assert_allclose(np.asarray(xla), np.asarray(want),
+                               atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("rows, d, width, pair", [
+    (65536, 2304, 896, (256, 896, 1152)),
+    (49152, 2048, 1024, (256, 1024, 1024))])
+def test_the_pair_tiling_is_reckoned_from_the_shapes(rows, d, width, pair):
+    """At the two decoder cells' expert shapes the two-pair kernel keeps
+    the contraction whole and halves the columns (the whole ``d`` would
+    take the single kernel's block twice over): two pairs of
+    double-buffered operands, the result tile and the float32 accumulator
+    inside the budget, the next wider tile outside it."""
+    from paddle_tpu.kernels import grouped_matmul as gm
+
+    tilings = gm._tilings(rows, d, width, 2)
+    assert tilings.pair_rows_gradient == pair
+    assert tilings.rows_gradient[:2] == pair[:2]
+    tm, k, tn = pair
+
+    def reckoned(cols):
+        return (2 * (2 * tm * k + 2 * cols * k) + 2 * tm * cols) * 2 \
+            + 4 * tm * cols
+
+    assert reckoned(tn) <= gm._VMEM_BUDGET < reckoned(d)
+    assert tilings.rows_gradient[2] >= tn   # one pair takes a wider tile
+
+
+def _plain_expert_mlp(rows, row_weight, counts, wg, wu, wd):
+    """The op's arithmetic as a plain composition that JAX differentiates
+    itself (what the op was until PR 34)."""
+    dtype = rows.dtype
+    gate = _ragged(rows, wg.astype(dtype), counts).astype(dtype)
+    up = _ragged(rows, wu.astype(dtype), counts).astype(dtype)
+    hidden = (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+              * row_weight[:, None]).astype(dtype)
+    return _ragged(hidden, wd.astype(dtype), counts).astype(dtype)
+
+
+def _expert_mlp_case(dtype, rows=256, d=128, width=128):
+    rng = np.random.RandomState(6)
+    counts = jnp.asarray([40, 0, 77, 19], jnp.int32)
+    live = (np.arange(rows) < int(counts.sum()))[:, None]
+    args = (jnp.asarray(rng.randn(rows, d), dtype),
+            jnp.asarray(rng.rand(rows), jnp.float32), counts,
+            jnp.asarray(rng.randn(4, d, width) * 0.1, jnp.float32),
+            jnp.asarray(rng.randn(4, d, width) * 0.1, jnp.float32),
+            jnp.asarray(rng.randn(4, width, d) * 0.1, jnp.float32))
+    target = jnp.asarray(rng.randn(rows, d), jnp.float32)
+
+    def loss(fn):
+        def of(rows_, weight_, wg, wu, wd):
+            out = fn(rows_, weight_, counts, wg, wu, wd)
+            return jnp.sum(jnp.where(live, out.astype(jnp.float32), 0)
+                           * target)
+        return jax.value_and_grad(of, argnums=(0, 1, 2, 3, 4))
+
+    return args, live, loss
+
+
+@pytest.mark.parametrize("form", ["xla", "kernels"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_the_expert_mlp_has_a_backward_of_its_own(dtype, form):
+    """``moe_expert_mlp``'s hand-written gradients (rows, row weights and
+    all three weights, which come back in the masters' float32) against
+    ``jax.grad`` of the plain composition, on the ``ragged_dot`` path and
+    with every kernel in interpret mode; in bf16 within bf16's rounding of
+    each gradient's largest entry."""
+    from paddle_tpu.ops import moe_ops
+
+    args, live, loss = _expert_mlp_case(dtype)
+
+    def own(rows_, weight_, counts, wg, wu, wd):
+        wg, wu, wd = (w.astype(rows_.dtype) for w in (wg, wu, wd))
+        return moe_ops._expert_mlp(rows_, weight_, counts, wg, wu, wd,
+                                   form == "kernels")
+
+    rest = args[:2] + args[3:]
+    value, got = loss(own)(*rest)
+    wanted, want = loss(_plain_expert_mlp)(*rest)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-4
+    np.testing.assert_allclose(float(value), float(wanted),
+                               rtol=tol, atol=tol)
+    masks = (live, live[:, 0], True, True, True)
+    for name, a, b, keep in zip(("rows", "row weights", "gate", "up", "down"),
+                                got, want, masks):
+        a = np.where(keep, np.asarray(a, np.float32), 0)
+        b = np.where(keep, np.asarray(b, np.float32), 0)
+        assert np.abs(a - b).max() <= tol * max(np.abs(b).max(), 1e-6), name
+    assert [g.dtype for g in got] == [dtype, jnp.float32, jnp.float32,
+                                      jnp.float32, jnp.float32]
+
+
+def test_the_expert_mlp_counts_the_form_of_its_pair_product():
+    """``moe.gmm_pair_xla`` / ``moe.gmm_pair_kernel``: call sites of the
+    two-pair product lowered in each form, counted where the backward is
+    traced: one a layer, XLA's on the CPU, the kernel's under the
+    interpreter."""
+    from paddle_tpu import observability as obs
+    from paddle_tpu.ops import moe_ops
+
+    obs.set_enabled(True)
+    names = ("moe.gmm_pair_kernel", "moe.gmm_pair_xla")
+    args, _, _ = _expert_mlp_case(jnp.float32)
+
+    def counted(interpret):
+        before = [obs.counter_value(n) for n in names]
+        jax.grad(lambda rows_: jnp.sum(moe_ops._expert_mlp(
+            rows_, *args[1:], interpret)[:8]))(args[0])
+        return [obs.counter_value(n) - was for n, was in zip(names, before)]
+
+    assert counted(False) == [0, 1]
+    assert counted(True) == [1, 0]
+    before = [obs.counter_value(n) for n in names]
+    rng = np.random.RandomState(3)
+    _expert_layer(rng.randn(16, D).astype(np.float32), _params(rng), 2, 2)
+    assert [obs.counter_value(n) for n in names] == before  # forward only
+
+
 def test_the_expert_layer_counts_itself():
     """Lowering-time counters (the layer, and the form its two forward row
     movements were lowered in: XLA's gathers on the CPU) and, under the
@@ -647,10 +803,14 @@ def test_the_sigmoid_shares_and_the_shared_expert_add_up_to_the_uncut_layer():
 
 
 def test_the_mellum2_step_is_lowered_as_before():
-    """With every new argument of the builder at its default the
-    ``mellum2_12b`` step (the configuration's own sizes, bf16, the CPU's
-    path) traces to the jaxpr it had at the parent commit (PR 32, 7296293):
-    a digest of the jaxpr text, source positions struck, taken there."""
+    """The ``mellum2_12b`` step (the configuration's own sizes, bf16, the
+    CPU's path) traces to the jaxpr this digest was taken from: the jaxpr
+    text, source positions struck. Pinned at PR 32's commit (7296293) for
+    PR 33's sake, whose builder arguments at their defaults had to leave
+    it alone; **re-pinned by PR 34**, which changes the step on purpose
+    (``moe_expert_mlp`` under a ``custom_vjp`` of its own: the CPU's
+    arithmetic is the same ``ragged_dot``s, the jaxpr is not). A PR that
+    means to leave this step alone sees here whether it did."""
     with open(os.path.join(ROOT, "benchmarks", "configs",
                            "mellum2_12b.json")) as f:
         cfg = json.load(f)
@@ -684,4 +844,4 @@ def test_the_mellum2_step_is_lowered_as_before():
     text = re.sub(r"\S+\.py:\d+", "F", text)
     exe.close()
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
-        "392233118762b739"
+        "2b79d84d5b45a830"

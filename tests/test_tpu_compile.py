@@ -368,6 +368,43 @@ def test_grouped_matmuls_compile(one_chip, monkeypatch, shape):
     assert hlo.count("tpu_custom_call") == 9
 
 
+@pytest.mark.parametrize("shape", list(EXPERT_SHAPES))
+def test_the_expert_mlp_backward_compiles(one_chip, monkeypatch, shape):
+    """One layer's ``moe_expert_mlp`` with its own backward at the decoder
+    cells' shapes, masters in float32 as the step holds them: 8 kernels
+    (three forward, the down projection's transposed product, ONE for the
+    rows' gradient from gate and up, three weights' gradients), and no
+    ``add`` over the ``[R, d]`` buffer: autodiff's ``add_any`` of two
+    rounded partial sums is gone."""
+    import sys
+
+    from paddle_tpu.ops import moe_ops
+
+    monkeypatch.setattr(sys.modules["paddle_tpu.kernels.grouped_matmul"],
+                        "_on_tpu", lambda: True)
+    tokens, d, width = EXPERT_SHAPES[shape]
+
+    def arg(shape_, dtype):
+        return jax.ShapeDtypeStruct(shape_, dtype, sharding=one_chip)
+
+    rows = arg((tokens * 8, d), jnp.bfloat16)
+    up = arg((16, d, width), jnp.float32)
+
+    def loss(rows_, weight_, gate_, up_, down_, sizes_):
+        gate_, up_, down_ = (w.astype(rows_.dtype)
+                             for w in (gate_, up_, down_))
+        out = moe_ops._expert_mlp(rows_, weight_, sizes_, gate_, up_, down_)
+        return jnp.sum(out[:1024].astype(jnp.float32))
+
+    assert moe_ops.gm.pair_by_kernel(tokens * 8, d, width)
+    hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)), rows,
+                   arg((tokens * 8,), jnp.float32), up, up,
+                   arg((16, width, d), jnp.float32),
+                   arg((16,), jnp.int32))
+    assert hlo.count("tpu_custom_call") == 8
+    assert not re.search(r"= bf16\[%d,%d\]\S* add\(" % (tokens * 8, d), hlo)
+
+
 # -- the rotation of Q and K (ops/nn_ops.py rotary_embedding)
 
 @pytest.mark.parametrize("which", ["q", "k"])
