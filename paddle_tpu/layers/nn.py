@@ -5,7 +5,8 @@ layer_norm:3070, matmul:4581, softmax_with_cross_entropy:5659...)."""
 from paddle_tpu.framework import Variable
 from paddle_tpu.layer_helper import LayerHelper
 from paddle_tpu.param_attr import ParamAttr
-from paddle_tpu.initializer import ConstantInitializer, NormalInitializer
+from paddle_tpu.initializer import (ConstantInitializer, NormalInitializer,
+                                    UniformInitializer)
 
 __all__ = [
     "fc",
@@ -2563,11 +2564,13 @@ def rotary_embedding(xs, rope_theta=10000.0, rope_type="default", name=None,
 
 
 def moe_router(input, num_experts, k, param_attr=None, name=None,
-               score_func="softmax", route_scale=1.0, bias_name=None):
+               score_func="softmax", route_scale=1.0, bias_name=None,
+               norm_eps=None):
     """Router over ``num_experts`` in float32 and its top ``k``, for the
     ``N`` tokens of ``input`` [..., d]: scores by ``score_func``
     (``softmax`` over all of them, or a ``sigmoid`` each); the weights are
-    the chosen scores renormalised over the k, times ``route_scale``.
+    the chosen scores renormalised over the k (a sigmoid router's over
+    their sum + ``norm_eps``; None: the op's 1e-20), times ``route_scale``.
     -> (weights [N, k] float32, ids [N, k] int32).
 
     ``bias_name`` names a balancing bias [num_experts]: persistable state
@@ -2592,6 +2595,8 @@ def moe_router(input, num_experts, k, param_attr=None, name=None,
         attrs["score_func"] = score_func
     if float(route_scale) != 1.0:
         attrs["route_scale"] = float(route_scale)
+    if norm_eps is not None:
+        attrs["norm_eps"] = float(norm_eps)
     if bias_name is None:
         helper.append_op(type="moe_router", inputs=inputs, outputs=outputs,
                          attrs=attrs)
@@ -2640,6 +2645,26 @@ def gated_mlp(input, width, gate_attr=None, up_attr=None, down_attr=None,
     helper.append_op(type="gated_mlp",
                      inputs={"X": [input], "Gate": [gate], "Up": [up],
                              "Down": [down]},
+                     outputs={"Out": [out]}, attrs={})
+    return out
+
+
+def gated_short_conv(input, filter_attr=None, taps=3, name=None):
+    """The middle of a gated short convolution: ``input`` [B, T, 3d], the
+    three chunks B, C, x of an input projection in this order, ->
+    C * conv(B * x) [B, T, d], the convolution causal, depthwise (one
+    filter of ``taps`` a channel, [d, taps]) and over time; no activation,
+    no bias. One op (``gated_short_conv``). The filter starts uniform
+    within +-taps^-1/2, what an untouched depthwise Conv1d starts from."""
+    helper = LayerHelper("gated_short_conv", name=name)
+    d = int(input.shape[-1]) // 3
+    bound = float(taps) ** -0.5
+    w = helper.create_parameter(
+        attr=filter_attr, shape=[d, int(taps)], dtype="float32",
+        default_initializer=UniformInitializer(-bound, bound))
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(type="gated_short_conv",
+                     inputs={"X": [input], "Filter": [w]},
                      outputs={"Out": [out]}, attrs={})
     return out
 

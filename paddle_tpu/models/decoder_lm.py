@@ -1,6 +1,6 @@
 """A decoder language model with a per-layer attention pattern and a
 mixture-of-experts feed-forward, for the chip's share of an
-expert-parallel deployment. One builder, two families of block; every
+expert-parallel deployment. One builder for every family of block; every
 argument past ``rms_norm_eps`` defaults to the plain pre-norm form:
 
     h = x + Wo . Attn(RoPE(Wq n1), RoPE(Wk n1), Wv n1)       n1 = RMSNorm(x)
@@ -36,6 +36,22 @@ What the arguments add, each alone:
                        (``layerN.expert_bias``) that the step itself moves,
                        after the backward, by that much against each
                        output's load (layers/nn.py ``moe_bias_update``)
+    route_norm_eps     what a sigmoid router adds to the sum of the chosen
+                       scores before it divides by it (None: the op's 1e-20)
+    a ``conv`` layer   (a kind in ``layer_types``) has no attention, no
+                       rotary table and no q/k norm: its token mixer is the
+                       gated short convolution
+                           z = n1 Win [d, 3d];  B, C, x = z's three chunks
+                           h = x + Wout . (C * conv(B * x))
+                       ``conv`` a causal depthwise convolution over time of
+                       ``conv_L_cache`` taps a channel (filter
+                       ``layerN.conv_filter`` [d, taps]; the last tap
+                       multiplies the current position), no activation, no
+                       bias (layers/nn.py ``gated_short_conv``); its norm is
+                       ``layerN.conv_norm``
+    tie_word_embeddings the head is the embedding itself: logits =
+                       RMSNorm(h) . tok_embedding^T, no ``lm_head``; the one
+                       leaf's gradient is the sum of both uses
 """
 
 import paddle_tpu.fluid as fluid
@@ -86,6 +102,18 @@ def attention(x, prefix, num_heads, num_kv_heads, head_dim, window, rope,
     return _proj(ctx, int(x.shape[-1]), prefix + "o_proj")
 
 
+def short_conv(x, prefix, taps):
+    """The gated short convolution in place of ``attention``: input
+    projection to the three chunks B, C, x, C * conv(B * x), output
+    projection."""
+    d = int(x.shape[-1])
+    mixed = _nn.gated_short_conv(
+        _proj(x, 3 * d, prefix + "conv_in_proj"),
+        filter_attr=fluid.ParamAttr(name=prefix + "conv_filter"), taps=taps)
+    obs.inc("decoder.conv_layers")
+    return _proj(mixed, d, prefix + "conv_out_proj")
+
+
 def gated_mlp(x, prefix, width):
     return _nn.gated_mlp(
         x, width, gate_attr=fluid.ParamAttr(name=prefix + "gate"),
@@ -95,7 +123,7 @@ def gated_mlp(x, prefix, width):
 
 def moe(x, prefix, router_experts, experts_held, expert_offset,
         experts_per_token, width, score_func="softmax", route_scale=1.0,
-        biased=False):
+        biased=False, norm_eps=None):
     """-> (the held experts' part of the layer [B, T, d], tokens each held
     expert received, the router's [load, bias] with ``biased`` or [])."""
     d = int(x.shape[-1])
@@ -104,7 +132,8 @@ def moe(x, prefix, router_experts, experts_held, expert_offset,
         flat, router_experts, experts_per_token,
         param_attr=fluid.ParamAttr(name=prefix + "router"),
         score_func=score_func, route_scale=route_scale,
-        bias_name=prefix + "expert_bias" if biased else None)
+        bias_name=prefix + "expert_bias" if biased else None,
+        norm_eps=norm_eps)
     out, counts = _nn.moe_experts(
         flat, weight, ids, experts_held, expert_offset, width,
         gate_attr=fluid.ParamAttr(name=prefix + "experts_gate"),
@@ -122,11 +151,13 @@ def get_model(batch_size, seq_len, vocab_size, hidden_size, num_hidden_layers,
               mlp_layer_types=None, intermediate_size=None,
               num_shared_experts=0, score_func="softmax", route_scale=1.0,
               load_balance_coeff=0.0, qk_norm=False, attention_gate=False,
-              post_norms=False, scale_embedding=False):
+              post_norms=False, scale_embedding=False, route_norm_eps=None,
+              conv_L_cache=3, tie_word_embeddings=False):
     """Next-token pre-training program; the configuration gives every size
     (``batch_size`` is the feed's own: the batch axis stays open).
-    ``layer_types`` names each layer ``sliding_attention`` or
-    ``full_attention`` (the first ``num_hidden_layers`` entries are used);
+    ``layer_types`` names each layer ``sliding_attention``,
+    ``full_attention`` or ``conv`` (the first ``num_hidden_layers`` entries
+    are used);
     ``rope_parameters`` gives each kind that is rotated its rotary
     attributes; ``mlp_layer_types`` names each layer ``sparse`` (the
     default) or ``dense``. The rest: the module's docstring."""
@@ -143,13 +174,18 @@ def get_model(batch_size, seq_len, vocab_size, hidden_size, num_hidden_layers,
         loads, balances = [], []
         for i in range(num_hidden_layers):
             prefix, kind = "layer%d." % i, layer_types[i]
-            rope = rope_parameters.get(kind)
-            attn = attention(
-                _norm(h, rms_norm_eps, prefix + "attn_norm"), prefix,
-                num_attention_heads, num_key_value_heads, head_dim,
-                sliding_window if kind == "sliding_attention" else None,
-                None if rope is None else dict(rope), rms_norm_eps, qk_norm,
-                attention_gate)
+            if kind == "conv":
+                attn = short_conv(
+                    _norm(h, rms_norm_eps, prefix + "conv_norm"), prefix,
+                    conv_L_cache)
+            else:
+                rope = rope_parameters.get(kind)
+                attn = attention(
+                    _norm(h, rms_norm_eps, prefix + "attn_norm"), prefix,
+                    num_attention_heads, num_key_value_heads, head_dim,
+                    sliding_window if kind == "sliding_attention" else None,
+                    None if rope is None else dict(rope), rms_norm_eps,
+                    qk_norm, attention_gate)
             if post_norms:
                 attn = _norm(attn, rms_norm_eps, prefix + "post_attn_norm")
             h = fluid.layers.elementwise_add(h, attn)
@@ -160,7 +196,7 @@ def get_model(batch_size, seq_len, vocab_size, hidden_size, num_hidden_layers,
                 part, counts, balance = moe(
                     n2, prefix, router_experts, experts_held, expert_offset,
                     num_experts_per_tok, moe_intermediate_size, score_func,
-                    route_scale, load_balance_coeff > 0)
+                    route_scale, load_balance_coeff > 0, route_norm_eps)
                 loads.append(counts)
                 if balance:
                     balances.append(balance)
@@ -173,9 +209,16 @@ def get_model(batch_size, seq_len, vocab_size, hidden_size, num_hidden_layers,
                 part = _norm(part, rms_norm_eps, prefix + "post_mlp_norm")
             h = fluid.layers.elementwise_add(h, part)
         h = _norm(h, rms_norm_eps, "final_norm")
-        logits = _proj(h, vocab_size, "lm_head")
+        if tie_word_embeddings:
+            logits = fluid.layers.matmul(
+                fluid.layers.reshape(h, shape=[-1, hidden_size]),
+                main.global_block().var("tok_embedding"), transpose_y=True)
+            obs.inc("decoder.tied_head")
+        else:
+            logits = fluid.layers.reshape(_proj(h, vocab_size, "lm_head"),
+                                          shape=[-1, vocab_size])
         loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
-            logits=fluid.layers.reshape(logits, shape=[-1, vocab_size]),
+            logits=logits,
             label=fluid.layers.reshape(labels, shape=[-1, 1])))
         if is_train:
             fluid.optimizer.Adam(learning_rate=lr).minimize(loss)

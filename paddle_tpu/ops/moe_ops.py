@@ -70,7 +70,8 @@ def moe_router(ctx, ins, attrs):
     held here or not. ``score_func`` ``softmax`` (the default): softmax
     over all E; ``sigmoid``: a sigmoid each. The k are the largest of score
     + Bias (the bias ranks and takes no gradient); their weights are the
-    scores alone, renormalised over the k and times ``route_scale``.
+    scores alone, renormalised over the k (a sigmoid router's over their
+    sum + ``norm_eps``, 1e-20 unless given) and times ``route_scale``.
     Logits and scores in float32 whatever the program's precision: the
     top-k is a discontinuity, and a rounded score flips it."""
     from paddle_tpu import observability as obs
@@ -91,7 +92,8 @@ def moe_router(ctx, ins, attrs):
         _, ids = lax.top_k(probs + lax.stop_gradient(bias), k)
         top = jnp.take_along_axis(probs, ids, axis=-1)
     if sigmoid:     # (sigmoids can all be small; a softmax's top-k cannot)
-        top = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20)
+        top = top / (jnp.sum(top, axis=-1, keepdims=True)
+                     + float(attrs.get("norm_eps", 1e-20)))
     else:
         top = top / jnp.sum(top, axis=-1, keepdims=True)
     scale = float(attrs.get("route_scale", 1.0))

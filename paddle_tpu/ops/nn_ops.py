@@ -532,6 +532,108 @@ def gated_mlp(ctx, ins, attrs):
     return {"Out": [out.reshape(x.shape[:-1] + (wd.shape[-1],))]}
 
 
+def _shift(x, steps):
+    """x [B, T, d] moved ``steps`` positions later along T (earlier where
+    negative), zeros coming in: out[t] = x[t - steps]."""
+    if steps == 0:
+        return x
+    t = x.shape[1]
+    if steps > 0:
+        return jnp.pad(x, ((0, 0), (steps, 0), (0, 0)))[:, :t]
+    return jnp.pad(x, ((0, 0), (0, -steps), (0, 0)))[:, -t:]
+
+
+def _short_conv_chunks(z, d):
+    """B, C, x of z [B, T, 3d] in float32, each sliced in z's dtype and
+    cast after: a cast of all of z first is a pass of its own on the chip,
+    or XLA folds it into the projection before the op, which then writes
+    z in float32."""
+    return [z[..., i * d:(i + 1) * d].astype(jnp.float32) for i in range(3)]
+
+
+def _short_conv_parts(z, w):
+    """(B, C, x, [p moved L - 1 - j positions later for every tap j], c) in
+    float32, p = B * x and c[t] = sum_j w[:, j] * p[t - (L - 1) + j] the
+    causal depthwise convolution (p zero before the sequence's first
+    position; tap L - 1 multiplies the current position). A moved p is
+    made from z moved, an operand, so that no float32 [T, d] is written
+    in between."""
+    d, taps = w.shape
+    b, c_gate, x = _short_conv_chunks(z, d)
+    moved = [b * x]
+    for steps in range(1, taps):
+        b_, _, x_ = _short_conv_chunks(_shift(z, steps), d)
+        moved.append(b_ * x_)
+    moved.reverse()                     # tap j reads p moved L - 1 - j
+    w = w.astype(jnp.float32)
+    c = sum(w[:, j] * moved[j] for j in range(taps))
+    return b, c_gate, x, moved, c
+
+
+@jax.custom_vjp
+def _gated_short_conv(z, w):
+    """C * conv(B * x) for z = [B, C, x] [batch, T, 3d] and the filter w
+    [d, L]: the taps as shifted multiply-adds along T, products and sum in
+    float32, the result rounded once to z's dtype. The backward keeps z
+    and w alone and makes p and c again (the barrier keeps XLA, which sees
+    forward and backward in one jitted step, from keeping the forward's
+    float32 p and c for it instead); dz is one concatenate of dB, dC and
+    dx (autodiff of the three slices would pad each to 3d columns and add
+    them). Which of the forms XLA makes most of on the chip: PERF.md,
+    Findings PR 35."""
+    _, c_gate, _, _, c = _short_conv_parts(z, w)
+    return (c_gate * c).astype(z.dtype)
+
+
+def _gated_short_conv_bwd(res, g):
+    z, w, g = lax.optimization_barrier(res + (g,))
+    d, taps = w.shape
+    b, c_gate, x, moved, c = _short_conv_parts(z, w)
+    g32, w32 = g.astype(jnp.float32), w.astype(jnp.float32)
+    gc = c_gate * g32
+
+    def gc_earlier(steps):
+        """C * g moved ``steps`` positions earlier, from z and g moved."""
+        if steps == 0:
+            return gc
+        return (_short_conv_chunks(_shift(z, -steps), d)[1]
+                * _shift(g, -steps).astype(jnp.float32))
+
+    dp = sum(w32[:, j] * gc_earlier(taps - 1 - j) for j in range(taps))
+    dw = jnp.stack([jnp.sum(gc * moved[j], axis=(0, 1))
+                    for j in range(taps)], axis=1)
+    dz = jnp.concatenate([dp * x, g32 * c, dp * b], axis=-1)
+    return dz.astype(z.dtype), dw.astype(w.dtype)
+
+
+_gated_short_conv.defvjp(lambda z, w: (_gated_short_conv(z, w), (z, w)),
+                         _gated_short_conv_bwd)
+
+
+@register_op("gated_short_conv")
+def gated_short_conv(ctx, ins, attrs):
+    """X [B, T, 3d] (the three chunks B, C, x of an input projection, in
+    this order), Filter [d, L] -> Out [B, T, d] = C * conv_L(B * x): a
+    causal depthwise convolution of L taps a channel over time (each row
+    of the batch a sequence of its own, zeros before its first position),
+    gated before and after; no activation, no bias. L is the filter's
+    second axis. One op type, so that a trace reads its time by type;
+    plain ``jax.numpy``, no kernel. X's dtype in, X's dtype out (bf16
+    under AMP, where the projection before it gives bf16); the filter
+    stays float32. ``short_conv.calls`` / ``short_conv.taps`` (``metrics``
+    flag) count the ops lowered into a step and the sum of their L."""
+    from paddle_tpu import observability as obs
+
+    z, w = single(ins, "X"), single(ins, "Filter")
+    if z.shape[-1] != 3 * w.shape[0]:
+        raise ValueError("gated_short_conv: X's last axis %d is not 3 x the "
+                         "filter's %d channels" % (z.shape[-1], w.shape[0]))
+    if lowered_into_a_step(ctx, "gated_short_conv"):
+        obs.inc("short_conv.calls")
+        obs.inc("short_conv.taps", int(w.shape[1]))
+    return {"Out": [_gated_short_conv(z, w)]}
+
+
 def rope_inv_freq(head_dim, attrs):
     """(inverse frequencies [head_dim / 2] float64, the factor on cos and
     sin) of a rotary embedding: ``rope_type`` ``default`` is

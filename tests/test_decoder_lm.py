@@ -1,8 +1,9 @@
 """The decoder language model (``models/decoder_lm.py``) and the ops it
 brought — ``rms_norm``, ``rotary_embedding``, the expert-layer ops,
-``gated_mlp`` — through ``Executor.run`` on the CPU at small sizes,
-against the benchmark's plain references (``benchmarks/reference/mellum2.py``
-and ``trinity_mini.py``, which import nothing of the program)."""
+``gated_mlp``, ``gated_short_conv`` — through ``Executor.run`` on the CPU
+at small sizes, against the benchmark's plain references
+(``benchmarks/reference/mellum2.py``, ``trinity_mini.py`` and
+``lfm2_moe.py``, which import nothing of the program)."""
 
 import hashlib
 import json
@@ -23,7 +24,7 @@ from paddle_tpu.ops.nn_ops import rope_inv_freq
 
 from benchmarks import compare
 from benchmarks.drivers import train
-from benchmarks.reference import common, mellum2, trinity_mini
+from benchmarks.reference import common, lfm2_moe, mellum2, trinity_mini
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 CELL = "mellum2_12b.pretrain_s4096_b2"
@@ -802,17 +803,351 @@ def test_the_sigmoid_shares_and_the_shared_expert_add_up_to_the_uncut_layer():
                                atol=1e-4, rtol=2e-4)
 
 
-def test_the_mellum2_step_is_lowered_as_before():
-    """The ``mellum2_12b`` step (the configuration's own sizes, bf16, the
-    CPU's path) traces to the jaxpr this digest was taken from: the jaxpr
-    text, source positions struck. Pinned at PR 32's commit (7296293) for
-    PR 33's sake, whose builder arguments at their defaults had to leave
-    it alone; **re-pinned by PR 34**, which changes the step on purpose
-    (``moe_expert_mlp`` under a ``custom_vjp`` of its own: the CPU's
-    arithmetic is the same ``ragged_dot``s, the jaxpr is not). A PR that
-    means to leave this step alone sees here whether it did."""
+# -- the conv / attention hybrid (the lfm2_24b_a2b cell) -----------------------
+
+LFM2 = "lfm2_24b_a2b.pretrain_b2"
+LFM2_SEED = 3000000007
+
+
+def _numpy_short_conv(z, w):
+    """C * conv(B * x) and, for the cotangent g, (dz, dw), in float64, by
+    loops over the taps: the issue's equations as they stand."""
+    z, w = z.astype(np.float64), w.astype(np.float64)
+    d, taps = w.shape
+    seq = z.shape[1]
+    b, c_gate, x = z[..., :d], z[..., d:2 * d], z[..., 2 * d:]
+    p = b * x
+    c = np.zeros_like(p)
+    for t in range(seq):
+        for j in range(taps):
+            if t - (taps - 1) + j >= 0:
+                c[:, t] += w[:, j] * p[:, t - (taps - 1) + j]
+
+    def backward(g):
+        gc = c_gate * g.astype(np.float64)
+        dp, dw = np.zeros_like(p), np.zeros_like(w)
+        for s in range(seq):
+            for j in range(taps):
+                if s + (taps - 1) - j < seq:
+                    dp[:, s] += w[:, j] * gc[:, s + (taps - 1) - j]
+        for j in range(taps):
+            for t in range(seq):
+                if t - (taps - 1) + j >= 0:
+                    dw[:, j] += (gc[:, t] * p[:, t - (taps - 1) + j]).sum(0)
+        return np.concatenate([dp * x, g * c, dp * b], -1), dw
+
+    return c_gate * c, backward
+
+
+def _short_conv(z, w, g=None):
+    """Out of the ``gated_short_conv`` op through Executor.run and, with a
+    cotangent ``g``, the gradients of sum(Out * g) for X and the filter."""
+    d, taps = w.shape
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        data = fluid.layers.data(name="z", shape=list(z.shape[1:]),
+                                 dtype="float32")
+        data.stop_gradient = False
+        out = _nn.gated_short_conv(
+            data, filter_attr=fluid.ParamAttr(name="filter"), taps=taps)
+        fetch = [out]
+        if g is not None:
+            loss = fluid.layers.reduce_sum(out * fluid.layers.assign(g))
+            fluid.optimizer.SGD(learning_rate=1.0).minimize(loss)
+            fetch += [main.global_block().var("z@GRAD"),
+                      main.global_block().var("filter@GRAD")]
+    assert tuple(main.global_block().var("filter").shape) == (d, taps)
+    exe, scope = fluid.Executor(), fluid.Scope()
+    exe.run(startup, scope=scope)
+    start = np.asarray(scope.get("filter"))
+    assert np.abs(start).max() <= taps ** -0.5 and start.std() > 0.2
+    scope.set("filter", jnp.asarray(w))
+    got = exe.run(main, feed={"z": z}, fetch_list=fetch, scope=scope)
+    exe.close()
+    return got
+
+
+@pytest.mark.parametrize("taps", [3, 4])
+def test_gated_short_conv_and_its_gradient_match_numpy(taps):
+    """C * conv(B * x) on [2, 9, 3 x 8] and both gradients of sum(Out * g)
+    against loops in numpy; the filter's length is read from its shape."""
+    rng = np.random.RandomState(20 + taps)
+    z = rng.randn(2, 9, 24).astype(np.float32)
+    w = rng.uniform(-0.6, 0.6, (8, taps)).astype(np.float32)
+    g = rng.randn(2, 9, 8).astype(np.float32)
+    out, dz, dw = _short_conv(z, w, g)
+    want, backward = _numpy_short_conv(z, w)
+    want_dz, want_dw = backward(g)
+    np.testing.assert_allclose(out, want, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(dz, want_dz, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(dw, want_dw, atol=2e-5, rtol=1e-5)
+    # the plain reference's own convolution is the same sum
+    np.testing.assert_allclose(
+        z[..., 8:16] * np.asarray(lfm2_moe.short_conv(
+            jnp.asarray(z[..., :8] * z[..., 16:]), jnp.asarray(w))),
+        want, atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("taps", [3, 4])
+def test_gated_short_conv_is_causal_and_starts_from_zeros(taps):
+    """Move one input position: the outputs at that position and the
+    ``taps - 1`` after it move (the gate C at the position itself), none
+    before it and none further on, and no other row of the batch. With a
+    filter whose last tap is zero the first position's output is zero: it
+    sees only the zeros before the sequence."""
+    rng = np.random.RandomState(30 + taps)
+    z = rng.randn(2, 12, 12).astype(np.float32)
+    w = rng.uniform(0.2, 0.6, (4, taps)).astype(np.float32)
+    (base,) = _short_conv(z, w)
+    moved = z.copy()
+    moved[1, 5] += 1.0
+    (out,) = _short_conv(moved, w)
+    changed = np.abs(out - base).max(-1) > 1e-6
+    assert changed[1].tolist() == [5 <= t < 5 + taps for t in range(12)]
+    assert not changed[0].any()
+    w[:, -1] = 0.0
+    (out,) = _short_conv(z, w)
+    assert np.all(out[:, 0] == 0) and np.abs(out[:, 1]).min() > 0
+    zeros = z.copy()
+    zeros[:, :taps - 1] = 0.0       # p = 0 on the first taps - 1 positions
+    (out,) = _short_conv(zeros, w)
+    assert np.all(out[:, :taps - 1] == 0)
+
+
+@pytest.fixture(scope="module")
+def lfm2():
+    """The cell's program at the rehearsal size, built and driven through
+    its first three steps once, with the metrics flag up."""
+    from paddle_tpu import observability as obs
+
+    workload, cfg, rows = _cell(LFM2)
+    names = ("decoder.conv_layers", "decoder.tied_head", "short_conv.calls",
+             "short_conv.taps", "attn.qk_norm", "moe.router_sigmoid",
+             "moe.bias_updates", "gated_mlp.calls", "rope.rotations",
+             "moe.shared_experts", "attn.gated")
+    obs.set_enabled(True)
+    before = {name: obs.counter_value(name) for name in names}
+    with fluid.unique_name.guard():
+        trainer = train.Trainer(cfg, rows, workload, rehearse=True)
+    trainer.start(LFM2_SEED)
+    program, _ = trainer.warm_up()
+    jax.effects_barrier()
+    counted = {name: obs.counter_value(name) - was
+               for name, was in before.items()}
+    yield dict(workload=workload, cfg=cfg, rows=rows, trainer=trainer,
+               program=program, counted=counted)
+    trainer.exe.close()
+
+
+def test_the_lfm2_program_follows_its_reference(lfm2):
+    """get_model with conv layers, a tied head and the router's epsilon ->
+    enable_bf16 -> Executor.run against the float32 reference through three
+    steps, under the cell's rehearsal limits: losses, first gradients (the
+    filters' and the tied leaf's among them), changes, the bias state's."""
+    cfg, trainer = lfm2["cfg"], lfm2["trainer"]
+    reference = common.follow(lfm2_moe, cfg, lfm2["rows"], LFM2_SEED)
+    correct, compared = compare.judge(
+        lfm2["program"], reference,
+        train.limits(lfm2["workload"], rehearse=True))
+    assert correct, compared
+    assert compared["loss_gap"]["value"] < 1e-3
+    ops = trainer.main.desc.global_block().ops
+    kinds = [op.type for op in ops]
+    assert kinds.count("gated_short_conv") == 4
+    assert kinds.count("gated_short_conv_grad") == 4
+    assert kinds.count("fused_attention") == 1          # no mixer but conv
+    assert kinds.count("rotary_embedding") == 1
+    assert kinds.count("gated_mlp") == 1 and kinds.count("moe_router") == 4
+    assert kinds.count("moe_bias_update") == 4
+    assert [op.attrs.get("norm_eps") for op in ops
+            if op.type == "moe_router"] == [1e-6] * 4
+    names = {p.name for p in trainer.main.all_parameters()}
+    assert "lm_head" not in names
+    assert {"layer0.conv_filter", "layer0.conv_in_proj",
+            "layer0.conv_out_proj", "layer0.conv_norm", "layer1.q_norm",
+            "layer1.attn_norm"} <= names
+    assert not {"layer0.q_proj", "layer0.attn_norm", "layer1.conv_filter"
+                } & names
+    for name in ("tok_embedding", "layer0.conv_filter", "layer4.conv_filter"):
+        assert reference["grad_norms"][name] > 0
+        assert lfm2["program"]["grad_norms"][name] == pytest.approx(
+            reference["grad_norms"][name], rel=0.05)
+    biases = sorted(lfm2_moe.state_specs(cfg))
+    assert biases == ["layer%d.expert_bias" % i for i in (1, 2, 3, 4)]
+    for name in biases:
+        moved = reference["change_norms"][name]
+        assert moved > 0
+        assert lfm2["program"]["change_norms"][name] == pytest.approx(
+            moved, rel=0.35)
+        bias = np.asarray(trainer.scope.get(name))
+        assert abs(bias.sum()) < 1e-6 and np.abs(bias).max() <= 6.1e-3
+
+
+def test_the_tied_leaf_takes_the_sum_of_both_gradients(lfm2):
+    """One leaf ``tok_embedding``, read by the look-up and, transposed, by
+    the head: its gradient is a ``sum`` op over the look-up's and the
+    matmul's, both non-zero, and Adam gets the sum."""
+    trainer = lfm2["trainer"]
+    ops = trainer.main.desc.global_block().ops
+    (total,) = [op for op in ops if op.type == "sum"
+                and op.output("Out") == ["tok_embedding@GRAD"]]
+    parts = total.input("X")
+    assert len(parts) == 2
+    makers = sorted(op.type for op in ops if op.type != "sum"
+                    and set(op.output_arg_names()) & set(parts))
+    assert makers == ["lookup_table_grad", "matmul_grad"]
+    head = [op for op in ops if op.type == "matmul"]
+    assert len(head) == 1 and head[0].input("Y") == ["tok_embedding"]
+    assert head[0].attrs["transpose_Y"]
+    (looked_up,) = [op.output("W@GRAD")[0] for op in ops
+                    if op.type == "lookup_table_grad"]
+    part, both = trainer.exe.run(
+        trainer.main, feed=trainer.pool[0], scope=trainer.scope,
+        fetch_list=[looked_up, "tok_embedding@GRAD"])
+    # (the sum writes over the matmul's part, so that one is not fetched:)
+    # a row no id of the batch names has the head's gradient alone, a row
+    # that one names has more than the look-up's
+    used = np.zeros(part.shape[0], bool)
+    used[np.asarray(trainer.pool[0]["ids"]).ravel()] = True
+    assert used.any() and not used.all()
+    assert np.all(part[~used] == 0) and np.abs(both[~used]).min(0).max() > 0
+    assert np.abs(part[used]).max() > 0
+    assert np.abs(both[used] - part[used]).max() > 0
+
+
+def test_the_lfm2_program_counts_what_it_was_built_with(lfm2):
+    """The new counters under the metrics flag: four conv layers of three
+    taps each, one tied head; one q/k-normed, rotated attention; one dense
+    and four sigmoid-routed layers with a bias; no shared expert, no gate."""
+    assert lfm2["counted"] == {
+        "decoder.conv_layers": 4, "decoder.tied_head": 1,
+        "short_conv.calls": 4, "short_conv.taps": 12, "attn.qk_norm": 1,
+        "moe.router_sigmoid": 4, "moe.bias_updates": 4,
+        "gated_mlp.calls": 1, "rope.rotations": 2, "moe.shared_experts": 0,
+        "attn.gated": 0}
+
+
+@pytest.mark.parametrize("eps", [1e-6, 0.25])
+def test_the_router_adds_norm_eps_to_the_sum_of_the_chosen_scores(eps):
+    """A sigmoid router at top 4 of 8 with ``norm_eps`` against numpy: the
+    weights are the chosen sigmoids over their sum + eps (0.25 is large
+    enough to show in float32; 1e-6 is the configuration's), and a router
+    built without the argument keeps 1e-20 and no attribute."""
+    rng = np.random.RandomState(40)
+    x = rng.randn(32, D).astype(np.float32)
+    params = (rng.randn(D, EXPERTS) * 0.3).astype(np.float32)
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        data = fluid.layers.data(name="x", shape=[D], dtype="float32")
+        fetch = list(_nn.moe_router(
+            data, EXPERTS, 4, param_attr=fluid.ParamAttr(name="router"),
+            score_func="sigmoid", norm_eps=eps))
+        fetch += _nn.moe_router(
+            data, EXPERTS, 4, param_attr=fluid.ParamAttr(name="router"),
+            score_func="sigmoid")
+    first, second = [op for op in main.global_block().ops
+                     if op.type == "moe_router"]
+    assert first.attr("norm_eps") == eps
+    assert "norm_eps" not in second.desc.attrs
+    exe, scope = fluid.Executor(), fluid.Scope()
+    exe.run(startup, scope=scope)
+    scope.set("router", jnp.asarray(params))
+    weight, ids, plain, plain_ids = exe.run(main, feed={"x": x},
+                                            fetch_list=fetch, scope=scope)
+    exe.close()
+    scores = 1.0 / (1.0 + np.exp(-(x.astype(np.float64) @ params)))
+    want_ids = np.argsort(-scores, axis=1, kind="stable")[:, :4]
+    top = np.take_along_axis(scores, want_ids, 1)
+    np.testing.assert_array_equal(ids, want_ids)
+    np.testing.assert_array_equal(plain_ids, want_ids)
+    np.testing.assert_allclose(weight, top / (top.sum(1, keepdims=True) + eps),
+                               rtol=2e-5)
+    np.testing.assert_allclose(plain, top / top.sum(1, keepdims=True),
+                               rtol=2e-5)
+    np.testing.assert_allclose(plain.sum(1), 1.0, rtol=1e-5)
+    if eps > 0.1:
+        assert (weight.sum(1) < 0.95).all()
+
+
+def _lfm2_share(x, params, held, offset, bias):
+    """The routed part (experts ``offset .. offset + held`` of 16, 4 a
+    token, epsilon 1e-6, a bias) of the program's expert layer."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.unique_name.guard(), fluid.program_guard(main, startup):
+        data = fluid.layers.data(name="x", shape=[D], dtype="float32")
+        weight, ids, _, _ = _nn.moe_router(
+            data, 16, 4, param_attr=fluid.ParamAttr(name="router"),
+            score_func="sigmoid", bias_name="bias", norm_eps=1e-6)
+        routed, _ = _nn.moe_experts(
+            data, weight, ids, held, offset, WIDTH,
+            gate_attr=fluid.ParamAttr(name="gate"),
+            up_attr=fluid.ParamAttr(name="up"),
+            down_attr=fluid.ParamAttr(name="down"))
+    exe, scope = fluid.Executor(), fluid.Scope()
+    exe.run(startup, scope=scope)
+    scope.set("router", jnp.asarray(params["router"]))
+    scope.set("bias", jnp.asarray(bias))
+    for name in ("gate", "up", "down"):
+        scope.set(name, jnp.asarray(params[name][offset:offset + held]))
+    (got,) = exe.run(main, feed={"x": x}, fetch_list=[routed], scope=scope)
+    exe.close()
+    return got
+
+
+def test_the_eight_shares_add_up_to_the_uncut_layer_of_the_lfm2_reference():
+    """16 experts in 8 shares of 2, 4 a token, no shared expert, a bias
+    that moves the choice: the eight shares' routed parts add up to what
+    ``lfm2_moe.experts`` gives for the whole layer (all 16 held), and each
+    share is the reference's own share."""
+    rng = np.random.RandomState(11)
+    x = rng.randn(40, D).astype(np.float32)
+    params = {
+        "router": (rng.randn(D, 16) * 0.3).astype(np.float32),
+        "gate": (rng.randn(16, D, WIDTH) * 0.3).astype(np.float32),
+        "up": (rng.randn(16, D, WIDTH) * 0.3).astype(np.float32),
+        "down": (rng.randn(16, WIDTH, D) * 0.3).astype(np.float32)}
+    bias = (rng.randn(16) * 0.2).astype(np.float32)
+
+    def reference(held, offset):
+        m = {"num_experts_per_tok": 4, "experts_held": held,
+             "expert_offset": offset, "route_scale": 1,
+             "route_norm_eps": 1e-6}
+        p = {"l.router": params["router"]}
+        for name in ("gate", "up", "down"):
+            p["l.experts_" + name] = params[name][offset:offset + held]
+        with jax.default_matmul_precision("highest"):
+            out, load = lfm2_moe.experts(
+                common.Matmuls("f32"), m,
+                {k: jnp.asarray(v) for k, v in p.items()}, "l.",
+                jnp.asarray(x), jnp.asarray(bias))
+        assert int(load.sum()) == x.shape[0] * 4
+        return np.asarray(out)
+
+    total = 0.0
+    for offset in range(0, 16, 2):
+        routed = _lfm2_share(x, params, 2, offset, bias)
+        np.testing.assert_allclose(routed, reference(2, offset),
+                                   atol=5e-5, rtol=2e-4)
+        total = total + routed
+    np.testing.assert_allclose(total, reference(16, 0), atol=1e-4, rtol=2e-4)
+
+
+@pytest.mark.parametrize("config, digest", [
+    ("mellum2_12b", "2b79d84d5b45a830"), ("trinity_mini", "28455a677ee1aa16")])
+def test_the_accepted_decoder_steps_are_lowered_as_before(config, digest):
+    """The ``mellum2_12b`` and ``trinity_mini`` steps (the configurations'
+    own sizes, bf16, the CPU's path) trace to the jaxprs these digests were
+    taken from: the jaxpr text, source positions struck. ``mellum2_12b``
+    pinned at PR 32's commit (7296293) for PR 33's sake, whose builder
+    arguments at their defaults had to leave it alone; **re-pinned by PR
+    34**, which changes the step on purpose (``moe_expert_mlp`` under a
+    ``custom_vjp`` of its own: the CPU's arithmetic is the same
+    ``ragged_dot``s, the jaxpr is not). ``trinity_mini`` pinned at PR 34's
+    commit (5dcbb94) for PR 35's sake (a ``conv`` layer kind, a tied head
+    and the router's ``norm_eps``, all at their defaults here). A PR that
+    means to leave these steps alone sees here whether it did."""
     with open(os.path.join(ROOT, "benchmarks", "configs",
-                           "mellum2_12b.json")) as f:
+                           config + ".json")) as f:
         cfg = json.load(f)
     from paddle_tpu.core.types import convert_dtype_to_np
     from paddle_tpu.models import decoder_lm
@@ -843,5 +1178,4 @@ def test_the_mellum2_step_is_lowered_as_before():
     text = re.sub(r" at 0x[0-9a-f]+", "", text)
     text = re.sub(r"\S+\.py:\d+", "F", text)
     exe.close()
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
-        "2b79d84d5b45a830"
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest

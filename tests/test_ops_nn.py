@@ -487,3 +487,37 @@ class TestSoftmaxWithCE:
                                    ref.detach().numpy(), atol=1e-5, rtol=1e-4)
         np.testing.assert_allclose(got["logits@GRAD"], tl.grad.numpy(),
                                    atol=1e-6, rtol=1e-4)
+
+
+class TestGatedShortConv:
+    @pytest.mark.parametrize("taps", [3, 4])
+    def test_against_a_depthwise_conv1d(self, taps):
+        """C * conv(B * x), [B, C, x] the chunks of X: torch's depthwise
+        ``conv1d`` over time, ``taps - 1`` zeros before each sequence and
+        the overhang cut (a causal ``Conv1d`` as the model's family writes
+        it), and its autograd for both gradients."""
+        rng = np.random.RandomState(15 + taps)
+        z = rng.randn(2, 10, 18).astype(np.float32)
+        w = rng.uniform(-0.6, 0.6, (6, taps)).astype(np.float32)
+        got = run_single_op(
+            "gated_short_conv", {"X": [("z", z)], "Filter": [("w", w)]},
+            ["Out"], grad_inputs=["z", "w"])
+        tz, tw = _t(z), _t(w)
+        b, c, x = tz.chunk(3, dim=-1)
+        conv = F.conv1d((b * x).transpose(1, 2), tw.unsqueeze(1),
+                        padding=taps - 1, groups=6)[..., :10]
+        ref = c * conv.transpose(1, 2)
+        ref.mean().backward()
+        np.testing.assert_allclose(got["out_out"], ref.detach().numpy(),
+                                   atol=1e-5, rtol=1e-5)
+        np.testing.assert_allclose(got["z@GRAD"], tz.grad.numpy(),
+                                   atol=1e-6, rtol=1e-4)
+        np.testing.assert_allclose(got["w@GRAD"], tw.grad.numpy(),
+                                   atol=1e-6, rtol=1e-4)
+
+    def test_the_filter_has_to_fit_the_input(self):
+        z = np.zeros((1, 4, 10), np.float32)
+        w = np.zeros((3, 3), np.float32)
+        with pytest.raises(Exception, match="3 x the filter"):
+            run_single_op("gated_short_conv",
+                          {"X": [("z", z)], "Filter": [("w", w)]}, ["Out"])

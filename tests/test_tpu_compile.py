@@ -269,25 +269,32 @@ def test_s8_matmul_compiles(one_chip):
     assert "s32[8,1000]" in hlo
 
 
-# -- the decoder cell's kernels: grouped-query heads, a window, head size 128
+# -- the decoder cells' kernels: grouped-query heads, a window, head sizes
+# 128 and 64
 
 DECODER = dict(rows=2, q_heads=32, kv_heads=4, seq=4096, dim=128)
-# (positions, window) of the decoder cells' attention layers: the first
-# cell's 4096 under 1024 and under none, the second's 3072 under 2048 and
-# under none; (tokens x 8 rows, d, width) of their expert layers, 16 held
-ATTENTION_SHAPES = {"window": (4096, 1024), "full": (4096, None),
-                    "s3072-window2048": (3072, 2048),
-                    "s3072-full": (3072, None)}
-EXPERT_SHAPES = {"d2304-w896": (8192, 2304, 896),
-                 "d2048-w1024": (6144, 2048, 1024)}
+# (positions, window, key/value heads, head size) of the decoder cells'
+# attention layers: the first cell's 4096 under 1024 and under none, the
+# second's 3072 under 2048 and under none, the third's 8192 causal under
+# none on 8 key/value heads of 64; (tokens, d, width, experts a token,
+# experts held) of their expert layers
+ATTENTION_SHAPES = {"window": (4096, 1024, 4, 128),
+                    "full": (4096, None, 4, 128),
+                    "s3072-window2048": (3072, 2048, 4, 128),
+                    "s3072-full": (3072, None, 4, 128),
+                    "s8192-full-kv8-d64": (8192, None, 8, 64)}
+EXPERT_SHAPES = {"d2304-w896": (8192, 2304, 896, 8, 16),
+                 "d2048-w1024": (6144, 2048, 1024, 8, 16),
+                 "d2048-w1536": (16384, 2048, 1536, 4, 8)}
 
 
-def _decoder_args(one_chip, seq=None):
-    b, t, d = DECODER["rows"], seq or DECODER["seq"], DECODER["dim"]
+def _decoder_args(one_chip, seq=None, kv_heads=None, dim=None):
+    b, t = DECODER["rows"], seq or DECODER["seq"]
+    d = dim or DECODER["dim"]
     q = jax.ShapeDtypeStruct((b, DECODER["q_heads"], t, d), jnp.bfloat16,
                              sharding=one_chip)
-    kv = jax.ShapeDtypeStruct((b, DECODER["kv_heads"], t, d), jnp.bfloat16,
-                              sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, kv_heads or DECODER["kv_heads"], t, d),
+                              jnp.bfloat16, sharding=one_chip)
     lse = jax.ShapeDtypeStruct((b * DECODER["q_heads"], t, _LSE_LANES),
                                jnp.float32, sharding=one_chip)
     return q, kv, lse
@@ -295,14 +302,14 @@ def _decoder_args(one_chip, seq=None):
 
 @pytest.mark.parametrize("shape", list(ATTENTION_SHAPES))
 def test_grouped_query_forward_compiles(one_chip, shape):
-    seq, window = ATTENTION_SHAPES[shape]
-    q, kv, _ = _decoder_args(one_chip, seq)
+    seq, window, kv_heads, dim = ATTENTION_SHAPES[shape]
+    q, kv, _ = _decoder_args(one_chip, seq, kv_heads, dim)
     blk = pick_block(seq, jnp.bfloat16)
 
     def fwd(q_, k_, v_):
         return flash_attention_raw_lse(
-            q_, k_, v_, None, 0, True, DECODER["dim"] ** -0.5, 0.0, blk,
-            blk, False, window)
+            q_, k_, v_, None, 0, True, dim ** -0.5, 0.0, blk, blk, False,
+            window)
 
     assert _compile(fwd, q, kv, kv).count("tpu_custom_call") == 1
 
@@ -311,15 +318,18 @@ def test_grouped_query_forward_compiles(one_chip, shape):
 @pytest.mark.parametrize("shape", list(ATTENTION_SHAPES))
 def test_grouped_query_backward_compiles(one_chip, monkeypatch, shape,
                                          form):
-    """Both backward forms at [2, 32/4, positions, 128] bf16: by the shapes
-    this attention takes the one kernel (its [positions, 128] dQ
-    accumulator fits); dK/dV come out per K/V head."""
+    """Both backward forms at [2, 32/4, positions, 128] and [2, 32/8, 8192,
+    64] bf16: by the shapes this attention takes the one kernel (its
+    [positions, head] dQ accumulator fits; 8192 positions at head size 64
+    are the last that do: twice as many take the pair); dK/dV come out per
+    K/V head."""
     import sys
 
-    seq, window = ATTENTION_SHAPES[shape]
-    q, kv, lse = _decoder_args(one_chip, seq)
+    seq, window, kv_heads, dim = ATTENTION_SHAPES[shape]
+    q, kv, lse = _decoder_args(one_chip, seq, kv_heads, dim)
     blk = pick_block(seq, jnp.bfloat16)
-    assert _bwd_fused_fits(seq, DECODER["dim"], jnp.bfloat16, blk, blk)
+    assert _bwd_fused_fits(seq, dim, jnp.bfloat16, blk, blk)
+    assert not _bwd_fused_fits(2 * 8192, 64, jnp.bfloat16, blk, blk)
     if form == "split":
         monkeypatch.setattr(
             sys.modules["paddle_tpu.kernels.flash_attention"],
@@ -328,7 +338,7 @@ def test_grouped_query_backward_compiles(one_chip, monkeypatch, shape,
     def bwd(q_, k_, v_, out, lse_, g):
         return _flash_backward(
             q_, k_, v_, out, lse_, g, None, None, None, 0, True,
-            DECODER["dim"] ** -0.5, 0.0, blk, blk, False, window)
+            dim ** -0.5, 0.0, blk, blk, False, window)
 
     assert _compile(bwd, q, kv, kv, q, lse, q).count("tpu_custom_call") == (
         1 if form == "fused" else 2)
@@ -340,22 +350,23 @@ def test_grouped_query_backward_compiles(one_chip, monkeypatch, shape,
 def test_grouped_matmuls_compile(one_chip, monkeypatch, shape):
     """The expert MLP's three products and their gradients at the decoder
     cells' shapes ([65536, 2304] rows, 16 experts of width 896; [49152,
-    2048] rows, 16 of width 1024): nine megablox kernels, each at the
-    tiling ``_tilings`` reckons for it."""
+    2048] rows, 16 of width 1024; [65536, 2048] rows, 8 of width 1536):
+    nine megablox kernels, each at the tiling ``_tilings`` reckons for
+    it."""
     import sys
 
     from paddle_tpu.kernels import grouped_matmul as gm
 
     monkeypatch.setattr(sys.modules["paddle_tpu.kernels.grouped_matmul"],
                         "_on_tpu", lambda: True)
-    tokens, d, width = EXPERT_SHAPES[shape]
-    rows = jax.ShapeDtypeStruct((tokens * 8, d), jnp.bfloat16,
+    tokens, d, width, k, held = EXPERT_SHAPES[shape]
+    rows = jax.ShapeDtypeStruct((tokens * k, d), jnp.bfloat16,
                                 sharding=one_chip)
-    up = jax.ShapeDtypeStruct((16, d, width), jnp.bfloat16,
+    up = jax.ShapeDtypeStruct((held, d, width), jnp.bfloat16,
                               sharding=one_chip)
-    down = jax.ShapeDtypeStruct((16, width, d), jnp.bfloat16,
+    down = jax.ShapeDtypeStruct((held, width, d), jnp.bfloat16,
                                 sharding=one_chip)
-    sizes = jax.ShapeDtypeStruct((16,), jnp.int32, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip)
 
     def loss(rows_, gate_, up_, down_, sizes_):
         hidden = (jax.nn.silu(gm.grouped_matmul(rows_, gate_, sizes_))
@@ -382,13 +393,13 @@ def test_the_expert_mlp_backward_compiles(one_chip, monkeypatch, shape):
 
     monkeypatch.setattr(sys.modules["paddle_tpu.kernels.grouped_matmul"],
                         "_on_tpu", lambda: True)
-    tokens, d, width = EXPERT_SHAPES[shape]
+    tokens, d, width, k, held = EXPERT_SHAPES[shape]
 
     def arg(shape_, dtype):
         return jax.ShapeDtypeStruct(shape_, dtype, sharding=one_chip)
 
-    rows = arg((tokens * 8, d), jnp.bfloat16)
-    up = arg((16, d, width), jnp.float32)
+    rows = arg((tokens * k, d), jnp.bfloat16)
+    up = arg((held, d, width), jnp.float32)
 
     def loss(rows_, weight_, gate_, up_, down_, sizes_):
         gate_, up_, down_ = (w.astype(rows_.dtype)
@@ -396,32 +407,40 @@ def test_the_expert_mlp_backward_compiles(one_chip, monkeypatch, shape):
         out = moe_ops._expert_mlp(rows_, weight_, sizes_, gate_, up_, down_)
         return jnp.sum(out[:1024].astype(jnp.float32))
 
-    assert moe_ops.gm.pair_by_kernel(tokens * 8, d, width)
+    assert moe_ops.gm.pair_by_kernel(tokens * k, d, width)
     hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)), rows,
-                   arg((tokens * 8,), jnp.float32), up, up,
-                   arg((16, width, d), jnp.float32),
-                   arg((16,), jnp.int32))
+                   arg((tokens * k,), jnp.float32), up, up,
+                   arg((held, width, d), jnp.float32),
+                   arg((held,), jnp.int32))
     assert hlo.count("tpu_custom_call") == 8
-    assert not re.search(r"= bf16\[%d,%d\]\S* add\(" % (tokens * 8, d), hlo)
+    assert not re.search(r"= bf16\[%d,%d\]\S* add\(" % (tokens * k, d), hlo)
 
 
 # -- the rotation of Q and K (ops/nn_ops.py rotary_embedding)
 
+ROTATIONS = {
+    "s4096-d128-yarn": (4096, 4, 128, {
+        "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+        "original_max_position_embeddings": 8192}),
+    "s8192-d64-default": (8192, 8, 64, {
+        "rope_type": "default", "rope_theta": 1000000})}
+
+
+@pytest.mark.parametrize("shape", list(ROTATIONS))
 @pytest.mark.parametrize("which", ["q", "k"])
-def test_the_rotation_is_one_pass_each_way(one_chip, which):
+def test_the_rotation_is_one_pass_each_way(one_chip, which, shape):
     """What the chip's compiler makes of x*cos + (x @ R)*sin at the
-    decoder cell's shapes, forward and cotangent: one output fusion, the
-    [128, 128] product with the multiply-add as its epilogue, and no
+    decoder cells' shapes (heads of 128 at 4096 positions; heads of 64,
+    half a lane tile, at 8192), forward and cotangent: one output fusion,
+    the [D, D] product with the multiply-add as its epilogue, and no
     float32 copy of the tensor or of its halves between instructions (the
     sliced form left five passes with both; PERF.md, Findings PR 32)."""
     from paddle_tpu.ops.nn_ops import _rotation
 
-    q, kv, _ = _decoder_args(one_chip)
+    seq, kv_heads, d, rope = ROTATIONS[shape]
+    q, kv, _ = _decoder_args(one_chip, seq, kv_heads, d)
     x = q if which == "q" else kv
-    d = DECODER["dim"]
-    rotate = _rotation(DECODER["seq"], d, x.dtype, {
-        "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
-        "original_max_position_embeddings": 8192})
+    rotate = _rotation(seq, d, x.dtype, rope)
 
     def cotangent(x_, g):
         return jax.vjp(rotate, x_)[1](g)[0]
@@ -441,6 +460,43 @@ def test_the_rotation_is_one_pass_each_way(one_chip, which):
         assert (got.shape, got.dtype) == (x.shape, x.dtype)
 
 
+# -- the gated short convolution (ops/nn_ops.py gated_short_conv): XLA's, no
+# kernel
+
+@pytest.mark.parametrize("which", ["forward", "backward"])
+def test_gated_short_conv_compiles_without_a_float32_copy(one_chip, which):
+    """The op and its gradient at [2, 8192, 6144] bf16 with 3 taps: what
+    the chip's compiler makes of the shifted multiply-adds holds no
+    float32 tensor as wide as the input (the cast of all of z that a
+    slice-after-cast form leaves: 805 MB written and read again), and the
+    forward none of [2, 8192, 2048] either: one pass from z to the
+    result."""
+    from paddle_tpu.ops.nn_ops import _gated_short_conv
+
+    z = jax.ShapeDtypeStruct((2, 8192, 6144), jnp.bfloat16,
+                             sharding=one_chip)
+    out = jax.ShapeDtypeStruct((2, 8192, 2048), jnp.bfloat16,
+                               sharding=one_chip)
+    w = jax.ShapeDtypeStruct((2048, 3), jnp.float32, sharding=one_chip)
+
+    def backward(z_, w_, g):
+        return jax.vjp(_gated_short_conv, z_, w_)[1](g)
+
+    fn, args = ((_gated_short_conv, (z, w)) if which == "forward"
+                else (backward, (z, w, out)))
+    hlo = _compile(fn, *args)
+    entry = hlo[hlo.index("\nENTRY "):].splitlines()
+    assert not [line for line in entry if " = f32[2,8192,6144]" in line]
+    got = jax.eval_shape(fn, *args)
+    if which == "forward":
+        assert not [line for line in entry if " = f32[2,8192,2048]" in line]
+        assert (got.shape, got.dtype) == (out.shape, out.dtype)
+        assert "tpu_custom_call" not in hlo
+    else:
+        assert [(g.shape, g.dtype) for g in got] == [
+            (z.shape, z.dtype), (w.shape, w.dtype)]
+
+
 # -- the expert layer's row movements (kernels/row_permute.py)
 
 @pytest.mark.parametrize("shape", list(EXPERT_SHAPES))
@@ -448,12 +504,12 @@ def test_the_rotation_is_one_pass_each_way(one_chip, which):
                                    "reduce_bfloat16"])
 def test_row_permute_compiles(one_chip, which, shape):
     """Both directions at the decoder cells' shapes, [8192 x 8 -> 65536,
-    2304] and [6144 x 8 -> 49152, 2048] bf16 with 16 experts held, at the
-    module's tile and chunk: one custom call each, the visit list round it
-    in XLA."""
+    2304] and [6144 x 8 -> 49152, 2048] bf16 with 16 experts held and
+    [16384 x 4 -> 65536, 2048] with 8, at the module's tile and chunk: one
+    custom call each, the visit list round it in XLA."""
     from paddle_tpu.kernels import row_permute as rp
 
-    (tokens, d, _), k, held = EXPERT_SHAPES[shape], 8, 16
+    tokens, d, _, k, held = EXPERT_SHAPES[shape]
     order = jax.ShapeDtypeStruct((tokens * k,), jnp.int32, sharding=one_chip)
     counts = jax.ShapeDtypeStruct((held,), jnp.int32, sharding=one_chip)
     if which == "expand":
