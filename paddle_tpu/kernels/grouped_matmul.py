@@ -9,26 +9,33 @@ buffer and out of it again is the sibling module's, ``row_permute.py``
 
 On the TPU these are the megablox Pallas kernels (``jax.experimental
 .pallas.ops.tpu.megablox``): their grids cover only the row tiles that
-groups occupy, so the unused part of the buffer costs nothing. Four
-products, each at a tiling of its own (``_tilings``): ``grouped_matmul``
-forward; ``grouped_matmul_t`` (``gmm`` against the transposed weights) for
-the rows' gradient of one product; ``grouped_matmul_pair_t`` for the rows'
-gradient of two products of the same rows (the expert MLP's gate and up),
-the one kernel whose body is this module's: megablox's grid, group
-metadata and store mask, but two left tiles and two weight blocks a
-visit, both products added in one float32 accumulator and the result tile
-written once, where autodiff would round each product to the rows' dtype
-and add them in a pass over ALL buffer rows; and
-``grouped_weight_gradient`` (``tgmm``). The contraction is kept whole
-where a weight block then fits VMEM, so that consecutive row tiles of one
-expert find its weights resident instead of fetching them again.
-``grouped_matmul`` alone carries a backward (``gmm`` + ``tgmm``, for
-whoever differentiates a single product); the expert MLP composes the four
-under its own (``ops/moe_ops.py``). Elsewhere, and for rows the kernels
-cannot tile, every product is ``jax.lax.ragged_dot`` (``ragged_dot_general``
-for the weights' gradient). Which of the two the chip runs, and the
+groups occupy, so the unused part of the buffer costs nothing. Six kinds
+of kernel, each at a tiling of its own (``_tilings``, ``gate_tile_rows``).
+Four products: ``grouped_matmul`` forward; ``grouped_matmul_t`` (``gmm``
+against the transposed weights) for the rows' gradient of one product;
+``grouped_matmul_pair_t`` for the rows' gradient of two products of the
+same rows (the expert MLP's gate and up), whose body is this module's:
+megablox's grid, group metadata and store mask, but two left tiles and two
+weight blocks a visit, both products added in one float32 accumulator and
+the result tile written once, where autodiff would round each product to
+the rows' dtype and add them in a pass over ALL buffer rows; and
+``grouped_weight_gradient`` (``tgmm``). And the elementwise middle of the
+expert MLP between them, ``gated`` (``weight * silu(gate) * up``) and its
+transpose ``gated_t``, this module's too: the rows are sorted by group and
+the dead ones stand at the end, so the live rows are a prefix of the
+buffer and the grid is as long as that prefix, ``(cdiv(sum(group_sizes),
+tm),)``, a traced length and no group metadata, where XLA's fusions pass
+over all rows of a buffer of which an eighth to two fifths are live. The
+contraction is kept whole where a weight block then fits VMEM, so that
+consecutive row tiles of one expert find its weights resident instead of
+fetching them again. ``grouped_matmul`` alone carries a backward (``gmm`` +
+``tgmm``, for whoever differentiates a single product); the expert MLP
+composes the six under its own (``ops/moe_ops.py``). Elsewhere, and for
+rows the kernels cannot tile, every product is ``jax.lax.ragged_dot``
+(``ragged_dot_general`` for the weights' gradient) and the gate XLA's
+(``silu_gate`` over all rows). Which of the two the chip runs, and the
 tilings, were read on the chip at the benchmark's shapes (PERF.md,
-Findings PR 29 and PR 34), not left to a flag.
+Findings PRs 29, 34 and 36), not left to a flag.
 """
 
 import collections
@@ -105,6 +112,16 @@ def _tilings(rows, k, n, item=2):
                     product(n, k, 2))
 
 
+def gate_tile_rows(rows, width, item=2):
+    """Rows of a tile of the elementwise gate between the products: the
+    width whole, and the transposed kernel's five ``[rows, width]`` blocks
+    (three read, two written), each double-buffered, inside the budget."""
+    tm = min(_TILE_ROWS, rows)
+    while 2 * 5 * tm * _lanes(width) * item > _VMEM_BUDGET and tm % 16 == 0:
+        tm //= 2
+    return tm
+
+
 def _by_kernel(rows, interpret):
     """Whether the Pallas kernels take a buffer of ``rows`` rows here (then
     interpreted exactly where this is not the TPU): decided by what the
@@ -118,6 +135,13 @@ def pair_by_kernel(rows, k, n, interpret=False):
     ``[G, k, n]`` weights is the one kernel: whole tiles of rows and whole
     lanes both ways, where the kernels run at all."""
     return _by_kernel(rows, interpret) and k % 128 == 0 and n % 128 == 0
+
+
+def gate_by_kernel(rows, width, interpret=False):
+    """Whether the gate between the products, on ``[rows, width]`` results
+    of ``grouped_matmul``, is ``gated`` / ``gated_t``: whole tiles of rows
+    and whole lanes, where the kernels run at all."""
+    return _by_kernel(rows, interpret) and width % 128 == 0
 
 
 def _gmm_t(g, rhs, group_sizes, tiling, interpret):
@@ -193,6 +217,83 @@ def _pair_gmm_t(g_a, g_b, rhs_a, rhs_b, group_sizes, tiling, interpret):
     )(*metadata, g_a, g_b, rhs_a, rhs_b)
 
 
+def silu_gate(gate, up, row_weight):
+    """The middle of the expert MLP: ``row_weight * silu(gate) * up`` in
+    float32, as ``gate``'s dtype; ``gate`` and ``up`` [R, w], ``row_weight``
+    [R] float32. Called on whole arrays this is XLA's form, a pass over ALL
+    buffer rows, for what the kernels do not take (``gate_by_kernel``); the
+    kernels' body is the same function on a tile."""
+    return (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
+            * row_weight[:, None]).astype(gate.dtype)
+
+
+def _gate_kernel(transposed, tiles, gate, up, weight, *refs):
+    """One ``[tm, w]`` tile of ``silu_gate`` or, ``transposed``, of its
+    ``jax.vjp``. The tile's weights arrive along the lanes (``[1, tm]`` of
+    the ``[1, R]`` view); the weights' gradient, the rows' sums over ``w``,
+    goes back the same way."""
+    if transposed:
+        d_hidden, d_gate, d_up, d_weight = refs
+        back = jax.vjp(silu_gate, gate[...], up[...], weight[0])[1]
+        d_gate[...], d_up[...], d_weight[0, :] = back(d_hidden[...])
+    else:
+        refs[0][...] = silu_gate(gate[...], up[...], weight[0])
+
+
+def live_tiles(group_sizes, tm):
+    """Tiles of ``tm`` rows that hold a live row: the rows are sorted by
+    group and the dead ones stand at the end, so the live ones are a
+    prefix of the buffer."""
+    return (jnp.sum(group_sizes, dtype=jnp.int32) + (tm - 1)) // tm
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _gate(gate, up, row_weight, d_hidden, group_sizes, tm, interpret):
+    """``gated`` (``d_hidden`` None) or ``gated_t`` as one kernel whose
+    grid is the buffer's live prefix, ``(live_tiles,)``, a traced length:
+    no group metadata, the kernel does not care which expert a row is on.
+    Tiles past the prefix are never visited; the partly live last one is
+    computed whole. Jitted as megablox's kernels are: the forward's call
+    and its replay inside the grad op then lower to one function, and XLA
+    merges the two custom calls (a bare ``pallas_call`` carries the trace's
+    name, ``gate`` / ``jvp(gate)``, into its body, and ran twice)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, width = gate.shape
+    assert rows % tm == 0, (gate.shape, tm)
+    transposed = d_hidden is not None
+    wide = pl.BlockSpec((tm, width), lambda i, tiles: (i, 0))
+    along = pl.BlockSpec((1, tm), lambda i, tiles: (0, i))
+    like = jax.ShapeDtypeStruct(gate.shape, gate.dtype)
+    tiles = live_tiles(group_sizes, tm)[None]
+    out = pl.pallas_call(
+        functools.partial(_gate_kernel, transposed),
+        out_shape=(like, like, jax.ShapeDtypeStruct(
+            (1, rows), jnp.float32)) if transposed else like,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            in_specs=[wide, wide, along] + ([wide] if transposed else []),
+            out_specs=(wide, wide, along) if transposed else wide,
+            grid=(tiles[0],)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        cost_estimate=pl.CostEstimate(
+            flops=(14 if transposed else 5) * rows * width,
+            transcendentals=rows * width,
+            bytes_accessed=(5 if transposed else 3) * rows * width
+            * gate.dtype.itemsize),
+        # the gradients take the places of gate and up, whose last use
+        # this is: a tile is read whole before it is written
+        input_output_aliases={1: 0, 2: 1} if transposed else {},
+        interpret=interpret, name="gate_t" if transposed else "gate",
+    )(tiles, gate, up, row_weight[None, :],
+      *([d_hidden] if transposed else []))
+    if transposed:
+        return out[0], out[1], out[2][0]
+    return out
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _pallas_gmm(lhs, rhs, group_sizes, tilings, interpret):
     megablox = _megablox()
@@ -266,6 +367,33 @@ def grouped_matmul_pair_t(g_a, g_b, rhs_a, rhs_b, group_sizes,
             not _on_tpu())
     return (_ragged_t(g_a, rhs_a, group_sizes)
             + _ragged_t(g_b, rhs_b, group_sizes)).astype(g_a.dtype)
+
+
+def _gate_by_kernel(gate, up, row_weight, d_hidden, group_sizes, interpret):
+    rows, width = gate.shape
+    assert gate_by_kernel(rows, width, interpret), gate.shape
+    return _gate(gate, up, row_weight, d_hidden,
+                 group_sizes.astype(jnp.int32),
+                 gate_tile_rows(rows, width, gate.dtype.itemsize),
+                 not _on_tpu())
+
+
+def gated(gate, up, row_weight, group_sizes, interpret=False):
+    """``silu_gate`` on the live row tiles only: ``gate`` and ``up`` [R, w]
+    (``grouped_matmul``'s results), ``row_weight`` [R] float32 -> [R, w] in
+    ``gate``'s dtype. For call sites where ``gate_by_kernel``; what the
+    result holds past the live prefix is unspecified."""
+    return _gate_by_kernel(gate, up, row_weight, None, group_sizes,
+                           interpret)
+
+
+def gated_t(gate, up, row_weight, d_hidden, group_sizes, interpret=False):
+    """``gated``'s transpose on the live row tiles only: what
+    ``jax.vjp(silu_gate, gate, up, row_weight)[1](d_hidden)`` returns on
+    them, float32 inside: (``d_gate``, ``d_up``) [R, w] in ``gate``'s dtype
+    and ``d_weight`` [R] float32, each row's sum over ``w``."""
+    return _gate_by_kernel(gate, up, row_weight, d_hidden, group_sizes,
+                           interpret)
 
 
 def grouped_weight_gradient(lhs, g, group_sizes, interpret=False):

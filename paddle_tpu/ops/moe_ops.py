@@ -12,9 +12,10 @@ the experts (``experts_held`` of ``num_experts``, from ``expert_offset``):
                     gathered into a buffer; second output: tokens each
                     held expert received
     moe_expert_mlp  down(w * silu(gate(x)) * up(x)) per expert: three
-                    grouped matmuls over the buffer, and a backward of
-                    its own (``_expert_mlp``): five more, the rows'
-                    gradient from gate and up in one of them
+                    grouped matmuls over the buffer with the gate
+                    between them, and a backward of its own
+                    (``_expert_mlp``): five more, the rows' gradient from
+                    gate and up in one of them, and the gate's transpose
     moe_combine     each token's sum over its held pairs' rows
 
 Dropless: the buffer has a row for every pair (tokens x k; a token's k
@@ -40,12 +41,17 @@ Everywhere else (the CPU, float32 programs, odd sizes) both are XLA gathers
 (a row's token one way, a pair's row the other), never a scatter-add: each
 pair has one row, so the inverse permutation is known. Which of the two a
 call site was lowered as is counted (``moe.permute_kernel`` /
-``moe.permute_xla``), and so is the form of the expert MLP's two-pair
+``moe.permute_xla``), and so are the forms of the expert MLP's two-pair
 product (``moe.gmm_pair_kernel`` / ``moe.gmm_pair_xla``: one call site a
-layer, counted where the backward is traced). Unused rows of a grouped
-matmul's result are unspecified, so whatever leaves the buffer is picked
-with ``where``, never by a product with zero; what the kernel writes into
-the buffer's unused tiles is unspecified too.
+layer, counted where the backward is traced) and of its gate
+(``moe.gate_kernel`` / ``moe.gate_xla``: two a layer, the forward's
+counted by the op's lowering, the transpose where the backward is traced;
+where the kernels run, the gauges ``moe.gate_tiles`` and, a step,
+``moe.gate_tiles_live``: tiles in the buffer and tiles visited). Unused
+rows of a grouped matmul's result are unspecified, so whatever leaves the
+buffer is picked with ``where``, never by a product with zero; what a
+kernel writes into the buffer's unused tiles, or leaves there, is
+unspecified too.
 """
 
 import functools
@@ -271,23 +277,21 @@ def _publish_load(counts, visits):
                   float(counts.max() / max(counts.mean(), 1e-9)))
 
 
-def _gated(gate, up, row_weight):
-    """The middle of the expert MLP, XLA's: w * silu(gate) * up in
-    float32, as the rows' dtype."""
-    return (jax.nn.silu(gate.astype(jnp.float32)) * up.astype(jnp.float32)
-            * row_weight[:, None]).astype(gate.dtype)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _expert_mlp(rows, row_weight, counts, wg, wu, wd, interpret=False):
     """down(w * silu(gate(x)) * up(x)) per expert, with a backward of its
-    own: three grouped matmuls forward; backward the down projection's
-    transposed product, XLA's transpose of ``_gated``, the rows' gradient
-    from gate and up in ONE kernel that adds both products in its float32
-    accumulator (autodiff would round each to the rows' dtype and add them
-    in a pass over all buffer rows), and three weights' gradients. The
-    weights arrive in the rows' dtype (the caller casts the masters, so the
-    cast's transpose rides in whatever reads the gradient)."""
+    own: forward three grouped matmuls and the gate between them; backward
+    the down projection's transposed product, the gate's transpose, the
+    rows' gradient from gate and up in ONE kernel that adds both products
+    in its float32 accumulator (autodiff would round each to the rows'
+    dtype and add them in a pass over all buffer rows), and three weights'
+    gradients. The gate and its transpose are the kernels over the live
+    row tiles where ``gate_by_kernel``, else XLA's: ``gm.silu_gate`` and
+    ``jax.vjp`` of it over all rows. Every reader of their results goes
+    by ``counts`` (the grouped products) or selects the dead rows out with
+    ``where`` (``_to_rows_bwd``, of the weights' gradient). The weights
+    arrive in the rows' dtype (the caller casts the masters, so the cast's
+    transpose rides in whatever reads the gradient)."""
     return _expert_mlp_fwd(rows, row_weight, counts, wg, wu, wd,
                            interpret)[0]
 
@@ -295,7 +299,10 @@ def _expert_mlp(rows, row_weight, counts, wg, wu, wd, interpret=False):
 def _expert_mlp_fwd(rows, row_weight, counts, wg, wu, wd, interpret):
     gate = gm.grouped_matmul(rows, wg, counts, interpret)
     up = gm.grouped_matmul(rows, wu, counts, interpret)
-    hidden = _gated(gate, up, row_weight)
+    if gm.gate_by_kernel(*gate.shape, interpret):
+        hidden = gm.gated(gate, up, row_weight, counts, interpret)
+    else:
+        hidden = gm.silu_gate(gate, up, row_weight)
     return (gm.grouped_matmul(hidden, wd, counts, interpret),
             (rows, row_weight, counts, wg, wu, wd, gate, up, hidden))
 
@@ -307,9 +314,15 @@ def _expert_mlp_bwd(interpret, res, g):
     obs.inc("moe.gmm_pair_kernel" if gm.pair_by_kernel(
         rows.shape[0], wg.shape[1], wg.shape[2], interpret)
         else "moe.gmm_pair_xla")
+    by_kernel = gm.gate_by_kernel(*gate.shape, interpret)
+    obs.inc("moe.gate_kernel" if by_kernel else "moe.gate_xla")
     d_hidden = gm.grouped_matmul_t(g, wd, counts, interpret)
-    d_gate, d_up, d_weight = jax.vjp(_gated, gate, up, row_weight)[1](
-        d_hidden)
+    if by_kernel:
+        d_gate, d_up, d_weight = gm.gated_t(gate, up, row_weight, d_hidden,
+                                            counts, interpret)
+    else:
+        d_gate, d_up, d_weight = jax.vjp(gm.silu_gate, gate, up,
+                                         row_weight)[1](d_hidden)
     d_rows = gm.grouped_matmul_pair_t(d_gate, d_up, wg, wu, counts,
                                       interpret)
     return (d_rows, d_weight, None,
@@ -327,12 +340,32 @@ def moe_expert_mlp(ctx, ins, attrs):
     / UpWeight [G, d, w], DownWeight [G, w, d] -> Out [R, d]: the gated
     SiLU MLP of each row's expert, times the row's weight. Rows past
     sum(Counts) are unspecified."""
+    from paddle_tpu import observability as obs
+
     rows, wg, wu, wd = amp_cast(single(ins, "Rows"),
                                 single(ins, "GateWeight"),
                                 single(ins, "UpWeight"),
                                 single(ins, "DownWeight"))
-    return {"Out": [_expert_mlp(rows, single(ins, "RowWeight"),
-                                single(ins, "Counts"), wg, wu, wd)]}
+    counts = single(ins, "Counts")
+    if lowered_into_a_step(ctx, "moe_expert_mlp"):
+        by_kernel = gm.gate_by_kernel(rows.shape[0], wg.shape[2])
+        obs.inc("moe.gate_kernel" if by_kernel else "moe.gate_xla")
+        if by_kernel and obs.enabled():
+            tile = gm.gate_tile_rows(rows.shape[0], wg.shape[2],
+                                     rows.dtype.itemsize)
+            obs.set_gauge("moe.gate_tiles", rows.shape[0] // tile)
+            jax.debug.callback(_publish_gate_tiles,
+                               gm.live_tiles(counts, tile))
+    return {"Out": [_expert_mlp(rows, single(ins, "RowWeight"), counts, wg,
+                                wu, wd)]}
+
+
+def _publish_gate_tiles(tiles):
+    """Per step, under the ``metrics`` flag: the row tiles the gate's
+    kernels visit, of ``moe.gate_tiles`` in the buffer."""
+    from paddle_tpu import observability as obs
+
+    obs.set_gauge("moe.gate_tiles_live", int(tiles))
 
 
 @jax.custom_vjp

@@ -260,6 +260,85 @@ def test_the_pair_tiling_is_reckoned_from_the_shapes(rows, d, width, pair):
     assert tilings.rows_gradient[2] >= tn   # one pair takes a wider tile
 
 
+GATE_CASES = {"every row live": [200, 0, 184, 128],
+              "an eighth live": [20, 0, 30, 14],
+              "a partly live last tile": [256, 1, 0, 43],
+              "no live row": [0, 0, 0, 0],
+              "NaN in the dead rows": [20, 0, 30, 14]}
+
+
+@pytest.mark.parametrize("case", list(GATE_CASES))
+def test_the_gate_kernels_visit_the_live_row_tiles_only(case):
+    """``gated`` / ``gated_t`` (interpret mode) against XLA's form,
+    ``silu_gate`` over all rows and ``jax.vjp`` of it, on the live rows,
+    float32 and bf16, in a buffer of two tiles: the grid is as long as the
+    live prefix (a tile past it does not hold what XLA's pass over all rows
+    computes there), the partly live last tile is computed whole, and NaN
+    in the dead rows of every input changes no live row of any result and,
+    through ``_expert_mlp`` with every kernel interpreted, nothing that a
+    consumer makes of them."""
+    from paddle_tpu.kernels import grouped_matmul as gm
+    from paddle_tpu.ops import moe_ops
+
+    rng = np.random.RandomState(8)
+    rows, width = 512, 128
+    sizes = jnp.asarray(GATE_CASES[case], jnp.int32)
+    live = int(sizes.sum())
+    dead = (np.arange(rows) >= live)[:, None]
+    assert gm.gate_by_kernel(rows, width, interpret=True)
+    assert not gm.gate_by_kernel(rows, width)       # the CPU, no interpreter
+    assert not gm.gate_by_kernel(rows, 100, interpret=True)
+    assert not gm.gate_by_kernel(300, width, interpret=True)
+    assert int(gm.live_tiles(sizes, 256)) == -(-live // 256)
+    for dtype in (jnp.float32, jnp.bfloat16):
+        gate, up, d_hidden = (jnp.asarray(rng.randn(rows, width), dtype)
+                              for _ in range(3))
+        weight = jnp.asarray(rng.rand(rows), jnp.float32)
+        want = (gm.silu_gate(gate, up, weight),
+                *jax.vjp(gm.silu_gate, gate, up, weight)[1](d_hidden))
+        if case.startswith("NaN"):
+            gate, up, d_hidden = (jnp.where(dead, jnp.nan, a)
+                                  for a in (gate, up, d_hidden))
+            weight = jnp.where(dead[:, 0], jnp.nan, weight)
+        got = (gm.gated(gate, up, weight, sizes, interpret=True),
+               *gm.gated_t(gate, up, weight, d_hidden, sizes,
+                           interpret=True))
+        for name, a, b in zip(("hidden", "d_gate", "d_up", "d_weight"),
+                              got, want):
+            assert (a.dtype, a.shape) == (b.dtype, b.shape), name
+            np.testing.assert_array_equal(
+                np.asarray(a, np.float32)[:live],
+                np.asarray(b, np.float32)[:live], err_msg=name)
+            # a tile past the live prefix was not computed
+            past = -(-live // 256) * 256
+            assert past == rows or not np.array_equal(
+                np.asarray(a, np.float32)[past:],
+                np.asarray(b, np.float32)[past:]), name
+    if not case.startswith("NaN"):
+        return
+    args, alive, _ = _expert_mlp_case(jnp.bfloat16)
+    rows_, weight_, counts, wg, wu, wd = args
+    wg, wu, wd = (w.astype(jnp.bfloat16) for w in (wg, wu, wd))
+    g = jnp.asarray(rng.randn(*rows_.shape), jnp.bfloat16)
+
+    def through(rows_, weight_, g):
+        out, back = jax.vjp(lambda r, w, a, b, c: moe_ops._expert_mlp(
+            r, w, counts, a, b, c, True), rows_, weight_, wg, wu, wd)
+        d_rows, d_weight, *d_weights = back(g)
+        return [np.where(alive, np.asarray(a, np.float32), 0)
+                for a in (out, d_rows)] + [
+            np.where(alive[:, 0], np.asarray(d_weight), 0)] + [
+            np.asarray(a, np.float32) for a in d_weights]
+
+    clean = through(rows_, weight_, g)
+    planted = through(jnp.where(alive, rows_, jnp.nan),
+                      jnp.where(alive[:, 0], weight_, jnp.nan),
+                      jnp.where(alive, g, jnp.nan))
+    for a, b in zip(planted, clean):
+        assert np.isfinite(a).all()
+        np.testing.assert_array_equal(a, b)
+
+
 def _plain_expert_mlp(rows, row_weight, counts, wg, wu, wd):
     """The op's arithmetic as a plain composition that JAX differentiates
     itself (what the op was until PR 34)."""
@@ -297,12 +376,17 @@ def _expert_mlp_case(dtype, rows=256, d=128, width=128):
 def test_the_expert_mlp_has_a_backward_of_its_own(dtype, form):
     """``moe_expert_mlp``'s hand-written gradients (rows, row weights and
     all three weights, which come back in the masters' float32) against
-    ``jax.grad`` of the plain composition, on the ``ragged_dot`` path and
-    with every kernel in interpret mode; in bf16 within bf16's rounding of
-    each gradient's largest entry."""
+    ``jax.grad`` of the plain composition, on the ``ragged_dot`` path with
+    XLA's gate and with every kernel in interpret mode (the grouped
+    matmuls' six kinds: ``gmm``, its transposed form, the two-pair kernel,
+    ``tgmm`` and, since PR 36, the gate and its transpose on the live
+    tiles); in bf16 within bf16's rounding of each gradient's largest
+    entry."""
     from paddle_tpu.ops import moe_ops
 
     args, live, loss = _expert_mlp_case(dtype)
+    assert moe_ops.gm.gate_by_kernel(256, 128, form == "kernels") == (
+        form == "kernels")
 
     def own(rows_, weight_, counts, wg, wu, wd):
         wg, wu, wd = (w.astype(rows_.dtype) for w in (wg, wu, wd))
@@ -349,6 +433,53 @@ def test_the_expert_mlp_counts_the_form_of_its_pair_product():
     rng = np.random.RandomState(3)
     _expert_layer(rng.randn(16, D).astype(np.float32), _params(rng), 2, 2)
     assert [obs.counter_value(n) for n in names] == before  # forward only
+
+
+def test_the_expert_mlp_counts_the_form_of_its_gate(monkeypatch):
+    """``moe.gate_xla`` / ``moe.gate_kernel``: call sites of the gate
+    lowered in each form, the forward's counted by the op's lowering and
+    the transpose where the backward is traced: XLA's on the CPU, the
+    kernels' under the interpreter. Where the kernels run, the gauges
+    ``moe.gate_tiles`` (tiles in the buffer) and, a step,
+    ``moe.gate_tiles_live`` (tiles visited); none on the CPU's path."""
+    from paddle_tpu import observability as obs
+    from paddle_tpu.kernels import grouped_matmul as gm
+    from paddle_tpu.ops import moe_ops
+
+    obs.set_enabled(True)
+    names = ("moe.gate_kernel", "moe.gate_xla")
+    args, _, _ = _expert_mlp_case(jnp.float32)
+
+    def counted(run):
+        before = [obs.counter_value(n) for n in names]
+        out = run()
+        jax.effects_barrier()
+        return [obs.counter_value(n) - was
+                for n, was in zip(names, before)], out
+
+    def backward(interpret):
+        return lambda: jax.grad(lambda rows_: jnp.sum(moe_ops._expert_mlp(
+            rows_, *args[1:], interpret)[:8]))(args[0])
+
+    assert counted(backward(False))[0] == [0, 1]
+    assert counted(backward(True))[0] == [1, 0]
+    rng = np.random.RandomState(3)
+    x, params = rng.randn(16, D).astype(np.float32), _params(rng)
+    count, (want, _) = counted(lambda: _expert_layer(x, params, 2, 2))
+    assert count == [0, 1]                           # forward only
+    gauges = obs.snapshot()["gauges"]
+    assert "moe.gate_tiles" not in gauges
+    assert "moe.gate_tiles_live" not in gauges
+    # the gate's kernels alone (interpreted: this is not the TPU) between
+    # ``ragged_dot``s: the buffer of 16 x TOP rows is one tile
+    monkeypatch.setattr(gm, "gate_by_kernel",
+                        lambda rows, width, interpret=False: True)
+    count, (got, counts) = counted(lambda: _expert_layer(x, params, 2, 2))
+    assert count == [1, 0]
+    np.testing.assert_allclose(got, want, atol=1e-6, rtol=1e-6)
+    gauges = obs.snapshot()["gauges"]
+    assert gauges["moe.gate_tiles"] == 1
+    assert gauges["moe.gate_tiles_live"] == (counts.sum() > 0)
 
 
 def test_the_expert_layer_counts_itself():
@@ -1144,8 +1275,14 @@ def test_the_accepted_decoder_steps_are_lowered_as_before(config, digest):
     ``custom_vjp`` of its own: the CPU's arithmetic is the same
     ``ragged_dot``s, the jaxpr is not). ``trinity_mini`` pinned at PR 34's
     commit (5dcbb94) for PR 35's sake (a ``conv`` layer kind, a tied head
-    and the router's ``norm_eps``, all at their defaults here). A PR that
-    means to leave these steps alone sees here whether it did."""
+    and the router's ``norm_eps``, all at their defaults here). **Both
+    stood through PR 36**: the gate's kernels are chosen by
+    ``gate_by_kernel``, which refuses the CPU, so this path still traces
+    the gate over all rows and its ``jax.vjp`` equation for equation
+    (``_gated`` moved beside the kernels as ``grouped_matmul.silu_gate``,
+    whose body they share; the predicate and the counters are Python at
+    trace time and leave nothing in the jaxpr). A PR that means to leave
+    these steps alone sees here whether it did."""
     with open(os.path.join(ROOT, "benchmarks", "configs",
                            config + ".json")) as f:
         cfg = json.load(f)

@@ -379,26 +379,14 @@ def test_grouped_matmuls_compile(one_chip, monkeypatch, shape):
     assert hlo.count("tpu_custom_call") == 9
 
 
-@pytest.mark.parametrize("shape", list(EXPERT_SHAPES))
-def test_the_expert_mlp_backward_compiles(one_chip, monkeypatch, shape):
-    """One layer's ``moe_expert_mlp`` with its own backward at the decoder
-    cells' shapes, masters in float32 as the step holds them: 8 kernels
-    (three forward, the down projection's transposed product, ONE for the
-    rows' gradient from gate and up, three weights' gradients), and no
-    ``add`` over the ``[R, d]`` buffer: autodiff's ``add_any`` of two
-    rounded partial sums is gone."""
-    import sys
-
+def _expert_mlp_hlo(one_chip, rows, d, width, held):
+    """One layer's ``moe_expert_mlp``, forward and its own backward,
+    masters in float32 as the step holds them, compiled for the chip."""
     from paddle_tpu.ops import moe_ops
-
-    monkeypatch.setattr(sys.modules["paddle_tpu.kernels.grouped_matmul"],
-                        "_on_tpu", lambda: True)
-    tokens, d, width, k, held = EXPERT_SHAPES[shape]
 
     def arg(shape_, dtype):
         return jax.ShapeDtypeStruct(shape_, dtype, sharding=one_chip)
 
-    rows = arg((tokens * k, d), jnp.bfloat16)
     up = arg((held, d, width), jnp.float32)
 
     def loss(rows_, weight_, gate_, up_, down_, sizes_):
@@ -407,13 +395,57 @@ def test_the_expert_mlp_backward_compiles(one_chip, monkeypatch, shape):
         out = moe_ops._expert_mlp(rows_, weight_, sizes_, gate_, up_, down_)
         return jnp.sum(out[:1024].astype(jnp.float32))
 
-    assert moe_ops.gm.pair_by_kernel(tokens * k, d, width)
-    hlo = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)), rows,
-                   arg((tokens * k,), jnp.float32), up, up,
-                   arg((held, width, d), jnp.float32),
-                   arg((held,), jnp.int32))
-    assert hlo.count("tpu_custom_call") == 8
-    assert not re.search(r"= bf16\[%d,%d\]\S* add\(" % (tokens * k, d), hlo)
+    return _compile(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
+                    arg((rows, d), jnp.bfloat16), arg((rows,), jnp.float32),
+                    up, up, arg((held, width, d), jnp.float32),
+                    arg((held,), jnp.int32))
+
+
+@pytest.mark.parametrize("shape", list(EXPERT_SHAPES))
+def test_the_expert_mlp_backward_compiles(one_chip, monkeypatch, shape):
+    """One layer's ``moe_expert_mlp`` with its own backward at the decoder
+    cells' shapes: 10 kernels (three products and the gate forward; the
+    down projection's transposed product, the gate's transpose, ONE kernel
+    for the rows' gradient from gate and up, three weights' gradients), no
+    ``add`` over the ``[R, d]`` buffer (autodiff's ``add_any`` of two
+    rounded partial sums is gone) and, since PR 36, no pass of XLA's over
+    the ``[R, w]`` buffer: every array of that shape is a kernel's."""
+    import sys
+
+    from paddle_tpu.ops import moe_ops
+
+    monkeypatch.setattr(sys.modules["paddle_tpu.kernels.grouped_matmul"],
+                        "_on_tpu", lambda: True)
+    tokens, d, width, k, held = EXPERT_SHAPES[shape]
+    rows = tokens * k
+    assert moe_ops.gm.pair_by_kernel(rows, d, width)
+    assert moe_ops.gm.gate_by_kernel(rows, width)
+    hlo = _expert_mlp_hlo(one_chip, rows, d, width, held)
+    assert hlo.count("tpu_custom_call") == 10
+    assert not re.search(r"= bf16\[%d,%d\]\S* add\(" % (rows, d), hlo)
+    made = set(re.findall(r"= bf16\[%d,%d\]\S* ([a-z-]+)\(" % (rows, width),
+                          hlo))
+    assert "custom-call" in made and not made & {
+        "fusion", "convert", "multiply", "logistic"}, made
+    assert "jit(silu)" not in hlo and "exponential(" not in hlo
+
+
+def test_the_gate_keeps_xlas_form_off_the_lanes(one_chip, monkeypatch):
+    """A width that is no multiple of 128 lanes: the predicate refuses the
+    gate's kernels, and XLA's ``silu_gate`` and its transpose compile between
+    the grouped products as before PR 36."""
+    import sys
+
+    from paddle_tpu.ops import moe_ops
+
+    monkeypatch.setattr(sys.modules["paddle_tpu.kernels.grouped_matmul"],
+                        "_on_tpu", lambda: True)
+    rows, d, width, held = 4096, 256, 200, 4
+    assert moe_ops.gm.gate_by_kernel(rows, 256)
+    assert not moe_ops.gm.gate_by_kernel(rows, width)
+    hlo = _expert_mlp_hlo(one_chip, rows, d, width, held)
+    assert "jit(silu)" in hlo and "exponential(" in hlo
+    assert "jit(_gate)" not in hlo
 
 
 # -- the rotation of Q and K (ops/nn_ops.py rotary_embedding)
