@@ -46,16 +46,23 @@ def append_backward(loss, parameter_list=None, no_grad_set=None,
                     callbacks=None):
     """Append gradient ops for ``loss``; returns [(param, grad_var)]
     (reference: backward.py:394)."""
+    from paddle_tpu import observability as obs
     from paddle_tpu.framework import OpRole
 
     block = loss.block
     program = block.program
     # Every op appended below is gradient machinery: stamp it Backward so
     # clone(for_test=True) prunes it (reference: backward.py:394 op_role).
-    with program._op_role_guard(OpRole.Backward):
-        return _append_backward_impl(
+    # A seam span: once a Program, recorded whatever is switched on, with
+    # the ops it appended to the loss's block.
+    before = len(block.desc.ops)
+    with obs.seam_span("append_backward", ops=0) as span, \
+            program._op_role_guard(OpRole.Backward):
+        params_grads = _append_backward_impl(
             loss, block, program, parameter_list, no_grad_set, callbacks
         )
+        span.args["ops"] = len(block.desc.ops) - before
+    return params_grads
 
 
 def _append_backward_impl(loss, block, program, parameter_list, no_grad_set,

@@ -255,7 +255,6 @@ class Engine:
             # never on a step
             obs.opprof.keep_note(compiled.opprof_note)
             compiled.opprof_note = None
-            obs.inc("opprof.executables")
         with (obs.seam_span("compile", fun_name=compiled.name,
                             step=self._run_counter) if first
               else obs.span("run", step=self._run_counter)), \
@@ -671,8 +670,7 @@ class Engine:
                     from paddle_tpu.analysis import memory as memplan
 
                     try:
-                        with obs.span("memory-plan"), \
-                                obs.time_block("engine.memory_plan_ms"):
+                        with obs.span("memory-plan"):
                             memory_plan = memplan.plan_memory(
                                 run_desc,
                                 feed_shapes={
@@ -712,14 +710,13 @@ class Engine:
                     # itself verified.
                     from paddle_tpu.analysis import verify_program
 
-                    with obs.span("verify"), \
-                            obs.time_block("engine.verify_ms"):
+                    with obs.span("verify"):
                         verify_program(
                             run_desc, feed_names=feed_names,
                             fetch_names=fetch_list, mesh=mesh,
                             shard_rules=shard_rules, data_axes=data_axes,
                             raise_on_error=True)
-                with obs.span("lower"), obs.time_block("engine.lower_ms"):
+                with obs.span("lower"):
                     try:
                         compiled = self._compile(
                             run_desc.block(block_idx), feed_names,
@@ -767,8 +764,7 @@ class Engine:
                 from paddle_tpu.analysis import spmd as spmd_analysis
 
                 try:
-                    with obs.span("spmd-plan"), \
-                            obs.time_block("engine.spmd_plan_ms"):
+                    with obs.span("spmd-plan"):
                         compiled.spmd_plan = spmd_analysis.analyze_spmd(
                             run_desc, mesh=mesh,
                             shard_rules=shard_rules,
@@ -1020,7 +1016,10 @@ class Engine:
             # (mesh, data_axes) instead of a threaded argument
             from paddle_tpu.parallel.mesh import spmd_lowering
 
-            with spmd_lowering(mesh, data_axes):
+            # live on a first call (a cache-miss seam): the ops' spans lie
+            # inside this one, its self time is the lowering's own Python,
+            # and the rest of JAX's tracing seconds is JAX's
+            with obs.span("traced-fn"), spmd_lowering(mesh, data_axes):
                 fetches, state_out = fn(feed_values, state_values, rng_key)
                 if sdc:
                     # fuse the step digest INTO the executable: abs-sum +

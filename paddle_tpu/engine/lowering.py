@@ -22,6 +22,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu import observability as obs
 from paddle_tpu.core.registry import OpRegistry, LowerContext
 from paddle_tpu.core.types import convert_dtype_to_np
 from paddle_tpu.observability import opprof as _opprof
@@ -166,7 +167,6 @@ def lower_block(block_program, is_test=False, executor=None, amp=False,
     of one end-of-step reduction wave. Neither mechanism changes a
     single collective count or payload; only scheduling freedom moves.
     """
-    from paddle_tpu import observability as obs
     from paddle_tpu.core.registry import amp_scope
     from paddle_tpu.core.selected_rows import SelectedRows
 
@@ -180,7 +180,6 @@ def lower_block(block_program, is_test=False, executor=None, amp=False,
         obs.observe("lower.ops", len(block_program.ops))
         obs.observe("lower.block_ops",
                     len([o for o in block.ops if o.type not in _SKIP_OPS]))
-        obs.inc("lower.blocks")
 
     def fn(feed_values, state_values, rng_key):
         env = {}
@@ -254,7 +253,15 @@ def run_op(op, block, env, rng_key, op_index, is_test, executor=None,
     op_metadata carries the framework-op identity through fusion, and
     the tag -> OpDesc binding is recorded for the attribution join.
     named_scope is metadata-only: the emitted computation is
-    bit-identical either way (tests/test_opprof.py asserts it)."""
+    bit-identical either way (tests/test_opprof.py asserts it).
+
+    While spans are live the lowering is the span ``op:<type>`` (``idx``
+    = ``<block>_<index>``, ``role`` by ``opprof.role_phase``, the device
+    join's rule). This function runs under JAX's trace, and an
+    executable's first call is a cache-miss seam (``compile``), so the
+    seconds of set-up's tracing are recorded by Fluid op whatever is
+    switched on, a grad op's ``jax.vjp`` replay inside its own span. A
+    retrace outside a seam, with nothing switched on, records nothing."""
     ins = {}
     for slot, names in op.inputs.items():
         vals = []
@@ -270,14 +277,19 @@ def run_op(op, block, env, rng_key, op_index, is_test, executor=None,
                     "holder)" % (op.type, slot, len(vals), n)
                 )
         ins[slot] = vals
+    block_idx = getattr(block, "idx", 0)
     if prov is not None:
-        tag = _opprof.provenance_tag(
-            op.type, getattr(block, "idx", 0), op_index)
+        tag = _opprof.provenance_tag(op.type, block_idx, op_index)
         prov[tag] = op
         scope = jax.named_scope(tag)
     else:
         scope = contextlib.nullcontext()
-    with scope:
+    # obs.span's own check, made before the arguments are
+    span = obs.tracer.span(
+        "op:" + op.type, idx="%d_%d" % (block_idx, op_index),
+        role=_opprof.role_phase(op.attrs.get("op_role", 0)),
+    ) if obs.spans_live() else obs.NULL_BLOCK
+    with span, scope:
         if op.type.endswith("_grad") and not OpRegistry.has(op.type):
             outs = _lower_grad_op(op, block, ins, rng_key, is_test)
         else:

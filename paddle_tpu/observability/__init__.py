@@ -27,8 +27,11 @@ While one is on, ``span`` is live with the flag down, and every live span
 is also written into the profiler's trace as ``pt.<name>``, on the clock
 of the device planes. Counters, histograms, the memory census and the
 rest stay on the flag, so a traced slice carries spans and nothing
-heavier. The cache-miss seam (``seam_span``: once an executable) is
-recorded in memory whatever is switched on.
+heavier. The seams (``seam_span``: once an executable, ``trace`` and
+its first call ``compile``; once a Program, ``minimize`` and
+``append_backward``) are recorded in memory whatever is switched on, and
+so is every span opened while one is open: a first call holds one span
+``op:<type>`` for every Fluid op lowered under JAX's trace.
 
 Entry points: ``snapshot()``, ``dump_chrome_trace(path)``,
 ``inc/observe/set_gauge/time_block``, ``span/event``, ``reset()``.

@@ -40,6 +40,16 @@ JAX_DURATIONS = {
     "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax_lower_s",
     "/jax/core/compile/backend_compile_duration": "backend_compile_s",
 }
+# What the persistent compilation cache did inside that first call's
+# backend seconds: JAX names no function on these, so they are charged to
+# the first-call span open on the reporting thread. A hit is counted and
+# reports the two durations; a miss is counted where its entry is written.
+CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_retrieval_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "compile_saved_s",
+}
 
 # perf_counter is monotonic but has an arbitrary zero; anchor it to the
 # epoch once so span starts align with device-trace timestamps.
@@ -168,7 +178,11 @@ class SpanTracer:
         function's ``__name__``) it is that function's first call: the
         seconds JAX reports for tracing, lowering and backend-compiling
         ``fun_name`` while the span is open become its arguments
-        (``JAX_DURATIONS``)."""
+        (``JAX_DURATIONS``), and so does what the compilation cache did
+        on this thread meanwhile (``CACHE_EVENTS``). Its duration less the
+        three ``JAX_DURATIONS`` is the rest of the first call: the
+        arguments' transfer, the first execution's dispatch, whatever JAX
+        does unnamed."""
         if fun_name is not None:
             self._listen()
         return _SeamSpan(self, name, args, fun_name)
@@ -182,17 +196,33 @@ class SpanTracer:
 
         jax.monitoring.register_event_duration_secs_listener(
             self._charge_jax_duration)
+        jax.monitoring.register_event_listener(self._charge_jax_count)
 
     def _charge_jax_duration(self, event, seconds, fun_name=None, **_):
-        """The program's one ``jax.monitoring`` listener: JAX names the
-        tracing event by the function and the two later ones by
+        """The program's one ``jax.monitoring`` listener of durations: JAX
+        names the tracing event by the function and the two later ones by
         ``jit(<function>)``; anything else (the benchmark's own jits,
         eager ops) is not the engine's."""
         span, key = self._first_call, JAX_DURATIONS.get(event)
-        if span is None or key is None or fun_name not in (
+        if key is None:
+            return self._charge_cache(event, seconds)
+        if span is None or fun_name not in (
                 span.fun_name, "jit(%s)" % span.fun_name):
             return
         span.args[key] = span.args.get(key, 0.0) + seconds
+
+    def _charge_jax_count(self, event, **_):
+        """... and its one listener of plain events."""
+        self._charge_cache(event, 1)
+
+    def _charge_cache(self, event, amount):
+        """The compilation cache's verdict (``CACHE_EVENTS``), from either
+        listener: counts and seconds alike are summed into the argument."""
+        span, key = self._first_call, CACHE_EVENTS.get(event)
+        if (span is None or key is None
+                or span.tid != threading.get_ident()):
+            return
+        span.args[key] = span.args.get(key, 0) + amount
 
     def current_phase(self):
         """The innermost open span's name (any thread), or None."""
@@ -411,7 +441,7 @@ class _Span:
 class _SeamSpan(_Span):
     """A span of the cache-miss seam (``SpanTracer.seam_span``)."""
 
-    __slots__ = ("fun_name", "_outer")
+    __slots__ = ("fun_name", "tid", "_outer")
 
     def __init__(self, tracer, name, args, fun_name=None):
         super().__init__(tracer, name, args)
@@ -422,6 +452,7 @@ class _SeamSpan(_Span):
     def __enter__(self):
         self.tracer.seam_open += 1
         if self.fun_name is not None:
+            self.tid = threading.get_ident()
             self._outer, self.tracer._first_call = (
                 self.tracer._first_call, self)
         return super().__enter__()

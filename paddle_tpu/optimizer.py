@@ -144,10 +144,18 @@ class Optimizer:
 
     def minimize(self, loss, startup_program=None, parameter_list=None,
                  no_grad_set=None):
-        params_grads = self.backward(
-            loss, startup_program, parameter_list, no_grad_set
-        )
-        optimize_ops = self.apply_gradients(params_grads)
+        from paddle_tpu import observability as obs
+
+        # a seam span, as ``append_backward``'s, which nests inside it:
+        # its self time is the optimizer's own ops
+        ops = loss.block.desc.ops
+        before = len(ops)
+        with obs.seam_span("minimize", ops=0) as span:
+            params_grads = self.backward(
+                loss, startup_program, parameter_list, no_grad_set
+            )
+            optimize_ops = self.apply_gradients(params_grads)
+            span.args["ops"] = len(ops) - before
         return optimize_ops, params_grads
 
 

@@ -196,6 +196,43 @@ def test_instrumentation_is_bit_exact(opt_level):
         flags.reset_flag("opprof")
 
 
+def test_live_spans_leave_the_lowered_step_as_it_is():
+    """The ``op:<type>`` spans round the lowerings (live on a first call,
+    under the flag or in a profiler session) are host-side only: the step
+    lowers to the same module, locations and all, with them and without."""
+    import jax
+
+    main, startup = Program(), Program()
+    with program_guard(main, startup):
+        loss = _build_mlp()
+        fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
+    exe, scope = fluid.Executor(), fluid.Scope()
+    feed = _mlp_feed()
+    with fluid.scope_guard(scope):
+        exe.run(startup)
+        exe.run(main, feed=feed, fetch_list=[loss.name])
+    compiled = list(exe.engine._cache.values())[-1]
+    args = ([feed[n] for n in compiled.block_program.feed_names],
+            [scope.get(n) for n in compiled.mutated_names],
+            [scope.get(n) for n in compiled.readonly_names],
+            (np.uint32(0), np.uint32(1)))
+
+    def lowered():  # a function of its own each time: JAX traces it anew
+        step = compiled.jitted.__wrapped__
+        return jax.jit(lambda *a: step(*a)).lower(*args).as_text(
+            debug_info=True)
+
+    obs.reset()
+    texts, op_spans = [], []
+    for seam in (obs.NULL_BLOCK, obs.seam_span("compile")):
+        with seam:  # one call site: the locations hold the caller's line
+            texts.append(lowered())
+        op_spans.append(
+            sum(s.name.startswith("op:") for s in obs.spans()))
+    assert op_spans == [0, len(compiled.block_program.ops)]
+    assert "pt.mul." in texts[0] and texts[0] == texts[1]
+
+
 # -- end-to-end attribution on a real profiled run ----------------------
 
 def test_profiled_mlp_attribution(tmp_path):
