@@ -39,9 +39,27 @@ kernel of ``kernels/row_permute.py``: whole tiles fetched, the permutation
 inside a tile a 0/1 product on the MXU, only the tiles in use visited.
 Everywhere else (the CPU, float32 programs, odd sizes) both are XLA gathers
 (a row's token one way, a pair's row the other), never a scatter-add: each
-pair has one row, so the inverse permutation is known. Which of the two a
-call site was lowered as is counted (``moe.permute_kernel`` /
-``moe.permute_xla``), and so are the forms of the expert MLP's two-pair
+pair has one row, so the inverse permutation is known.
+
+**The scalars of a pair** (its weight, its weight's gradient, its score)
+are moved by no gather and no scatter either, on any backend: the chip does
+a gather or a scatter of single elements one element at a time, 6-10 ns a
+gathered and up to 17 a scatter-added one: 0.3-0.7 and 0.4-1.1 ms for the
+49,152-65,536 pairs of a layer, where a sort of as many keys takes
+0.04-0.08 (PERF.md, Findings PR 38). A permutation of scalars rides
+in a sort: ``moe_dispatch``'s sort by expert carries the flattened
+``TopkWeight`` as a payload, so ``RowWeight`` comes out in row order (until
+PR 38 ``weight[PairOfRow]``), and the weights' gradient goes back to pair
+order as the payload of a sort by ``PairOfRow`` (``g[RowOfPair]``):
+``_by_expert``. A choice of k of E scores is a compare over the E outputs:
+``moe_router`` ranks with ``top_k`` for the ids alone and takes the scores
+by ``sum_e where(ids == e, probs, 0)`` (``take_along_axis``, or ``top_k``'s
+own values, whose transpose is a scatter-add into [tokens, E]); the
+gradient is the dense mirror, each entry one term or none: ``_chosen``.
+
+Which form of a row movement a call site was lowered as is counted
+(``moe.permute_kernel`` / ``moe.permute_xla``), and so are the forms of the
+expert MLP's two-pair
 product (``moe.gmm_pair_kernel`` / ``moe.gmm_pair_xla``: one call site a
 layer, counted where the backward is traced) and of its gate
 (``moe.gate_kernel`` / ``moe.gate_xla``: two a layer, the forward's
@@ -92,11 +110,9 @@ def moe_router(ctx, ins, attrs):
         probs = jax.nn.sigmoid(logits)
     else:
         probs = jax.nn.softmax(logits, axis=-1)
-    if bias is None:
-        top, ids = lax.top_k(probs, k)
-    else:
-        _, ids = lax.top_k(probs + lax.stop_gradient(bias), k)
-        top = jnp.take_along_axis(probs, ids, axis=-1)
+    _, ids = lax.top_k(
+        lax.stop_gradient(probs if bias is None else probs + bias), k)
+    top = _chosen(probs, ids)
     if sigmoid:     # (sigmoids can all be small; a softmax's top-k cannot)
         top = top / (jnp.sum(top, axis=-1, keepdims=True)
                      + float(attrs.get("norm_eps", 1e-20)))
@@ -117,6 +133,39 @@ def moe_router(ctx, ins, attrs):
         if bias is not None:
             jax.debug.callback(_publish_router_load, load)
     return outs
+
+
+def _is_chosen(ids, experts):
+    """[N, k, E] bool: router output e is token t's j-th choice."""
+    return ids[:, :, None] == lax.broadcasted_iota(jnp.int32,
+                                                   (1, 1, experts), 2)
+
+
+@jax.custom_vjp
+def _chosen(probs, ids):
+    """top[t, j] = probs[t, ids[t, j]], by a compare over the E outputs and
+    a sum (one term and zeros), not ``take_along_axis``: the module's
+    docstring says why. The backward keeps ``ids`` alone."""
+    return jnp.sum(jnp.where(_is_chosen(ids, probs.shape[1]),
+                             probs[:, None, :], 0), axis=2)
+
+
+def _chosen_fwd(probs, ids):
+    # (an empty array carries E and the scores' dtype to the backward)
+    return _chosen(probs, ids), (ids, jnp.zeros((0, probs.shape[1]),
+                                                probs.dtype))
+
+
+def _chosen_bwd(res, g):
+    ids, like = res
+    # a token's k choices are distinct: each entry gets one term or none,
+    # which is what the gather's transpose, a scatter-add, wrote
+    return (jnp.sum(jnp.where(_is_chosen(ids, like.shape[1]),
+                              g[:, :, None], 0), axis=1).astype(like.dtype),
+            None)
+
+
+_chosen.defvjp(_chosen_fwd, _chosen_bwd)
 
 
 def _publish_router_load(load):
@@ -200,12 +249,45 @@ def _sums_of_rows(rows, row_of_pair, pair_held, pair_of_row, counts, dtype):
 
 
 @jax.custom_vjp
+def _by_expert(key, weight):
+    """(PairOfRow [R] int32, each row's pair's weight [R]): the pairs in
+    the order of a stable sort by ``key``, their weights carried through
+    the sort as its payload instead of gathered after it. The pair's index
+    is the low digits of what is sorted (``key * R + pair``: the caller
+    sees to it that this fits int32), so the keys are distinct and the sort
+    need be neither stable nor carry an iota of its own: the chip's
+    compiler is slow on sorts, and a stable one of (key, iota, weight)
+    added 16-25 s to a step's 31-54 s of compile where this form adds 3-14
+    (this sandbox's CPU, the three decoder steps for a described v5e; on
+    the chip's host either form read 7-22 s more than the parent, the two
+    never on one machine: PERF.md, Findings PR 38). Backward: the rows'
+    gradient back in pair order by one sort more (``PairOfRow`` is a
+    permutation of 0 .. R-1, so sorted by it, position p holds the row of
+    pair p)."""
+    rows = key.shape[0]
+    digits, in_rows = lax.sort(
+        (key * rows + lax.iota(jnp.int32, rows), weight), num_keys=1)
+    return digits % rows, in_rows
+
+
+def _by_expert_fwd(key, weight):
+    pair_of_row, in_rows = _by_expert(key, weight)
+    return (pair_of_row, in_rows), pair_of_row
+
+
+def _by_expert_bwd(pair_of_row, g):
+    return None, lax.sort((pair_of_row, g[1]), num_keys=1)[1]
+
+
+_by_expert.defvjp(_by_expert_fwd, _by_expert_bwd)
+
+
+@jax.custom_vjp
 def _to_rows(x, weight, pair_of_row, counts, row_of_pair, pair_held):
-    """(rows[r] = x[token of row r], its pair's weight) where the row is
-    in use, else 0."""
+    """(rows[r] = x[token of row r], ``weight[r]``, the row's pair's)
+    where the row is in use, else 0."""
     return (_rows_of_tokens(x, pair_of_row, counts, row_of_pair),
-            jnp.where(_live(counts, pair_of_row.shape[0]),
-                      weight.reshape(-1)[pair_of_row], 0))
+            jnp.where(_live(counts, pair_of_row.shape[0]), weight, 0))
 
 
 def _to_rows_fwd(x, weight, pair_of_row, counts, row_of_pair, pair_held):
@@ -219,8 +301,9 @@ def _to_rows_bwd(res, g):
     _count_form(_by_kernel(g_rows, row_of_pair))
     dx = _sums_of_rows(g_rows, row_of_pair, pair_held, pair_of_row, counts,
                        g_rows.dtype)
-    return (dx, jnp.where(pair_held, g_weight[row_of_pair], 0), None, None,
-            None, None)
+    # (a dead row's gradient is unspecified: selected out, never multiplied)
+    return (dx, jnp.where(_live(counts, pair_of_row.shape[0]), g_weight, 0),
+            None, None, None, None)
 
 
 _to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
@@ -242,13 +325,17 @@ def moe_dispatch(ctx, ins, attrs):
     held, local = _held(ids, attrs)
     # held pairs first, by expert; the others after them in any order
     key = jnp.where(held, local, held_n).reshape(-1)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    if (held_n + 1) * n * k > 2 ** 31:
+        raise ValueError(
+            "moe_dispatch: %d pairs on %d held experts: a pair's index and "
+            "its expert do not fit one int32 sort key" % (n * k, held_n))
+    order, weight = _by_expert(key, single(ins, "TopkWeight").reshape(-1))
     row_of_pair = jnp.argsort(order).astype(jnp.int32).reshape(n, k)
     counts = jnp.sum(
         key[:, None] == jnp.arange(held_n, dtype=jnp.int32)[None, :],
         axis=0, dtype=jnp.int32)
-    rows, row_weight = _to_rows(amp_cast(x), single(ins, "TopkWeight"),
-                                order, counts, row_of_pair, held)
+    rows, row_weight = _to_rows(amp_cast(x), weight, order, counts,
+                                row_of_pair, held)
     if lowered_into_a_step(ctx, "moe_dispatch") and obs.enabled():
         by_kernel = _by_kernel(rows, row_of_pair)
         _count_form(by_kernel)

@@ -88,7 +88,10 @@ def test_every_op_is_a_span_inside_the_first_call_and_no_step_adds_one():
     assert by_type["op:adam"] == "optimizer"
     # by op type with no code of its own: the summary's rows
     row = obs.tracer.summary()["op:mul_grad"]
-    assert row["calls"] == 3 and 0.0 < row["self_ms"] <= row["total_ms"]
+    # (three childless spans: self time is the total but for the sums' last
+    # digit, which read 8.438229000000002 against 8.438229 once)
+    assert row["calls"] == 3
+    assert 0.0 < row["self_ms"] <= row["total_ms"] * (1 + 1e-9)
     # the ops' seconds are inside the engine's traced function, and that
     # inside what JAX reports for the jaxpr tracing
     (body,) = _named("traced-fn")

@@ -560,6 +560,86 @@ def test_row_permute_compiles(one_chip, which, shape):
     assert (got.shape, got.dtype) == out
 
 
+# -- the scalars of the routing (ops/moe_ops.py moe_router, moe_dispatch)
+
+ROUTERS = {"d2304-w896": ("softmax", False, 64),
+           "d2048-w1024": ("sigmoid", True, 128),
+           "d2048-w1536": ("sigmoid", True, 64)}
+
+
+@pytest.mark.parametrize("which", ["forward", "gradient"])
+@pytest.mark.parametrize("op", ["router", "dispatch"])
+@pytest.mark.parametrize("shape", list(EXPERT_SHAPES))
+def test_the_routing_moves_no_single_scalar(one_chip, monkeypatch, shape, op,
+                                            which):
+    """``moe_router`` and ``moe_dispatch``, forward and gradient, at the
+    decoder cells' shapes (8,192 x 2304 -> 64 softmax outputs, 8 a token,
+    16 held; 6,144 x 2048 -> 128 sigmoid outputs ranked with a bias, 8, 16;
+    16,384 x 2048 -> 64 with a bias, 4, 8): the chip's executable holds no
+    ``gather`` and no ``scatter``. When it did (until PR 38: the chosen
+    scores by ``take_along_axis``, the rows' weights by ``weight[PairOf
+    Row]``, their transposes a scatter-add and ``g[RowOfPair]``), each ran
+    one element at a time, 6-10 ns an element and up to 17 the
+    scatter-add: 0.31-0.66 ms a gather and 0.43-1.1 a scatter-add over
+    49,152-65,536 pairs, four of them a layer (three where no bias ranks),
+    5.6-11.8 ms of a 154-229 ms step (PERF.md, Findings PR 38), where a
+    sort of as many keys takes 0.04-0.08 ms and the compare over the
+    outputs fuses with what reads it."""
+    import sys
+
+    from paddle_tpu.ops import moe_ops
+    from test_moe_ops import _ctx
+
+    monkeypatch.setattr(sys.modules["paddle_tpu.kernels.row_permute"],
+                        "_on_tpu", lambda: True)
+    tokens, d, _, k, held = EXPERT_SHAPES[shape]
+    score_func, biased, experts = ROUTERS[shape]
+
+    def arg(shape_, dtype):
+        return jax.ShapeDtypeStruct(shape_, dtype, sharding=one_chip)
+
+    if op == "router":
+        args = [arg((tokens, d), jnp.bfloat16), arg((d, experts),
+                                                    jnp.float32)]
+        if biased:
+            args.append(arg((experts,), jnp.float32))
+
+        def fn(x, w, *bias):
+            outs = moe_ops.moe_router(
+                _ctx("moe_router"),
+                {"X": [x], "Weight": [w], "Bias": list(bias)},
+                {"k": k, "score_func": score_func, "route_scale": 2.826,
+                 "norm_eps": 1e-6})
+            return {name: v[0] for name, v in outs.items()}
+
+        def loss(x, w, *bias):
+            return jnp.sum(fn(x, w, *bias)["TopkWeight"] ** 2)
+    else:
+        args = [arg((tokens, d), jnp.bfloat16), arg((tokens, k),
+                                                    jnp.float32),
+                arg((tokens, k), jnp.int32)]
+
+        def fn(x, weight, ids):
+            outs = moe_ops.moe_dispatch(
+                _ctx("moe_dispatch"),
+                {"X": [x], "TopkWeight": [weight], "TopkIds": [ids]},
+                {"experts_held": held})
+            return {name: v[0] for name, v in outs.items()}
+
+        def loss(x, weight, ids):
+            outs = fn(x, weight, ids)
+            return (jnp.sum(outs["RowWeight"] ** 2)
+                    + jnp.sum(outs["Rows"].astype(jnp.float32) ** 2))
+    hlo = _compile(fn if which == "forward"
+                   else jax.grad(loss, argnums=(0, 1)), *args)
+    made = set(re.findall(r" ([a-z][a-z-]*)\(", hlo))
+    assert "sort" in made and "fusion" in made
+    assert not made & {"gather", "scatter"}, made & {"gather", "scatter"}
+    if op == "dispatch":    # the rows are the kernel's, both ways
+        assert hlo.count("tpu_custom_call") == (1 if which == "forward"
+                                                else 2)
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_one_row_of_a_2d_hbm_ref_is_still_refused(one_chip, dtype):
     """Why ``row_permute`` moves whole tiles: a DMA of single rows of a 2-D
