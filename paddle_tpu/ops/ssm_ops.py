@@ -1,5 +1,7 @@
 """The Mamba-2 mixer's three ops (Dao and Gu, "Transformers are SSMs",
-arXiv:2405.21060), in plain ``jax.numpy`` / ``lax``, no kernel:
+arXiv:2405.21060), in plain ``jax.numpy`` / ``lax``, and the scan by two
+Pallas kernels of its own on the TPU (which form runs: the end of this
+docstring):
 
     ssm_conv1d      silu(bias + a causal depthwise convolution over time) of
                     the mixer's x, B and C channels
@@ -36,6 +38,15 @@ argument: ``s_i - s_j`` is masked to -inf above the diagonal before the
 ``exp``. The backward is the same algebra transposed; it keeps the op's
 inputs and the states entering each chunk ([B, chunks, H, P, N] float32)
 and makes the rest again.
+
+**Which form runs.** Where ``kernels/ssd_scan.by_kernel`` sees the TPU,
+bf16 activations, chunks and states of whole 128 lanes and a group's
+heads filling whole lane tiles with whole heads, the scan and its
+backward are that module's two Pallas kernels: the same algebra and
+rounding points, each chunk's ``[Q, Q]`` decays and products and the
+carried state kept in VMEM. Everywhere else (the CPU, float32 programs,
+odd sizes) the plain form above runs; it shares no code with the
+kernels and is their oracle in the tests. No flag chooses.
 """
 
 import functools
@@ -45,6 +56,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from paddle_tpu.core.registry import register_op
+from paddle_tpu.kernels import ssd_scan as ssd_kernel
 from paddle_tpu.ops.common import fp32_accum, lowered_into_a_step, single
 from paddle_tpu.ops.nn_ops import _shift
 
@@ -190,6 +202,9 @@ def _ssd_fwd(x, dt, a_log, b, c, d, chunk):
 
 
 def _ssd_bwd(chunk, res, gy):
+    from paddle_tpu import observability as obs
+
+    obs.inc("ssm.scan_xla")
     x, dt, a_log, b, c, d, states, gy = lax.optimization_barrier(
         res + (gy,))
     cd, (rows, t, h) = x.dtype, x.shape[:3]
@@ -250,9 +265,12 @@ def ssd_scan(ctx, ins, attrs):
     state-space scan of every row from a zero state, by chunks of
     ``chunk_size`` positions (the module's docstring). Δ = softplus(Dt +
     DtBias) in float32, whatever Dt's dtype. X's dtype in and out; B and C
-    are taken in X's dtype. ``ssm.layers`` / ``ssm.scan_chunks``
-    (``metrics`` flag) count the ops lowered into a step and the chunks a
-    row of each."""
+    are taken in X's dtype. The two Pallas kernels of
+    ``kernels/ssd_scan.py`` where ``by_kernel``, else the plain form.
+    ``ssm.layers`` / ``ssm.scan_chunks`` (``metrics`` flag) count the ops
+    lowered into a step and the chunks a row of each; ``ssm.scan_kernel``
+    / ``ssm.scan_xla`` the call sites in each form, the forward's counted
+    by this lowering, the backward's where it is traced."""
     from paddle_tpu import observability as obs
 
     x = single(ins, "X")
@@ -265,11 +283,14 @@ def ssd_scan(ctx, ins, attrs):
         raise ValueError("ssd_scan: X %s, Dt %s, B %s, C %s: the heads must "
                          "fall into B's and C's groups evenly"
                          % (x.shape, dt.shape, b.shape, c.shape))
+    by_kernel = ssd_kernel.by_kernel(x, b, chunk)
     if lowered_into_a_step(ctx, "ssd_scan"):
         obs.inc("ssm.layers")
         obs.inc("ssm.scan_chunks", -(-x.shape[1] // chunk))
-    return {"Out": [_ssd_scan(x, dt, single(ins, "ALog"), b, c,
-                              single(ins, "D"), chunk)]}
+        obs.inc("ssm.scan_kernel" if by_kernel else "ssm.scan_xla")
+    scan = ssd_kernel.scan if by_kernel else _ssd_scan
+    return {"Out": [scan(x, dt, single(ins, "ALog"), b, c, single(ins, "D"),
+                         chunk)]}
 
 
 # -- gated_rms_norm ------------------------------------------------------------

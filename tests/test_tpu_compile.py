@@ -683,8 +683,9 @@ def test_one_row_of_a_2d_hbm_ref_is_still_refused(one_chip, dtype):
 
 
 # -- the Mamba-2 mixer's ops (ops/ssm_ops.py) and the relu2 expert MLP
-# (ops/moe_ops.py), at the nemotron_twotower_30b_a3b cell's widths: XLA's,
-# no kernel of their own
+# (ops/moe_ops.py), at the nemotron_twotower_30b_a3b cell's widths: the scan
+# by two kernels of its own (kernels/ssd_scan.py) or in its plain form, the
+# rest XLA's
 
 def test_the_relu2_expert_mlp_backward_compiles(one_chip, monkeypatch):
     """One layer's relu2 ``moe_expert_mlp`` with its own backward at
@@ -734,11 +735,18 @@ def _ssm_args(one_chip):
     return x, dt, per_head, bc
 
 
+# a float32 array whose last two axes are a chunk's [Q, Q]: the plain
+# form's decays, M and dM
+CHUNK_SQUARES = re.compile(r"f32\[(\d+,)*128,128\]")
+
+
 @pytest.mark.parametrize("which", ["forward", "backward"])
 def test_the_chunked_scan_compiles(one_chip, which):
-    """``ssd_scan`` at [2, 4096, 64 heads of 64] with B and C [2, 4096,
-    8, 128] bf16 and chunks of 128, forward and its own backward: no
-    kernel, bf16 out for bf16 in, float32 for the float32 leaves, and the
+    """The plain form of ``ssd_scan`` (what float32 programs and sizes
+    the kernels do not tile run) at [2, 4096, 64 heads of 64] with B and C
+    [2, 4096, 8, 128] bf16 and chunks of 128, forward and its own
+    backward: no kernel, its float32 ``[..., Q, Q]`` decays through HBM,
+    bf16 out for bf16 in, float32 for the float32 leaves, and the
     backward keeps within 2 GB of temporaries."""
     from paddle_tpu.ops.ssm_ops import _ssd_scan
 
@@ -755,6 +763,7 @@ def test_the_chunked_scan_compiles(one_chip, which):
                 else (backward, args + (x,)))
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" not in compiled.as_text()
+    assert CHUNK_SQUARES.search(compiled.as_text())
     assert compiled.memory_analysis().temp_size_in_bytes < 2e9
     got = jax.eval_shape(fn, *args)
     if which == "forward":
@@ -762,6 +771,93 @@ def test_the_chunked_scan_compiles(one_chip, which):
     else:
         assert [(g.shape, g.dtype) for g in got] == [
             (a.shape, a.dtype) for a in args[:-1]]
+
+
+def test_the_chunked_scan_kernels_compile(one_chip, monkeypatch):
+    """The same scan where the TPU takes the two kernels: the forward op's
+    call and its replay inside the grad op's ``jax.vjp`` (one jitted
+    function, so that XLA merges their custom calls) and the backward,
+    two Mosaic calls in all, and no float32 ``[..., Q, Q]`` array left in
+    the optimized HLO: the decays and products stay in VMEM."""
+    from paddle_tpu.kernels import ssd_scan as kernel
+
+    monkeypatch.setattr(kernel, "_on_tpu", lambda: True)
+    x, dt, per_head, bc = _ssm_args(one_chip)
+    assert kernel.by_kernel(x, bc, SSM["chunk"])
+
+    def scan(*a):
+        return kernel.scan(*a, SSM["chunk"])
+
+    def step(x_, dt_, a_, b_, c_, d_, g):
+        y = scan(x_, dt_, a_, b_, c_, d_)
+        return y, jax.vjp(scan, x_, dt_, a_, b_, c_, d_)[1](g)
+
+    args = (x, dt, per_head, bc, bc, per_head, x)
+    hlo = _compile(step, *args)
+    assert hlo.count("tpu_custom_call") == 2
+    assert "ssd_scan_fwd" in hlo and "ssd_scan_bwd" in hlo
+    assert not CHUNK_SQUARES.search(hlo), CHUNK_SQUARES.search(hlo).group()
+    y, grads = jax.eval_shape(step, *args)
+    assert (y.shape, y.dtype) == (x.shape, x.dtype)
+    assert [(g.shape, g.dtype) for g in grads] == [
+        (a.shape, a.dtype) for a in args[:-1]]
+
+
+def test_the_cells_step_takes_the_scan_kernels(topo, monkeypatch):
+    """The ``nemotron_twotower_30b_a3b`` cell's step (its configuration's
+    own sizes, bf16) lowered for a described v5e with the kernels' call
+    sites seeing the TPU: its three mixers' scans take the kernels both
+    ways, ``ssm.scan_kernel`` / ``ssm.scan_xla`` 6 / 0 (0 / 6 on the CPU:
+    ``tests/test_nemotron.py``)."""
+    import importlib
+    import json
+
+    import paddle_tpu.fluid as fluid
+    from paddle_tpu import observability as obs
+    from paddle_tpu.core.types import convert_dtype_to_np
+    from paddle_tpu.models import decoder_lm
+
+    for name in ("flash_attention", "grouped_matmul", "row_permute",
+                 "ssd_scan"):
+        monkeypatch.setattr(importlib.import_module(
+            "paddle_tpu.kernels." + name), "_on_tpu", lambda: True)
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmarks", "configs",
+            "nemotron_twotower_30b_a3b.json")) as f:
+        cfg = json.load(f)
+    with fluid.unique_name.guard():
+        main, _, handle = decoder_lm.get_model(batch_size=2, lr=1e-4,
+                                               **cfg["model"])
+    fluid.contrib.mixed_precision.enable_bf16(main)
+    exe, block = fluid.Executor(), main.desc.block(0)
+    seq = cfg["model"]["seq_len"]
+    names, values = exe.engine._coerce_feed(block, {
+        "ids": np.zeros((2, seq), np.int64),
+        "labels": np.zeros((2, seq), np.int64)})
+    step = exe.engine.get_compiled(main.desc, 0, names, values,
+                                   [handle["loss"].name], False, True, True,
+                                   1)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def shape(name):
+        var = block.find_var_recursive(name)
+        return jax.ShapeDtypeStruct(tuple(var.shape),
+                                    convert_dtype_to_np(var.dtype),
+                                    sharding=one_chip)
+
+    obs.set_enabled(True)
+    before = [obs.counter_value(n) for n in ("ssm.scan_kernel",
+                                             "ssm.scan_xla")]
+    text = step.jitted.lower(
+        [jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
+         for v in values],
+        [shape(n) for n in step.mutated_names],
+        [shape(n) for n in step.readonly_names],
+        (np.uint32(0), np.uint32(1))).as_text()
+    exe.close()
+    assert [obs.counter_value(n) - b for n, b in zip(
+        ("ssm.scan_kernel", "ssm.scan_xla"), before)] == [6, 0]
+    assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
 
 
 @pytest.mark.parametrize("which", ["forward", "backward"])
