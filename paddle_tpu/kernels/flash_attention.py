@@ -37,7 +37,11 @@ window as it is above by causality, in the loop bounds and in the fetch
 index, so a window layer visits fewer tiles, not the same tiles with more
 masking. K/V may have fewer heads than Q (grouped-query attention): Q head
 ``h`` reads K/V head ``h // (Hq // Hkv)`` through the K/V index maps; the
-backward makes dK/dV per Q head and ``_flash_backward`` sums each group. Attention dropout runs *inside* the kernel using a
+backward makes dK/dV per Q head and ``_flash_backward`` sums each group.
+V may be narrower or wider than Q and K (latent attention's 192-wide q·k
+over 128-wide values): every kernel contracts Q with K over their width
+and P with V over V's, so the output, its cotangent and dV are V's width
+and dQ, dK Q's; equal widths lower as they always did. Attention dropout runs *inside* the kernel using a
 counter-based hash RNG (murmur3 finalizer over the global (batch, q, k)
 coordinate), so the forward and every backward kernel regenerate the
 identical mask from (seed, coords) with no [Tq, Tk] mask ever stored.
@@ -172,7 +176,7 @@ def _attn_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
     def _step():
         q = q_ref[0]                           # [block_q, D], input dtype
         k_blk = k_ref[0]                       # [block_k, D]
-        v_blk = v_ref[0]
+        v_blk = v_ref[0]                       # [block_k, Dv]
         q_pos = j * block_q + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 0)
         sij = jax.lax.dot_general(
@@ -278,9 +282,10 @@ def _flash_forward(q, k, v, seq_lens, offsets, seed, causal, scale, rate,
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     group = _kv_group(q, k, window, causal)
+    Dv = v.shape[-1]
     qr = q.reshape(B * H, Tq, D)
     kr = k.reshape(B * H // group, Tk, D)
-    vr = v.reshape(B * H // group, Tk, D)
+    vr = v.reshape(B * H // group, Tk, Dv)
     block_q = min(block_q, Tq)
     block_k = min(block_k, Tk)
     grid = (B * H, Tq // block_q)
@@ -300,7 +305,7 @@ def _flash_forward(q, k, v, seq_lens, offsets, seed, causal, scale, rate,
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[
-            jax.ShapeDtypeStruct(qr.shape, q.dtype),
+            jax.ShapeDtypeStruct((B * H, Tq, Dv), q.dtype),
             jax.ShapeDtypeStruct((B * H, Tq, _LSE_LANES), jnp.float32),
         ],
         grid=grid + (Tk // block_k,),
@@ -310,19 +315,19 @@ def _flash_forward(q, k, v, seq_lens, offsets, seed, causal, scale, rate,
             _smem_spec(),
             pl.BlockSpec((1, block_q, D), lambda b, j, s: (b, j, 0)),
             pl.BlockSpec((1, block_k, D), _kvmap),
-            pl.BlockSpec((1, block_k, D), _kvmap),
+            pl.BlockSpec((1, block_k, Dv), _kvmap),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, D), lambda b, j, s: (b, j, 0)),
+            pl.BlockSpec((1, block_q, Dv), lambda b, j, s: (b, j, 0)),
             pl.BlockSpec((1, block_q, _LSE_LANES),
                          lambda b, j, s: (b, j, 0)),
         ],
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_q, Dv), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32)],
         interpret=interpret,
     )(lens, seed_arr, _offsets_arr(offsets), qr, kr, vr)
-    return out.reshape(B, H, Tq, D), lse
+    return out.reshape(B, H, Tq, Dv), lse
 
 
 def _bwd_dq_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
@@ -353,7 +358,7 @@ def _bwd_dq_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
     @pl.when(live)
     def _step():
         q = q_ref[0]                          # [block_q, D]
-        do = do_ref[0]                        # [block_q, D]
+        do = do_ref[0]                        # [block_q, Dv]
         lse = lse_ref[0][:, :1]               # [block_q, 1]
         delta = delta_ref[0][:, :1]           # [block_q, 1]
         k_blk = k_ref[0]                      # [block_k, D]
@@ -436,9 +441,9 @@ def _bwd_fused_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref,
 
     def compute():
         k_blk = k_ref[0]                       # [block_k, D]
-        v_blk = v_ref[0]                       # [block_k, D]
+        v_blk = v_ref[0]                       # [block_k, Dv]
         q = q_ref[0]                           # [block_q, D]
-        do = do_ref[0]                         # [block_q, D]
+        do = do_ref[0]                         # [block_q, Dv]
         lse = lse_ref[0][:, :1]                # [block_q, 1]
         delta = delta_ref[0][:, :1]            # [block_q, 1]
         sij = jax.lax.dot_general(
@@ -509,18 +514,22 @@ def _bwd_fused_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref,
 _FUSED_BWD_VMEM_LIMIT = 32 * 2 ** 20
 
 
-def _bwd_fused_fits(tq, d, dtype, block_q, block_k, rate=0.0):
+def _bwd_fused_fits(tq, d, dtype, block_q, block_k, rate=0.0, dv=None):
     """Whether the one-kernel backward applies, reckoned from the static
     shapes: the VMEM that grows with the sequence (the f32 dQ accumulator
     and its double-buffered output block), the streamed and resident
     blocks and the tile's intermediates, minor dims padded to 128 lanes,
-    within seven eighths of the kernel's limit. Longer sequences run the
+    within seven eighths of the kernel's limit. Q, K, dQ and dK are ``d``
+    wide, V, dO and dV ``dv`` (``d`` where None). Longer sequences run the
     two kernels, whose VMEM does not grow with the length."""
     item = jnp.dtype(dtype).itemsize
     lanes = -(-d // 128) * 128
+    lanes_v = lanes if dv is None else -(-dv // 128) * 128
     dq = tq * lanes * (4 + 2 * item)
-    streamed = 2 * block_q * (2 * lanes * item + 2 * 128 * 4)
-    resident = block_k * lanes * (2 * 4 * item + 2 * 4)
+    # q and dO streamed, double-buffered, with lse and delta
+    streamed = 2 * block_q * ((lanes + lanes_v) * item + 2 * 128 * 4)
+    # K and V in, dK and dV out, double-buffered, and their f32 accumulators
+    resident = block_k * (lanes + lanes_v) * (4 * item + 4)
     # what Mosaic keeps of a tile: the scores in f32, p and ds in the
     # MXU's operand dtype, the dropout mask as a word (compiled for a
     # v5e, bf16 and f32, 256..1024 squared, 2048..16384 positions: the
@@ -544,13 +553,14 @@ def _flash_backward(q, k, v, out, lse, g, g_lse, seq_lens, offsets, seed,
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
     group = _kv_group(q, k, window, causal)
+    Dv = v.shape[-1]
     qr = q.reshape(B * H, Tq, D)
     kr = k.reshape(B * H // group, Tk, D)
-    vr = v.reshape(B * H // group, Tk, D)
-    do = g.reshape(B * H, Tq, D)
+    vr = v.reshape(B * H // group, Tk, Dv)
+    do = g.reshape(B * H, Tq, Dv)
     bq, bk = min(block_q, Tq), min(block_k, Tk)
     fused_blocks = pick_bwd_blocks(Tq, Tk, q.dtype, (bq, bk))
-    fused = _bwd_fused_fits(Tq, D, q.dtype, *fused_blocks, rate)
+    fused = _bwd_fused_fits(Tq, D, q.dtype, *fused_blocks, rate, Dv)
     obs.inc("flash.bwd_fused" if fused else "flash.bwd_split")
 
     masked = seq_lens is not None
@@ -567,7 +577,7 @@ def _flash_backward(q, k, v, out, lse, g, g_lse, seq_lens, offsets, seed,
     # differentiates through lse) folds in exactly: d s from g_lse is
     # p * g_lse, and ds = p * (dp - delta + g_lse) — so delta -= g_lse.
     delta = jnp.sum(
-        do.astype(jnp.float32) * out.reshape(B * H, Tq, D).astype(
+        do.astype(jnp.float32) * out.reshape(B * H, Tq, Dv).astype(
             jnp.float32), axis=-1)
     if g_lse is not None:
         delta = delta - g_lse.reshape(B * H, Tq).astype(jnp.float32)
@@ -595,8 +605,8 @@ def _flash_backward(q, k, v, out, lse, g, g_lse, seq_lens, offsets, seed,
                 _smem_spec(),
                 qspec(D),
                 pl.BlockSpec((1, bk, D), _kvmap),
-                pl.BlockSpec((1, bk, D), _kvmap),
-                qspec(D),
+                pl.BlockSpec((1, bk, Dv), _kvmap),
+                qspec(Dv),
                 qspec(_LSE_LANES),
                 qspec(_LSE_LANES),
             ],
@@ -630,16 +640,18 @@ def _flash_backward(q, k, v, out, lse, g, g_lse, seq_lens, offsets, seed,
     else:
         def _qmap(b, s, j):
             return (b, j, 0)
-    kspec = pl.BlockSpec((1, bk, D), lambda b, s, j: (b, s, 0))
+    kspec, vspec = (pl.BlockSpec((1, bk, width), lambda b, s, j: (b, s, 0))
+                    for width in (D, Dv))
     # grouped heads: every Q head reads its group's K/V tile and writes a
     # dK/dV of its own (one kernel body for both cases); the group's sum
     # is taken below, in XLA
-    kin = kspec if group == 1 else pl.BlockSpec(
-        (1, bk, D), lambda b, s, j: (b // group, s, 0))
+    kin, vin = (spec if group == 1 else pl.BlockSpec(
+        (1, bk, width), lambda b, s, j: (b // group, s, 0))
+        for spec, width in ((kspec, D), (vspec, Dv)))
     kv_shapes = [jax.ShapeDtypeStruct((B * H, Tk, D), k.dtype),
-                 jax.ShapeDtypeStruct((B * H, Tk, D), v.dtype)]
+                 jax.ShapeDtypeStruct((B * H, Tk, Dv), v.dtype)]
     kv_scratch = [pltpu.VMEM((bk, D), jnp.float32),
-                  pltpu.VMEM((bk, D), jnp.float32)]
+                  pltpu.VMEM((bk, Dv), jnp.float32)]
     call = functools.partial(
         pl.pallas_call,
         functools.partial(_bwd_fused_kernel, with_dq=fused, block_q=bq,
@@ -651,8 +663,8 @@ def _flash_backward(q, k, v, out, lse, g, g_lse, seq_lens, offsets, seed,
             _smem_spec(),
             pl.BlockSpec((1, bq, D), _qmap),
             kin,
-            kin,
-            pl.BlockSpec((1, bq, D), _qmap),
+            vin,
+            pl.BlockSpec((1, bq, Dv), _qmap),
             pl.BlockSpec((1, bq, _LSE_LANES), _qmap),
             pl.BlockSpec((1, bq, _LSE_LANES), _qmap),
         ],
@@ -661,20 +673,20 @@ def _flash_backward(q, k, v, out, lse, g, g_lse, seq_lens, offsets, seed,
         dq, dk, dv = call(
             out_shape=[jax.ShapeDtypeStruct(qr.shape, q.dtype)] + kv_shapes,
             out_specs=[pl.BlockSpec((1, Tq, D), lambda b, s, j: (b, 0, 0)),
-                       kspec, kspec],
+                       kspec, vspec],
             scratch_shapes=[pltpu.VMEM((Tq, D), jnp.float32)] + kv_scratch,
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=_FUSED_BWD_VMEM_LIMIT),
         )(*args)
     else:
-        dk, dv = call(out_shape=kv_shapes, out_specs=[kspec, kspec],
+        dk, dv = call(out_shape=kv_shapes, out_specs=[kspec, vspec],
                       scratch_shapes=kv_scratch)(*args)
 
     if group > 1:
-        dk, dv = (x.reshape(B, H // group, group, Tk, D).astype(
+        dk, dv = (x.reshape(B, H // group, group, Tk, x.shape[-1]).astype(
             jnp.float32).sum(axis=2).astype(x.dtype) for x in (dk, dv))
     return (dq.reshape(B, H, Tq, D), dk.reshape(B, H // group, Tk, D),
-            dv.reshape(B, H // group, Tk, D))
+            dv.reshape(B, H // group, Tk, Dv))
 
 
 def _repeat_kv(x, heads):

@@ -2566,6 +2566,40 @@ def rotary_embedding(xs, rope_theta=10000.0, rope_type="default", name=None,
     return outs
 
 
+def latent_qkv(cq, ckv, kpe, heads, qk_nope_head_dim, qk_rope_head_dim,
+               v_head_dim, q_b_attr=None, kv_b_attr=None, name=None,
+               rope_theta=10000.0, rope_type="default", interleaved=False,
+               **rope):
+    """Latent attention's queries, keys and values [B, H, T, .] from the
+    normed low-rank query ``cq`` [B, T, Cq], the normed latent ``ckv``
+    [B, T, Ckv] and the shared rotary key ``kpe`` [B, T, R]: the
+    up-projections WUQ [Cq, H (N + R)] and WUKV [Ckv, H (N + V)], the
+    rotation of each head's last R query columns and of ``kpe`` (by
+    ``interleaved`` pairs or by halves; ``rotary_embedding``'s other
+    attributes), the key broadcast to every head. One op
+    (``latent_qkv``, ops/nn_ops.py). -> (q, k, v)."""
+    helper = LayerHelper("latent_qkv", name=name)
+    nope, pe = int(qk_nope_head_dim), int(qk_rope_head_dim)
+    wuq, wukv = (
+        helper.create_parameter(attr=attr, shape=shape, dtype="float32",
+                                is_bias=False)
+        for attr, shape in (
+            (q_b_attr, [int(cq.shape[-1]), heads * (nope + pe)]),
+            (kv_b_attr, [int(ckv.shape[-1]),
+                         heads * (nope + int(v_head_dim))])))
+    q, k, v = (helper.create_variable_for_type_inference(cq.dtype)
+               for _ in range(3))
+    attrs = dict(rope, heads=int(heads), qk_nope_head_dim=nope,
+                 qk_rope_head_dim=pe, v_head_dim=int(v_head_dim),
+                 rope_theta=float(rope_theta), rope_type=rope_type,
+                 interleaved=bool(interleaved))
+    helper.append_op(type="latent_qkv",
+                     inputs={"CQ": [cq], "CKV": [ckv], "KPE": [kpe],
+                             "WUQ": [wuq], "WUKV": [wukv]},
+                     outputs={"Q": [q], "K": [k], "V": [v]}, attrs=attrs)
+    return q, k, v
+
+
 def moe_router(input, num_experts, k, param_attr=None, name=None,
                score_func="softmax", route_scale=1.0, bias_name=None,
                norm_eps=None):
@@ -2743,7 +2777,7 @@ def gated_rms_norm(input, gate, groups, epsilon=1e-6, param_attr=None,
 
 def moe_experts(input, topk_weight, topk_ids, experts_held, expert_offset,
                 width, gate_attr=None, up_attr=None, down_attr=None,
-                name=None, activation="swiglu"):
+                name=None, activation="swiglu", capacity_per_token=None):
     """The part of a mixture-of-experts layer that the ``experts_held``
     experts from ``expert_offset`` give: dispatch by expert (dropless: a
     buffer row for every token-expert pair), the gated SiLU expert MLP as
@@ -2751,6 +2785,8 @@ def moe_experts(input, topk_weight, topk_ids, experts_held, expert_offset,
     ``[experts_held, width, d]``; the pair's weight goes in before the
     down projection), combine. ``activation`` ``relu2``: the non-gated
     ``down(relu(up(x))^2)`` as two grouped matmuls, no gate weight.
+    ``capacity_per_token``: a held expert takes at most ceil(N x it) of
+    its pairs, the first in token order (ops/moe_ops.py ``_held``).
     ``input`` [N, d] ->
     (out [N, d] float32, counts [experts_held] int32: the tokens each held
     expert received). Four op types (``moe_dispatch``, ``moe_expert_mlp``,
@@ -2760,6 +2796,9 @@ def moe_experts(input, topk_weight, topk_ids, experts_held, expert_offset,
     d = int(input.shape[-1])
     share = {"experts_held": int(experts_held),
              "expert_offset": int(expert_offset)}
+    if capacity_per_token:
+        # (dropless programs carry no such attribute: they are what they were)
+        share["capacity_per_token"] = float(capacity_per_token)
 
     def ints():
         return helper.create_variable_for_type_inference(

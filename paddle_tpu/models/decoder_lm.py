@@ -73,6 +73,37 @@ What the arguments add, each alone:
                        Wdown relu(Wup m)^2, no gate (default ``swiglu``)
     shared_expert_intermediate_size  the shared expert's width (default
                        ``num_shared_experts`` x ``moe_intermediate_size``)
+    mla                latent attention on every attention layer, its sizes
+                       ``q_lora_rank`` (Cq), ``kv_lora_rank`` (Ckv),
+                       ``qk_nope_head_dim`` (N), ``qk_rope_head_dim`` (R),
+                       ``v_head_dim`` (V) and ``rope_interleave``:
+                           c_q = RMSNorm(n1 Wq_a);  [c_kv | k_pe] = n1 Wkv_a
+                           [q_nope | q_pe] = c_q Wq_b        N + R a head
+                           [k_nope | v] = RMSNorm(c_kv) Wkv_b   N + V a head
+                           q = [q_nope | RoPE(q_pe)]
+                           k = [k_nope | RoPE(k_pe)], k_pe one head for all
+                           h = x + Wo . Attn(q, k, v), scale (N + R)^-1/2
+                       the rotation by interleaved pairs with
+                       ``rope_interleave``; the up-projections, rotations
+                       and broadcast are one op (layers/nn.py
+                       ``latent_qkv``); ``head_dim`` and
+                       ``num_key_value_heads`` go unused
+    mtp                one multi-token-prediction module (DeepSeek-V3,
+                       arXiv:2412.19437 section 2.2, at depth 1) after the
+                       last layer, whose output h it reads before the final
+                       norm: h' = Weh [RMSNorm(E[t_{i+1}]) ; RMSNorm(h_i)],
+                       one more ``full_attention`` layer with experts
+                       (``mtp.`` leaves), its own norm and the shared head
+                       and embedding; the loss adds ``loss_weight`` x the
+                       mean cross-entropy of positions 0..T-2 against
+                       t_{i+2}. t_{i+1} is ``labels[i]``: nothing more is
+                       fed. The handle's ``mtp`` gives its logits, targets
+                       and loss
+    expert_capacity_factor  a held expert takes at most this times its
+                       balanced share of the pairs (tokens x
+                       ``num_experts_per_tok`` / ``router_experts``), the
+                       first in token order; its later pairs add nothing,
+                       as the absent experts' do
 """
 
 import paddle_tpu.fluid as fluid
@@ -165,6 +196,57 @@ def mamba(x, prefix, heads, head_dim, state, groups, taps, chunk, eps):
     return _proj(y, d, prefix + "out_proj")
 
 
+def latent_attention(x, prefix, heads, mla, rope, eps):
+    """Latent attention in place of ``attention`` (the module's
+    docstring): the low-rank query and the latent with its shared rotary
+    key, their norms, the up-projections (``latent_qkv``), causal flash
+    attention whose q.k width is N + R and whose values are V wide, the
+    output projection."""
+    d = int(x.shape[-1])
+    nope, pe = mla["qk_nope_head_dim"], mla["qk_rope_head_dim"]
+    width = mla["v_head_dim"]
+    cq = _norm(_proj(x, mla["q_lora_rank"], prefix + "q_a_proj"), eps,
+               prefix + "q_a_norm")
+    ckv, kpe = fluid.layers.split(
+        _proj(x, mla["kv_lora_rank"] + pe, prefix + "kv_a_proj"),
+        [mla["kv_lora_rank"], pe], dim=-1)
+    q, k, v = _nn.latent_qkv(
+        cq, _norm(ckv, eps, prefix + "kv_a_norm"), kpe, heads, nope, pe,
+        width, q_b_attr=fluid.ParamAttr(name=prefix + "q_b_proj"),
+        kv_b_attr=fluid.ParamAttr(name=prefix + "kv_b_proj"),
+        interleaved=mla.get("rope_interleave", False), **rope)
+    ctx = _nn.fused_attention(q, k, v, causal=True,
+                              scale=(nope + pe) ** -0.5)
+    ctx = fluid.layers.transpose(ctx, perm=[0, 2, 1, 3])
+    ctx = fluid.layers.reshape(ctx, shape=[0, 0, heads * width])
+    obs.inc("attn.mla_layers")
+    return _proj(ctx, d, prefix + "o_proj")
+
+
+def next_token_module(h, labels, seq_len, vocab_size, layer, head, eps, mtp):
+    """The multi-token-prediction module of depth 1 (the module's
+    docstring), from the last layer's output ``h`` before the final norm:
+    -> (its logits over positions 0..T-2, their targets t_{i+2})."""
+    if mtp.get("num_nextn_predict_layers", 1) != 1:
+        raise ValueError("multi-token prediction is built at depth 1 only")
+    d = int(h.shape[-1])
+    # the next token's embedding, from the shared table: t_{i+1} is
+    # labels[i], so position T-1 has one too
+    emb = fluid.layers.embedding(
+        input=labels, size=[vocab_size, d],
+        param_attr=fluid.ParamAttr(name="tok_embedding"))
+    joined = fluid.layers.concat(
+        [_norm(emb, eps, "mtp.enorm"), _norm(h, eps, "mtp.hnorm")], axis=-1)
+    out = layer(_proj(joined, d, "mtp.eh_proj"), "mtp.", "full_attention",
+                False)
+    obs.inc("decoder.mtp_depth")
+    # position T-1 has no t_{T+1}: its prediction is left out
+    logits = head(fluid.layers.slice(out, axes=[1], starts=[0],
+                                     ends=[seq_len - 1]), "mtp.final_norm")
+    return logits, fluid.layers.slice(labels, axes=[1], starts=[1],
+                                      ends=[seq_len])
+
+
 def relu2_mlp(x, prefix, width):
     """Wdown relu(Wup x)^2, no biases: fc, relu, square, fc."""
     return _proj(fluid.layers.square(fluid.layers.relu(
@@ -180,7 +262,8 @@ def gated_mlp(x, prefix, width):
 
 def moe(x, prefix, router_experts, experts_held, expert_offset,
         experts_per_token, width, score_func="softmax", route_scale=1.0,
-        biased=False, norm_eps=None, activation="swiglu"):
+        biased=False, norm_eps=None, activation="swiglu",
+        capacity_factor=None):
     """-> (the held experts' part of the layer [B, T, d], tokens each held
     expert received, the router's [load, bias] with ``biased`` or [])."""
     d = int(x.shape[-1])
@@ -196,7 +279,9 @@ def moe(x, prefix, router_experts, experts_held, expert_offset,
         gate_attr=fluid.ParamAttr(name=prefix + "experts_gate"),
         up_attr=fluid.ParamAttr(name=prefix + "experts_up"),
         down_attr=fluid.ParamAttr(name=prefix + "experts_down"),
-        activation=activation)
+        activation=activation,
+        capacity_per_token=capacity_factor and (
+            capacity_factor * experts_per_token / router_experts))
     return (fluid.layers.reshape(out, shape=[-1, int(x.shape[1]), d]), counts,
             balance)
 
@@ -214,7 +299,8 @@ def get_model(batch_size, seq_len, vocab_size, hidden_size, num_hidden_layers,
               hybrid_override_pattern=None, moe_activation="swiglu",
               shared_expert_intermediate_size=None, mamba_num_heads=None,
               mamba_head_dim=None, ssm_state_size=None, n_groups=None,
-              conv_kernel=4, chunk_size=128):
+              conv_kernel=4, chunk_size=128, mla=None, mtp=None,
+              expert_capacity_factor=None):
     """Next-token pre-training program; the configuration gives every size
     (``batch_size`` is the feed's own: the batch axis stays open).
     ``layer_types`` names each layer ``sliding_attention``,
@@ -242,7 +328,7 @@ def get_model(batch_size, seq_len, vocab_size, hidden_size, num_hidden_layers,
                 n2, prefix, router_experts, experts_held, expert_offset,
                 num_experts_per_tok, moe_intermediate_size, score_func,
                 route_scale, load_balance_coeff > 0, route_norm_eps,
-                moe_activation)
+                moe_activation, expert_capacity_factor)
             loads.append(counts)
             if balance:
                 balances.append(balance)
@@ -257,9 +343,9 @@ def get_model(batch_size, seq_len, vocab_size, hidden_size, num_hidden_layers,
         single = bool(hybrid_override_pattern)
         if single:
             layer_types = [_PATTERN[c] for c in hybrid_override_pattern]
-        for i in range(num_hidden_layers):
-            prefix, kind = "layer%d." % i, layer_types[i]
 
+        def layer(h, prefix, kind, dense):
+            """One decoder layer of ``kind``, its MLP ``dense`` or not."""
             def norm(x, name):
                 return _norm(x, rms_norm_eps,
                              prefix + ("norm" if single else name))
@@ -270,6 +356,10 @@ def get_model(batch_size, seq_len, vocab_size, hidden_size, num_hidden_layers,
                 attn = mamba(norm(h, "attn_norm"), prefix, mamba_num_heads,
                              mamba_head_dim, ssm_state_size, n_groups,
                              conv_kernel, chunk_size, rms_norm_eps)
+            elif kind != "moe" and mla:
+                attn = latent_attention(
+                    norm(h, "attn_norm"), prefix, num_attention_heads, mla,
+                    rope_parameters[kind], rms_norm_eps)
             elif kind != "moe":
                 rope = rope_parameters.get(kind)
                 attn = attention(
@@ -286,30 +376,50 @@ def get_model(batch_size, seq_len, vocab_size, hidden_size, num_hidden_layers,
             if single:
                 obs.inc("decoder.single_part_layers")
                 if kind != "moe":
-                    continue
+                    return h
             n2 = norm(h, "mlp_norm")
-            if mlp_layer_types and mlp_layer_types[i] == "dense":
+            if dense:
                 part = gated_mlp(n2, prefix + "mlp_", intermediate_size)
             else:
                 part = experts(n2, prefix)
             if post_norms:
                 part = _norm(part, rms_norm_eps, prefix + "post_mlp_norm")
-            h = fluid.layers.elementwise_add(h, part)
-        h = _norm(h, rms_norm_eps, "final_norm")
-        if tie_word_embeddings:
-            logits = fluid.layers.matmul(
-                fluid.layers.reshape(h, shape=[-1, hidden_size]),
-                main.global_block().var("tok_embedding"), transpose_y=True)
-            obs.inc("decoder.tied_head")
-        else:
-            logits = fluid.layers.reshape(_proj(h, vocab_size, "lm_head"),
-                                          shape=[-1, vocab_size])
-        loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
-            logits=logits,
-            label=fluid.layers.reshape(labels, shape=[-1, 1])))
+            return fluid.layers.elementwise_add(h, part)
+
+        def head(h, norm_name):
+            """Logits [tokens, vocabulary] of RMSNorm(h)."""
+            h = _norm(h, rms_norm_eps, norm_name)
+            if tie_word_embeddings:
+                obs.inc("decoder.tied_head")
+                return fluid.layers.matmul(
+                    fluid.layers.reshape(h, shape=[-1, hidden_size]),
+                    main.global_block().var("tok_embedding"),
+                    transpose_y=True)
+            return fluid.layers.reshape(_proj(h, vocab_size, "lm_head"),
+                                        shape=[-1, vocab_size])
+
+        def cross_entropy(logits, targets):
+            return fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
+                logits=logits,
+                label=fluid.layers.reshape(targets, shape=[-1, 1])))
+
+        for i in range(num_hidden_layers):
+            h = layer(h, "layer%d." % i, layer_types[i],
+                      bool(mlp_layer_types) and mlp_layer_types[i] == "dense")
+        loss = cross_entropy(head(h, "final_norm"), labels)
+        predicted = {}
+        if mtp:
+            logits, targets = next_token_module(
+                h, labels, seq_len, vocab_size, layer, head, rms_norm_eps,
+                mtp)
+            predicted = {"logits": logits, "targets": targets,
+                         "loss": cross_entropy(logits, targets)}
+            loss = fluid.layers.elementwise_add(loss, fluid.layers.scale(
+                predicted["loss"], scale=float(mtp["loss_weight"])))
         if is_train:
             fluid.optimizer.Adam(learning_rate=lr).minimize(loss)
             for load, bias in balances:
                 _nn.moe_bias_update(bias, load, load_balance_coeff)
     return main, startup, {"feeds": {"ids": ids, "labels": labels},
-                           "loss": loss, "expert_loads": loads}
+                           "loss": loss, "expert_loads": loads,
+                           "mtp": predicted}

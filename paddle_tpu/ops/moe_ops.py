@@ -26,7 +26,10 @@ experts are distinct and up to all of them may be held here), so no
 routing can overflow it; the grouped matmuls visit only the rows in use.
 What the experts held elsewhere would add is left out: on one chip there
 is no exchange and nothing stands in for it. A token's weights are
-renormalised over all k of its experts, held or not.
+renormalised over all k of its experts, held or not. Where the attribute
+``capacity_per_token`` is given, a held expert takes at most ceil(tokens x
+it) pairs, the first in token order; the rest are treated as held
+elsewhere (``_held``).
 
 The pair's weight multiplies the row before the down projection (which is
 linear, so the sum is the weighted combine): the combine's backward then
@@ -76,6 +79,7 @@ unspecified too.
 """
 
 import functools
+import math
 
 import numpy as np
 
@@ -201,9 +205,25 @@ def moe_bias_update(ctx, ins, attrs):
 
 
 def _held(ids, attrs):
-    """(pair is held here [N, k] bool, its local expert [N, k] int32)."""
+    """(pair is held here [N, k] bool, its local expert [N, k] int32). With
+    ``capacity_per_token``, a held expert takes only the first
+    ceil(N x it) of its pairs in token order, and the later ones are held
+    nowhere: ``moe_dispatch`` and ``moe_combine`` both ask here, so they
+    agree on which."""
     local = ids - int(attrs.get("expert_offset", 0))
-    return (local >= 0) & (local < int(attrs["experts_held"])), local
+    held_n = int(attrs["experts_held"])
+    held = (local >= 0) & (local < held_n)
+    share = float(attrs.get("capacity_per_token", 0.0))
+    if share > 0.0:
+        capacity = math.ceil(round(ids.shape[0] * share, 6))
+        # a token picks an expert once: its pair's rank on that expert (1
+        # for the first) is the count of the tokens up to it that picked it
+        mine = local.reshape(-1)[:, None] == jnp.arange(held_n,
+                                                        dtype=local.dtype)
+        rank = jnp.sum(jnp.where(mine, jnp.cumsum(mine, 0, dtype=jnp.int32),
+                                 0), axis=1)
+        held = held & (rank <= capacity).reshape(ids.shape)
+    return held, local
 
 
 def _by_kernel(like, row_of_pair):

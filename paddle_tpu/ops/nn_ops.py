@@ -16,6 +16,8 @@ cell's 1% bound; the pass, its flag and the NHWC branches here were
 deleted on that reading (PERF.md, Findings PR 31).
 """
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -347,6 +349,20 @@ def _ring_attention_from_attrs(q, k, v, attrs):
         batch_axis=attrs.get("sp_batch_axis", None) or None)
 
 
+def _count_split_v(q, k, v, attrs):
+    """``flash.split_v_calls`` (``metrics`` flag): the flash kernel calls
+    of a lowered step whose V is not as wide as Q and K (latent
+    attention's 192-wide q.k over 128-wide values)."""
+    from paddle_tpu import observability as obs
+    from paddle_tpu.kernels.flash_attention import flash_dispatch_ok
+
+    force = attrs.get("force_flash", None)
+    flash = (flash_dispatch_ok(q.shape[2], k.shape[2]) if force is None
+             else bool(force))
+    if flash and v.shape[-1] != q.shape[-1]:
+        obs.inc("flash.split_v_calls")
+
+
 def _check_ring_supported(rate, lens):
     if rate > 0.0:
         raise NotImplementedError(
@@ -393,6 +409,8 @@ def fused_attention_op(ctx, ins, attrs):
         attrs.get("force_flash", None),
         raw_lse=True,  # kernel-native layout: zero-relayout backward read
         window=attrs.get("window", None))
+    if lowered_into_a_step(ctx, "fused_attention"):
+        _count_split_v(q, k, v, attrs)
     # the XLA branch's lse binds the program's Lse var too (the direct
     # grad op ignores it there and XLA DCEs it when nothing reads it)
     return {"Out": [out], "Lse": [lse]}
@@ -419,7 +437,8 @@ def fused_attention_grad_op(ctx, ins, attrs):
     scale = attrs.get("scale", None)
     window = attrs.get("window", None)
     g = single(ins, "Out@GRAD")
-    g = jnp.asarray(g, q.dtype).reshape(q.shape)
+    # Out (and so its cotangent) is V's width, which may not be Q's
+    g = jnp.asarray(g, q.dtype).reshape(q.shape[:-1] + v.shape[-1:])
     if bool(attrs.get("sequence_parallel", False)):
         _check_ring_supported(rate, lens)
         _, vjp = jax.vjp(
@@ -455,6 +474,8 @@ def fused_attention_grad_op(ctx, ins, attrs):
             q, k, v, out.astype(q.dtype), lse_k, g, lens,
             seed, causal, scale_, rate, min(bq, Tq), min(bk, Tk),
             not _on_tpu(), window)
+        if lowered_into_a_step(ctx, "fused_attention_grad"):
+            _count_split_v(q, k, v, attrs)
         return {"Q@GRAD": [dq], "K@GRAD": [dk], "V@GRAD": [dv]}
 
     # program lacks the saved residuals (old desc) or took the XLA branch:
@@ -670,19 +691,28 @@ def rope_inv_freq(head_dim, attrs):
 def _rotation(t, d, dtype, attrs):
     """rotate(x) = x*cos + (x @ R)*sin for x [..., t, d] of ``dtype``: R
     the [d, d] matrix of 0 / +-1 with x @ R = [-x2, x1], cos and sin the
-    [t, d] float32 tables, each half twice. The product only selects, so
+    [t, d] float32 tables, each half twice. With the attribute
+    ``interleaved`` the pairs are (2i, 2i+1) instead of (i, i + d/2):
+    (x @ R)[2i] = -x[2i+1], (x @ R)[2i+1] = x[2i], and each angle's cos
+    and sin stand twice side by side. The product only selects, so
     it is exact: bf16 operands accumulate in float32, float32 operands
     ask for HIGHEST so that the chip does not round them to bf16. Its
     cotangent is the rotation by the negative angle, g*cos - (g @ R)*sin,
     so each way is one pass over the tensor (PERF.md, Findings PR 32)."""
     inv, scaling = rope_inv_freq(d, attrs)
     angle = np.arange(t, dtype=np.float64)[:, None] * inv[None, :]
-    cos = jnp.asarray(np.tile(np.cos(angle) * scaling, 2), jnp.float32)
-    sin = jnp.asarray(np.tile(np.sin(angle) * scaling, 2), jnp.float32)
     half = d // 2
     r = np.zeros((d, d), np.float32)
-    r[half:, :half] = -np.eye(half)
-    r[:half, half:] = np.eye(half)
+    if attrs.get("interleaved", False):
+        twice = functools.partial(np.repeat, repeats=2, axis=1)
+        r[1::2, 0::2] = -np.eye(half)
+        r[0::2, 1::2] = np.eye(half)
+    else:
+        twice = functools.partial(np.tile, reps=2)
+        r[half:, :half] = -np.eye(half)
+        r[:half, half:] = np.eye(half)
+    cos = jnp.asarray(twice(np.cos(angle) * scaling), jnp.float32)
+    sin = jnp.asarray(twice(np.sin(angle) * scaling), jnp.float32)
     r = jnp.asarray(r, dtype)
     precision = lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
@@ -718,6 +748,52 @@ def rotary_embedding(ctx, ins, attrs):
     if lowered_into_a_step(ctx, "rotary_embedding"):
         obs.inc("rope.rotations", len(outs))
     return {"Out": outs}
+
+
+@register_op("latent_qkv")
+def latent_qkv(ctx, ins, attrs):
+    """Latent attention's up-projections, rotations and key broadcast, in
+    the flash kernels' [B, H, T, D] layout, from CQ [B, T, Cq] (the normed
+    low-rank query), CKV [B, T, Ckv] (the normed latent), KPE [B, T, R]
+    (the one rotary key all heads share), WUQ [Cq, H (N + R)] and WUKV
+    [Ckv, H (N + V)], each head's columns together:
+
+        [q_nope | q_pe] = CQ WUQ                  N + R a head
+        [k_nope | v]    = CKV WUKV                N + V a head
+        Q = [q_nope | rot(q_pe)],  K = [k_nope | rot(KPE) on every head]
+
+    ``heads``, ``qk_nope_head_dim`` (N), ``qk_rope_head_dim`` (R),
+    ``v_head_dim`` (V) and the rotary attributes of ``rotary_embedding``
+    (``interleaved`` among them) are attributes. One op type, so that a
+    trace reads the mixer's time by type; bf16 operands under AMP; the
+    gradient is the engine's generic ``jax.vjp`` of this lowering (the
+    broadcast key's is the sum over the heads). ``rope.rotations`` and
+    ``rope.interleaved`` (``metrics`` flag) count the two tensors it
+    rotates in a lowered step."""
+    from paddle_tpu import observability as obs
+
+    cq, ckv, kpe, wuq, wukv = amp_cast(
+        single(ins, "CQ"), single(ins, "CKV"), single(ins, "KPE"),
+        single(ins, "WUQ"), single(ins, "WUKV"))
+    heads, nope = int(attrs["heads"]), int(attrs["qk_nope_head_dim"])
+    rope, width = int(attrs["qk_rope_head_dim"]), int(attrs["v_head_dim"])
+
+    def up(x, w):
+        return jnp.einsum("btc,chd->bhtd", x,
+                          w.reshape(w.shape[0], heads, -1))
+
+    q, kv = up(cq, wuq), up(ckv, wukv)
+    rotate = _rotation(cq.shape[1], rope, cq.dtype, attrs)
+    # rotated once, then the same key for every head
+    k_pe = jnp.broadcast_to(rotate(kpe[:, None]), kv.shape[:3] + (rope,))
+    if lowered_into_a_step(ctx, "latent_qkv"):
+        obs.inc("rope.rotations", 2)
+        if attrs.get("interleaved", False):
+            obs.inc("rope.interleaved", 2)
+    return {"Q": [jnp.concatenate([q[..., :nope], rotate(q[..., nope:])],
+                                  -1)],
+            "K": [jnp.concatenate([kv[..., :nope], k_pe], -1)],
+            "V": [kv[..., nope:nope + width]]}
 
 
 @register_op("dropout", needs_rng=True)

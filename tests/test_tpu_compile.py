@@ -803,12 +803,10 @@ def test_the_chunked_scan_kernels_compile(one_chip, monkeypatch):
         (a.shape, a.dtype) for a in args[:-1]]
 
 
-def test_the_cells_step_takes_the_scan_kernels(topo, monkeypatch):
-    """The ``nemotron_twotower_30b_a3b`` cell's step (its configuration's
-    own sizes, bf16) lowered for a described v5e with the kernels' call
-    sites seeing the TPU: its three mixers' scans take the kernels both
-    ways, ``ssm.scan_kernel`` / ``ssm.scan_xla`` 6 / 0 (0 / 6 on the CPU:
-    ``tests/test_nemotron.py``)."""
+def _lowered_cell_step(topo, monkeypatch, config, rows, counters):
+    """A cell's step (its configuration's own sizes, bf16) lowered for a
+    described v5e with the kernels' call sites seeing the TPU -> (the
+    lowered text, what each of ``counters`` counted meanwhile)."""
     import importlib
     import json
 
@@ -823,17 +821,19 @@ def test_the_cells_step_takes_the_scan_kernels(topo, monkeypatch):
             "paddle_tpu.kernels." + name), "_on_tpu", lambda: True)
     with open(os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "benchmarks", "configs",
-            "nemotron_twotower_30b_a3b.json")) as f:
+            config + ".json")) as f:
         cfg = json.load(f)
+    obs.set_enabled(True)
+    before = [obs.counter_value(n) for n in counters]
     with fluid.unique_name.guard():
-        main, _, handle = decoder_lm.get_model(batch_size=2, lr=1e-4,
+        main, _, handle = decoder_lm.get_model(batch_size=rows, lr=1e-4,
                                                **cfg["model"])
     fluid.contrib.mixed_precision.enable_bf16(main)
     exe, block = fluid.Executor(), main.desc.block(0)
     seq = cfg["model"]["seq_len"]
     names, values = exe.engine._coerce_feed(block, {
-        "ids": np.zeros((2, seq), np.int64),
-        "labels": np.zeros((2, seq), np.int64)})
+        "ids": np.zeros((rows, seq), np.int64),
+        "labels": np.zeros((rows, seq), np.int64)})
     step = exe.engine.get_compiled(main.desc, 0, names, values,
                                    [handle["loss"].name], False, True, True,
                                    1)
@@ -845,9 +845,6 @@ def test_the_cells_step_takes_the_scan_kernels(topo, monkeypatch):
                                     convert_dtype_to_np(var.dtype),
                                     sharding=one_chip)
 
-    obs.set_enabled(True)
-    before = [obs.counter_value(n) for n in ("ssm.scan_kernel",
-                                             "ssm.scan_xla")]
     text = step.jitted.lower(
         [jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=one_chip)
          for v in values],
@@ -855,9 +852,152 @@ def test_the_cells_step_takes_the_scan_kernels(topo, monkeypatch):
         [shape(n) for n in step.readonly_names],
         (np.uint32(0), np.uint32(1))).as_text()
     exe.close()
-    assert [obs.counter_value(n) - b for n, b in zip(
-        ("ssm.scan_kernel", "ssm.scan_xla"), before)] == [6, 0]
+    return text, {n: obs.counter_value(n) - b
+                  for n, b in zip(counters, before)}
+
+
+def test_the_cells_step_takes_the_scan_kernels(topo, monkeypatch):
+    """The ``nemotron_twotower_30b_a3b`` cell's step lowered for a
+    described v5e: its three mixers' scans take the kernels both ways,
+    ``ssm.scan_kernel`` / ``ssm.scan_xla`` 6 / 0 (0 / 6 on the CPU:
+    ``tests/test_nemotron.py``)."""
+    text, counted = _lowered_cell_step(
+        topo, monkeypatch, "nemotron_twotower_30b_a3b", 2,
+        ("ssm.scan_kernel", "ssm.scan_xla", "flash.split_v_calls"))
+    assert counted == {"ssm.scan_kernel": 6, "ssm.scan_xla": 0,
+                       "flash.split_v_calls": 0}
     assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
+
+
+def test_the_latent_attention_step_takes_the_flash_kernels(topo,
+                                                           monkeypatch):
+    """The ``joyai_llm_flash`` cell's step (1 x 4096, bf16) lowered for a
+    described v5e: 5 layers and the multi-token-prediction module, each
+    with latent attention, take the Pallas flash kernels at q.k 192 / v
+    128, 6 forward and 6 one-kernel backward calls (``flash.bwd_fused``;
+    the pair is not needed at 4096 positions); q_pe and k_pe of each are
+    rotated by interleaved pairs."""
+    text, counted = _lowered_cell_step(
+        topo, monkeypatch, "joyai_llm_flash", 1,
+        ("flash.split_v_calls", "flash.bwd_fused", "flash.bwd_split",
+         "rope.interleaved", "attn.mla_layers", "decoder.mtp_depth"))
+    assert counted == {"flash.split_v_calls": 12, "flash.bwd_fused": 6,
+                       "flash.bwd_split": 0, "rope.interleaved": 12,
+                       "attn.mla_layers": 6, "decoder.mtp_depth": 1}
+    assert text.count("tpu_custom_call") >= 12
+
+
+@pytest.mark.parametrize("form", ["forward", "fused", "split"])
+def test_flash_at_a_values_width_of_its_own_compiles(one_chip, monkeypatch,
+                                                     form):
+    """The cell's attention, [1, 32, 4096] with q and k 192 wide and v 128,
+    causal, bf16: the forward kernel, the one-kernel backward (which the
+    reckoning admits at this length) and the dQ + dK/dV pair each compile
+    to their custom calls, the output and dV 128 wide."""
+    import sys
+
+    T = 4096
+    q = jax.ShapeDtypeStruct((1, 32, T, 192), jnp.bfloat16, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((1, 32, T, 128), jnp.bfloat16, sharding=one_chip)
+    _, lse, _ = _attention_args((1, 32, T, 192), one_chip, False)
+    blk = pick_block(T, jnp.bfloat16)
+    assert _bwd_fused_fits(T, 192, jnp.bfloat16,
+                           *pick_bwd_blocks(T, T, jnp.bfloat16, (blk, blk)),
+                           dv=128)
+    if form == "split":
+        monkeypatch.setattr(sys.modules["paddle_tpu.kernels.flash_attention"],
+                            "_bwd_fused_fits", lambda *a: False)
+
+    def forward(q_, k_, v_):
+        return flash_attention_raw_lse(q_, k_, v_, None, 0, True,
+                                       192 ** -0.5, 0.0, blk, blk, False)
+
+    def backward(q_, k_, v_, o_, lse_, g_):
+        return _flash_backward(q_, k_, v_, o_, lse_, g_, None, None, None, 0,
+                               True, 192 ** -0.5, 0.0, blk, blk, False)
+
+    if form == "forward":
+        fn, args = forward, (q, q, v)
+        shapes = [(1, 32, T, 128), (1, 32, T, _LSE_LANES)]
+    else:
+        fn, args = backward, (q, q, v, v, lse, v)
+        shapes = [(1, 32, T, 192), (1, 32, T, 192), (1, 32, T, 128)]
+    hlo = _compile(fn, *args)
+    assert hlo.count("tpu_custom_call") == (2 if form == "split" else 1)
+    assert [tuple(x.shape) for x in jax.eval_shape(fn, *args)] == shapes
+
+
+def _mosaic_kernels(text):
+    """The Mosaic kernels of a lowered text's TPU custom calls, printed
+    without source locations (which move whenever a line above a kernel
+    does), each followed by the rest of its configuration."""
+    import base64
+    import json
+
+    from jax._src.lib.mlir import ir
+
+    out = []
+    for config in re.findall(r'backend_config = "([^"]*)"', text):
+        config = json.loads(config.replace("\\22", '"'))["custom_call_config"]
+        with ir.Context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            module = ir.Module.parse(base64.b64decode(config.pop("body")))
+            out.append(module.operation.get_asm(enable_debug_info=False))
+        out.append(json.dumps(config, sort_keys=True))
+    return "\n".join(out)
+
+
+# (B, Hq, Hkv, T, D, causal, masked by seq_lens, window): the flash calls of
+# the accepted cells' steps, and the digest of their kernels as lowered at
+# a5a425e, before V could have a width of its own
+ACCEPTED_FLASH = {
+    "bert_base_s2048": ((8, 12, 12, 2048, 64, False, True, None),
+                        "8d6993e9dfdc66d1"),
+    "mellum2_12b.window": ((2, 32, 4, 4096, 128, True, False, 1024),
+                           "604a432460d9b66b"),
+    "mellum2_12b.full": ((2, 32, 4, 4096, 128, True, False, None),
+                         "a1043396976d7263"),
+    "trinity_mini.window": ((2, 32, 4, 3072, 128, True, False, 2048),
+                            "5774b19e8c747c87"),
+    "trinity_mini.full": ((2, 32, 4, 3072, 128, True, False, None),
+                          "1b822470763b1579"),
+    "lfm2_24b_a2b": ((2, 32, 8, 8192, 64, True, False, None),
+                     "1cde101847b887e9"),
+    "nemotron_twotower_30b_a3b": ((2, 32, 2, 4096, 128, True, False, None),
+                                  "aea6624e7704d0d8"),
+}
+
+
+@pytest.mark.parametrize("cell", list(ACCEPTED_FLASH))
+def test_the_accepted_cells_flash_kernels_are_lowered_as_before(one_chip,
+                                                                cell):
+    """Forward and backward at each accepted cell's shapes, bf16, as the
+    fused_attention ops call them: the Mosaic kernels in their custom
+    calls are those lowered before V could be narrower than Q and K."""
+    import hashlib
+
+    (b, hq, hkv, t, d, causal, masked, window), digest = ACCEPTED_FLASH[cell]
+    q = jax.ShapeDtypeStruct((b, hq, t, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, hkv, t, d), jnp.bfloat16,
+                              sharding=one_chip)
+    _, lse, lens = _attention_args((b, hq, t, d), one_chip, masked)
+    block = pick_block(t, jnp.bfloat16)
+
+    def forward(q_, k_, v_, lens_):
+        return flash_attention_raw_lse(q_, k_, v_, lens_, 0, causal,
+                                       d ** -0.5, 0.0, block, block, False,
+                                       window)
+
+    def backward(q_, k_, v_, o_, lse_, g_, lens_):
+        return _flash_backward(q_, k_, v_, o_, lse_, g_, None, lens_, None,
+                               0, causal, d ** -0.5, 0.0, block, block,
+                               False, window)
+
+    kernels = (
+        _mosaic_kernels(jax.jit(forward).lower(q, kv, kv, lens).as_text())
+        + "\n" + _mosaic_kernels(jax.jit(backward).lower(
+            q, kv, kv, q, lse, q, lens).as_text()))
+    assert hashlib.sha256(kernels.encode()).hexdigest()[:16] == digest
 
 
 @pytest.mark.parametrize("which", ["forward", "backward"])
