@@ -35,7 +35,15 @@ is a static flag, and so is ``window`` (causal only: query ``t`` sees keys
 ``t-window+1 .. t``): the K-block range of a Q tile is clamped below by the
 window as it is above by causality, in the loop bounds and in the fetch
 index, so a window layer visits fewer tiles, not the same tiles with more
-masking. K/V may have fewer heads than Q (grouped-query attention): Q head
+masking. A causal call at static zero offsets with equal lengths and
+blocks, no key lengths and no dropout sorts its live tiles into classes
+(``_tile_class``, asked by every kernel): a tile where no key is masked
+runs without the mask; a tile on the diagonal, or on the oldest edge of a
+window that is a multiple of the block, is worked in row bands of 256,
+each over the keys its rows can see, with the mask on the one sub-tile
+that straddles the boundary; any other live tile, and every tile of any
+other call, runs the masked body. K/V may have fewer heads than Q
+(grouped-query attention): Q head
 ``h`` reads K/V head ``h // (Hq // Hkv)`` through the K/V index maps; the
 backward makes dK/dV per Q head and ``_flash_backward`` sums each group.
 V may be narrower or wider than Q and K (latent attention's 192-wide q·k
@@ -130,6 +138,158 @@ def _window_last_q_block(s, block_q, block_k, window):
     return ((s + 1) * block_k - 1 + window - 1) // block_q
 
 
+def _tile_live(q_off, k_off, j, s, block_q, block_k, window):
+    """Whether any query of Q block ``j`` sees a key of K block ``s`` in a
+    causal call: its last row is not before the block's first key and,
+    under a ``window``, its first row is less than ``window`` past the
+    block's last key. Plain operators, so it also reckons Python ints."""
+    live = (j + 1) * block_q - 1 + q_off >= s * block_k + k_off
+    if window is not None:
+        live = live & (j * block_q + q_off
+                       - ((s + 1) * block_k - 1 + k_off) < window)
+    return live
+
+
+# Query rows of a band: the tiles on the causal diagonal and on the
+# window's oldest edge are worked this many rows at a time
+_BAND = 256
+
+
+def _band_rows(block):
+    return _BAND if block % _BAND == 0 else block
+
+
+def _tile_class(j, s, block, window):
+    """The class of the tile (Q block ``j``, K block ``s``) of a banded
+    call (``_banded_kw``): ``(live, full, bands)``. ``full``: no key of
+    the tile is masked, for no row is before it or, under a window, a
+    ``window`` or more past it. ``bands``: ``(kind, condition)`` of the
+    tiles worked in row bands, ``diagonal`` (``s == j``, where a window
+    does not reach into it) and ``edge`` (the window's oldest edge, at the
+    fixed ``j - s`` of a window that is a multiple of the block). Other
+    live tiles take the masked body. Plain operators, so that the counts
+    of a call reckon its classes with Python ints."""
+    live = _tile_live(0, 0, j, s, block, block, window)
+    full = j * block >= (s + 1) * block - 1
+    if window is not None:
+        full = full & ((j + 1) * block - 1 - s * block < window)
+    bands = []
+    if window is None or window >= block:
+        bands.append(("diagonal", j == s))
+    if window is not None and window % block == 0:
+        bands.append(("edge", j - s == window // block))
+    return live, full, bands
+
+
+def _band_segments(kind, r, band, block):
+    """Key columns that band ``r`` of a ``kind`` tile sees, as ``(first,
+    end, mask)``: all before the band's own sub-tile on the diagonal, all
+    after it on the window's edge, and that sub-tile, which alone is
+    masked (``mask`` its kind, None where no key is masked)."""
+    lo, hi = r * band, (r + 1) * band
+    if kind == "diagonal":
+        segments = [(0, lo, None), (lo, hi, kind)]
+    else:
+        segments = [(lo, hi, kind), (hi, block, None)]
+    return [seg for seg in segments if seg[1] > seg[0]]
+
+
+# The banded bodies are written in ``lax`` primitives: ``jnp``'s functions
+# and operators trace through an inner jit each, and a banded kernel holds
+# a few hundred of them at every call site of a step (set-up time)
+
+
+def _cols(x, width):
+    """``[n, 1]`` broadcast to ``[n, width]``."""
+    return jax.lax.broadcast_in_dim(x, (x.shape[0], width), (0, 1))
+
+
+def _row_reduce(op, x):
+    """``op`` (``reduce_max``, ``reduce_sum``) over the last axis, kept."""
+    return jax.lax.broadcast_in_dim(op(x, (1,)), (x.shape[0], 1), (0,))
+
+
+def _segment_scores(q, k_blk, mask, scale):
+    """Scaled scores of query rows against a segment of keys; a masked
+    segment is its band's own square sub-tile, where a query sees the keys
+    at or before it (``diagonal``) or strictly after it (``edge``, which
+    is a whole window back)."""
+    sij = jax.lax.mul(jax.lax.dot_general(
+        q, k_blk, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32), jnp.float32(scale))
+    if mask is None:
+        return sij
+    row = jax.lax.broadcasted_iota(jnp.int32, sij.shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, sij.shape, 1)
+    seen = (jax.lax.ge(row, col) if mask == "diagonal"
+            else jax.lax.lt(row, col))
+    return jax.lax.select(seen, sij, jax.lax.full(sij.shape, _NEG,
+                                                  jnp.float32))
+
+
+def _by_class(j, s, block, window, classes, rows, masked_body):
+    """Run the live tile (``j``, ``s``) of a banded call by its class:
+    ``rows(first, end, segments)`` over every row and key of a full tile
+    and band by band over the keys each band sees on a diagonal or edge
+    tile; ``masked_body`` on every other live tile. Only the bodies of the
+    ``classes`` that the call's tiles hold (``_class_counts``) are
+    lowered."""
+    live, full, bands = _tile_class(j, s, block, window)
+    if "full" in classes:
+        pl.when(full)(lambda: rows(0, block, [(0, block, None)]))
+    rest = jnp.logical_and(live, jnp.logical_not(full))
+    band = _band_rows(block)
+    for kind, on in bands:
+        def banded(kind=kind):
+            for r in range(block // band):
+                rows(r * band, (r + 1) * band,
+                     _band_segments(kind, r, band, block))
+        if kind in classes:
+            pl.when(on)(banded)
+        rest = jnp.logical_and(rest, jnp.logical_not(on))
+    if "masked" in classes:
+        pl.when(rest)(masked_body)
+
+
+def _banded_kw(bh, t_q, t_k, block_q, block_k, causal, offsets, masked,
+               rate, window):
+    """The kernels' ``banded`` keyword, the classes (``_tile_class``) that
+    a causal call at static zero offsets with equal lengths and blocks, no
+    key lengths and no dropout holds. Left out otherwise, so that every
+    other call keeps the masked body on every live tile and is lowered as
+    it always was. Counts the call's live tiles of each class over its
+    ``bh`` = B·H (``flash.full_tiles``, ``flash.edge_tiles``, the
+    diagonal's and the window edge's together) and the band sub-tiles not
+    computed (``flash.edge_subtiles_skipped``)."""
+    from paddle_tpu import observability as obs
+
+    if not (causal and offsets is None and t_q == t_k and block_q == block_k
+            and not masked and rate == 0.0):
+        return {}
+    counts = _class_counts(t_q, block_q, window)
+    edge = counts.get("diagonal", 0) + counts.get("edge", 0)
+    n = block_q // _band_rows(block_q)
+    obs.inc("flash.full_tiles", bh * counts.get("full", 0))
+    obs.inc("flash.edge_tiles", bh * edge)
+    obs.inc("flash.edge_subtiles_skipped", bh * edge * n * (n - 1) // 2)
+    return {"banded": tuple(sorted(counts))}
+
+
+def _class_counts(t, block, window):
+    """One head's live tiles of a banded call at ``t`` positions, counted
+    by class: ``full``, ``diagonal``, ``edge`` (in row bands) and
+    ``masked``; a class that holds no tile is left out."""
+    counts = {}
+    for j in range(t // block):
+        for s in range(t // block):
+            live, full, bands = _tile_class(j, s, block, window)
+            if live:
+                kind = "full" if full else next(
+                    (kind for kind, on in bands if on), "masked")
+                counts[kind] = counts.get(kind, 0) + 1
+    return counts
+
+
 def _score_mask(sij, q_pos, k_pos, q_off, k_off, length, causal, window,
                 masked):
     """The scores with every key a query may not see set to ``_NEG``."""
@@ -145,12 +305,13 @@ def _score_mask(sij, q_pos, k_pos, q_off, k_off, length, causal, window,
 
 def _attn_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
                  lse_ref, acc_s, m_s, l_s, *, block_q, block_k, causal,
-                 scale, rate, masked, t_k, window=None):
+                 scale, rate, masked, t_k, window=None, banded=()):
     """Online-softmax forward with K/V STREAMED over the innermost grid
     axis (grid = (B*H, Tq/block_q, Tk/block_k)) and the (acc, m, l)
     carry in VMEM scratch — VMEM bounded by the block sizes, not Tk
     (the resident-K/V form capped context at ~8k: seq-16384 overran the
-    16MB scoped limit in this kernel by 768KB)."""
+    16MB scoped limit in this kernel by 768KB). A ``banded`` call works
+    each live tile by its class (``_by_class``)."""
     b = pl.program_id(0)
     j = pl.program_id(1)
     s = pl.program_id(2)
@@ -165,14 +326,29 @@ def _attn_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
         m_s[...] = jnp.full_like(m_s, _NEG)
         l_s[...] = jnp.zeros_like(l_s)
 
-    causal_hi = _causal_blocks(q_off, k_off, j, block_q, block_k)
-    nk_eff = _nk_limit(ns, causal_hi, length, block_k, masked, causal)
-    live = s < nk_eff
-    if window is not None:
-        live = jnp.logical_and(live, s >= _window_first_block(
-            q_off, k_off, j, block_q, block_k, window))
+    def _rows(first, end, segments):
+        # the online softmax of rows first:end over the key segments
+        lax = jax.lax
+        q = q_ref[0, first:end, :]
+        scores = [_segment_scores(q, k_ref[0, c0:c1, :], mask, scale)
+                  for c0, c1, mask in segments]
+        m = m_s[first:end, :]
+        m_new = m
+        for sij in scores:
+            m_new = lax.max(m_new, _row_reduce(lax.reduce_max, sij))
+        corr = lax.exp(lax.sub(m, m_new))
+        l = lax.mul(l_s[first:end, :], corr)
+        acc = lax.mul(acc_s[first:end, :], _cols(corr, acc_s.shape[1]))
+        for sij, (c0, c1, _) in zip(scores, segments):
+            p = lax.exp(lax.sub(sij, _cols(m_new, c1 - c0)))
+            l = lax.add(l, _row_reduce(lax.reduce_sum, p))
+            acc = lax.add(acc, lax.dot_general(
+                lax.convert_element_type(p, v_ref.dtype), v_ref[0, c0:c1, :],
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32))
+        m_s[first:end, :] = m_new
+        l_s[first:end, :] = l
+        acc_s[first:end, :] = acc
 
-    @pl.when(live)
     def _step():
         q = q_ref[0]                           # [block_q, D], input dtype
         k_blk = k_ref[0]                       # [block_k, D]
@@ -200,6 +376,17 @@ def _attn_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, o_ref,
         acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
             p_acc.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
+
+    if banded:
+        _by_class(j, s, block_q, window, banded, _rows, _step)
+    else:
+        causal_hi = _causal_blocks(q_off, k_off, j, block_q, block_k)
+        nk_eff = _nk_limit(ns, causal_hi, length, block_k, masked, causal)
+        live = s < nk_eff
+        if window is not None:
+            live = jnp.logical_and(live, s >= _window_first_block(
+                q_off, k_off, j, block_q, block_k, window))
+        pl.when(live)(_step)
 
     @pl.when(s == ns - 1)
     def _emit():
@@ -301,7 +488,9 @@ def _flash_forward(q, k, v, seq_lens, offsets, seed, causal, scale, rate,
     kernel = functools.partial(
         _attn_kernel, block_q=block_q, block_k=block_k, causal=causal,
         scale=scale, rate=rate, masked=masked, t_k=Tk,
-        **_window_kw(window))
+        **_window_kw(window),
+        **_banded_kw(B * H, Tq, Tk, block_q, block_k, causal, offsets,
+                     masked, rate, window))
     out, lse = pl.pallas_call(
         kernel,
         out_shape=[
@@ -330,9 +519,50 @@ def _flash_forward(q, k, v, seq_lens, offsets, seed, causal, scale, rate,
     return out.reshape(B, H, Tq, Dv), lse
 
 
+def _bwd_rows(refs, first, end, segments, scale, dkv, with_dq=True):
+    """The backward of a banded call's rows ``first:end`` of a tile over
+    its key segments (``_by_class``): p from the saved lse (every row of
+    such a call sees its own key, so no row's lse is the empty one's),
+    dV and dK added into the segments' rows of ``dkv`` = (dK, dV)
+    accumulators where given; returns the rows' dQ ``with_dq``."""
+    lax = jax.lax
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = refs
+    q = q_ref[0, first:end, :]
+    do = do_ref[0, first:end, :]
+    lse = lse_ref[0, first:end, :1]
+    delta = delta_ref[0, first:end, :1]
+    dq = None
+    for c0, c1, mask in segments:
+        k_blk = k_ref[0, c0:c1, :]
+        v_blk = v_ref[0, c0:c1, :]
+        p = lax.exp(lax.sub(_segment_scores(q, k_blk, mask, scale),
+                            _cols(lse, c1 - c0)))
+        dp = lax.dot_general(
+            do, v_blk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = lax.convert_element_type(lax.mul(lax.mul(
+            p, lax.sub(dp, _cols(delta, c1 - c0))), jnp.float32(scale)),
+            q.dtype)
+        if dkv is not None:
+            dk_acc, dv_acc = dkv
+            dv_acc[c0:c1, :] = lax.add(dv_acc[c0:c1, :], lax.dot_general(
+                lax.convert_element_type(p, do.dtype), do,
+                (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32))
+            dk_acc[c0:c1, :] = lax.add(dk_acc[c0:c1, :], lax.dot_general(
+                ds, q, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+        if with_dq:
+            part = lax.dot_general(
+                ds, k_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            dq = part if dq is None else lax.add(dq, part)
+    return dq
+
+
 def _bwd_dq_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, delta_ref, dq_ref, dq_acc, *, block_q, block_k,
-                   causal, scale, rate, masked, t_k, window=None):
+                   causal, scale, rate, masked, t_k, window=None,
+                   banded=()):
     """dQ with K/V streamed over the innermost grid axis and the dq
     accumulator in VMEM scratch (same restructure as the forward — the
     resident-K/V form's VMEM grew with Tk)."""
@@ -348,14 +578,11 @@ def _bwd_dq_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    causal_hi = _causal_blocks(q_off, k_off, j, block_q, block_k)
-    nk_eff = _nk_limit(ns, causal_hi, length, block_k, masked, causal)
-    live = s < nk_eff
-    if window is not None:
-        live = jnp.logical_and(live, s >= _window_first_block(
-            q_off, k_off, j, block_q, block_k, window))
+    def _rows(first, end, segments):
+        dq_acc[first:end, :] = jax.lax.add(dq_acc[first:end, :], _bwd_rows(
+            (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), first, end,
+            segments, scale, None))
 
-    @pl.when(live)
     def _step():
         q = q_ref[0]                          # [block_q, D]
         do = do_ref[0]                        # [block_q, Dv]
@@ -386,6 +613,17 @@ def _bwd_dq_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
             ds.astype(k_blk.dtype), k_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
+    if banded:
+        _by_class(j, s, block_q, window, banded, _rows, _step)
+    else:
+        causal_hi = _causal_blocks(q_off, k_off, j, block_q, block_k)
+        nk_eff = _nk_limit(ns, causal_hi, length, block_k, masked, causal)
+        live = s < nk_eff
+        if window is not None:
+            live = jnp.logical_and(live, s >= _window_first_block(
+                q_off, k_off, j, block_q, block_k, window))
+        pl.when(live)(_step)
+
     @pl.when(s == ns - 1)
     def _emit():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
@@ -394,7 +632,7 @@ def _bwd_dq_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref, do_ref,
 def _bwd_fused_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref,
                       do_ref, lse_ref, delta_ref, *outs_and_scratch, with_dq,
                       block_q, block_k, causal, scale, rate, masked,
-                      window=None):
+                      window=None, banded=()):
     """The backward with a K/V tile resident and the Q dimension STREAMED
     over the innermost grid axis (grid = (B*H, Tk/block_k, Tq/block_q)),
     f32 accumulation in VMEM scratch — the earlier form held full-length
@@ -483,18 +721,25 @@ def _bwd_fused_kernel(len_ref, seed_ref, off_ref, q_ref, k_ref, v_ref,
                 ds, k_blk, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-    if causal:
+    def rows(first, end, segments):
+        dq = _bwd_rows(
+            (q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref), first, end,
+            segments, scale, (dk_acc, dv_acc), with_dq)
+        if with_dq:
+            at = pl.ds(pl.multiple_of(j * block_q + first, end - first),
+                       end - first)
+            dq_acc[at, :] = jax.lax.add(dq_acc[at, :], dq)
+
+    if banded:
+        _by_class(j, s_idx, block_q, window, banded, rows,
+                  compute)
+    elif causal:
         # q blocks whose last global row is before this k block's first
         # see none of it — same frontier as the old fori j0, now a
         # skipped grid step; under a window so do the q blocks whose
         # first row is ``window`` or more past this k block's last key
-        live = ((j + 1) * block_q - 1 + q_off
-                >= s_idx * block_k + k_off)
-        if window is not None:
-            live = jnp.logical_and(
-                live, j * block_q + q_off
-                - ((s_idx + 1) * block_k - 1 + k_off) < window)
-        pl.when(live)(compute)
+        pl.when(_tile_live(q_off, k_off, j, s_idx, block_q, block_k,
+                           window))(compute)
     else:
         compute()
 
@@ -583,12 +828,14 @@ def _flash_backward(q, k, v, out, lse, g, g_lse, seq_lens, offsets, seed,
         delta = delta - g_lse.reshape(B * H, Tq).astype(jnp.float32)
     delta = jnp.broadcast_to(delta[..., None], (B * H, Tq, _LSE_LANES))
     args = (lens, seed_arr, off_arr, qr, kr, vr, do, lse, delta)
-    static = dict(causal=causal, scale=scale, rate=rate, masked=masked,
-                  **_window_kw(window))
-
     if fused:
         bq, bk = fused_blocks
-    else:
+    static = dict(causal=causal, scale=scale, rate=rate, masked=masked,
+                  **_window_kw(window),
+                  **_banded_kw(B * H, Tq, Tk, bq, bk, causal, offsets,
+                               masked, rate, window))
+
+    if not fused:
         _kvmap = _stream_kvmap(bq, bk, causal, offsets, window, group)
 
         def qspec(lanes):

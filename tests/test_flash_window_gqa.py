@@ -1,8 +1,11 @@
-"""The flash kernels' window and grouped-query forms, in interpret mode on
-the CPU against the XLA composition: forward, dQ, dK and dV, at a window
-that is no multiple of the block, a window at least as long as the
-sequence, both backward forms; and the kernels of the benchmark's 2048 cell
-lowered as they were before either form existed."""
+"""The flash kernels' window and grouped-query forms and their tile
+classes, in interpret mode on the CPU against the XLA composition: forward,
+dQ, dK and dV, at a window that is no multiple of the block, a window that
+is one (its edge tiles worked in row bands), a window at least as long as
+the sequence, causal tiles of 1024 in four bands, q·k wider than v, both
+backward forms; the classes and their counters at the cells' shapes; and
+the kernels of the benchmark's 2048 cell lowered as they were before
+either form existed."""
 
 import hashlib
 import importlib
@@ -39,26 +42,43 @@ def form(request, monkeypatch):
     assert obs.counter_value("flash.bwd_" + other) == 0
 
 
-# (Tq, block, window, Q heads, K/V heads): a window that crosses block
-# edges at no multiple of the block; one at least as long as the sequence;
-# one narrower than a block; grouped heads with and without a window
+# (Tq, block, window, Q heads, K/V heads, q·k width, v width): a window
+# that crosses block edges at no multiple of the block; one at least as long
+# as the sequence; one narrower than a block; grouped heads with and without
+# a window; tiles of 1024, full and diagonal, the diagonal in four bands of
+# 256 rows, without a window and under one that is no multiple of the block
+# (its edge tiles masked whole); a window of two blocks of 512 (full,
+# diagonal and edge tiles, two bands each) on grouped heads; q·k 192 wide
+# over v 128 in bands
 CASES = [
-    (256, 64, 100, 4, 2),
-    (256, 64, 300, 4, 1),
-    (256, 128, 40, 2, 2),
-    (512, 128, None, 8, 2),
-    (1024, 256, 300, 2, 1),
+    (256, 64, 100, 4, 2, 16, 16),
+    (256, 64, 300, 4, 1, 16, 16),
+    (256, 128, 40, 2, 2, 16, 16),
+    (512, 128, None, 8, 2, 16, 16),
+    (1024, 256, 300, 2, 1, 16, 16),
+    (2048, 1024, None, 2, 2, 16, 16),
+    (2048, 1024, 1500, 2, 2, 16, 16),
+    (2048, 512, 1024, 4, 2, 16, 16),
+    (1024, 512, None, 2, 2, 192, 128),
 ]
 
 
-@pytest.mark.parametrize("T,block,window,hq,hkv", CASES)
-def test_window_and_grouped_heads_match_xla(T, block, window, hq, hkv, form):
+def _case_id(case):
+    shape = "-".join(str(x) for x in case[:5])
+    return shape if case[5:] == (16, 16) else "%s-%d-%d" % ((shape,)
+                                                           + case[5:])
+
+
+@pytest.mark.parametrize("T,block,window,hq,hkv,D,Dv", CASES,
+                         ids=[_case_id(case) for case in CASES])
+def test_window_and_grouped_heads_match_xla(T, block, window, hq, hkv, D, Dv,
+                                            form):
     from paddle_tpu import observability as obs
 
-    B, D = 2, 16
+    B = 2
     q = _rand((B, hq, T, D), 0)
-    k, v = _rand((B, hkv, T, D), 1), _rand((B, hkv, T, D), 2)
-    g = _rand((B, hq, T, D), 3)
+    k, v = _rand((B, hkv, T, D), 1), _rand((B, hkv, T, Dv), 2)
+    g = _rand((B, hq, T, Dv), 3)
 
     def flash(q_, k_, v_):
         return _fa.flash_attention(q_, k_, v_, None, 0, True, None, 0.0,
@@ -79,6 +99,9 @@ def test_window_and_grouped_heads_match_xla(T, block, window, hq, hkv, form):
     assert (obs.counter_value("flash.gqa_calls") > 0) == (hq != hkv)
     assert (obs.counter_value("flash.window_calls") > 0) == (
         window is not None)
+    # a window narrower than the block leaves every tile masked whole
+    assert (obs.counter_value("flash.edge_tiles") > 0) == (
+        window is None or window >= block)
 
 
 def test_window_with_lengths_and_dropout_agrees_across_forms(monkeypatch):
@@ -128,6 +151,55 @@ def test_a_window_visits_fewer_tiles():
             min(int(_fa._window_last_q_block(s, block, block, 1024)), n - 1)
             - (s * block) // block + 1 for s in range(n))
         assert seen == tiles(block, 1024)
+
+
+def test_tiles_are_sorted_into_full_and_banded_classes():
+    """At 4096 positions in tiles of 1024 a causal head's 10 live tiles are
+    6 full and 4 on the diagonal, each of which skips 6 of its 16 band
+    sub-tiles; under a window of 1024 all 7 are banded (the diagonal and
+    the window's edge); at 3072 under a window of 2048, 2 full and 4
+    banded. The counters of one lowered call at the ``joyai_llm_flash``
+    cell's shape ([1, 32, 4096], q·k 192, v 128) say the same over its 32
+    heads, forward and backward alike."""
+    from paddle_tpu import observability as obs
+
+    assert _fa._class_counts(4096, 1024, None) == {"full": 6, "diagonal": 4}
+    assert _fa._class_counts(4096, 1024, 1024) == {"diagonal": 4, "edge": 3}
+    assert _fa._class_counts(3072, 1024, 2048) == {
+        "full": 2, "diagonal": 3, "edge": 1}
+    assert _fa._class_counts(3072, 1024, None) == {"full": 3, "diagonal": 3}
+    # a window that is no multiple of the block leaves its edges masked:
+    # only the diagonal is banded, and no tile is full
+    assert _fa._class_counts(4096, 1024, 1500) == {
+        "diagonal": 4, "masked": 5}
+
+    names = ("flash.full_tiles", "flash.edge_tiles",
+             "flash.edge_subtiles_skipped")
+    obs.set_enabled(True)
+    T = 4096
+    q = jax.ShapeDtypeStruct((1, 32, T, 192), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((1, 32, T, 128), jnp.bfloat16)
+    lse = jax.ShapeDtypeStruct((32, T, 1), jnp.float32)
+    blk = _fa.pick_block(T, jnp.bfloat16)
+    assert blk == 1024
+    before = [obs.counter_value(n) for n in names]
+    jax.make_jaxpr(lambda q_, k_, v_: _fa.flash_attention_raw_lse(
+        q_, k_, v_, None, 0, True, 192 ** -0.5, 0.0, blk, blk, False))(
+            q, q, v)
+    forward = [obs.counter_value(n) - b for n, b in zip(names, before)]
+    assert forward == [32 * 6, 32 * 4, 32 * 4 * 6]
+    jax.make_jaxpr(lambda q_, k_, v_, o_, l_, g_: _fa._flash_backward(
+        q_, k_, v_, o_, l_, g_, None, None, None, 0, True, 192 ** -0.5, 0.0,
+        blk, blk, False))(q, q, v, v, lse, v)
+    assert [obs.counter_value(n) - b for n, b in zip(names, before)] == [
+        2 * x for x in forward]
+    # key lengths leave every tile unclassified, as in the BERT cell
+    lens = jax.ShapeDtypeStruct((1,), jnp.int32)
+    jax.make_jaxpr(lambda q_, k_, v_, l_: _fa.flash_attention_raw_lse(
+        q_, k_, v_, l_, 0, True, 192 ** -0.5, 0.0, blk, blk, False))(
+            q, q, v, lens)
+    assert [obs.counter_value(n) - b for n, b in zip(names, before)] == [
+        2 * x for x in forward]
 
 
 def test_a_window_needs_causal_attention():
