@@ -1,14 +1,14 @@
 """The Mamba-2 mixer's three ops (Dao and Gu, "Transformers are SSMs",
-arXiv:2405.21060), in plain ``jax.numpy`` / ``lax``, and the scan by two
-Pallas kernels of its own on the TPU (which form runs: the end of this
-docstring):
+arXiv:2405.21060), in plain ``jax.numpy`` / ``lax``, and the scan and the
+gated norm by two Pallas kernels each of their own on the TPU (which form
+runs: the end of this docstring):
 
     ssm_conv1d      silu(bias + a causal depthwise convolution over time) of
                     the mixer's x, B and C channels
     ssd_scan        the selective state-space scan in its chunked form, with
                     a backward of its own
     gated_rms_norm  RMSNorm in groups of the channels of y * silu(z), times
-                    a scale
+                    a scale, with a backward of its own on the TPU
 
 One op type each, so that a trace reads their time by type. AMP is each
 lowering's own, as for every op here: activations come in and go out in the
@@ -47,6 +47,13 @@ rounding points, each chunk's ``[Q, Q]`` decays and products and the
 carried state kept in VMEM. Everywhere else (the CPU, float32 programs,
 odd sizes) the plain form above runs; it shares no code with the
 kernels and is their oracle in the tests. No flag chooses.
+
+Likewise the gated norm: where ``kernels/gated_norm.by_kernel`` sees the
+TPU, groups of whole 128 lanes and rows in whole tiles, it and its
+backward are that module's two Pallas kernels over row tiles, whose
+backward keeps X, Z and each (row, group)'s float32 rstd; everywhere else
+(the CPU, the rehearsal's groups of 32) the XLA form runs, and its
+gradient is ``jax.vjp`` of it.
 """
 
 import functools
@@ -56,6 +63,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from paddle_tpu.core.registry import register_op
+from paddle_tpu.kernels import gated_norm as norm_kernel
 from paddle_tpu.kernels import ssd_scan as ssd_kernel
 from paddle_tpu.ops.common import fp32_accum, lowered_into_a_step, single
 from paddle_tpu.ops.nn_ops import _shift
@@ -295,17 +303,36 @@ def ssd_scan(ctx, ins, attrs):
 
 # -- gated_rms_norm ------------------------------------------------------------
 
+def _gated_rms_norm_xla(x, z, scale, groups, eps):
+    """The XLA form: y = x · silu(z) in float32, each group scaled by
+    rsqrt(mean(y²) + eps), times the scale, in X's dtype; the gradient is
+    ``jax.vjp`` of it."""
+    y = fp32_accum(x) * jax.nn.silu(fp32_accum(z))
+    grouped = y.reshape(y.shape[:-1] + (groups, -1))
+    grouped = grouped * lax.rsqrt(jnp.mean(jnp.square(grouped), -1,
+                                           keepdims=True) + eps)
+    return (grouped.reshape(y.shape) * scale).astype(x.dtype)
+
+
 @register_op("gated_rms_norm")
 def gated_rms_norm(ctx, ins, attrs):
     """X, Z [..., I], Scale [I] -> Y = RMS(X * silu(Z)) * Scale, the RMS
     over each of ``groups`` equal groups of the last axis (the gate before
-    the norm), in float32, returned in X's dtype. The gradient is the
-    engine's generic ``jax.vjp`` of this lowering."""
+    the norm), in float32, returned in X's dtype. Where
+    ``kernels/gated_norm.by_kernel`` sees the TPU, groups of whole 128
+    lanes and rows in whole tiles, the norm and its backward are that
+    module's two Pallas kernels over row tiles, the backward keeping X, Z
+    and each group's float32 rstd alone; everywhere else (the CPU, odd
+    sizes) the XLA form, whose gradient is ``jax.vjp`` of it. No flag
+    chooses. ``ssm.gated_norm_kernel`` / ``ssm.gated_norm_xla``
+    (``metrics`` flag) count the ops lowered into a step in each form."""
+    from paddle_tpu import observability as obs
+
     x, z, scale = single(ins, "X"), single(ins, "Z"), single(ins, "Scale")
-    groups = int(attrs["groups"])
-    y = fp32_accum(x) * jax.nn.silu(fp32_accum(z))
-    grouped = y.reshape(y.shape[:-1] + (groups, -1))
-    grouped = grouped * lax.rsqrt(jnp.mean(jnp.square(grouped), -1,
-                                           keepdims=True)
-                                  + attrs.get("epsilon", 1e-6))
-    return {"Y": [(grouped.reshape(y.shape) * scale).astype(x.dtype)]}
+    groups, eps = int(attrs["groups"]), attrs.get("epsilon", 1e-6)
+    by_kernel = norm_kernel.by_kernel(x, z, scale, groups)
+    if lowered_into_a_step(ctx, "gated_rms_norm"):
+        obs.inc("ssm.gated_norm_kernel" if by_kernel
+                else "ssm.gated_norm_xla")
+    norm = norm_kernel.norm if by_kernel else _gated_rms_norm_xla
+    return {"Y": [norm(x, z, scale, groups, eps)]}

@@ -376,7 +376,8 @@ def nemotron():
     names = ("ssm.layers", "ssm.scan_chunks", "moe.relu2_layers",
              "decoder.single_part_layers", "moe.shared_experts",
              "attn.unrotated_layers", "rope.rotations", "moe.router_sigmoid",
-             "moe.bias_updates", "ssm.scan_kernel", "ssm.scan_xla")
+             "moe.bias_updates", "ssm.scan_kernel", "ssm.scan_xla",
+             "ssm.gated_norm_kernel", "ssm.gated_norm_xla")
     obs.set_enabled(True)
     before = {name: obs.counter_value(name) for name in names}
     with fluid.unique_name.guard():
@@ -470,11 +471,14 @@ def test_the_program_counts_what_it_was_built_with(nemotron):
     """3 Mamba layers of 3 chunks a row (64 positions at chunks of 24), 3
     relu2 expert layers with a shared expert each, 7 single-part layers,
     one unrotated attention; on the CPU the three scans in the plain form
-    both ways (6 / 0 with the kernels in the cell's step for the TPU:
-    ``tests/test_tpu_compile.py``)."""
+    both ways and the three gated norms in XLA's (6 / 0 and 3 / 0 with the
+    kernels in the cell's step for the TPU: ``tests/test_tpu_compile.py``;
+    the rehearsal's groups are 32 wide, no lane tile, so even there the
+    norm would keep XLA's form)."""
     assert nemotron["counted"] == {
         "ssm.layers": 3, "ssm.scan_chunks": 9, "ssm.scan_kernel": 0,
-        "ssm.scan_xla": 6, "moe.relu2_layers": 3,
+        "ssm.scan_xla": 6, "ssm.gated_norm_kernel": 0,
+        "ssm.gated_norm_xla": 3, "moe.relu2_layers": 3,
         "decoder.single_part_layers": 7, "moe.shared_experts": 3,
         "attn.unrotated_layers": 1, "rope.rotations": 0,
         "moe.router_sigmoid": 3, "moe.bias_updates": 3}

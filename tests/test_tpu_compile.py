@@ -803,6 +803,45 @@ def test_the_chunked_scan_kernels_compile(one_chip, monkeypatch):
         (a.shape, a.dtype) for a in args[:-1]]
 
 
+# a float32 array of the gated norm's whole [rows, I]: what XLA's form and
+# its autodiff keep and pass between fusions
+NORM_F32 = re.compile(r"f32\[(2,4096|8192),4096\]")
+
+
+def test_the_gated_norm_kernels_compile(one_chip, monkeypatch):
+    """``gated_rms_norm`` at the cell's [2, 4096, 4096] bf16 in 8 groups
+    of 512 where the TPU takes the two kernels: the forward op's call and
+    its replay inside the grad op's ``jax.vjp`` merged, and the backward,
+    two Mosaic calls in all within their VMEM limit, and no float32
+    [rows, I] array left in the optimized HLO (the residuals are X, Z and
+    the [8, 8192] float32 rstd)."""
+    from paddle_tpu.kernels import gated_norm as kernel
+
+    monkeypatch.setattr(kernel, "_on_tpu", lambda: True)
+    x = jax.ShapeDtypeStruct((SSM["rows"], SSM["seq"],
+                              SSM["heads"] * SSM["head_dim"]), jnp.bfloat16,
+                             sharding=one_chip)
+    scale = jax.ShapeDtypeStruct(x.shape[-1:], jnp.float32, sharding=one_chip)
+    assert kernel.by_kernel(x, x, scale, SSM["groups"])
+
+    def norm(*a):
+        return kernel.norm(*a, SSM["groups"], 1e-5)
+
+    def step(x_, z_, s_, g):
+        y = norm(x_, z_, s_)
+        return y, jax.vjp(norm, x_, z_, s_)[1](g)
+
+    hlo = _compile(step, x, x, scale, x)
+    assert hlo.count("tpu_custom_call") == 2
+    assert "gated_norm_fwd" in hlo and "gated_norm_bwd" in hlo
+    assert not NORM_F32.search(hlo), NORM_F32.search(hlo).group()
+    assert "f32[8,8192]" in hlo
+    y, grads = jax.eval_shape(step, x, x, scale, x)
+    assert (y.shape, y.dtype) == (x.shape, x.dtype)
+    assert [(g.shape, g.dtype) for g in grads] == [
+        (a.shape, a.dtype) for a in (x, x, scale)]
+
+
 def _lowered_cell_step(topo, monkeypatch, config, rows, counters):
     """A cell's step (its configuration's own sizes, bf16) lowered for a
     described v5e with the kernels' call sites seeing the TPU -> (the
@@ -816,7 +855,7 @@ def _lowered_cell_step(topo, monkeypatch, config, rows, counters):
     from paddle_tpu.models import decoder_lm
 
     for name in ("flash_attention", "grouped_matmul", "row_permute",
-                 "ssd_scan"):
+                 "ssd_scan", "gated_norm"):
         monkeypatch.setattr(importlib.import_module(
             "paddle_tpu.kernels." + name), "_on_tpu", lambda: True)
     with open(os.path.join(os.path.dirname(os.path.dirname(
@@ -860,13 +899,17 @@ def test_the_cells_step_takes_the_scan_kernels(topo, monkeypatch):
     """The ``nemotron_twotower_30b_a3b`` cell's step lowered for a
     described v5e: its three mixers' scans take the kernels both ways,
     ``ssm.scan_kernel`` / ``ssm.scan_xla`` 6 / 0 (0 / 6 on the CPU:
-    ``tests/test_nemotron.py``)."""
+    ``tests/test_nemotron.py``), and their gated norms too,
+    ``ssm.gated_norm_kernel`` / ``ssm.gated_norm_xla`` 3 / 0 (0 / 3)."""
     text, counted = _lowered_cell_step(
         topo, monkeypatch, "nemotron_twotower_30b_a3b", 2,
-        ("ssm.scan_kernel", "ssm.scan_xla", "flash.split_v_calls"))
+        ("ssm.scan_kernel", "ssm.scan_xla", "ssm.gated_norm_kernel",
+         "ssm.gated_norm_xla", "flash.split_v_calls"))
     assert counted == {"ssm.scan_kernel": 6, "ssm.scan_xla": 0,
+                       "ssm.gated_norm_kernel": 3, "ssm.gated_norm_xla": 0,
                        "flash.split_v_calls": 0}
     assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
+    assert "gated_norm_fwd" in text and "gated_norm_bwd" in text
 
 
 def test_the_latent_attention_step_takes_the_flash_kernels(topo,
