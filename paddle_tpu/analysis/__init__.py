@@ -40,15 +40,8 @@ from paddle_tpu.analysis.transforms import (  # noqa: F401
     transform_passes,
 )
 from paddle_tpu.analysis.memory import (  # noqa: F401
-    DonationPlan,
     LivenessReport,
-    MemoryPlan,
-    RematPlan,
     analyze_liveness,
-    plan_donation,
-    plan_memory,
-    plan_remat,
-    replan_segments,
 )
 from paddle_tpu.analysis.spmd import (  # noqa: F401
     Collective,
@@ -60,15 +53,14 @@ from paddle_tpu.analysis.spmd import (  # noqa: F401
 
 __all__ = [
     "AnalysisContext", "DEFAULT_PASSES", "DiagnosticReport",
-    "DonationPlan", "Finding", "Graph", "LivenessReport", "MemoryPlan",
+    "Finding", "Graph", "LivenessReport",
     "OpNode", "PASS_REGISTRY", "Pass",
-    "RematPlan", "Severity", "TRANSFORM_PIPELINE", "TransformContext",
+    "Severity", "TRANSFORM_PIPELINE", "TransformContext",
     "TransformPass", "TransformReport", "VarNode", "VerificationError",
     "Collective", "SpmdReport", "analyze_spmd", "hlo_collectives",
     "measured_collectives",
     "analyze_liveness", "build_graph", "default_passes",
-    "optimize_program", "plan_donation", "plan_memory",
-    "plan_remat", "register_pass", "replan_segments",
+    "optimize_program", "register_pass",
     "transform_passes", "run_passes",
     "verify_graph", "verify_program",
 ]

@@ -43,8 +43,7 @@ devices needed) and a ``ShardingRules`` table, ``analyze_spmd``
 
 The engine validates the schedule at its mesh cache-miss seam: on the
 first run of a mesh-compiled executable it parses the jitted HLO
-(``hlo_collectives``) and emits ``spmd.prediction_delta`` telemetry —
-the same measured-feedback pattern as ``memory_plan_delta``.
+(``hlo_collectives``) and emits ``spmd.prediction_delta`` telemetry.
 
 Known model limits (reported, not silently wrong): the shard_map-wrapped
 flash-attention dispatch (kernels/flash_attention.py) spans the mesh's
@@ -72,7 +71,8 @@ __all__ = [
     "measured_collectives", "op_flops_bytes",
 ]
 
-# Optimize-role bit (framework.OpRole mirror; see analysis/memory.py).
+# Optimize-role bit (mirrors framework.OpRole, reference:
+# op_proto_maker.h, without the import cycle).
 _ROLE_OPTIMIZE = 0x0002
 
 # Replicated tensors at or above this size, produced from sharded inputs,

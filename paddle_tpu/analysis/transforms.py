@@ -149,8 +149,8 @@ def optimize_program(program_or_desc, level=None, feed_names=None,
         from paddle_tpu import flags
         level = int(flags.get_flag("opt_level"))
     level = int(level)
-    if level > 3:
-        raise ValueError("opt_level %d: valid levels are 0 to 3" % level)
+    if level > 2:
+        raise ValueError("opt_level %d: valid levels are 0 to 2" % level)
     selected = transform_passes(level) if passes is None else list(passes)
     report = TransformReport(level)
     if level <= 0 or not selected:

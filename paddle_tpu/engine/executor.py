@@ -9,6 +9,8 @@ stats) stays resident on device between runs as jax Arrays held by the Scope,
 mirroring how the reference keeps them in device Tensors.
 """
 
+import dataclasses
+
 import numpy as np
 
 import jax
@@ -21,9 +23,53 @@ from paddle_tpu.engine.pipeline import (DeferredFetch, DispatchWindow,
 from paddle_tpu.resilience import faultinject
 
 
+@dataclasses.dataclass(frozen=True)
+class Lowering:
+    """How one executable is lowered, and what rides on that choice."""
+
+    kind: str  # "plain" (lower_block), "remat" or "accumulated"
+    accumulate_steps: int
+    remat_segments: int
+    # ZeRO-1 sharded update and its gradient buckets (plain mesh training
+    # steps only: the scan and remat lowerings keep the replicated update)
+    zero: bool
+    grad_bucket_mb: float
+    # whether the SDC digest covers the parameter gradients: the scan and
+    # remat lowerings cannot fetch them, so there it covers the updated
+    # state only
+    sdc_grads: bool
+
+
+def lowering_for(is_test, accumulate_steps, remat_segments, mesh, sdc):
+    """The one place that decides which block lowering a compile runs and
+    which features it excludes; ``Engine.get_compiled`` keys its cache on
+    the result and ``Engine._compile`` builds from it."""
+    if accumulate_steps > 1 and remat_segments:
+        raise NotImplementedError(
+            "accumulate_steps and remat_segments cannot combine yet; "
+            "pick one memory lever per program")
+    from paddle_tpu import flags
+
+    if accumulate_steps > 1:
+        kind = "accumulated"
+    elif remat_segments:
+        kind = "remat"
+    else:
+        kind = "plain"
+    plain = kind == "plain"
+    zero = bool(plain and mesh is not None and not is_test
+                and flags.get_flag("zero"))
+    return Lowering(
+        kind=kind, accumulate_steps=accumulate_steps,
+        remat_segments=remat_segments, zero=zero,
+        grad_bucket_mb=(float(flags.get_flag("grad_bucket_mb"))
+                        if zero else 0.0),
+        sdc_grads=bool(sdc) and plain)
+
+
 class CompiledBlock:
     def __init__(self, block_program, jitted, mutated_names, readonly_names,
-                 in_shardings=None, memory_plan=None, remat_segments=0):
+                 in_shardings=None):
         self.block_program = block_program
         self.jitted = jitted
         # executions so far: 0 means the next jitted call pays the XLA
@@ -31,10 +77,7 @@ class CompiledBlock:
         # as "compile", later ones as "run"
         self.run_count = 0
         # state vars both read and re-emitted -> donated to XLA (functional
-        # form of the reference's in-place ParamOut/MomentOut updates).
-        # Under an opt-level-3 memory plan this is the plan's donate
-        # subset; held mutated vars ride in readonly_names (the step
-        # still re-emits them by name — grouping only controls donation).
+        # form of the reference's in-place ParamOut/MomentOut updates)
         self.mutated_names = mutated_names
         # state vars only read (e.g. params in a test program) -> not donated
         self.readonly_names = readonly_names
@@ -42,12 +85,6 @@ class CompiledBlock:
         # multi-host run path needs them to build global jax.Arrays from
         # host values (None when compiled without a mesh)
         self.in_shardings = in_shardings
-        # the analysis.memory plan this executable was compiled under
-        # (opt level 3 only) + the remat segment count actually lowered —
-        # the first run compares plan.predicted_peak_bytes against XLA's
-        # measured memory_analysis peak
-        self.memory_plan = memory_plan
-        self.remat_segments = remat_segments
         # SDC sentinel (resilience/sentinel.py): when compiled with
         # sdc=True the jitted step returns one extra uint32[4] digest
         # fetch and grad fetches ride behind the user fetch_list;
@@ -58,17 +95,8 @@ class CompiledBlock:
         # once at the first run (goodput ledger / MFU attribution);
         # None until captured, 0.0 when the backend reports nothing
         self.flops = None
-        # measured-feedback re-planning (analysis/memory.replan_segments):
-        # replanned bounds the loop to ONE re-jit per cache entry;
-        # auto_remat_eligible mirrors the get_compiled auto-remat guard
-        # (no mesh/accumulation/test program/manual segments); _rebuild
-        # re-compiles with a new segment count; mem_budget is the HBM
-        # budget the plan was made against
-        self.replanned = False
-        self.auto_remat_eligible = False
-        self.mem_budget = None
+        # the executable-cache key it is stored under (set by get_compiled)
         self._cache_key = None
-        self._rebuild = None
 
 
 class Engine:
@@ -329,33 +357,11 @@ class Engine:
                     compiled.jitted,
                     (feed_values, mutated, readonly, rng_seed),
                     label="block%d" % block_idx)
-                if compiled.memory_plan is not None and measured:
-                    # every plan is accountable: predicted (liveness /
-                    # remat cost model) vs measured (XLA's
-                    # memory_analysis of the executable that just ran)
-                    predicted = int(
-                        compiled.memory_plan.predicted_peak_bytes)
-                    obs.set_gauge("hbm.plan_predicted_peak_bytes",
-                                  predicted)
-                    obs.event(
-                        "memory_plan_delta",
-                        predicted_bytes=predicted,
-                        measured_bytes=int(measured),
-                        delta_bytes=int(measured) - predicted,
-                        remat_segments=compiled.remat_segments,
-                        donated=len(compiled.mutated_names))
-                    # measured-feedback loop: a miss beyond the
-                    # replan_tolerance re-plans the segment count from
-                    # the realized peak and re-jits once (bounded by
-                    # compiled.replanned); the swapped executable serves
-                    # the NEXT step — this one already ran
-                    self._maybe_replan(compiled, int(measured))
                 spmd_plan = getattr(compiled, "spmd_plan", None)
                 if (mesh is not None and spmd_plan is not None
                         and not spmd_plan.empty
                         and _flags.get_flag("spmd_predict")):
-                    # Collective-schedule analog of memory_plan_delta:
-                    # parse the HLO of the executable that just ran
+                    # Parse the HLO of the executable that just ran
                     # (lower() hits jax's caches — a retrace, not a
                     # second XLA compile) and hold the static prediction
                     # accountable against the partitioner's actual
@@ -580,37 +586,24 @@ class Engine:
             opt_level = int(flags.get_flag("opt_level"))
         else:
             opt_level = int(opt_level)
+        lowering = lowering_for(is_test, accumulate_steps, remat_segments,
+                                mesh, sdc)
         # Mesh-targeted compiles key on the mesh identity (axis
         # names/sizes + device ids) and the sharding-rule table, so the
         # same program compiled for two meshes — or two rule tables —
         # yields two executables; the no-mesh path keys on None and
-        # keeps hitting its existing entry.
+        # keeps hitting its existing entry. ZeRO and its bucket size key
+        # the cache so toggling them never serves a stale executable.
         if mesh is not None:
             from paddle_tpu.parallel.mesh import mesh_signature
 
-            # ZeRO-1 weight-update sharding gate: training-step compiles
-            # on the plain lower_block path only (the scan/remat
-            # lowerings keep the replicated update). Both knobs key the
-            # cache so toggling them never serves a stale executable.
-            zero = (bool(flags.get_flag("zero")) and not is_test
-                    and accumulate_steps <= 1 and not remat_segments)
-            grad_bucket_mb = (float(flags.get_flag("grad_bucket_mb"))
-                              if zero else 0.0)
             mesh_key = (mesh_signature(mesh),
                         shard_rules.signature()
                         if shard_rules is not None else None,
-                        tuple(data_axes), zero, grad_bucket_mb)
+                        tuple(data_axes), lowering.zero,
+                        lowering.grad_bucket_mb)
         else:
-            zero, grad_bucket_mb = False, 0.0
             mesh_key = None
-        # Level-3 plans depend on the HBM budget (device limit × budget
-        # frac), so the budget is part of the key: retuning the budget
-        # never serves a stale plan's executable.
-        mem_budget = None
-        if opt_level >= 3:
-            from paddle_tpu.analysis import memory as memplan
-
-            mem_budget = memplan.hbm_budget_bytes()
         key = (
             program_desc.cached_fingerprint(),
             block_idx,
@@ -625,7 +618,6 @@ class Engine:
             cache_key_extra,
             opt_level,
             mesh_key,
-            mem_budget,
             sdc,
             bool(flags.get_flag("opprof")),
         )
@@ -661,43 +653,6 @@ class Engine:
                     run_desc, _report = optimize_program(
                         program_desc, level=opt_level,
                         feed_names=feed_names, fetch_names=fetch_list)
-                memory_plan, auto_remat = None, 0
-                if opt_level >= 3:
-                    # Memory planning on the POST-transform desc (the
-                    # one that lowers), crash-isolated like every other
-                    # pass: a planner bug degrades to the level-2
-                    # behavior, never takes down the compile.
-                    from paddle_tpu.analysis import memory as memplan
-
-                    try:
-                        with obs.span("memory-plan"):
-                            memory_plan = memplan.plan_memory(
-                                run_desc,
-                                feed_shapes={
-                                    n: tuple(v.shape) for n, v in
-                                    zip(feed_names, feed_values)},
-                                fetch_names=fetch_list,
-                                budget_bytes=mem_budget)
-                    except Exception:
-                        obs.inc("memory.plan_crashes")
-                        memory_plan = None
-                    if (memory_plan is not None and not remat_segments
-                            and accumulate_steps <= 1 and mesh is None
-                            and not is_test):
-                        # auto-remat only where the manual knob would be
-                        # legal: training step, no accumulation scan, no
-                        # mesh (the shard_map'd step keeps its explicit
-                        # knob)
-                        auto_remat = int(memory_plan.remat.n_segments)
-                    if memory_plan is not None and obs.enabled():
-                        obs.event(
-                            "memory_plan",
-                            predicted_peak_bytes=int(
-                                memory_plan.predicted_peak_bytes),
-                            budget_bytes=mem_budget,
-                            remat_segments=auto_remat,
-                            donated=len(memory_plan.donation.donate),
-                            held=len(memory_plan.donation.held))
                 if verify is None:
                     verify = flags.get_flag("verify")
                 if verify:
@@ -717,48 +672,16 @@ class Engine:
                             shard_rules=shard_rules, data_axes=data_axes,
                             raise_on_error=True)
                 with obs.span("lower"):
-                    try:
-                        compiled = self._compile(
-                            run_desc.block(block_idx), feed_names,
-                            fetch_list, is_test, donate_state, mesh=mesh,
-                            feed_values=feed_values,
-                            shard_rules=shard_rules,
-                            data_axes=data_axes, amp=amp,
-                            accumulate_steps=accumulate_steps,
-                            remat_segments=remat_segments or auto_remat,
-                            memory_plan=memory_plan, sdc=sdc,
-                            zero=zero and not auto_remat,
-                            grad_bucket_mb=grad_bucket_mb, name=name,
-                        )
-                    except NotImplementedError:
-                        # the remat lowering statically rejects some
-                        # program shapes (intermediate-grad fetches,
-                        # non-@GRAD optimizer inputs...) — an
-                        # auto-chosen plan falls back to donation-only;
-                        # a user-set knob still raises
-                        if not auto_remat:
-                            raise
-                        obs.inc("memory.autoremat_fallback")
-                        compiled = self._compile(
-                            run_desc.block(block_idx), feed_names,
-                            fetch_list, is_test, donate_state, mesh=mesh,
-                            feed_values=feed_values,
-                            shard_rules=shard_rules,
-                            data_axes=data_axes, amp=amp,
-                            accumulate_steps=accumulate_steps,
-                            remat_segments=remat_segments,
-                            memory_plan=memory_plan, sdc=sdc,
-                            zero=zero, grad_bucket_mb=grad_bucket_mb, name=name,
-                        )
-            # measured-feedback re-planning metadata (_maybe_replan):
-            # eligible exactly where auto-remat was legal, with a rebuild
-            # closure that re-lowers the SAME post-transform desc at a
-            # new segment count — the transform work is not redone
+                    compiled = self._compile(
+                        run_desc.block(block_idx), feed_names, fetch_list,
+                        is_test, donate_state, lowering, mesh=mesh,
+                        feed_values=feed_values, shard_rules=shard_rules,
+                        data_axes=data_axes, amp=amp, sdc=sdc, name=name)
             # Static SPMD plan on the POST-transform desc (mesh compiles
-            # only), crash-isolated like the memory planner: the
-            # predicted collective schedule rides on the executable and
-            # is validated against the jitted HLO on its first run
-            # (spmd.prediction_delta — see _run_block_impl).
+            # only), crash-isolated: the predicted collective schedule
+            # rides on the executable and is validated against the jitted
+            # HLO on its first run (spmd.prediction_delta — see
+            # _run_block_impl).
             compiled.spmd_plan = None
             if mesh is not None:
                 from paddle_tpu.analysis import spmd as spmd_analysis
@@ -774,7 +697,7 @@ class Engine:
                                 n: tuple(v.shape) for n, v in
                                 zip(feed_names, feed_values)},
                             fetch_names=fetch_list,
-                            block_idx=block_idx, zero1=zero)
+                            block_idx=block_idx, zero1=lowering.zero)
                     if obs.enabled() and compiled.spmd_plan is not None:
                         plan = compiled.spmd_plan
                         obs.event(
@@ -787,24 +710,7 @@ class Engine:
                 except Exception:
                     obs.inc("spmd.plan_crashes")
                     compiled.spmd_plan = None
-            compiled.auto_remat_eligible = bool(
-                memory_plan is not None and not remat_segments
-                and accumulate_steps <= 1 and mesh is None and not is_test)
-            compiled.mem_budget = mem_budget
             compiled._cache_key = key
-
-            def _rebuild(new_segments, new_plan, _desc=run_desc):
-                return self._compile(
-                    _desc.block(block_idx), feed_names, fetch_list,
-                    is_test, donate_state, mesh=mesh,
-                    feed_values=feed_values, shard_rules=shard_rules,
-                    data_axes=data_axes, amp=amp,
-                    accumulate_steps=accumulate_steps,
-                    remat_segments=new_segments, memory_plan=new_plan,
-                    sdc=sdc, zero=zero and not new_segments,
-                    grad_bucket_mb=grad_bucket_mb, name=name)
-
-            compiled._rebuild = _rebuild
             # the cache-miss build (trace/transform/verify/lower) is
             # wall the step did not spend computing — charge it now so
             # the step-boundary mark books only the remainder as compute
@@ -817,70 +723,6 @@ class Engine:
             self._cache.move_to_end(key)
             obs.inc("engine.cache_hit")
         return compiled
-
-    def _maybe_replan(self, compiled, measured_bytes):
-        """Close the memory_plan_delta loop: when XLA's realized peak
-        misses the plan's prediction beyond PADDLE_TPU_REPLAN_TOLERANCE,
-        re-run the segment search with the cost model rescaled by the
-        measurement (analysis/memory.replan_segments) and re-jit ONCE,
-        swapping the cache entry so the next step runs the corrected
-        executable. Bounded: each entry re-plans at most once, and the
-        replacement is itself marked re-planned."""
-        from paddle_tpu import flags
-        from paddle_tpu.analysis import memory as memplan
-
-        tol = float(flags.get_flag("replan_tolerance"))
-        plan = compiled.memory_plan
-        if (tol <= 0 or compiled.replanned or plan is None
-                or measured_bytes <= 0 or not compiled.mem_budget
-                or not compiled.auto_remat_eligible
-                or compiled._rebuild is None):
-            return
-        compiled.replanned = True  # one attempt per entry, hit or miss
-        predicted = int(plan.predicted_peak_bytes)
-        if predicted > 0 and abs(measured_bytes - predicted) <= tol * predicted:
-            return
-        new_remat = memplan.replan_segments(
-            plan, measured_bytes, compiled.mem_budget)
-        if int(new_remat.n_segments) == int(compiled.remat_segments):
-            if obs.enabled():
-                obs.event("memory_replan_skipped",
-                          measured_bytes=int(measured_bytes),
-                          predicted_bytes=predicted,
-                          remat_segments=int(compiled.remat_segments),
-                          reason=new_remat.reason)
-            return
-        # never swap under in-flight deferred steps: they hold the old
-        # executable's donated buffers, so the window drains first
-        self.window.sync()
-        new_plan = memplan.MemoryPlan(plan.liveness, plan.donation,
-                                      new_remat)
-        try:
-            with obs.span("replan"), obs.time_block("engine.replan_ms"):
-                fresh = compiled._rebuild(int(new_remat.n_segments),
-                                          new_plan)
-        except NotImplementedError:
-            # same static rejections as the auto-remat path: keep the
-            # executable we measured
-            obs.inc("memory.replan_fallback")
-            return
-        fresh.replanned = True
-        fresh.auto_remat_eligible = False
-        fresh.mem_budget = compiled.mem_budget
-        fresh._cache_key = compiled._cache_key
-        fresh._rebuild = compiled._rebuild
-        key = compiled._cache_key
-        if self._cache.get(key) is compiled:
-            self._cache[key] = fresh
-        obs.inc("memory.replan")
-        if obs.enabled():
-            obs.event("memory_replan",
-                      measured_bytes=int(measured_bytes),
-                      predicted_bytes=predicted,
-                      old_segments=int(compiled.remat_segments),
-                      new_segments=int(new_remat.n_segments),
-                      est_peak_bytes=int(new_remat.est_peak_bytes),
-                      reason=new_remat.reason)
 
     @staticmethod
     def _state_value(scope, name):
@@ -895,16 +737,10 @@ class Engine:
 
     # -- internals ---------------------------------------------------------
     def _compile(self, block, feed_names, fetch_list, is_test, donate_state,
-                 mesh=None, feed_values=None, shard_rules=None,
-                 data_axes=("dp",), amp=False, accumulate_steps=1,
-                 remat_segments=0, memory_plan=None, sdc=False,
-                 zero=False, grad_bucket_mb=0.0, name="pt_block"):
-        if accumulate_steps > 1 and remat_segments:
-            raise NotImplementedError(
-                "accumulate_steps and remat_segments cannot combine yet; "
-                "pick one memory lever per program")
+                 lowering, mesh=None, feed_values=None, shard_rules=None,
+                 data_axes=("dp",), amp=False, sdc=False, name="pt_block"):
         extra_live = ()
-        if remat_segments:
+        if lowering.kind == "remat":
             # keep the loss-computing ops alive: the remat lowering
             # differentiates the loss VALUE, which the explicit grad
             # chain never reads (its seed is a fill op), so plain DCE
@@ -915,12 +751,10 @@ class Engine:
                 if op.attrs.get("__is_loss_grad__")
                 for n in op.output_arg_names() if n.endswith("@GRAD"))
         sdc_grad_names = []
-        if sdc and accumulate_steps <= 1 and not remat_segments:
+        if lowering.sdc_grads:
             # Fetch the parameter gradients alongside the user fetches so
             # the in-graph digest covers them AND the seam can recompute
-            # the same digest eagerly over the materialized arrays. Under
-            # the scan/remat lowerings grad fetches are not supported, so
-            # the digest degrades to updated-params-only there.
+            # the same digest eagerly over the materialized arrays.
             seen = set(fetch_list)
             for op in block.ops:
                 for n in op.output_arg_names():
@@ -934,13 +768,12 @@ class Engine:
         bp = BlockProgram(block, feed_names,
                           list(fetch_list) + sdc_grad_names, (),
                           extra_live_vars=extra_live)
-        # ZeRO-1 plan (mesh training compiles on the plain path only):
-        # which params' updates shard over the data axes, which slot
-        # vars live partitioned, and where the grads get constrained so
-        # the partitioner reduce-scatters instead of all-reducing
+        # ZeRO-1 plan: which params' updates shard over the data axes,
+        # which slot vars live partitioned, and where the grads get
+        # constrained so the partitioner reduce-scatters instead of
+        # all-reducing
         zplan = None
-        if (zero and mesh is not None and not is_test
-                and accumulate_steps <= 1 and not remat_segments):
+        if lowering.zero:
             from paddle_tpu.parallel.sharding import zero1_plan
 
             zplan = zero1_plan(block, mesh.shape, data_axes=data_axes,
@@ -951,7 +784,7 @@ class Engine:
                 obs.event("zero1_plan",
                           params=len(zplan.param_specs),
                           slots=len(zplan.slot_specs),
-                          bucket_mb=float(grad_bucket_mb))
+                          bucket_mb=lowering.grad_bucket_mb)
         # opprof provenance collection: a dict the lowering fills at jit
         # trace time (tag -> OpDesc) — lazily, on the wrapped fn's first
         # trace, so the recorded tags always match exactly what was
@@ -960,18 +793,18 @@ class Engine:
         from paddle_tpu import flags as _flags
 
         prov = {} if _flags.get_flag("opprof") else None
-        if accumulate_steps > 1:
+        if lowering.kind == "accumulated":
             from paddle_tpu.engine.lowering import lower_block_accumulated
 
             fn = lower_block_accumulated(
-                bp, accumulate_steps, is_test=is_test, executor=self,
-                amp=amp, prov=prov)
-        elif remat_segments:
+                bp, lowering.accumulate_steps, is_test=is_test,
+                executor=self, amp=amp, prov=prov)
+        elif lowering.kind == "remat":
             from paddle_tpu.engine.lowering import lower_block_remat
 
             fn = lower_block_remat(
-                bp, remat_segments, is_test=is_test, executor=self,
-                amp=amp, prov=prov)
+                bp, lowering.remat_segments, is_test=is_test,
+                executor=self, amp=amp, prov=prov)
         else:
             grad_sh = None
             if zplan is not None:
@@ -982,23 +815,12 @@ class Engine:
             fn = lower_block(
                 bp, is_test=is_test, executor=self, amp=amp,
                 grad_shardings=grad_sh,
-                grad_bucket_bytes=int(float(grad_bucket_mb) * 2 ** 20),
+                grad_bucket_bytes=int(lowering.grad_bucket_mb * 2 ** 20),
                 prov=prov)
 
         out_set = set(bp.state_out_names)
         mutated = [n for n in bp.state_in_names if n in out_set]
         readonly = [n for n in bp.state_in_names if n not in out_set]
-        if memory_plan is not None and memory_plan.donation is not None:
-            # The donation plan's safety filter (analysis/memory.py
-            # plan_donation): mutated vars it held — fetched names,
-            # non-tensor kinds, sub-block reads — move to the undonated
-            # group. The step still re-emits them by name; only the
-            # donate_argnums grouping changes.
-            allow = memory_plan.donation.donate
-            held = [n for n in mutated if n not in allow]
-            if held:
-                mutated = [n for n in mutated if n in allow]
-                readonly = readonly + held
         mutated_idx = {n: i for i, n in enumerate(mutated)}
         readonly_idx = {n: i for i, n in enumerate(readonly)}
 
@@ -1111,8 +933,7 @@ class Engine:
         in_sh = (tuple(jit_kwargs["in_shardings"][:3])
                  if "in_shardings" in jit_kwargs else None)
         cb = CompiledBlock(bp, jitted, mutated, readonly,
-                           in_shardings=in_sh, memory_plan=memory_plan,
-                           remat_segments=remat_segments)
+                           in_shardings=in_sh)
         cb.name = wrapped.__name__
         cb.provenance = prov
         cb.opprof_note = None

@@ -36,28 +36,16 @@ DEFS = {
         "the engine's cache-miss seam (analysis/transforms.py "
         "optimize_program): 0 = off, 1 = attention-pattern rewrite to "
         "the fused flash-attention op, 2 = + elementwise+activation "
-        "fusion, constant folding, and CSE, 3 = + memory planning "
-        "(analysis/memory.py): liveness-driven state donation and "
-        "automatic rematerialization under the HBM budget "
-        "(PADDLE_TPU_HBM_BUDGET_FRAC); a level above 3 raises "
+        "fusion, constant folding, and CSE; a level above 2 raises "
         "ValueError. Rewrites operate on a clone; the program desc is "
         "never mutated."),
-    "replan_tolerance": (
-        float, 0.0,
-        "Measured-feedback memory re-planning: when the realized XLA "
-        "peak (memory_plan_delta telemetry, first run of a planned "
-        "executable) misses the prediction by more than this relative "
-        "tolerance, re-plan the remat segment count from the measured "
-        "peak and re-jit once (bounded; counted in memory.replan). "
-        "Requires PADDLE_TPU_METRICS=1. <=0 disables."),
     "spmd_predict": (
         bool, False,
         "Validate the static SPMD collective schedule "
         "(analysis/spmd.py) against the compiled executable on the "
         "first run of every mesh-compiled block: parse the jitted HLO, "
         "compare predicted psum/all-gather counts and payload bytes, "
-        "and emit spmd.prediction_delta telemetry — the collective-"
-        "schedule analog of memory_plan_delta. Requires "
+        "and emit spmd.prediction_delta telemetry. Requires "
         "PADDLE_TPU_METRICS=1; no-op without a mesh."),
     "zero": (
         bool, False,
@@ -85,16 +73,6 @@ DEFS = {
         "are unchanged — only scheduling freedom moves — so "
         "spmd.prediction_delta stays exact at every bucket size. "
         "<=0 = one unbucketed schedule (XLA's default placement)."),
-    "hbm_budget_frac": (
-        float, 0.9,
-        "Fraction of device memory (observability.memory."
-        "device_memory_limit — allocator bytes_limit, overridable via "
-        "PADDLE_TPU_DEVICE_MEMORY_BYTES) the opt-level-3 memory planner "
-        "budgets a step against: when the liveness peak estimate "
-        "exceeds budget, automatic rematerialization picks the "
-        "smallest jax.checkpoint segment count that fits. <=0 or an "
-        "unknowable device limit disables auto-remat (donation "
-        "planning still runs)."),
     "dispatch_steps": (
         int, 1,
         "Depth of the engine's async dispatch window "

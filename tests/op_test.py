@@ -102,37 +102,41 @@ class OpTest:
             gname = grad_input_name + "@GRAD"
             (analytic,) = exe.run(main, feed=feed, fetch_list=[gname])
 
-        # numeric gradient of mean(op(x)) wrt the named input
+        # numeric gradient of mean(op(x)) wrt the named input: one
+        # forward-only program and one executor, compiled once; each
+        # evaluation changes only the fed value of the perturbed input
+        main2 = Program()
+        startup2 = Program()
+        feed2 = {}
+        with program_guard(main2, startup2):
+            block = main2.global_block()
+            in_vars2 = {}
+            for slot, arrs in inputs.items():
+                items = arrs if isinstance(arrs, list) else [
+                    (slot.lower(), arrs)
+                ]
+                names = []
+                for name, arr in items:
+                    arr = np.asarray(arr)
+                    block.create_var(
+                        name=name, shape=list(arr.shape),
+                        dtype=convert_np_dtype_to_dtype_(arr.dtype),
+                    )
+                    feed2[name] = arr
+                    names.append(name)
+                in_vars2[slot] = names
+            block.create_var(name="op_out", shape=None, dtype="float32")
+            block.append_op(
+                type=op_type, inputs=in_vars2,
+                outputs={output_slot: ["op_out"]}, attrs=attrs or {},
+            )
+        exe2 = fluid.Executor(fluid.CPUPlace())
+        x_orig = feed2[grad_input_name]
+
         def f(x_flat):
-            main2 = Program()
-            startup2 = Program()
-            with program_guard(main2, startup2):
-                block = main2.global_block()
-                feed2 = {}
-                in_vars2 = {}
-                for slot, arrs in inputs.items():
-                    items = arrs if isinstance(arrs, list) else [
-                        (slot.lower(), arrs)
-                    ]
-                    names = []
-                    for name, arr in items:
-                        arr = np.asarray(arr)
-                        if name == grad_input_name:
-                            arr = x_flat.reshape(arr.shape).astype(arr.dtype)
-                        block.create_var(
-                            name=name, shape=list(arr.shape),
-                            dtype=convert_np_dtype_to_dtype_(arr.dtype),
-                        )
-                        feed2[name] = arr
-                        names.append(name)
-                    in_vars2[slot] = names
-                block.create_var(name="op_out", shape=None, dtype="float32")
-                block.append_op(
-                    type=op_type, inputs=in_vars2,
-                    outputs={output_slot: ["op_out"]}, attrs=attrs or {},
-                )
-                exe2 = fluid.Executor(fluid.CPUPlace())
-                (val,) = exe2.run(main2, feed=feed2, fetch_list=["op_out"])
+            feed2[grad_input_name] = x_flat.reshape(x_orig.shape).astype(
+                x_orig.dtype)
+            (val,) = exe2.run(main2, feed=feed2, fetch_list=["op_out"])
             return float(np.mean(val))
 
         base = None

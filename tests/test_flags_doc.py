@@ -37,7 +37,16 @@ def test_drift_is_detected(tmp_path):
 def test_layout_flags_are_gone():
     # the registry is what the README table and --flags are checked
     # against: a removed flag is unknown to it, not silently ignored
-    assert len(flags.DEFS) == 59
+    assert len(flags.DEFS) == 57
     assert not [name for name in flags.DEFS if "layout" in name]
     with pytest.raises(KeyError):
         flags.set_flags({"layout": "nhwc"})
+
+
+def test_memory_planning_flags_are_gone():
+    # opt level 3's budget and re-plan tolerance went with the planner
+    assert len(flags.DEFS) == 57
+    assert "hbm_budget_frac" not in flags.DEFS
+    assert "replan_tolerance" not in flags.DEFS
+    with pytest.raises(KeyError):
+        flags.set_flags({"replan_tolerance": 0.25})

@@ -1,35 +1,10 @@
-"""Memory-planning suite (analysis/memory.py + the engine's opt-level-3
-seam): liveness intervals/peak on known toy programs, the
-donation-safety property (a donated buffer never aliases a live fetch),
-and opt-2 vs opt-3 loss parity — auto-remat forced via a tiny
-PADDLE_TPU_DEVICE_MEMORY_BYTES budget — on bert/resnet, including under
-a 1-device mesh."""
-
-import numpy as np
-import pytest
-
-import jax
+"""Liveness analysis (analysis/memory.py): intervals and the peak on
+known toy programs, and persistable state pinned for the whole program."""
 
 import paddle_tpu.fluid as fluid
-from paddle_tpu import flags, models, parallel
-from paddle_tpu.analysis import build_graph
-from paddle_tpu.analysis.memory import (
-    RematPlan,
-    analyze_liveness,
-    plan_donation,
-    plan_memory,
-    plan_remat,
-    replan_segments,
-)
+from paddle_tpu import models
+from paddle_tpu.analysis.memory import analyze_liveness
 from paddle_tpu.framework import Program, program_guard
-
-
-@pytest.fixture(autouse=True)
-def _restore_flags():
-    yield
-    for name in ("opt_level", "device_memory_bytes", "hbm_budget_frac",
-                 "replan_tolerance", "metrics", "dispatch_steps"):
-        flags.reset_flag(name)
 
 
 # -- liveness units ---------------------------------------------------------
@@ -87,376 +62,3 @@ def test_liveness_persistable_pinned_whole_program():
              if n.endswith("@GRAD") and not rep.intervals[n].persistable]
     assert grads
     assert any(rep.intervals[g].start > 0 for g in grads)
-
-
-# -- donation safety --------------------------------------------------------
-def _mlp():
-    main, startup = Program(), Program()
-    with program_guard(main, startup):
-        x = fluid.layers.data(name="x", shape=[12], dtype="float32")
-        y = fluid.layers.data(name="y", shape=[1], dtype="int64")
-        h = fluid.layers.fc(input=x, size=16, act="relu",
-                            param_attr=fluid.ParamAttr(name="w1"))
-        pred = fluid.layers.fc(input=h, size=4,
-                               param_attr=fluid.ParamAttr(name="w2"))
-        loss = fluid.layers.mean(fluid.layers.softmax_with_cross_entropy(
-            logits=pred, label=y))
-        fluid.optimizer.Adam(learning_rate=0.05).minimize(loss)
-    return main, startup, loss
-
-
-def _mlp_feed(rng, batch=16):
-    return {"x": rng.randn(batch, 12).astype(np.float32),
-            "y": rng.randint(0, 4, (batch, 1)).astype(np.int64)}
-
-
-def test_donation_never_aliases_a_live_fetch():
-    """The safety property: any name in the fetch list is HELD, so a
-    donated buffer can never be reused for a user-visible result."""
-    main, startup, loss = _mlp()
-    plan = plan_memory(main.desc,
-                       feed_shapes={"x": (16, 12), "y": (16, 1)},
-                       fetch_names=[loss.name, "w1"])
-    assert not (plan.donation.donate & {loss.name, "w1"})
-    assert "w1" in plan.donation.held
-    assert "fetched" in plan.donation.held["w1"]
-    # everything donated is genuinely mutated state (read AND re-emitted)
-    graph = build_graph(main.desc)
-    for name in plan.donation.donate:
-        v = graph.var(0, name)
-        assert v is not None and v.persistable
-
-
-def test_donation_plan_threads_into_the_engine():
-    """At opt 3 the compiled executable's donated group excludes fetched
-    state, and fetching that state returns correct values step over step
-    (parity with opt 2)."""
-    def run(opt_level):
-        main, startup, loss = _mlp()
-        flags.set_flags({"opt_level": opt_level})
-        exe = fluid.Executor(fluid.CPUPlace())
-        scope = fluid.Scope()
-        rng = np.random.RandomState(3)
-        out = []
-        with fluid.scope_guard(scope):
-            exe.run(startup)
-            for _ in range(4):
-                l, w = exe.run(main, feed=_mlp_feed(rng),
-                               fetch_list=[loss, "w1"])
-                out.append((float(np.asarray(l).reshape(-1)[0]),
-                            np.asarray(w)))
-        return out, exe
-
-    out2, _ = run(2)
-    out3, exe3 = run(3)
-    for (l2, w2), (l3, w3) in zip(out2, out3):
-        np.testing.assert_allclose(l3, l2, rtol=1e-5, atol=1e-6)
-        np.testing.assert_allclose(w3, w2, rtol=1e-5, atol=1e-6)
-    compiled = [c for c in exe3.engine._cache.values()
-                if c.memory_plan is not None
-                and "w1" in c.block_program.state_in_names]
-    assert compiled, "opt 3 did not attach a plan to the training step"
-    for c in compiled:
-        assert "w1" not in c.mutated_names  # fetched -> held, not donated
-        assert "w1" in c.readonly_names
-        # ... but the step still re-emits it
-        assert "w1" in c.block_program.state_out_names
-
-
-def test_remat_plan_budget_policy():
-    main, startup, loss = _mlp()
-    graph = build_graph(main.desc)
-    liveness = analyze_liveness(graph,
-                                feed_shapes={"x": (64, 12), "y": (64, 1)})
-    # generous budget: no remat
-    none = plan_remat(graph, liveness, budget_bytes=1 << 40)
-    assert none.n_segments == 0 and "fits" in none.reason
-    # no budget: no remat
-    off = plan_remat(graph, liveness, budget_bytes=None)
-    assert off.n_segments == 0
-    # tight budget: remat fires with a power-of-two segment count and a
-    # peak estimate no worse than the unplanned peak
-    tight = plan_remat(graph, liveness, budget_bytes=liveness.peak_bytes // 2)
-    assert tight.n_segments in (2, 4, 8, 16, 32)
-    assert tight.est_peak_bytes <= liveness.peak_bytes
-    assert tight.activation_bytes > 0
-    # inference program: never
-    main_t, _ = Program(), None
-    with program_guard(main_t, Program()):
-        x = fluid.layers.data(name="x", shape=[4], dtype="float32")
-        fluid.layers.fc(x, size=2)
-    g_t = build_graph(main_t.desc)
-    r_t = plan_remat(g_t, analyze_liveness(g_t), budget_bytes=1)
-    assert r_t.n_segments == 0
-
-
-# -- opt2 vs opt3 parity ----------------------------------------------------
-def _train_model(build, feed_fn, opt_level, steps=3, device_bytes=None,
-                 mesh=None, fetch_extra=()):
-    flags.set_flags({"opt_level": opt_level})
-    if device_bytes is not None:
-        flags.set_flags({"device_memory_bytes": device_bytes})
-    try:
-        main, startup, h = build()
-        exe = fluid.Executor(fluid.CPUPlace())
-        scope = fluid.Scope()
-        rng = np.random.RandomState(0)
-        losses = []
-        with fluid.scope_guard(scope):
-            exe.run(startup)
-            for _ in range(steps):
-                vals = exe.run(main, feed=feed_fn(rng),
-                               fetch_list=[h["loss"]] + list(fetch_extra),
-                               mesh=mesh)
-                losses.append(float(np.asarray(vals[0]).reshape(-1)[0]))
-        remats = [c.remat_segments for c in exe.engine._cache.values()]
-        return losses, remats
-    finally:
-        flags.reset_flag("opt_level")
-        if device_bytes is not None:
-            flags.reset_flag("device_memory_bytes")
-
-
-def _bert_tiny():
-    main, startup, h = models.bert.get_model(
-        batch_size=2, seq_len=32, vocab_size=128, d_model=32, n_layers=2,
-        n_heads=2, d_inner=64, dropout=0.0, max_position=64,
-        use_fused_attention=True)
-    return main, startup, h
-
-
-def _bert_feed(rng):
-    return models.bert.make_fake_batch(2, 32, 128, rng=rng)
-
-
-def _resnet_tiny():
-    main, startup, h = models.resnet.get_model(batch_size=4,
-                                               dataset="cifar10", depth=20)
-    return main, startup, h
-
-
-def _resnet_feed(rng):
-    return {"img": rng.randn(4, 3, 32, 32).astype(np.float32),
-            "label": rng.randint(0, 10, (4, 1)).astype(np.int64)}
-
-
-@pytest.mark.parametrize("build,feed_fn", [
-    (_bert_tiny, _bert_feed),
-    (_resnet_tiny, _resnet_feed),
-], ids=["bert", "resnet"])
-def test_opt3_loss_parity_with_auto_remat(build, feed_fn):
-    """A 2 MiB device budget forces the planner's auto-remat; the opt-3
-    trajectory must match opt 2 step for step."""
-    l2, _ = _train_model(build, feed_fn, 2)
-    l3, remats = _train_model(build, feed_fn, 3, device_bytes=2 << 20)
-    assert any(r > 0 for r in remats), \
-        "auto-remat did not fire under the tiny budget"
-    np.testing.assert_allclose(l3, l2, rtol=1e-4, atol=1e-5)
-
-
-@pytest.mark.parametrize("build,feed_fn", [
-    (_bert_tiny, _bert_feed),
-    (_resnet_tiny, _resnet_feed),
-], ids=["bert", "resnet"])
-def test_opt3_loss_parity_donation_only(build, feed_fn):
-    """With no budget pressure opt 3 is donation-planning only — still
-    parity."""
-    l2, _ = _train_model(build, feed_fn, 2)
-    l3, remats = _train_model(build, feed_fn, 3)
-    assert all(r == 0 for r in remats)
-    np.testing.assert_allclose(l3, l2, rtol=1e-4, atol=1e-5)
-
-
-@pytest.mark.multichip
-def test_opt3_parity_under_1device_mesh():
-    """Donation planning composes with the GSPMD path: a 1-device mesh at
-    opt 3 matches the no-mesh opt-2 trajectory (the PR 6 bit-identity
-    contract extended to the planned executable)."""
-    mesh = parallel.make_mesh({"dp": 1}, devices=jax.devices()[:1])
-    l2, _ = _train_model(_mlp_h, _mlp_feed, 2)
-    l3m, remats = _train_model(_mlp_h, _mlp_feed, 3, mesh=mesh)
-    # auto-remat stays off under a mesh; donation still applies
-    assert all(r == 0 for r in remats)
-    np.testing.assert_allclose(l3m, l2, rtol=1e-5, atol=1e-6)
-
-
-def _mlp_h():
-    main, startup, loss = _mlp()
-    return main, startup, {"loss": loss}
-
-
-def test_opt3_passes_post_pass_verification():
-    """Every planned program re-verifies: verify=True at opt 3 (the
-    verifier sees the post-transform desc the plan was made for)."""
-    main, startup, loss = _mlp()
-    flags.set_flags({"opt_level": 3, "device_memory_bytes": 1 << 20})
-    exe = fluid.Executor(fluid.CPUPlace())
-    scope = fluid.Scope()
-    with fluid.scope_guard(scope):
-        exe.run(startup)
-        (l,) = exe.run(main, feed=_mlp_feed(np.random.RandomState(0)),
-                       fetch_list=[loss], verify=True)
-    assert np.isfinite(float(np.asarray(l).reshape(-1)[0]))
-
-
-# -- measured-feedback re-planning (engine._maybe_replan) -------------------
-def _seed_measurement(monkeypatch, value):
-    """Make XLA's post-compile memory measurement 'observe' a fixed
-    peak: the engine reads it through obs.memory.record_compile_memory
-    at the once-per-executable seam, so patching the module attr seeds
-    a predicted-vs-measured miss without touching the engine."""
-    from paddle_tpu import observability as obs
-
-    monkeypatch.setattr(obs.memory, "record_compile_memory",
-                        lambda *a, **k: int(value))
-
-
-def _replan_train(steps=4, dispatch_steps=None):
-    np.random.seed(11)
-    main, startup, h = _resnet_tiny()
-    exe = fluid.Executor(fluid.CPUPlace())
-    scope = fluid.Scope()
-    rng = np.random.RandomState(0)
-    losses = []
-    with fluid.scope_guard(scope):
-        exe.run(startup)
-        for _ in range(steps):
-            (l,) = exe.run(main, feed=_resnet_feed(rng),
-                           fetch_list=[h["loss"]],
-                           dispatch_steps=dispatch_steps)
-            losses.append(float(np.asarray(l).reshape(-1)[0]))
-        exe.sync()
-    return losses, exe
-
-
-def test_replan_segments_rescales_cost_model():
-    """Pure cost-model unit (no jit): the measurement rescales
-    est(n) = base + 2A/n multiplicatively, so an overcounting static
-    model collapses to 0 segments, a confirming measurement keeps the
-    count, and an undercounting one escalates it (capped)."""
-    # static model: A = 1 MiB activations, 4 segments, predicted peak
-    # base + ceil(2A/4) = 1.5 MiB over a 2 MiB budget
-    A = 1 << 20
-    plan = RematPlan(4, A, (1 << 20) + (2 * A + 3) // 4, [], "unit")
-    # measured far below prediction: unsegmented peak fits -> 0 segments
-    low = replan_segments(plan, 64 << 10, 2 << 20)
-    assert low.n_segments == 0
-    assert low.est_peak_bytes <= 2 << 20
-    # measured == predicted against the budget the plan was made for:
-    # the search re-lands on the same count (caller skips the re-jit)
-    same = replan_segments(plan, plan.est_peak_bytes, plan.est_peak_bytes)
-    assert same.n_segments == plan.n_segments
-    # measured far above: more segments, capped at max_segments
-    high = replan_segments(plan, 64 << 20, 1 << 20, max_segments=8)
-    assert plan.n_segments < high.n_segments <= 8
-    # degenerate inputs fall back to the existing plan, never crash
-    assert replan_segments(plan, 0, 1 << 20).n_segments == 4
-    assert replan_segments(plan, 1 << 20, 0).n_segments == 4
-
-
-@pytest.mark.slow
-def test_replan_closes_seeded_miss_with_one_rejit(monkeypatch):
-    """The 2 MiB budget makes auto-remat segment the step; a seeded
-    measurement far BELOW prediction (the static model overcounted)
-    must re-plan to the unsegmented executable: exactly one re-jit,
-    cache entry swapped, memory.replan telemetry, losses still finite
-    and on the opt-2 trajectory."""
-    from paddle_tpu import observability as obs
-
-    flags.set_flags({"opt_level": 3, "device_memory_bytes": 2 << 20,
-                     "metrics": True, "replan_tolerance": 0.25})
-    _seed_measurement(monkeypatch, 64 << 10)  # 64 KiB: fits any budget
-    c0 = obs.counter_value("memory.replan")
-    losses, exe = _replan_train()
-    assert obs.counter_value("memory.replan") == c0 + 1
-    entries = list(exe.engine._cache.values())
-    planned = [c for c in entries if c.memory_plan is not None
-               and "img" in c.block_program.feed_names]
-    assert planned
-    # the remat executable was REPLACED: the measurement said the
-    # activations fit, so no segment survives in the cache
-    assert all(c.remat_segments == 0 for c in planned)
-    assert all(c.replanned for c in planned)
-    assert all(np.isfinite(v) for v in losses)
-    # parity with the unplanned trajectory: the swap changed memory
-    # strategy, not math
-    flags.reset_flag("replan_tolerance")
-    l2, _ = _train_model(_resnet_tiny, _resnet_feed, 2, steps=4)
-    np.testing.assert_allclose(losses, l2, rtol=1e-4, atol=1e-5)
-
-
-@pytest.mark.slow
-def test_replan_is_bounded_to_one_attempt(monkeypatch):
-    """The fresh executable is itself marked re-planned: its own
-    first-run measurement (still seeded to miss) must NOT trigger a
-    second re-jit, however many steps follow."""
-    from paddle_tpu import observability as obs
-
-    flags.set_flags({"opt_level": 3, "device_memory_bytes": 2 << 20,
-                     "metrics": True, "replan_tolerance": 0.25})
-    _seed_measurement(monkeypatch, 64 << 10)
-    c0 = obs.counter_value("memory.replan")
-    losses, exe = _replan_train(steps=6)
-    assert obs.counter_value("memory.replan") == c0 + 1
-    assert len(losses) == 6
-
-
-@pytest.mark.slow
-def test_replan_respects_default_tolerance_off(monkeypatch):
-    """replan_tolerance defaults to 0 = feedback loop disarmed: the
-    same seeded miss changes nothing."""
-    from paddle_tpu import observability as obs
-
-    flags.set_flags({"opt_level": 3, "device_memory_bytes": 2 << 20,
-                     "metrics": True})
-    _seed_measurement(monkeypatch, 64 << 10)
-    c0 = obs.counter_value("memory.replan")
-    _, exe = _replan_train(steps=2)
-    assert obs.counter_value("memory.replan") == c0
-    planned = [c for c in exe.engine._cache.values()
-               if c.memory_plan is not None]
-    assert any(c.remat_segments > 0 for c in planned)  # remat kept
-
-
-@pytest.mark.slow
-def test_replan_drains_dispatch_window_before_swap(monkeypatch):
-    """Under dispatch_steps=4 the swap may not happen beneath in-flight
-    steps (they hold the old executable's donated buffers): the engine
-    must drain via window.sync first, and the windowed trajectory stays
-    bit-exact with the depth-1 one (same executables, same rng)."""
-    from paddle_tpu import observability as obs
-
-    flags.set_flags({"opt_level": 3, "device_memory_bytes": 2 << 20,
-                     "metrics": True, "replan_tolerance": 0.25})
-    _seed_measurement(monkeypatch, 64 << 10)
-    l1, _ = _replan_train(steps=4, dispatch_steps=1)
-
-    _seed_measurement(monkeypatch, 64 << 10)
-    np.random.seed(11)
-    main, startup, h = _resnet_tiny()
-    exe = fluid.Executor(fluid.CPUPlace())
-    scope = fluid.Scope()
-    rng = np.random.RandomState(0)
-    syncs_at_replan = []
-    orig_sync = exe.engine.window.sync
-
-    def spy_sync():
-        syncs_at_replan.append(obs.counter_value("memory.replan"))
-        return orig_sync()
-
-    monkeypatch.setattr(exe.engine.window, "sync", spy_sync)
-    c0 = obs.counter_value("memory.replan")
-    deferred = []
-    with fluid.scope_guard(scope):
-        exe.run(startup)
-        for _ in range(4):
-            (l,) = exe.run(main, feed=_resnet_feed(rng),
-                           fetch_list=[h["loss"]], dispatch_steps=4)
-            deferred.append(l)
-        exe.sync()
-    l4 = [float(np.asarray(v).reshape(-1)[0]) for v in deferred]
-    assert obs.counter_value("memory.replan") == c0 + 1
-    # at least one full drain was taken BEFORE the counter bumped —
-    # i.e. the sync preceded the swap, not the other way around
-    assert any(v == c0 for v in syncs_at_replan)
-    assert l4 == l1

@@ -15,11 +15,9 @@ All six checkers run (use-before-def, shape-dtype, waw-hazard,
 grad-pairing, dead-op, sharding). ``--opt-level N`` first runs the
 transform pipeline (analysis/transforms.py) over each program and lints
 the *transformed* desc — the same desc the engine would compile at that
-level. ``--memory`` additionally prints each main program's memory plan
-(analysis/memory.py): liveness peak + top-10 contributors, the
-donate/held split, and the remat segment choice under ``--budget-mb``
-(default: the device-derived HBM budget, usually absent on CPU — remat
-reads "off"). ``--freeze`` additionally runs each built model through
+level. ``--memory`` additionally prints each main program's liveness
+report (analysis/memory.py): the estimated peak and its top-10
+contributors. ``--freeze`` additionally runs each built model through
 the inference freeze + INT8 post-training-quantization pipeline
 (paddle_tpu.inference) and prints the op/var counts before/after, the
 batch-norm folds, and the quantized-vs-skipped table with per-op
@@ -48,7 +46,7 @@ tools/ (library -> CLI layering). Exit code 1 iff any ERROR finding.
   python tools/lint_program.py --model fit_a_line --model word2vec -v
   python tools/lint_program.py --mesh dp=4,tp=2 --rule '.*fc.*w:,tp'
   python tools/lint_program.py --program /tmp/main.prog --opt-level 2
-  python tools/lint_program.py --model mnist_mlp --memory --budget-mb 4
+  python tools/lint_program.py --model mnist_mlp --memory
   python tools/lint_program.py --model recognize_digits_conv --freeze
 """
 
@@ -163,21 +161,13 @@ def _maybe_optimize(program, args, feed_names=None, fetch_names=None):
     return desc
 
 
-def _print_memory_plan(program_or_desc, args, fetch_names=None):
-    """The --memory report: liveness peak + top contributors, donation
-    split, and the remat choice under the requested budget, straight off
-    MemoryPlan.render() — the same planner the engine runs at opt 3."""
-    from paddle_tpu.analysis import memory as memplan
+def _print_liveness(program_or_desc):
+    """The --memory report: the liveness peak estimate and its top
+    contributors (LivenessReport.render())."""
+    from paddle_tpu.analysis.memory import analyze_liveness
 
-    if args.budget_mb is not None:
-        budget = int(args.budget_mb * (1 << 20))
-    else:
-        budget = memplan.hbm_budget_bytes()
-    plan = memplan.plan_memory(program_or_desc, fetch_names=fetch_names,
-                               budget_bytes=budget)
-    print("-- memory plan (budget: %s) --"
-          % ("%d MiB" % (budget >> 20) if budget else "none"))
-    print(plan.render())
+    print("-- memory --")
+    print(analyze_liveness(program_or_desc).render())
 
 
 def _print_spmd_report(program_or_desc, args, feed_names=None,
@@ -427,7 +417,7 @@ def _lint_built_model(name, builder, args):
         startup_report = verify_program(startup)
         report.extend(startup_report.findings)
         if args.memory:
-            _print_memory_plan(main_desc, args, fetch_names=fetches)
+            _print_liveness(main_desc)
         if args.spmd:
             _print_spmd_report(main_desc, args, feed_names=feeds,
                                fetch_names=fetches)
@@ -469,7 +459,7 @@ def _lint_file(path, args):
     report = verify_program(program, mesh=_parse_mesh(args.mesh),
                             shard_rules=_parse_rules(args.rule))
     if args.memory:
-        _print_memory_plan(program, args)
+        _print_liveness(program)
     if args.spmd:
         _print_spmd_report(program, args)
     min_sev = Severity.INFO if args.verbose else Severity.WARNING
@@ -500,15 +490,9 @@ def main(argv=None):
                              "lint the transformed desc (0 off, 1 "
                              "fuse-attention, 2 + fusion/folding/cse)")
     parser.add_argument("--memory", action="store_true",
-                        help="print each main program's memory plan "
-                             "(liveness peak + top contributors, "
-                             "donation split, remat choice) after "
-                             "linting it")
-    parser.add_argument("--budget-mb", type=float, default=None,
-                        metavar="MB",
-                        help="HBM budget for the --memory remat policy "
-                             "(default: device limit x "
-                             "PADDLE_TPU_HBM_BUDGET_FRAC, if knowable)")
+                        help="print each main program's liveness "
+                             "report (peak estimate + top contributors) "
+                             "after linting it")
     parser.add_argument("--freeze", action="store_true",
                         help="after linting each built model, run the "
                              "inference freeze + INT8 PTQ pipeline over "
