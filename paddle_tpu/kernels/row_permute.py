@@ -227,8 +227,10 @@ def expand(x, pair_of_row, counts, k, tile=_TILE_ROWS, chunk=_CHUNK_ROWS,
 
 
 def reduce(rows, pair_of_row, counts, k, out_dtype, tile=_TILE_ROWS,
-           chunk=_CHUNK_ROWS, interpret=False):
-    """``rows`` [R, d] bf16 -> out [R // k, d] in ``out_dtype``: each token's
-    float32 sum over the live rows of its pairs."""
-    return _move(rows, pair_of_row, counts, k, rows.shape[0] // k,
+           chunk=_CHUNK_ROWS, interpret=False, tokens=None):
+    """``rows`` [R, d] bf16 -> out [tokens, d] in ``out_dtype``: each
+    token's float32 sum over the live rows of its pairs. ``tokens``
+    defaults to R // k, a buffer with a row for every pair."""
+    return _move(rows, pair_of_row, counts, k,
+                 rows.shape[0] // k if tokens is None else tokens,
                  jnp.dtype(out_dtype), True, tile, chunk, interpret)

@@ -2775,16 +2775,31 @@ def gated_rms_norm(input, gate, groups, epsilon=1e-6, param_attr=None,
     return out
 
 
+def moe_latent(input, size, param_attr=None, name=None):
+    """``input`` [..., a] times a weight [a, ``size``], no bias: a LatentMoE
+    layer's projection into the held experts' latent or out of it. One op
+    (``moe_latent``, ops/moe_ops.py)."""
+    helper = LayerHelper("moe_latent", name=name)
+    w = helper.create_parameter(
+        attr=param_attr, shape=[int(input.shape[-1]), int(size)],
+        dtype="float32", is_bias=False)
+    out = helper.create_variable_for_type_inference(input.dtype)
+    helper.append_op(type="moe_latent", inputs={"X": [input], "Weight": [w]},
+                     outputs={"Out": [out]}, attrs={})
+    return out
+
+
 def moe_experts(input, topk_weight, topk_ids, experts_held, expert_offset,
                 width, gate_attr=None, up_attr=None, down_attr=None,
                 name=None, activation="swiglu", capacity_per_token=None):
     """The part of a mixture-of-experts layer that the ``experts_held``
     experts from ``expert_offset`` give: dispatch by expert (dropless: a
-    buffer row for every token-expert pair), the gated SiLU expert MLP as
-    three grouped matmuls (weights stacked ``[experts_held, d, width]`` /
-    ``[experts_held, width, d]``; the pair's weight goes in before the
-    down projection), combine. ``activation`` ``relu2``: the non-gated
-    ``down(relu(up(x))^2)`` as two grouped matmuls, no gate weight.
+    buffer row for every pair that can be live here), the gated SiLU
+    expert MLP as three grouped matmuls (weights stacked
+    ``[experts_held, d, width]`` / ``[experts_held, width, d]``; the
+    pair's weight goes in before the down projection), combine.
+    ``activation`` ``relu2``: the non-gated ``down(relu(up(x))^2)`` as
+    two grouped matmuls, no gate weight.
     ``capacity_per_token``: a held expert takes at most ceil(N x it) of
     its pairs, the first in token order (ops/moe_ops.py ``_held``).
     ``input`` [N, d] ->

@@ -104,6 +104,20 @@ What the arguments add, each alone:
                        ``num_experts_per_tok`` / ``router_experts``), the
                        first in token order; its later pairs add nothing,
                        as the absent experts' do
+    moe_latent_size    LatentMoE: the held experts work on a latent of this
+                       width between two projections (``moe_latent`` ops,
+                       ``layerN.latent_down`` / ``latent_up``, no norm, no
+                       bias); the router reads the full-width token and the
+                       shared expert stays at full width:
+                           l = m Wdown_lat
+                           y = h + (sum over held e of w_e Expert_e(l)) Wup_lat
+                                 + Shared(m)
+    mtp_hybrid_override_pattern  the multi-token-prediction module's layers
+                       as single-part layers of this pattern (``*E``: an
+                       attention layer, then an expert layer), ``mtp.0.``,
+                       ``mtp.1.`` ..., in place of the one two-part
+                       ``full_attention`` layer; needs
+                       ``hybrid_override_pattern``
 """
 
 import paddle_tpu.fluid as fluid
@@ -223,10 +237,13 @@ def latent_attention(x, prefix, heads, mla, rope, eps):
     return _proj(ctx, d, prefix + "o_proj")
 
 
-def next_token_module(h, labels, seq_len, vocab_size, layer, head, eps, mtp):
+def next_token_module(h, labels, seq_len, vocab_size, layer, head, eps, mtp,
+                      pattern=None):
     """The multi-token-prediction module of depth 1 (the module's
     docstring), from the last layer's output ``h`` before the final norm:
-    -> (its logits over positions 0..T-2, their targets t_{i+2})."""
+    -> (its logits over positions 0..T-2, their targets t_{i+2}). Its
+    layers are one two-part ``full_attention`` layer, or the single-part
+    layers that ``pattern`` names."""
     if mtp.get("num_nextn_predict_layers", 1) != 1:
         raise ValueError("multi-token prediction is built at depth 1 only")
     d = int(h.shape[-1])
@@ -237,9 +254,14 @@ def next_token_module(h, labels, seq_len, vocab_size, layer, head, eps, mtp):
         param_attr=fluid.ParamAttr(name="tok_embedding"))
     joined = fluid.layers.concat(
         [_norm(emb, eps, "mtp.enorm"), _norm(h, eps, "mtp.hnorm")], axis=-1)
-    out = layer(_proj(joined, d, "mtp.eh_proj"), "mtp.", "full_attention",
-                False)
+    out = _proj(joined, d, "mtp.eh_proj")
+    if pattern:
+        for i, c in enumerate(pattern):
+            out = layer(out, "mtp.%d." % i, _PATTERN[c], False)
+    else:
+        out = layer(out, "mtp.", "full_attention", False)
     obs.inc("decoder.mtp_depth")
+    obs.inc("decoder.mtp_layers", len(pattern) if pattern else 1)
     # position T-1 has no t_{T+1}: its prediction is left out
     logits = head(fluid.layers.slice(out, axes=[1], starts=[0],
                                      ends=[seq_len - 1]), "mtp.final_norm")
@@ -263,9 +285,11 @@ def gated_mlp(x, prefix, width):
 def moe(x, prefix, router_experts, experts_held, expert_offset,
         experts_per_token, width, score_func="softmax", route_scale=1.0,
         biased=False, norm_eps=None, activation="swiglu",
-        capacity_factor=None):
+        capacity_factor=None, latent=None):
     """-> (the held experts' part of the layer [B, T, d], tokens each held
-    expert received, the router's [load, bias] with ``biased`` or [])."""
+    expert received, the router's [load, bias] with ``biased`` or []).
+    ``latent``: the experts work between the projections into and out of
+    a latent of that width (the module's ``moe_latent_size``)."""
     d = int(x.shape[-1])
     flat = fluid.layers.reshape(x, shape=[-1, d])
     weight, ids, *balance = _nn.moe_router(
@@ -274,14 +298,22 @@ def moe(x, prefix, router_experts, experts_held, expert_offset,
         score_func=score_func, route_scale=route_scale,
         bias_name=prefix + "expert_bias" if biased else None,
         norm_eps=norm_eps)
+    rows = flat
+    if latent:
+        rows = _nn.moe_latent(flat, latent,
+                              fluid.ParamAttr(name=prefix + "latent_down"))
+        obs.inc("moe.latent_layers")
     out, counts = _nn.moe_experts(
-        flat, weight, ids, experts_held, expert_offset, width,
+        rows, weight, ids, experts_held, expert_offset, width,
         gate_attr=fluid.ParamAttr(name=prefix + "experts_gate"),
         up_attr=fluid.ParamAttr(name=prefix + "experts_up"),
         down_attr=fluid.ParamAttr(name=prefix + "experts_down"),
         activation=activation,
         capacity_per_token=capacity_factor and (
             capacity_factor * experts_per_token / router_experts))
+    if latent:
+        out = _nn.moe_latent(out, d,
+                             fluid.ParamAttr(name=prefix + "latent_up"))
     return (fluid.layers.reshape(out, shape=[-1, int(x.shape[1]), d]), counts,
             balance)
 
@@ -300,7 +332,8 @@ def get_model(batch_size, seq_len, vocab_size, hidden_size, num_hidden_layers,
               shared_expert_intermediate_size=None, mamba_num_heads=None,
               mamba_head_dim=None, ssm_state_size=None, n_groups=None,
               conv_kernel=4, chunk_size=128, mla=None, mtp=None,
-              expert_capacity_factor=None):
+              expert_capacity_factor=None, moe_latent_size=None,
+              mtp_hybrid_override_pattern=None):
     """Next-token pre-training program; the configuration gives every size
     (``batch_size`` is the feed's own: the batch axis stays open).
     ``layer_types`` names each layer ``sliding_attention``,
@@ -328,7 +361,7 @@ def get_model(batch_size, seq_len, vocab_size, hidden_size, num_hidden_layers,
                 n2, prefix, router_experts, experts_held, expert_offset,
                 num_experts_per_tok, moe_intermediate_size, score_func,
                 route_scale, load_balance_coeff > 0, route_norm_eps,
-                moe_activation, expert_capacity_factor)
+                moe_activation, expert_capacity_factor, moe_latent_size)
             loads.append(counts)
             if balance:
                 balances.append(balance)
@@ -343,6 +376,9 @@ def get_model(batch_size, seq_len, vocab_size, hidden_size, num_hidden_layers,
         single = bool(hybrid_override_pattern)
         if single:
             layer_types = [_PATTERN[c] for c in hybrid_override_pattern]
+        elif mtp_hybrid_override_pattern:
+            raise ValueError("mtp_hybrid_override_pattern names single-part "
+                             "layers: it needs hybrid_override_pattern")
 
         def layer(h, prefix, kind, dense):
             """One decoder layer of ``kind``, its MLP ``dense`` or not."""
@@ -411,7 +447,7 @@ def get_model(batch_size, seq_len, vocab_size, hidden_size, num_hidden_layers,
         if mtp:
             logits, targets = next_token_module(
                 h, labels, seq_len, vocab_size, layer, head, rms_norm_eps,
-                mtp)
+                mtp, mtp_hybrid_override_pattern)
             predicted = {"logits": logits, "targets": targets,
                          "loss": cross_entropy(logits, targets)}
             loss = fluid.layers.elementwise_add(loss, fluid.layers.scale(

@@ -1,5 +1,6 @@
-"""A mixture-of-experts layer as four ops, for a chip that holds some of
-the experts (``experts_held`` of ``num_experts``, from ``expert_offset``):
+"""A mixture-of-experts layer as four ops (five with a latent), for a chip
+that holds some of the experts (``experts_held`` of ``num_experts``, from
+``expert_offset``):
 
     moe_router      scores over ALL experts in float32 (softmax, or a
                     sigmoid each), top-k (by score + a bias that takes no
@@ -20,16 +21,19 @@ the experts (``experts_held`` of ``num_experts``, from ``expert_offset``):
                     down(w * relu(up(x))^2), two forward and four backward
                     (``_relu2_expert_mlp``)
     moe_combine     each token's sum over its held pairs' rows
+    moe_latent      a LatentMoE layer's projection into the experts'
+                    latent before the dispatch, and out of it after the
+                    combine
 
-Dropless: the buffer has a row for every pair (tokens x k; a token's k
-experts are distinct and up to all of them may be held here), so no
-routing can overflow it; the grouped matmuls visit only the rows in use.
-What the experts held elsewhere would add is left out: on one chip there
-is no exchange and nothing stands in for it. A token's weights are
-renormalised over all k of its experts, held or not. Where the attribute
-``capacity_per_token`` is given, a held expert takes at most ceil(tokens x
-it) pairs, the first in token order; the rest are treated as held
-elsewhere (``_held``).
+Dropless: the buffer has a row for every pair that can be live here
+(``buffer_rows``): a token's k experts are distinct, so at most min(k,
+experts held) of its pairs fall on held experts, and where the attribute
+``capacity_per_token`` is given a held expert takes at most ceil(tokens x
+it) pairs, the first in token order, the rest treated as held elsewhere
+(``_held``). No routing can overflow it; the grouped matmuls visit only
+the rows in use. What the experts held elsewhere would add is left out: on
+one chip there is no exchange and nothing stands in for it. A token's
+weights are renormalised over all k of its experts, held or not.
 
 The pair's weight multiplies the row before the down projection (which is
 linear, so the sum is the weighted combine): the combine's backward then
@@ -204,6 +208,30 @@ def moe_bias_update(ctx, ins, attrs):
     return {"BiasOut": [bias + (delta - jnp.mean(delta)).astype(bias.dtype)]}
 
 
+def _capacity(tokens, attrs):
+    """ceil(tokens x ``capacity_per_token``): the pairs a held expert takes
+    at most (None where the attribute is absent)."""
+    share = float(attrs.get("capacity_per_token", 0.0))
+    return math.ceil(round(tokens * share, 6)) if share > 0.0 else None
+
+
+def buffer_rows(tokens, k, attrs):
+    """Rows of the dispatch buffer: room for every pair that can be live
+    here. A token picks an expert once, so at most min(k, experts held) of
+    its pairs are held here; with a capacity, no more than experts held x
+    capacity in all. Rounded up to whole row tiles of the grouped matmuls
+    (which ``row_permute``'s tiles divide), so that the kernels take the
+    buffer, and never past tokens x k: where k <= experts held and there
+    is no capacity, the buffer is the tokens x k of every pair."""
+    held = int(attrs["experts_held"])
+    rows = tokens * min(k, held)
+    capacity = _capacity(tokens, attrs)
+    if capacity is not None:
+        rows = min(rows, held * capacity)
+    tile = gm._TILE_ROWS
+    return min(tokens * k, -(-rows // tile) * tile)
+
+
 def _held(ids, attrs):
     """(pair is held here [N, k] bool, its local expert [N, k] int32). With
     ``capacity_per_token``, a held expert takes only the first
@@ -213,9 +241,8 @@ def _held(ids, attrs):
     local = ids - int(attrs.get("expert_offset", 0))
     held_n = int(attrs["experts_held"])
     held = (local >= 0) & (local < held_n)
-    share = float(attrs.get("capacity_per_token", 0.0))
-    if share > 0.0:
-        capacity = math.ceil(round(ids.shape[0] * share, 6))
+    capacity = _capacity(ids.shape[0], attrs)
+    if capacity is not None:
         # a token picks an expert once: its pair's rank on that expert (1
         # for the first) is the count of the tokens up to it that picked it
         mine = local.reshape(-1)[:, None] == jnp.arange(held_n,
@@ -226,11 +253,12 @@ def _held(ids, attrs):
     return held, local
 
 
-def _by_kernel(like, row_of_pair):
-    """Whether ``row_permute`` moves rows like ``like`` ([., d]) for these
-    tokens x k pairs: decided by what the call site can see, not by a flag."""
-    tokens, k = row_of_pair.shape
-    return row_permute.applies(tokens, tokens * k, like.shape[1], like.dtype)
+def _by_kernel(like, row_of_pair, pair_of_row):
+    """Whether ``row_permute`` moves rows like ``like`` ([., d]) between
+    these tokens (``RowOfPair`` [N, k]) and this buffer (``PairOfRow``
+    [R]): decided by what the call site can see, not by a flag."""
+    return row_permute.applies(row_of_pair.shape[0], pair_of_row.shape[0],
+                               like.shape[1], like.dtype)
 
 
 def _count_form(by_kernel):
@@ -252,7 +280,7 @@ def _rows_of_tokens(x, pair_of_row, counts, row_of_pair):
     """rows[r] = x[token of row r] where the row is in use (else 0, or
     unspecified in a tile the kernel never visits)."""
     k = row_of_pair.shape[1]
-    if _by_kernel(x, row_of_pair):
+    if _by_kernel(x, row_of_pair, pair_of_row):
         return row_permute.expand(x, pair_of_row, counts, k)
     return _rows_of_tokens_xla(x, pair_of_row, counts, k)
 
@@ -265,9 +293,10 @@ def _sums_of_rows_xla(rows, row_of_pair, pair_held, dtype):
 def _sums_of_rows(rows, row_of_pair, pair_held, pair_of_row, counts, dtype):
     """out[t] = the float32 sum of the rows of t's held pairs, as ``dtype``:
     by the inverse permutation, no scatter-add."""
-    if _by_kernel(rows, row_of_pair):
+    if _by_kernel(rows, row_of_pair, pair_of_row):
         return row_permute.reduce(rows, pair_of_row, counts,
-                                  row_of_pair.shape[1], dtype)
+                                  row_of_pair.shape[1], dtype,
+                                  tokens=row_of_pair.shape[0])
     return _sums_of_rows_xla(rows, row_of_pair, pair_held, dtype)
 
 
@@ -321,7 +350,7 @@ def _to_rows_fwd(x, weight, pair_of_row, counts, row_of_pair, pair_held):
 def _to_rows_bwd(res, g):
     pair_of_row, counts, row_of_pair, pair_held = res
     g_rows, g_weight = g
-    _count_form(_by_kernel(g_rows, row_of_pair))
+    _count_form(_by_kernel(g_rows, row_of_pair, pair_of_row))
     dx = _sums_of_rows(g_rows, row_of_pair, pair_held, pair_of_row, counts,
                        g_rows.dtype)
     # (a dead row's gradient is unspecified: selected out, never multiplied)
@@ -334,12 +363,13 @@ _to_rows.defvjp(_to_rows_fwd, _to_rows_bwd)
 
 @register_op("moe_dispatch", no_grad_inputs=("TopkIds",))
 def moe_dispatch(ctx, ins, attrs):
-    """X [N, d], TopkWeight and TopkIds [N, k] -> Rows [N*k, d] (the held
-    pairs' token rows, sorted by expert, in the program's compute dtype),
-    Counts [experts_held] int32 (tokens each held expert received),
-    RowWeight [N*k] float32 (each row's pair's weight), RowOfPair [N, k]
-    and PairOfRow [N*k] int32 (the permutation and its inverse; pair
-    ``t * k + j`` is token ``t``'s ``j``-th expert)."""
+    """X [N, d], TopkWeight and TopkIds [N, k] -> Rows [R, d] (the held
+    pairs' token rows, sorted by expert, in the program's compute dtype; R
+    is ``buffer_rows``), Counts [experts_held] int32 (tokens each held
+    expert received), RowWeight [R] float32 (each row's pair's weight),
+    RowOfPair [N, k] and PairOfRow [R] int32 (the permutation and its
+    inverse on the live rows; pair ``t * k + j`` is token ``t``'s ``j``-th
+    expert; a pair held nowhere has some row that it never reads)."""
     from paddle_tpu import observability as obs
 
     x, ids = single(ins, "X"), single(ins, "TopkIds")
@@ -354,16 +384,21 @@ def moe_dispatch(ctx, ins, attrs):
             "its expert do not fit one int32 sort key" % (n * k, held_n))
     order, weight = _by_expert(key, single(ins, "TopkWeight").reshape(-1))
     row_of_pair = jnp.argsort(order).astype(jnp.int32).reshape(n, k)
+    rows_n = buffer_rows(n, k, attrs)
+    if rows_n < n * k:
+        # the live pairs sort first, and the buffer has room for all of them
+        order, weight = order[:rows_n], weight[:rows_n]
+        row_of_pair = jnp.minimum(row_of_pair, rows_n - 1)
     counts = jnp.sum(
         key[:, None] == jnp.arange(held_n, dtype=jnp.int32)[None, :],
         axis=0, dtype=jnp.int32)
     rows, row_weight = _to_rows(amp_cast(x), weight, order, counts,
                                 row_of_pair, held)
     if lowered_into_a_step(ctx, "moe_dispatch") and obs.enabled():
-        by_kernel = _by_kernel(rows, row_of_pair)
+        by_kernel = _by_kernel(rows, row_of_pair, order)
         _count_form(by_kernel)
         obs.inc("moe.layers")
-        obs.set_gauge("moe.buffer_rows", n * k)
+        obs.set_gauge("moe.buffer_rows", rows_n)
         obs.set_gauge("moe.experts_held", held_n)
         jax.debug.callback(
             _publish_load, counts,
@@ -525,6 +560,22 @@ def _publish_gate_tiles(tiles):
     obs.set_gauge("moe.gate_tiles_live", int(tiles))
 
 
+@register_op("moe_latent")
+def moe_latent(ctx, ins, attrs):
+    """X [..., a], Weight [a, b] -> Out [..., b]: a LatentMoE layer's
+    projection of the normed token into the held experts' latent (before
+    ``moe_dispatch``) or of the combined latent back to the model's width
+    (after ``moe_combine``). ``mul``'s arithmetic, bf16 operands under
+    AMP; an op type of its own so that a trace books the expert layer's
+    projections apart from the other products. The gradient is the
+    engine's generic ``jax.vjp`` of this lowering."""
+    x = single(ins, "X")
+    x2, w = amp_cast(x.reshape(-1, x.shape[-1]), single(ins, "Weight"))
+    pet = None if x2.dtype == jnp.bfloat16 else jnp.float32
+    out = jnp.matmul(x2, w, preferred_element_type=pet)
+    return {"Out": [out.reshape(x.shape[:-1] + (w.shape[-1],))]}
+
+
 @jax.custom_vjp
 def _sum_of_rows(rows, row_of_pair, pair_held, pair_of_row, counts):
     """out[t] = sum of the rows of t's held pairs, float32."""
@@ -544,7 +595,7 @@ def _sum_bwd(res, g):
     # a row's gradient is its token's, in the rows' dtype (the cast and the
     # movement commute)
     g = g.astype(like.dtype)
-    _count_form(_by_kernel(g, row_of_pair))
+    _count_form(_by_kernel(g, row_of_pair, pair_of_row))
     return (_rows_of_tokens(g, pair_of_row, counts, row_of_pair), None, None,
             None, None)
 
@@ -555,13 +606,13 @@ _sum_of_rows.defvjp(_sum_fwd, _sum_bwd)
 @register_op("moe_combine",
              no_grad_inputs=("TopkIds", "RowOfPair", "PairOfRow", "Counts"))
 def moe_combine(ctx, ins, attrs):
-    """Rows [N*k, d] (the expert MLP's weighted result), TopkIds,
+    """Rows [R, d] (the expert MLP's weighted result), TopkIds,
     RowOfPair, PairOfRow, Counts -> Out [N, d] float32: every token's sum
     over the pairs held here (zero where none is)."""
     rows, row_of_pair = single(ins, "Rows"), single(ins, "RowOfPair")
+    pair_of_row = single(ins, "PairOfRow")
     held, _ = _held(single(ins, "TopkIds"), attrs)
     if lowered_into_a_step(ctx, "moe_combine"):
-        _count_form(_by_kernel(rows, row_of_pair))
-    return {"Out": [_sum_of_rows(rows, row_of_pair, held,
-                                 single(ins, "PairOfRow"),
+        _count_form(_by_kernel(rows, row_of_pair, pair_of_row))
+    return {"Out": [_sum_of_rows(rows, row_of_pair, held, pair_of_row,
                                  single(ins, "Counts"))]}

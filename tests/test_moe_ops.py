@@ -4,6 +4,7 @@ row order (a payload of the sort), against the forms they replaced: a
 gather of tokens x k single elements each way, and the gathers'
 transposes. The lowerings themselves are called, outside any engine."""
 
+import functools
 import types
 
 import numpy as np
@@ -220,3 +221,97 @@ def test_the_dispatch_refuses_a_sort_key_past_int32(held, fits):
     else:
         with pytest.raises(ValueError, match="one int32 sort key"):
             shapes()
+
+
+# (tokens, k, router outputs, experts held, capacity factor, buffer rows)
+BUFFERS = {
+    # a token's 6 pairs fall on at most 4 held experts: 256 x 4, not x 6
+    "k_over_held": (256, 6, 16, 4, None, 256 * 4),
+    # each of 4 held experts takes at most ceil(512 x 4 / 16) = 128 pairs
+    "capacity": (512, 4, 16, 4, 1.0, 4 * 128),
+}
+
+
+def _relu2_layer(x, weight, ids, wu, wd, attrs):
+    """``moe_dispatch``, the relu² ``moe_expert_mlp`` and ``moe_combine``
+    as their lowerings compute them -> (the layer's out, the buffer's rows,
+    the tokens each held expert took)."""
+    outs = moe_ops.moe_dispatch(
+        _ctx("moe_dispatch"),
+        {"X": [x], "TopkWeight": [weight], "TopkIds": [ids]}, attrs)
+    mlp = moe_ops.moe_expert_mlp(
+        _ctx("moe_expert_mlp"),
+        {"Rows": outs["Rows"], "RowWeight": outs["RowWeight"],
+         "Counts": outs["Counts"], "UpWeight": [wu], "DownWeight": [wd]},
+        {"activation": "relu2"})["Out"]
+    out = moe_ops.moe_combine(
+        _ctx("moe_combine"),
+        {"Rows": mlp, "TopkIds": [ids], "RowOfPair": outs["RowOfPair"],
+         "PairOfRow": outs["PairOfRow"], "Counts": outs["Counts"]}, attrs)
+    return out["Out"][0], outs["Rows"][0].shape[0], outs["Counts"][0]
+
+
+@pytest.mark.parametrize("form", ["xla", "kernel"])
+@pytest.mark.parametrize("case", list(BUFFERS))
+def test_the_sized_buffer_gives_the_dropless_result(monkeypatch, case,
+                                                    form):
+    """The buffer has room for the pairs that can be live on the held
+    experts and no more (``buffer_rows``): at k over the experts held,
+    tokens x experts held rows; with a capacity, experts held x capacity.
+    Out and the gradients of X, TopkWeight and the experts' weights are
+    those of a buffer with a row for every pair, through XLA's gathers or
+    (interpreted, in bf16) the row kernel, whose visits the smaller buffer
+    bounds."""
+    from paddle_tpu.kernels import row_permute as rp
+
+    tokens, k, experts, held, factor, rows = BUFFERS[case]
+    attrs = {"experts_held": held}
+    if factor:
+        attrs["capacity_per_token"] = factor * k / experts
+    assert moe_ops.buffer_rows(tokens, k, attrs) == rows < tokens * k
+    if form == "kernel":
+        monkeypatch.setattr(rp, "_on_tpu", lambda: True)
+        for name in ("expand", "reduce"):
+            monkeypatch.setattr(rp, name, functools.partial(
+                getattr(rp, name), interpret=True))
+    dtype = jnp.bfloat16 if form == "kernel" else jnp.float32
+    rng = np.random.RandomState(len(case) + len(form))
+    ids = jnp.asarray(np.asarray([rng.permutation(experts)[:k]
+                                  for _ in range(tokens)], np.int32))
+    args = (jnp.asarray(rng.randn(tokens, 128), dtype),
+            jnp.asarray(rng.rand(tokens, k) + 0.1, jnp.float32),
+            jnp.asarray(rng.randn(held, 128, 64) * 0.1, dtype),
+            jnp.asarray(rng.randn(held, 64, 128) * 0.1, dtype))
+    g = jnp.asarray(rng.randn(tokens, 128), jnp.float32)
+
+    def run():
+        out, pull = jax.vjp(
+            lambda *a: _relu2_layer(a[0], a[1], ids, a[2], a[3], attrs)[0],
+            *args)
+        _, used, counts = _relu2_layer(args[0], args[1], ids, args[2],
+                                       args[3], attrs)
+        return out, pull(g), used, np.asarray(counts)
+
+    sized, sized_grads, used, counts = run()
+    assert used == rows
+    if factor:
+        assert counts.max() == rows // held and counts.sum() < tokens * k
+    else:
+        assert counts.sum() == int((np.asarray(ids) < held).sum())
+    monkeypatch.setattr(moe_ops, "buffer_rows", lambda n, k_, a: n * k_)
+    dropless, dropless_grads, used, dropless_counts = run()
+    assert used == tokens * k
+    np.testing.assert_array_equal(counts, dropless_counts)
+    # out and the gradients of X and TopkWeight bit for bit; the experts'
+    # weights' gradients sum the same live rows in another order (to one
+    # rounding of the bf16 result)
+    tol = 1e-6 if form == "xla" else 1e-3
+    for i, (a, b) in enumerate(zip((sized,) + tuple(sized_grads),
+                                   (dropless,) + tuple(dropless_grads))):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.abs(b).max() > 0
+        if i < 3:
+            np.testing.assert_array_equal(a, b)
+        else:
+            np.testing.assert_allclose(a, b, rtol=0,
+                                       atol=tol * np.abs(b).max())

@@ -930,6 +930,30 @@ def test_the_latent_attention_step_takes_the_flash_kernels(topo,
     assert text.count("tpu_custom_call") >= 12
 
 
+def test_the_latent_moe_step_takes_the_kernels(topo, monkeypatch):
+    """The ``nemotron3_super_120b_a12b`` cell's step (1 x 4096, bf16)
+    lowered for a described v5e: the six LatentMoE layers (five and the
+    MTP module's) move their 1,024-wide latent rows into and out of a
+    buffer of 1,536 rows (the 8 held experts' capped 1,408 pairs in whole
+    tiles) by the row kernel, four call sites each; the five Mamba
+    mixers' scans and gated norms take their kernels at 16 heads of 64 in
+    one group; the two attentions (4 query heads on 1) the one-kernel
+    flash backward."""
+    text, counted = _lowered_cell_step(
+        topo, monkeypatch, "nemotron3_super_120b_a12b", 1,
+        ("moe.permute_kernel", "moe.permute_xla", "ssm.scan_kernel",
+         "ssm.scan_xla", "ssm.gated_norm_kernel", "ssm.gated_norm_xla",
+         "flash.bwd_fused", "flash.bwd_split", "moe.latent_layers",
+         "decoder.mtp_layers", "moe.relu2_layers"))
+    assert counted == {"moe.permute_kernel": 24, "moe.permute_xla": 0,
+                       "ssm.scan_kernel": 10, "ssm.scan_xla": 0,
+                       "ssm.gated_norm_kernel": 5, "ssm.gated_norm_xla": 0,
+                       "flash.bwd_fused": 2, "flash.bwd_split": 0,
+                       "moe.latent_layers": 6, "decoder.mtp_layers": 2,
+                       "moe.relu2_layers": 6}
+    assert "tensor<1536x1024xbf16>" in text
+
+
 @pytest.mark.parametrize("form", ["forward", "fused", "split"])
 def test_flash_at_a_values_width_of_its_own_compiles(one_chip, monkeypatch,
                                                      form):
